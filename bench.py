@@ -1,9 +1,13 @@
 """Benchmark: particle-updates/sec/chip on the Sedov blast (driver contract).
 
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", "extra"}.
-The headline metric is std SPH at Sedov BENCH_SIDE^3; "extra" carries the
-flagship VE pipeline and VE+gravity (Evrard) throughputs, so every
-pipeline the framework ships is pinned by the bench.
+Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", "device",
+"extra"}. The headline metric is std SPH at Sedov BENCH_SIDE^3; "extra"
+carries the flagship VE pipeline and VE+gravity (Evrard) throughputs, so
+every pipeline the framework ships is pinned by the bench.
+
+Chip-only: it refuses to start when jax finds no TPU (a CPU number under
+these metric names would be a wrong record), stamps the device it ran on
+into the JSON, and a line that raises fails the run.
 
 Baseline: BASELINE.md's north star is Sedov 100^3 within 2x of sphexa-cuda
 per-chip throughput (16xA100 vs v5e-16). The reference publishes no absolute
@@ -30,7 +34,8 @@ AUX_STEPS = int(os.environ.get("BENCH_AUX_STEPS", "6"))
 
 
 def _measure(sim, n, steps):
-    """Clean reconfigure-free window throughput (updates/s) or None."""
+    """Clean reconfigure-free window throughput (updates/s); raises when
+    three attempts find no such window."""
     import jax
 
     for _ in range(WARMUP):
@@ -53,7 +58,7 @@ def _measure(sim, n, steps):
         if d["reconfigured"] == 0.0 and not tainted:
             return n * steps / elapsed
         tainted = d["reconfigured"] > 0.0
-    return None
+    raise RuntimeError("bench: no reconfigure-free window in 3 attempts")
 
 
 def _gravity_scale_line(n=1_000_000):
@@ -79,6 +84,7 @@ def _gravity_scale_line(n=1_000_000):
     from sphexa_tpu.init.plummer import sample_plummer
     from sphexa_tpu.sfc.box import BoundaryType, Box
     from sphexa_tpu.sfc.keys import compute_sfc_keys
+    from sphexa_tpu.util.device import on_tpu
 
     x, y, z, m = sample_plummer(n)
     ext = float(np.max(np.abs(np.stack([x, y, z])))) * 1.001
@@ -92,7 +98,7 @@ def _gravity_scale_line(n=1_000_000):
     cfg = estimate_gravity_caps(
         xs, ys, zs, ms, skeys, box, gtree, meta,
         GravityConfig(theta=0.5, bucket_size=64, G=1.0,
-                      **gravity_tuning(n, jax.default_backend() == "tpu")),
+                      **gravity_tuning(n, on_tpu())),
         margin=1.6)
     hs = jnp.full_like(xs, 1e-3)
     args = (xs, ys, zs, ms, hs, skeys, box, gtree, meta)
@@ -154,6 +160,11 @@ def _gravity_scale_line(n=1_000_000):
 
 
 def main() -> int:
+    from sphexa_tpu.util.device import enable_compile_cache, require_tpu
+
+    dev = require_tpu("bench.py")
+    enable_compile_cache()
+
     from sphexa_tpu.init import init_evrard, init_sedov
     from sphexa_tpu.observables import ObservableSpec
     from sphexa_tpu.simulation import Simulation
@@ -196,18 +207,12 @@ def main() -> int:
     if trace_dir:
         jax.profiler.stop_trace()
         print(f"bench: profiler trace -> {trace_dir}", file=sys.stderr)
-        try:
-            from sphexa_tpu.telemetry.traceview import (
-                phase_attr_digest,
-                summarize_trace,
-            )
+        from sphexa_tpu.telemetry.traceview import (
+            phase_attr_digest,
+            summarize_trace,
+        )
 
-            phase_attr = phase_attr_digest(summarize_trace(trace_dir))
-        except Exception as e:  # attribution must never sink the bench
-            print(f"bench: trace attribution failed: {e}", file=sys.stderr)
-    if std_ups is None:
-        print("bench: no reconfigure-free window in 3 attempts", file=sys.stderr)
-        return 1
+        phase_attr = phase_attr_digest(summarize_trace(trace_dir))
 
     extra = {}
     # how the headline run's knobs were chosen (heuristic, or a table
@@ -224,44 +229,31 @@ def main() -> int:
 
         if math.isfinite(sim.energy_drift):
             extra["std_energy_drift"] = float(f"{sim.energy_drift:.3e}")
-    try:
-        n_aux = AUX_SIDE**3
-        state, box, const = init_sedov(AUX_SIDE)
-        sim = Simulation(state, box, const, prop="ve", block=8192,
-                         check_every=AUX_STEPS, telemetry=tel,
-                         obs_spec=ObservableSpec())
-        ve_ups = _measure(sim, n_aux, AUX_STEPS)
-        if ve_ups:
-            extra["ve_updates_per_sec"] = round(ve_ups, 1)
-            extra["ve_side"] = AUX_SIDE
-            extra["ve_vs_baseline"] = round(ve_ups / BASELINE_UPDATES_PER_SEC, 4)
-    except Exception as e:  # aux lines must never sink the headline metric
-        print(f"bench: VE line failed: {e}", file=sys.stderr)
-    try:
-        state, box, const = init_evrard(AUX_SIDE)
-        sim = Simulation(state, box, const, prop="ve", block=8192,
-                         check_every=AUX_STEPS, telemetry=tel,
-                         obs_spec=ObservableSpec())
-        nev = int(state.n)
-        veg_ups = _measure(sim, nev, AUX_STEPS)
-        if veg_ups:
-            extra["ve_gravity_updates_per_sec"] = round(veg_ups, 1)
-            extra["ve_gravity_n"] = nev
-            extra["ve_gravity_vs_baseline"] = round(
-                veg_ups / BASELINE_UPDATES_PER_SEC, 4
-            )
-    except Exception as e:
-        print(f"bench: VE+gravity line failed: {e}", file=sys.stderr)
-    try:
-        # gravity at >=1e6 particles (VERDICT r3 #4): the Barnes-Hut
-        # solve alone on a 1M Plummer sphere (the centrally-concentrated
-        # distribution that stresses the MAC), dense classification at
-        # the coarse target_block the Simulation picks at this N
-        gup = _gravity_scale_line()
-        if gup:
-            extra.update(gup)
-    except Exception as e:
-        print(f"bench: gravity-scale line failed: {e}", file=sys.stderr)
+    n_aux = AUX_SIDE**3
+    state, box, const = init_sedov(AUX_SIDE)
+    sim = Simulation(state, box, const, prop="ve", block=8192,
+                     check_every=AUX_STEPS, telemetry=tel,
+                     obs_spec=ObservableSpec())
+    ve_ups = _measure(sim, n_aux, AUX_STEPS)
+    extra["ve_updates_per_sec"] = round(ve_ups, 1)
+    extra["ve_side"] = AUX_SIDE
+    extra["ve_vs_baseline"] = round(ve_ups / BASELINE_UPDATES_PER_SEC, 4)
+    state, box, const = init_evrard(AUX_SIDE)
+    sim = Simulation(state, box, const, prop="ve", block=8192,
+                     check_every=AUX_STEPS, telemetry=tel,
+                     obs_spec=ObservableSpec())
+    nev = int(state.n)
+    veg_ups = _measure(sim, nev, AUX_STEPS)
+    extra["ve_gravity_updates_per_sec"] = round(veg_ups, 1)
+    extra["ve_gravity_n"] = nev
+    extra["ve_gravity_vs_baseline"] = round(
+        veg_ups / BASELINE_UPDATES_PER_SEC, 4
+    )
+    # gravity at >=1e6 particles (VERDICT r3 #4): the Barnes-Hut solve
+    # alone on a 1M Plummer sphere (the centrally-concentrated
+    # distribution that stresses the MAC), dense classification at the
+    # coarse target_block the Simulation picks at this N
+    extra.update(_gravity_scale_line())
 
     # per-run health counters from the shared registry (a clean bench
     # window should show retraces only from first compiles; the
@@ -292,6 +284,8 @@ def main() -> int:
                 "value": round(std_ups, 1),
                 "unit": "particles/s",
                 "vs_baseline": round(std_ups / BASELINE_UPDATES_PER_SEC, 4),
+                "device": {"platform": dev.platform, "kind": dev.kind,
+                           "count": dev.count},
                 "extra": extra,
                 "manifest": build_manifest(
                     config={"side": SIDE, "steps": STEPS,

@@ -35,7 +35,27 @@ def _output_fields(
     x, y, z, h, m = (g(state.x), g(state.y), g(state.z), g(state.h), g(state.m))
     temp = g(state.temp)
 
-    if cfg.backend == "pallas":
+    occ = jnp.int32(0)
+    if cfg.backend == "pallas" and cfg.shard_axis is not None:
+        # mesh run: a Mosaic call has no GSPMD partitioning rule ("wrap
+        # the call in a shard_map"), so rho comes from the step's own
+        # sharded force stage — per-shard kernels + the sized halo
+        # exchange — at the price of its unused force outputs (a dump
+        # costs about one step)
+        from sphexa_tpu.propagator import (
+            _std_forces_sharded,
+            _ve_forces_sharded,
+        )
+
+        sstate = jax.tree.map(
+            lambda a: a[order] if getattr(a, "ndim", 0) >= 1 else a, state)
+        if pipeline == "ve":
+            rho, c, _, occ, *_ = _ve_forces_sharded(sstate, box, cfg, skeys)
+            p = rho * cfg.const.cv * temp * (cfg.const.gamma - 1.0)
+        else:
+            rho, _, _, occ, *_ = _std_forces_sharded(sstate, box, cfg, skeys)
+            p, c = hydro_std.compute_eos_std(temp, rho, cfg.const)
+    elif cfg.backend == "pallas":
         # the fused engine avoids the XLA path's (N, W3*cap) candidate
         # materialization, which can exceed HBM for strongly compressed
         # states (e.g. Noh's center drives the cell cap into the 1000s)
@@ -84,7 +104,7 @@ def _output_fields(
     u = cfg.const.cv * state.temp
     vel = jnp.sqrt(state.vx**2 + state.vy**2 + state.vz**2)
     r = jnp.sqrt(state.x**2 + state.y**2 + state.z**2)
-    return {"r": r, "rho": rho, "p": p, "u": u, "vel": vel, "c": c}
+    return {"r": r, "rho": rho, "p": p, "u": u, "vel": vel, "c": c}, occ
 
 
 def compute_output_fields(
@@ -94,7 +114,15 @@ def compute_output_fields(
     from a conserved-field state, as numpy arrays in the state's particle
     order. ``pipeline`` selects the density/EOS estimator consistent with
     the propagator that evolved the state ('std' or 've')."""
-    out = _output_fields(state, box, cfg, "ve" if pipeline == "ve" else "std")
+    out, occ = _output_fields(state, box, cfg,
+                              "ve" if pipeline == "ve" else "std")
+    if int(occ) > cfg.nbr.cap:
+        # only the sharded recompute reports it: the cell cap or the halo
+        # window (cap + 1 sentinel) no longer covers the re-sorted state
+        raise RuntimeError(
+            f"output-field recompute overflowed its neighbor config "
+            f"(occupancy {int(occ)} > cap {cfg.nbr.cap}); step once more "
+            "so the driver re-sizes, then dump")
     return {k: np.asarray(v) for k, v in out.items()}
 
 

@@ -187,6 +187,12 @@ def main(argv=None) -> int:
             print(f"--cpu-mesh: {e}", file=sys.stderr)
             return 2
 
+    # persistent compile cache, placed before the first jit
+    # (JAX_COMPILATION_CACHE_DIR, else on a TPU <checkout>/.jax_cache)
+    from sphexa_tpu.util.device import device_info, enable_compile_cache
+
+    enable_compile_cache()
+
     from sphexa_tpu.init import make_initializer
     from sphexa_tpu.observables import (
         ConstantsWriter,
@@ -418,6 +424,11 @@ def main(argv=None) -> int:
             recorder.dump(reason=f"simulation construction failed: {e}")
             recorder.close()
         return 2
+    # the Simulation owns the (placed, possibly sharded) state from here on:
+    # drop this frame's reference so the initializer's device-0 copy is
+    # freed instead of riding along for the whole run
+    n_particles, t_start = state.n, float(state.ttot)
+    del state
     if args.telemetry_dir:
         from sphexa_tpu.telemetry import emit_memory_event, write_manifest
 
@@ -426,7 +437,7 @@ def main(argv=None) -> int:
             args.telemetry_dir,
             config={k: v for k, v in vars(args).items()
                     if isinstance(v, (str, int, float, bool, type(None)))},
-            particles=state.n,
+            particles=n_particles,
             mesh_shape=tuple(mesh.devices.shape) if mesh is not None
             else None,
             extra={"case": case_name or args.init, "prop": args.prop,
@@ -444,13 +455,18 @@ def main(argv=None) -> int:
             devices=list(mesh.devices.flat) if mesh is not None else None,
         )
         log(f"# telemetry -> {args.telemetry_dir}")
-    log(f"# sphexa-tpu --init {args.init} N={state.n} prop={args.prop}")
+    # the resolved engine and the device it runs on, so no console log can
+    # be read as a chip run when it was not
+    dev = device_info()
+    log(f"# sphexa-tpu --init {args.init} N={n_particles} prop={args.prop} "
+        f"backend={sim._cfg.backend} platform={dev.platform} "
+        f"device_kind={dev.kind!r} devices={dev.count}")
 
     # resuming from a snapshot continues the iteration numbering, and an
     # integer -s is the END iteration (sphexa.cpp main-loop semantics)
     if is_restart:
         sim.iteration = restart_iteration
-        log(f"# restart from iteration {sim.iteration}, t={float(state.ttot):.6g}"
+        log(f"# restart from iteration {sim.iteration}, t={t_start:.6g}"
             + (f" (case {case_name})" if case_name else ""))
 
     num_steps = int(args.stop) if float(args.stop).is_integer() else None
@@ -464,7 +480,7 @@ def main(argv=None) -> int:
     w = args.write_every
     w_steps = int(w) if w > 0 and float(w).is_integer() else None
     w_time = w if w > 0 and w_steps is None else None
-    next_dump_time = [float(state.ttot) + w_time] if w_time else None
+    next_dump_time = [t_start + w_time] if w_time else None
     if w > 0 or args.wextra:
         # on restart, keep dumping under the ORIGINAL case's name (the
         # reference appends Step#n to the restarted file) instead of a
@@ -541,7 +557,7 @@ def main(argv=None) -> int:
         from sphexa_tpu.analysis import compute_output_fields
 
         pipeline = "ve" if args.prop in ("ve", "turb-ve") else "std"
-        return compute_output_fields(sim.state, sim.box, sim._cfg,
+        return compute_output_fields(sim.state, sim.box, sim.active_cfg,
                                      pipeline=pipeline)
 
     last_dump_iteration = [None]
@@ -808,7 +824,7 @@ def main(argv=None) -> int:
     if recorder is not None:
         recorder.close()  # clean exit: disarm the crash hooks, no blackbox
     log(f"# {n_done} iterations in {dt_wall:.2f}s "
-        f"({state.n * n_done / dt_wall / 1e6:.3f}M particle-updates/s)")
+        f"({n_particles * n_done / dt_wall / 1e6:.3f}M particle-updates/s)")
     return 0
 
 

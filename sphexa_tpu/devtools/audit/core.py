@@ -185,7 +185,7 @@ class EntryPoint:
     mesh_axes: Tuple[str, ...] = ()
     # jaxpr-constant size budget (bytes) for the const-bloat audit
     const_bytes_limit: int = 1 << 20
-    # trace under jax.experimental.enable_x64 (fixture use: the f64
+    # trace under jax.enable_x64 (fixture use: the f64
     # rule can't fire with x64 off — jax silently demotes)
     x64: bool = False
     # per-entry override of the JXA202 per-device HBM budget (bytes);
@@ -337,9 +337,9 @@ class EntryTrace:
 
         if not self.entry.x64:
             return contextlib.nullcontext()
-        from jax.experimental import enable_x64
+        import jax
 
-        return enable_x64()
+        return jax.enable_x64()
 
     @property
     def closed_jaxpr(self):

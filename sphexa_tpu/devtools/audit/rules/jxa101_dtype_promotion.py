@@ -9,7 +9,7 @@ it silently doubles memory traffic and de-synchronizes CI numerics from
 chip numerics.
 
 With x64 DISABLED jax demotes f64 requests on the spot, so the rule can
-only fire under ``jax.experimental.enable_x64`` — entries opt in via
+only fire under ``jax.enable_x64`` — entries opt in via
 ``x64=True`` (the fixture does; package entries trace under the ambient
 config so this is the forward guard for x64-enabled diagnostics runs).
 

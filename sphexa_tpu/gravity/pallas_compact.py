@@ -38,7 +38,12 @@ first ``cap`` entries in candidate order — exactly the truncation the
 Values are carried in the low IDX_BITS of the packed int32 (class in the
 bits above), and ride the MXU in f32 — exact for indices < 2^24, which
 bounds the tree size this kernel accepts (~16.7M nodes; a 400^3 run's
-~1.4M-node tree fits with room).
+~1.4M-node tree fits with room). Exact ONLY at fp32 contract precision,
+which the value gather states: Mosaic's default runs an f32 dot in bf16
+passes and rounds the indices to 8 significant bits (first chip run,
+v5e / jax 0.9.0: 7091 came back as 7104, the solve lost 25% rms against
+the direct sum). The mask and rank products carry 0/1 operands and sums
+<= 128, exact at any precision, and stay at the default.
 """
 
 import functools
@@ -94,6 +99,7 @@ def _kernel(pk_ref, out0_ref, out1_ref, cnt_ref, stage_ref):
                 tgt = ((lan2 - fill + 128) & 127).astype(jnp.float32)
                 onehot = jnp.where(rcol == tgt, dcol, 0.0)  # (128, 128)
                 comp = jnp.dot(val, onehot,
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)  # (1,128)
                 m0 = (lane1 >= fill) & (lane1 < fill + cnt)
                 m1 = lane1 < (fill + cnt - 128)

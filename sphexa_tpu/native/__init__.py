@@ -3,10 +3,12 @@
 The compute path is JAX/XLA/Pallas; the *host runtime* around it — key
 generation, sort-order and occupancy/window accounting at reconfiguration
 time — has a native C++ implementation (sfc_runtime.cpp), mirroring the
-reference's C++ host drivers. The library is built with ``make -C
-sphexa_tpu/native`` (attempted automatically once on first use); every
-entry point degrades gracefully to the numpy/jax implementation when the
-library is unavailable, so the package stays import-safe everywhere.
+reference's C++ host drivers. The library is built from sfc_runtime.cpp
+on first use, and rebuilt when the source is newer than the library
+(``make -C sphexa_tpu/native`` does the same by hand); every entry point
+takes the numpy/jax implementation when the library is unavailable, so
+the package stays import-safe everywhere — ``describe()`` names the route
+in use.
 """
 
 import ctypes
@@ -17,36 +19,60 @@ from typing import Optional, Tuple
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
+_SRC_PATH = os.path.join(_DIR, "sfc_runtime.cpp")
 _LIB_PATH = os.path.join(_DIR, "libsfc_runtime.so")
 _lib = None
 _tried_build = False
+#: why the numpy route is in use ("" while the native library serves)
+_fallback_reason = ""
+
+
+def _stale() -> bool:
+    """The library is missing or older than its source (a working tree
+    copied with a leftover build must not run yesterday's code)."""
+    try:
+        return os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC_PATH)
+    except OSError:
+        return True
+
+
+def _build() -> None:
+    """Compile the library to a process-unique temp name and atomically
+    rename, so concurrent builders never dlopen a partial file."""
+    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(
+            ["g++", "-O3", "-std=c++17", "-fPIC", "-fopenmp", "-Wall",
+             "-shared", "-o", tmp, _SRC_PATH],
+            check=True, capture_output=True, timeout=120,
+        )
+        os.replace(tmp, _LIB_PATH)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    """dlopen the runtime library, building it once if missing."""
-    global _lib, _tried_build
+    """dlopen the runtime library, (re)building it once per process when
+    it is missing or older than sfc_runtime.cpp. Returns None — every
+    entry point then takes its numpy route — when it cannot be built or
+    loaded; ``describe()`` says which route runs and why."""
+    global _lib, _tried_build, _fallback_reason
     if _lib is not None:
         return _lib
-    if not os.path.exists(_LIB_PATH) and not _tried_build:
+    if _stale() and not _tried_build:
         _tried_build = True
         try:
-            # build to a process-unique temp name and atomically rename so
-            # concurrent builders never dlopen a partially written library
-            tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
-            subprocess.run(
-                ["g++", "-O3", "-std=c++17", "-fPIC", "-fopenmp", "-Wall",
-                 "-shared", "-o", tmp,
-                 os.path.join(_DIR, "sfc_runtime.cpp")],
-                check=True, capture_output=True, timeout=120,
-            )
-            os.replace(tmp, _LIB_PATH)
-        except Exception:
-            return None
+            _build()
+        except (OSError, subprocess.SubprocessError) as e:
+            _fallback_reason = f"build failed: {e}"
     if not os.path.exists(_LIB_PATH):
+        _fallback_reason = _fallback_reason or "library not built"
         return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
+    except OSError as e:
+        _fallback_reason = f"dlopen failed: {e}"
         return None
 
     u32p = np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS")
@@ -64,13 +90,23 @@ def _load() -> Optional[ctypes.CDLL]:
     ]
     lib.sfc_runtime_abi_version.restype = ctypes.c_int
     if lib.sfc_runtime_abi_version() != 1:
+        _fallback_reason = "ABI version mismatch"
         return None
     _lib = lib
+    _fallback_reason = ""
     return _lib
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def describe() -> str:
+    """Which host runtime serves this process: the native library or
+    the numpy route, with the reason for the latter."""
+    if available():
+        return "native (libsfc_runtime.so)"
+    return f"numpy ({_fallback_reason})"
 
 
 def compute_keys(x, y, z, box_lo, box_len, curve: str = "hilbert") -> np.ndarray:

@@ -219,4 +219,7 @@ def make_sharded_step(mesh: Mesh, cfg: PropagatorConfig, step_fn=step_hydro_std,
     # ...and the jitted callable itself, so tests can .lower() the step
     # and pin lowering identities (the grav_window=0 byte-identity gate)
     stepper._jitted = jitted
+    # the sharded config the step runs under (mesh, shard axis, halo
+    # caps): the dump's output-field recompute reuses its force stages
+    stepper.cfg = cfg
     return stepper

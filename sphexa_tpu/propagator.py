@@ -18,6 +18,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from sphexa_tpu.gravity.ewald import EwaldConfig, compute_gravity_ewald
 from sphexa_tpu.gravity.traversal import GravityConfig, compute_gravity
@@ -47,17 +48,6 @@ from sphexa_tpu.sph.timestep import (
     rho_timestep,
 )
 from sphexa_tpu.util.phases import phase_scope
-
-try:  # jax >= 0.6 exports shard_map at the top level
-    from jax import shard_map as _jax_shard_map
-except ImportError:  # older jax keeps it in the experimental namespace
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-
-import inspect as _inspect
-
-_SHARD_MAP_PARAMS = frozenset(
-    _inspect.signature(_jax_shard_map).parameters
-)
 
 #: Canonical scalar diagnostics every propagator's step emits — the
 #: naming contract between the step functions, the Simulation driver's
@@ -117,15 +107,6 @@ def _dt_limiter(min_dt_prev, const: SimConstants, courant=None, rho=None,
     stack = jnp.stack([inf if c is None else jnp.asarray(c, jnp.float32)
                        for c in cands])
     return jnp.argmin(stack).astype(jnp.int32)
-
-
-def shard_map(*args, **kwargs):
-    """Version-compat shard_map: the replication check kwarg was renamed
-    check_rep -> check_vma across jax releases; translate so the same
-    call sites run on both."""
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _jax_shard_map(*args, **kwargs)
 
 
 @dataclasses.dataclass(frozen=True)

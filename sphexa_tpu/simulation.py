@@ -45,6 +45,7 @@ from sphexa_tpu.sfc.box import BoundaryType, Box
 from sphexa_tpu.sph.blockdt import make_blockdt_state
 from sphexa_tpu.sph.particles import ParticleState, SimConstants
 from sphexa_tpu.state import SimState
+from sphexa_tpu.util.device import device_info, on_tpu, resolve_backend
 
 _PROPAGATORS: Dict[str, Callable] = {
     "std": step_hydro_std,
@@ -131,9 +132,7 @@ def make_propagator_config(
     for the device_sizing path, so a caller that also needs keys (the
     gravity reconfigure) computes them once.
     """
-    if backend == "auto":
-        # fused pallas kernels on TPU, portable gather path elsewhere
-        backend = "pallas" if jax.default_backend() == "tpu" else "xla"
+    backend = resolve_backend(backend)
     # tuned knob resolution (docs/TUNING.md): the engine knobs default to
     # None so an explicit kwarg stays detectable; precedence is explicit
     # kwarg > table entry (``tuned=``) > the measured defaults below.
@@ -392,9 +391,7 @@ class Simulation:
 
         tuned_knobs, self.tuning_provenance = resolve_knobs(
             tuned, workload=workload, n=state.n, p=num_devices or 1,
-            backend=backend if backend != "auto" else
-            ("pallas" if jax.default_backend() == "tpu" else "xla"),
-            explicit=explicit_knobs,
+            backend=resolve_backend(backend), explicit=explicit_knobs,
         )
 
         def _knob(name, default):
@@ -558,7 +555,7 @@ class Simulation:
             raise ValueError(f"donate must be 'auto'|True|False, got "
                              f"{donate!r}")
         self._donate_active = donate is True or (
-            donate == "auto" and jax.default_backend() == "tpu"
+            donate == "auto" and on_tpu()
         )
         # runtime sanitizer (--debug-checks): the step runs under
         # jax.experimental.checkify with NaN/Inf + out-of-bounds-index
@@ -720,7 +717,39 @@ class Simulation:
         # construction-time sizing stays out of the health counter
         if reason != "initial":
             self.telemetry.count("reconfigures")
-        self.telemetry.event("reconfigure", it=self.iteration, reason=reason)
+        self.telemetry.event("reconfigure", it=self.iteration, reason=reason,
+                             engine=self._engine_facts())
+
+    @property
+    def active_cfg(self) -> PropagatorConfig:
+        """The config the launched step runs under: on a mesh the
+        sharded stepper's (mesh, shard axis, sized halo caps), else the
+        plain one. What a pass that re-runs step stages outside the step
+        (the dump's derived-field recompute) must be given."""
+        return self._stepper.cfg if self._mesh is not None else self._cfg
+
+    def _engine_facts(self) -> Dict:
+        """What this configure resolved, for the run record: the engine
+        behind the step, whether its kernels are compiled or interpreted,
+        donation, the list engine, the gravity solver shape. Read by
+        chip_smoke.py so a run reports what ran, not what was assumed."""
+        from sphexa_tpu.sph.pallas_pairs import pallas_interpret
+
+        cfg = self._cfg
+        g = cfg.gravity
+        return {
+            "backend": cfg.backend,
+            "interpret": (pallas_interpret() if cfg.backend == "pallas"
+                          else None),
+            "donate": self._donate_active,
+            "lists": self._use_lists,
+            "gravity": None if g is None else {
+                "compaction": g.compaction,
+                "target_block": g.target_block,
+                "super_factor": g.super_factor,
+                "use_pallas": g.use_pallas,
+            },
+        }
 
     def _configure_impl(self, min_cap: int = 0, grav_margin: float = 1.5):
         self._lists = None  # any static re-size invalidates the lists
@@ -786,10 +815,7 @@ class Simulation:
         and should share _configure_impl's keygen cache."""
         if self.prop_name == "nbody":
             return False
-        backend = self.backend
-        if backend == "auto":
-            backend = "pallas" if jax.default_backend() == "tpu" else "xla"
-        return backend == "pallas"
+        return resolve_backend(self.backend) == "pallas"
 
     def _configure_sharded(self, sizing_cache=None):
         """(Re)build the sharded stepper: size the per-peer halo window
@@ -1094,7 +1120,7 @@ class Simulation:
         second program entering the per-thread queues mid-flight deadlocks
         the all-reduce rendezvous (observed: evrard-cooling CLI hang).
         Real TPU meshes execute programs FIFO per core — no drain there."""
-        if self._mesh is not None and jax.default_backend() == "cpu":
+        if self._mesh is not None and device_info().platform == "cpu":
             jax.block_until_ready(
                 [a for a in jax.tree.leaves(out) if hasattr(a, "block_until_ready")]
             )
@@ -1490,6 +1516,8 @@ class Simulation:
             return float(fn(finite)) if finite.size else float("nan")
 
         agg = {
+            "nc_mean_min": ext("nc_mean", np.min),
+            "nc_mean_max": ext("nc_mean", np.max),
             "nc_clip": max(int(d.get("n_nc_clip", 0)) for d in ds),
             "h_sat": max(int(d.get("n_h_sat", 0)) for d in ds),
             "rho_min": ext("rho_min", np.min),

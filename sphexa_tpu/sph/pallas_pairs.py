@@ -46,6 +46,7 @@ from jax.experimental.pallas import tpu as pltpu
 from sphexa_tpu.dtypes import KEY_BITS, KEY_DTYPE
 from sphexa_tpu.neighbors.cell_list import NeighborConfig, _window_offsets
 from sphexa_tpu.sfc.box import BoundaryType, Box
+from sphexa_tpu.util.device import on_tpu
 from sphexa_tpu.util.phases import named_phase
 from sphexa_tpu.sfc.hilbert import hilbert_encode
 from sphexa_tpu.sfc.morton import morton_encode
@@ -438,8 +439,13 @@ def chunk_aabb_table(x, y, z, cap: int) -> jax.Array:
 
 def pallas_interpret() -> bool:
     """Run Mosaic kernels in interpret mode off-TPU (single policy for
-    every engine consumer — SPH ops, gravity, analysis)."""
-    return jax.default_backend() != "tpu"
+    every engine consumer — SPH ops, gravity, analysis).
+
+    Read at TRACE time inside the jitted steps and not part of their
+    cache key: a trace taken under one answer is replayed under the
+    other. Harmless because the answer is a function of the platform,
+    which cannot change within a process (util.device.device_info)."""
+    return not on_tpu()
 
 
 def group_pair_engine(

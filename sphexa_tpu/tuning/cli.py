@@ -160,10 +160,9 @@ def main(argv=None) -> int:
     if usable and result["improved"]:
         win = (base["value"] - best["value"]) / base["value"]
 
-    import jax
+    from sphexa_tpu.util.device import resolve_backend
 
-    backend = spec.backend if spec.backend != "auto" else (
-        "pallas" if jax.default_backend() == "tpu" else "xla")
+    backend = resolve_backend(spec.backend)
     workload = args.workload or spec.case
     # the decision event: what the sweep concluded, in the same stream
     # as the per-candidate evidence

@@ -2,7 +2,7 @@
 
 With x64 disabled jax silently demotes f64 requests, so these entries
 opt into ``x64=True`` — the auditor traces them under
-``jax.experimental.enable_x64`` (the config a conservation-diagnostics
+``jax.enable_x64`` (the config a conservation-diagnostics
 run would use) where the cast really produces float64.
 """
 

@@ -1,0 +1,86 @@
+"""Device resolver, compile-cache placement and the chip-only entry points
+(util/device.py, chip_smoke.py, bench.py) as seen from a CPU host: the
+portable paths resolve, every demand-a-chip call fails and names what it
+found, and nothing writes a number.
+"""
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+from sphexa_tpu.util import device
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(code_or_script, *, cwd=ROOT, env_extra=None, script=False):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.update(env_extra or {})
+    cmd = [sys.executable, code_or_script] if script \
+        else [sys.executable, "-c", code_or_script]
+    return subprocess.run(cmd, cwd=cwd, env=env, capture_output=True,
+                          text=True, timeout=300)
+
+
+class TestResolver:
+    def test_cpu_resolves_portable_paths(self):
+        from sphexa_tpu.sph.pallas_pairs import pallas_interpret
+
+        info = device.device_info()
+        assert info.platform == "cpu" and info.count >= 1 and info.kind
+        assert not device.on_tpu()
+        assert device.resolve_backend("auto") == "xla"
+        assert device.resolve_backend("pallas") == "pallas"
+        assert pallas_interpret() is True
+
+    def test_demand_a_chip_raises_naming_the_platform(self):
+        with pytest.raises(RuntimeError, match="platform='cpu'"):
+            device.require_tpu("this test")
+
+
+_CACHE_PROBE = """
+import jax
+from sphexa_tpu.util import device
+{patch}
+print(repr(device.enable_compile_cache()))
+print(repr(jax.config.jax_compilation_cache_dir))
+"""
+
+
+class TestCompileCache:
+    def test_env_set_leaves_config_untouched(self, tmp_path):
+        r = _run(_CACHE_PROBE.format(patch="device.on_tpu = lambda: True"),
+                 env_extra={"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+        helper, config = r.stdout.strip().splitlines()
+        # jax itself read the variable; the helper set nothing else
+        assert helper == config == repr(str(tmp_path)), r.stderr[-2000:]
+
+    def test_unset_places_checkout_cache_from_any_cwd(self, tmp_path):
+        want = repr(os.path.join(ROOT, ".jax_cache"))
+        for cwd in (ROOT, str(tmp_path)):
+            r = _run(_CACHE_PROBE.format(
+                patch="device.on_tpu = lambda: True"), cwd=cwd)
+            assert r.stdout.strip().splitlines() == [want, want], \
+                r.stderr[-2000:]
+
+    def test_unset_off_tpu_places_nothing(self, monkeypatch):
+        import jax
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert device.enable_compile_cache() is None
+        assert jax.config.jax_compilation_cache_dir is None
+
+
+class TestChipOnlyEntryPoints:
+    @pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+    def test_refuses_without_a_chip(self, script, tmp_path):
+        """Exits non-zero before compiling anything, names the platform
+        it found, prints no result line."""
+        r = _run(os.path.join(ROOT, script), cwd=str(tmp_path), script=True)
+        assert r.returncode != 0
+        assert "platform='cpu'" in r.stderr
+        assert '"ok"' not in r.stdout and '"value"' not in r.stdout
+        assert not os.listdir(tmp_path)  # no leg ran, nothing written

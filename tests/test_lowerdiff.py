@@ -98,7 +98,7 @@ class TestFingerprint:
         mesh = jax.make_mesh((2,), ("p",))
         from functools import partial
 
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         @partial(shard_map, mesh=mesh, in_specs=P("p"), out_specs=P())

@@ -91,3 +91,25 @@ def test_config_pipeline_uses_native(cloud):
     sim = Simulation(state, box, const, prop="std", block=256)
     d = sim.step()
     assert np.isfinite(d["dt"])
+
+
+def test_loader_rebuilds_library_older_than_source(tmp_path, monkeypatch):
+    """A leftover library older than sfc_runtime.cpp (a copied working
+    tree) must be rebuilt, not dlopen'ed as it stands."""
+    import os
+    import shutil
+
+    src = tmp_path / "sfc_runtime.cpp"
+    lib = tmp_path / "libsfc_runtime.so"
+    shutil.copy(native._SRC_PATH, src)
+    lib.write_bytes(b"stale build")  # dlopen of THIS would fail
+    old = os.path.getmtime(src) - 3600
+    os.utime(lib, (old, old))
+    monkeypatch.setattr(native, "_SRC_PATH", str(src))
+    monkeypatch.setattr(native, "_LIB_PATH", str(lib))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried_build", False)
+    monkeypatch.setattr(native, "_fallback_reason", "")
+    assert native.available(), native.describe()
+    assert os.path.getmtime(lib) >= os.path.getmtime(src)
+    assert native.describe().startswith("native")
