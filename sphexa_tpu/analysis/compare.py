@@ -19,6 +19,8 @@ from sphexa_tpu.sfc.box import Box
 from sphexa_tpu.sfc.keys import compute_sfc_keys
 from sphexa_tpu.sph import hydro_std, hydro_ve
 from sphexa_tpu.sph.particles import ParticleState
+from sphexa_tpu.telemetry.registry import span
+from sphexa_tpu.util.phases import phase_scope
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "pipeline"))
@@ -28,12 +30,15 @@ def _output_fields(
     # Neighbor search needs key order; results are scattered back to the
     # caller's particle order so they stay aligned with the conserved
     # fields of `state` (which a snapshot writes as-is).
-    keys = compute_sfc_keys(state.x, state.y, state.z, box, curve=cfg.curve)
-    order = jnp.argsort(keys)
-    skeys = keys[order]
-    g = lambda a: a[order]
-    x, y, z, h, m = (g(state.x), g(state.y), g(state.z), g(state.h), g(state.m))
-    temp = g(state.temp)
+    with phase_scope("output-fields"):
+        keys = compute_sfc_keys(state.x, state.y, state.z, box,
+                                curve=cfg.curve)
+        order = jnp.argsort(keys)
+        skeys = keys[order]
+        g = lambda a: a[order]
+        x, y, z, h, m = (g(state.x), g(state.y), g(state.z), g(state.h),
+                         g(state.m))
+        temp = g(state.temp)
 
     occ = jnp.int32(0)
     if cfg.backend == "pallas" and cfg.shard_axis is not None:
@@ -47,8 +52,10 @@ def _output_fields(
             _ve_forces_sharded,
         )
 
-        sstate = jax.tree.map(
-            lambda a: a[order] if getattr(a, "ndim", 0) >= 1 else a, state)
+        with phase_scope("output-fields"):
+            sstate = jax.tree.map(
+                lambda a: a[order] if getattr(a, "ndim", 0) >= 1 else a,
+                state)
         if pipeline == "ve":
             rho, c, _, occ, *_ = _ve_forces_sharded(sstate, box, cfg, skeys)
             p = rho * cfg.const.cv * temp * (cfg.const.gamma - 1.0)
@@ -99,11 +106,12 @@ def _output_fields(
         )
         p, c = hydro_std.compute_eos_std(temp, rho, cfg.const)
 
-    unsort = lambda a: jnp.zeros_like(a).at[order].set(a)
-    rho, p, c = unsort(rho), unsort(p), unsort(c)
-    u = cfg.const.cv * state.temp
-    vel = jnp.sqrt(state.vx**2 + state.vy**2 + state.vz**2)
-    r = jnp.sqrt(state.x**2 + state.y**2 + state.z**2)
+    with phase_scope("output-fields"):
+        unsort = lambda a: jnp.zeros_like(a).at[order].set(a)
+        rho, p, c = unsort(rho), unsort(p), unsort(c)
+        u = cfg.const.cv * state.temp
+        vel = jnp.sqrt(state.vx**2 + state.vy**2 + state.vz**2)
+        r = jnp.sqrt(state.x**2 + state.y**2 + state.z**2)
     return {"r": r, "rho": rho, "p": p, "u": u, "vel": vel, "c": c}, occ
 
 
@@ -114,16 +122,23 @@ def compute_output_fields(
     from a conserved-field state, as numpy arrays in the state's particle
     order. ``pipeline`` selects the density/EOS estimator consistent with
     the propagator that evolved the state ('std' or 've')."""
-    out, occ = _output_fields(state, box, cfg,
-                              "ve" if pipeline == "ve" else "std")
-    if int(occ) > cfg.nbr.cap:
+    with span("sphexa:dump-program"):
+        # launched until ``occ`` is on the host: the program's outputs
+        # become ready together, so this is its device time
+        out, occ = _output_fields(state, box, cfg,
+                                  "ve" if pipeline == "ve" else "std")
+        occ = int(occ)
+    if occ > cfg.nbr.cap:
         # only the sharded recompute reports it: the cell cap or the halo
         # window (cap + 1 sentinel) no longer covers the re-sorted state
         raise RuntimeError(
             f"output-field recompute overflowed its neighbor config "
-            f"(occupancy {int(occ)} > cap {cfg.nbr.cap}); step once more "
+            f"(occupancy {occ} > cap {cfg.nbr.cap}); step once more "
             "so the driver re-sizes, then dump")
-    return {k: np.asarray(v) for k, v in out.items()}
+    with span("sphexa:dump-fetch", fields=len(out)) as sp:
+        fields = {k: np.asarray(v) for k, v in out.items()}
+        sp["bytes"] = sum(v.nbytes for v in fields.values())
+    return fields
 
 
 def l1_error(sim: np.ndarray, sol: np.ndarray) -> float:

@@ -344,10 +344,11 @@ def main(argv=None) -> int:
 
         cooling_cfg = CoolingConfig(gamma=const.gamma, evolve_species=True)
 
-    # telemetry registry shared by the driver, the loop Timer and the
-    # profile series; --telemetry-dir adds the persisted JSONL sink (the
-    # sink-less registry costs counters only)
+    # telemetry registry shared by the driver, the loop and the dump's
+    # spans; --telemetry-dir adds the persisted JSONL sink (the sink-less
+    # registry costs counters only)
     from sphexa_tpu.telemetry import JsonlSink, Telemetry
+    from sphexa_tpu.telemetry.registry import set_current
 
     sinks = []
     recorder = None
@@ -355,6 +356,7 @@ def main(argv=None) -> int:
         sinks.append(JsonlSink(os.path.join(args.telemetry_dir,
                                             "events.jsonl")))
     telemetry = Telemetry(sinks=sinks)
+    set_current(telemetry)
     if args.telemetry_dir:
         # crash flight recorder: ring-buffer the event tail and dump
         # blackbox.json (+ a first-class ``crash`` event) on abnormal
@@ -566,51 +568,52 @@ def main(argv=None) -> int:
         """Write one output (restartable HDF5 snapshot, or ASCII columns
         with --ascii); derived fields are recomputed like the reference's
         saveFields pass, consistently with the active propagator."""
-        last_dump_iteration[0] = it
-        extra = output_fields()
-        if want_fields:
-            unknown = [f for f in want_fields if f not in extra]
-            if unknown:
-                print(f"# -f fields not available, skipped: {unknown}",
-                      file=sys.stderr)
-            extra = {k: v for k, v in extra.items() if k in want_fields}
+        with telemetry.span("sphexa:dump"):
+            last_dump_iteration[0] = it
+            extra = output_fields()
+            if want_fields:
+                unknown = [f for f in want_fields if f not in extra]
+                if unknown:
+                    print(f"# -f fields not available, skipped: {unknown}",
+                          file=sys.stderr)
+                extra = {k: v for k, v in extra.items() if k in want_fields}
 
-        if args.ascii:
-            from sphexa_tpu.io import write_ascii
-            from sphexa_tpu.io.snapshot import CONSERVED_FIELDS
+            if args.ascii:
+                from sphexa_tpu.io import write_ascii
+                from sphexa_tpu.io.snapshot import CONSERVED_FIELDS
 
-            cols = {f: np.asarray(getattr(sim.state, f)) for f in CONSERVED_FIELDS}
-            cols.update(extra)
-            path = dump_path.replace(".txt", f"_it{it}.txt")
-            write_ascii(path, cols)
-            log(f"# wrote ASCII dump -> {path} (not restartable)")
-            return
+                cols = {f: np.asarray(getattr(sim.state, f)) for f in CONSERVED_FIELDS}
+                cols.update(extra)
+                path = dump_path.replace(".txt", f"_it{it}.txt")
+                write_ascii(path, cols)
+                log(f"# wrote ASCII dump -> {path} (not restartable)")
+                return
 
-        from sphexa_tpu.io import write_snapshot
-        from sphexa_tpu.io.snapshot import write_snapshot_sharded
+            from sphexa_tpu.io import write_snapshot
+            from sphexa_tpu.io.snapshot import write_snapshot_sharded
 
-        if sim.turb_state is not None:
-            from sphexa_tpu.sph.hydro_turb import turbulence_state_to_fields
+            if sim.turb_state is not None:
+                from sphexa_tpu.sph.hydro_turb import turbulence_state_to_fields
 
-            extra = {
-                **extra,
-                **turbulence_state_to_fields(sim.turb_state, sim.turb_cfg),
-            }
-        if sim.chem is not None:
-            from sphexa_tpu.physics.cooling import chemistry_to_fields
+                extra = {
+                    **extra,
+                    **turbulence_state_to_fields(sim.turb_state, sim.turb_cfg),
+                }
+            if sim.chem is not None:
+                from sphexa_tpu.physics.cooling import chemistry_to_fields
 
-            extra = {**extra, **chemistry_to_fields(sim.chem)}
-        # on a mesh, dump file-per-shard (no global gather — the
-        # reference's parallel MPI-IO role); restart reads the base path
-        writer = (write_snapshot_sharded
-                  if getattr(sim, "_mesh", None) is not None
-                  else write_snapshot)
-        step = writer(
-            dump_path, sim.state, sim.box, const, iteration=it,
-            extra_fields=extra, case=case_name,
-            case_settings=case_overrides,
-        )
-        log(f"# wrote Step#{step} -> {dump_path}")
+                extra = {**extra, **chemistry_to_fields(sim.chem)}
+            # on a mesh, dump file-per-shard (no global gather — the
+            # reference's parallel MPI-IO role); restart reads the base path
+            writer = (write_snapshot_sharded
+                      if getattr(sim, "_mesh", None) is not None
+                      else write_snapshot)
+            step = writer(
+                dump_path, sim.state, sim.box, const, iteration=it,
+                extra_fields=extra, case=case_name,
+                case_settings=case_overrides,
+            )
+            log(f"# wrote Step#{step} -> {dump_path}")
 
     def maybe_dump(it):
         """-w schedule + --wextra one-shot triggers."""
@@ -636,7 +639,7 @@ def main(argv=None) -> int:
 
     from sphexa_tpu.util.timer import ProfileRecorder, Timer
 
-    timer = Timer(telemetry=telemetry)
+    timer = Timer()
     # in-situ viz adaptor: init before the loop, execute per iteration,
     # finalize after (sphexa.cpp:141-142,172,179 hook points)
     insitu = None
@@ -674,9 +677,9 @@ def main(argv=None) -> int:
     it0 = sim.iteration
     nan = float("nan")
     if args.trace_dir:
-        # whole-run profiler capture: the TraceAnnotation scopes the
-        # Simulation emits (sphexa:launch/flush/reconfigure/rebuild-lists)
-        # name the spans inside this trace
+        # whole-run profiler capture: the program's host spans
+        # (Telemetry.span: sphexa:launch/flush/fetch/dump-*...) are in
+        # this trace under the names their ``span`` events carry
         import jax as _jax
 
         os.makedirs(args.trace_dir, exist_ok=True)
@@ -788,19 +791,7 @@ def main(argv=None) -> int:
     n_done = sim.iteration - it0
     if args.profile:
         profile_path = f"{args.out_dir}/profile.npz"
-        # per-substep breakdown (the reference's per-phase Timer print,
-        # util/timer.hpp): an equivalent SPLIT execution of the final
-        # state, timed stage by stage (the fused production step has no
-        # internal walls — its fusion is the design); skipped when there
-        # is no series to attach it to
-        from sphexa_tpu.util.substep_profile import substep_breakdown
-
-        sub = substep_breakdown(sim, telemetry=telemetry) if profile.rows \
-            else {}
-        if sub:
-            log("# substeps (s, split-execution upper bound): "
-                + " ".join(f"{k}={v:.4f}" for k, v in sub.items()))
-        if profile.save(profile_path, substeps=sub):
+        if profile.save(profile_path):
             means = profile.summary()
             log("# profile (mean s/iter): "
                 + " ".join(f"{k}={v:.4f}" for k, v in means.items()

@@ -878,8 +878,9 @@ def compute_gravity(
                     pk, scap, 128, interpret=interp)
                 return sc, sn
 
-            scand, scand_n = jax.lax.map(
-                pre_chunk, sidx_p.reshape(nsc, spc, sblk))
+            with phase_scope("gravity-mac"):
+                scand, scand_n = jax.lax.map(
+                    pre_chunk, sidx_p.reshape(nsc, spc, sblk))
             scand = scand.reshape(-1, scap)[:num_super]
             scand_n = scand_n.reshape(-1)[:num_super]
             c_max = jnp.max(scand_n)
@@ -898,7 +899,16 @@ def compute_gravity(
                         pk, cfg.m2p_cap, cfg.p2p_cap, interpret=interp)
                 return jax.vmap(_eval_bm)(bidx, om, mn, op, pn)
 
-            out = jax.lax.map(one_super_main, (scand, scand_n, idxb))
+            # the block loops carry the MAC's scope: the loop op, its
+            # per-iteration slicing and stacking and the copies XLA adds
+            # round its carry belong to no stage of the body (on the v5e
+            # 151 ms of an Evrard 1.1M step, PERF.md PR 23). The body's
+            # stages keep their scopes further down the op's path
+            # (.../sphexa/gravity-mac/while/body/.../sphexa/gravity-m2p/):
+            # a reader that takes the innermost scope sees them, one
+            # that takes the outermost sees the loop whole
+            with phase_scope("gravity-mac"):
+                out = jax.lax.map(one_super_main, (scand, scand_n, idxb))
         else:
             geo0 = let_geo if use_let else dense_geo
 
@@ -909,7 +919,8 @@ def compute_gravity(
                         pk, cfg.m2p_cap, cfg.p2p_cap, interpret=interp)
                 return jax.vmap(_eval_bm)(bidx, om, mn, op, pn)
 
-            out = jax.lax.map(one_chunk_bm, idx)
+            with phase_scope("gravity-mac"):
+                out = jax.lax.map(one_chunk_bm, idx)
 
     if not use_bitmask and sf > 0:
         # superblock pre-pass (the two-level hierarchical classification):
@@ -939,9 +950,10 @@ def compute_gravity(
         sidx_p = jnp.concatenate(
             [sidx, jnp.broadcast_to(sidx[-1:], (nsc * chunk - num_super, sblk))]
         ) if nsc * chunk > num_super else sidx
-        scand, scand_ok, spar, scand_n = jax.lax.map(
-            jax.vmap(one_super), sidx_p.reshape(nsc, chunk, sblk)
-        )
+        with phase_scope("gravity-mac"):
+            scand, scand_ok, spar, scand_n = jax.lax.map(
+                jax.vmap(one_super), sidx_p.reshape(nsc, chunk, sblk)
+            )
         scand = scand.reshape(-1, scap)
         scand_ok = scand_ok.reshape(-1, scap)
         spar = spar.reshape(-1, scap)
@@ -1048,7 +1060,8 @@ def compute_gravity(
             bidx, bn = args
             return jax.vmap(one_block)(bidx, bn)
 
-        out = jax.lax.map(one_chunk, (idx, bnum))
+        with phase_scope("gravity-mac"):
+            out = jax.lax.map(one_chunk, (idx, bnum))
     escaped = jnp.asarray(False)
     grav_halo_metrics = None
     if cfg.use_pallas:

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from sphexa_tpu.dtypes import COORD_DTYPE, HYDRO_DTYPE
 from sphexa_tpu.sfc.box import BoundaryType, Box
 from sphexa_tpu.sph.particles import ParticleState, SimConstants
+from sphexa_tpu.telemetry.registry import span
 
 try:
     import h5py
@@ -105,9 +106,11 @@ def write_snapshot(
     ``num_particles_global`` overrides the numParticlesGlobal attribute
     (sharded part files record the global count, not their row count).
     """
-    fields = {f: np.asarray(getattr(state, f)) for f in CONSERVED_FIELDS}
-    if extra_fields:
-        fields.update({k: np.asarray(v) for k, v in extra_fields.items()})
+    with span("sphexa:dump-fetch") as sp:
+        sources = {f: getattr(state, f) for f in CONSERVED_FIELDS}
+        sources.update(extra_fields or {})
+        fields = {k: np.asarray(v) for k, v in sources.items()}
+        _fetched(sp, sources, fields)
     attrs = _step_attrs(state, box, const, iteration, num_particles_global)
     if case:
         attrs["initCase"] = np.bytes_(case)
@@ -119,10 +122,12 @@ def write_snapshot(
 
         attrs["caseSettings"] = np.bytes_(json.dumps(case_settings))
 
+    nbytes = sum(v.nbytes for v in fields.values())
     if _is_h5(path):
         if not _HAVE_H5PY:
             raise RuntimeError("h5py unavailable; use a .npz path instead")
-        with h5py.File(path, "a") as f:
+        with span("sphexa:dump-h5", bytes=nbytes, format="h5"), \
+                h5py.File(path, "a") as f:
             step = len([k for k in f.keys() if k.startswith("Step#")])
             g = f.create_group(f"Step#{step}")
             for k, v in attrs.items():
@@ -133,8 +138,17 @@ def write_snapshot(
 
     arrays = {f"field_{k}": v for k, v in fields.items()}
     arrays.update({f"attr_{k}": v for k, v in attrs.items()})
-    np.savez_compressed(path, **arrays)
+    with span("sphexa:dump-h5", bytes=nbytes, format="npz"):
+        np.savez_compressed(path, **arrays)
     return 0
+
+
+def _fetched(sp, sources, fields) -> None:
+    """Payload of a ``sphexa:dump-fetch`` span: the arrays that were not
+    on the host already, and their bytes."""
+    copied = [k for k, v in sources.items() if not isinstance(v, np.ndarray)]
+    sp["fields"] = len(copied)
+    sp["bytes"] = sum(fields[k].nbytes for k in copied)
 
 
 def _part_path(path: str, k: int, P: int) -> str:
@@ -187,7 +201,10 @@ def write_snapshot_sharded(
     rows = n // P
     # ONE host fetch per extra field (inside the shard loop each
     # np.asarray would re-gather the full array P times)
-    extras_np = {k2: np.asarray(v) for k2, v in (extra_fields or {}).items()}
+    with span("sphexa:dump-fetch") as sp:
+        extras_np = {k2: np.asarray(v)
+                     for k2, v in (extra_fields or {}).items()}
+        _fetched(sp, extra_fields or {}, extras_np)
     step = 0
     for sh in shards:
         sl = sh.index[0] if sh.index else slice(0, n)
@@ -198,21 +215,25 @@ def write_snapshot_sharded(
             pass
 
         part = _Part()
-        for f in CONSERVED_FIELDS:
-            a = getattr(state, f)
-            starts = [s.index[0].start or 0 for s in a.addressable_shards]
-            if start not in starts:
-                raise ValueError(
-                    f"field {f}: no shard starting at row {start} "
-                    f"(shard starts {sorted(starts)}) — uneven or "
-                    "mismatched sharding across fields")
-            ash = a.addressable_shards[starts.index(start)]
-            if ash.data.shape[0] != rows:
-                raise ValueError(
-                    f"field {f}: shard at row {start} has "
-                    f"{ash.data.shape[0]} rows, expected {rows} — "
-                    "sharded snapshots require equal-size shards")
-            setattr(part, f, np.asarray(ash.data))
+        with span("sphexa:dump-fetch",
+                  fields=len(CONSERVED_FIELDS)) as sp:
+            for f in CONSERVED_FIELDS:
+                a = getattr(state, f)
+                starts = [s.index[0].start or 0 for s in a.addressable_shards]
+                if start not in starts:
+                    raise ValueError(
+                        f"field {f}: no shard starting at row {start} "
+                        f"(shard starts {sorted(starts)}) — uneven or "
+                        "mismatched sharding across fields")
+                ash = a.addressable_shards[starts.index(start)]
+                if ash.data.shape[0] != rows:
+                    raise ValueError(
+                        f"field {f}: shard at row {start} has "
+                        f"{ash.data.shape[0]} rows, expected {rows} — "
+                        "sharded snapshots require equal-size shards")
+                setattr(part, f, np.asarray(ash.data))
+            sp["bytes"] = sum(getattr(part, f).nbytes
+                              for f in CONSERVED_FIELDS)
         part.n = rows
         part.ttot = state.ttot
         part.min_dt = state.min_dt

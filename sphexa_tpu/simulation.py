@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from sphexa_tpu.telemetry import Telemetry, emit_memory_event
+from sphexa_tpu.telemetry.registry import set_current
 
 from sphexa_tpu.gravity.traversal import GravityConfig, estimate_gravity_caps
 from sphexa_tpu.neighbors.cell_list import (
@@ -358,10 +359,14 @@ class Simulation:
         # here. A sink-less default keeps counters for free; pass a
         # Telemetry with sinks (app --telemetry-dir) to persist them.
         # Hot-loop contract: the instrumentation below is host-only —
-        # perf_counter stamps, Counter bumps, jit-cache-size reads — and
-        # must NEVER add a device->host transfer to the deferred happy
-        # path (pinned by tests/test_telemetry.py's no-sync guard).
+        # spans (perf_counter stamps), Counter bumps, jit-cache-size
+        # reads — and must NEVER add a device->host transfer to the
+        # deferred happy path (pinned by tests/test_telemetry.py's
+        # no-sync guard).
         self.telemetry = telemetry if telemetry is not None else Telemetry()
+        # the dump's spans (analysis/compare.py, io/snapshot.py) are
+        # opened without a handle: they report to the latest Simulation's
+        set_current(self.telemetry)
         self._window_t0 = None  # host stamp of the open window's 1st launch
         # tuned knob resolution (sphexa_tpu/tuning): precedence is
         # explicit kwarg > table entry > gravity_tuning/default heuristic,
@@ -709,7 +714,7 @@ class Simulation:
 
     def _configure(self, min_cap: int = 0, grav_margin: float = 1.5,
                    reason: str = "reconfigure"):
-        with self.telemetry.annotate("sphexa:reconfigure"):
+        with self.telemetry.span("sphexa:reconfigure", reason=reason):
             self._configure_impl(min_cap, grav_margin)
         # a reconfigure used to be visible only as one dict entry
         # (``reconfigured``) on one step's diagnostics — as telemetry it
@@ -786,28 +791,33 @@ class Simulation:
                 curve=self.curve,
             )
             sizing_cache = (keys_d, jnp.argsort(keys_d), gbox)
-        self._cfg = make_propagator_config(
-            self.state, self.box, self.const,
-            ngmax=self.ngmax, block=self.block, curve=self.curve, min_cap=min_cap,
-            av_clean=self.av_clean, keep_accels=self.keep_accels,
-            keep_fields=self.keep_fields, backend=self.backend,
-            device_sizing=self._mesh is not None,
-            use_lists=self._lists_eligible,
-            list_skin_rel=self._list_skin_rel,
-            list_slot_margin=self._slot_margin,
-            sizing_cache=sizing_cache[:2] if sizing_cache else None,
-            obs_spec=self._obs_spec,
-            snap_spec=self._snap_spec,
-            dt_bins=self.dt_bins, bin_sync_every=self.bin_sync_every,
-            bin_resort_drift=self.bin_resort_drift,
-            # table-resolved neighbor-engine knobs (cell_target/run_cap/
-            # gap/group); absent keys fall to the factory defaults
-            **self._nbr_knobs,
-        )
+        with self.telemetry.span("sphexa:size-neighbors"):
+            self._cfg = make_propagator_config(
+                self.state, self.box, self.const,
+                ngmax=self.ngmax, block=self.block, curve=self.curve,
+                min_cap=min_cap,
+                av_clean=self.av_clean, keep_accels=self.keep_accels,
+                keep_fields=self.keep_fields, backend=self.backend,
+                device_sizing=self._mesh is not None,
+                use_lists=self._lists_eligible,
+                list_skin_rel=self._list_skin_rel,
+                list_slot_margin=self._slot_margin,
+                sizing_cache=sizing_cache[:2] if sizing_cache else None,
+                obs_spec=self._obs_spec,
+                snap_spec=self._snap_spec,
+                dt_bins=self.dt_bins, bin_sync_every=self.bin_sync_every,
+                bin_resort_drift=self.bin_resort_drift,
+                # table-resolved neighbor-engine knobs (cell_target/
+                # run_cap/gap/group); absent keys fall to the factory
+                # defaults
+                **self._nbr_knobs,
+            )
         if self.gravity_on:
-            self._configure_gravity(grav_margin, keys_cache=sizing_cache)
+            with self.telemetry.span("sphexa:size-gravity"):
+                self._configure_gravity(grav_margin, keys_cache=sizing_cache)
         if self._mesh is not None:
-            self._configure_sharded(sizing_cache)
+            with self.telemetry.span("sphexa:size-halo"):
+                self._configure_sharded(sizing_cache)
 
     def _halo_sizing_needed(self) -> bool:
         """Whether _configure_sharded will run the explicit halo-need
@@ -1089,7 +1099,7 @@ class Simulation:
                 # (self._lists stays None; steps run with lists=None)
                 return
             aux = self.chem if self.prop_name == "std-cooling" else None
-            with self.telemetry.annotate("sphexa:rebuild-lists"):
+            with self.telemetry.span("sphexa:rebuild-lists"):
                 state, box, lists, aux = rebuild_pair_lists(
                     self.state, self.box, self._cfg, aux
                 )
@@ -1261,17 +1271,18 @@ class Simulation:
         fn0 = id(self._checked_cache.get("fn")) if self.debug_checks \
             else None
         donate_now = donate_ok and self._donate_active
-        with self.telemetry.annotate("sphexa:launch"):
+        with self.telemetry.span("sphexa:launch", donated=donate_now) as sp:
             out = self._launch_impl(donate_ok)
-        delta = self._compiled_cache_size() - c0
-        if (self.debug_checks and delta <= 0
-                and id(self._checked_cache.get("fn")) != fn0):
-            delta = 1
-        sig = self._launch_signature(donate_now)
-        warm = delta <= 0 and sig not in self._launched_sigs
-        self._launched_sigs.add(sig)
-        if delta > 0 or warm:
-            n = max(delta, 1)
+            delta = self._compiled_cache_size() - c0
+            if (self.debug_checks and delta <= 0
+                    and id(self._checked_cache.get("fn")) != fn0):
+                delta = 1
+            sig = self._launch_signature(donate_now)
+            warm = delta <= 0 and sig not in self._launched_sigs
+            self._launched_sigs.add(sig)
+            n = max(delta, 1) if delta > 0 or warm else 0
+            sp["retrace"] = n
+        if n:
             self.telemetry.count("retraces", n)
             self.telemetry.event("retrace", it=self.iteration, delta=n,
                                  warm=warm)
@@ -1693,13 +1704,18 @@ class Simulation:
         reveal a cell-cap overflow (truncated neighbor candidates) is
         discarded and re-run under a freshly sized config — overflow must
         never corrupt state."""
+        with self.telemetry.span("sphexa:step"):
+            return self._step_checked_impl()
+
+    def _step_checked_impl(self) -> Dict[str, float]:
         reconfigured = False
         grav_margin = 1.5
         grav_blown_once = False
         t0 = time.perf_counter()
         for _attempt in range(4):
             out = self._launch()
-            diagnostics = {**out[1], **self._fetch_scalars(out[1])}
+            with self.telemetry.span("sphexa:fetch"):
+                diagnostics = {**out[1], **self._fetch_scalars(out[1])}
             if not self._overflowed(diagnostics):
                 break
             if not self._lists_fresh(diagnostics):
@@ -1745,7 +1761,6 @@ class Simulation:
             for k, v in diagnostics.items()
         }
         result["reconfigured"] = float(reconfigured)
-        self.telemetry.timing("step", wall)
         self.telemetry.event(
             "step", it=self.iteration, wall_s=round(wall, 6),
             dt=float(result["dt"]) if "dt" in result else None,
@@ -1779,6 +1794,11 @@ class Simulation:
         between check boundaries are the last verified ones, marked
         ``{"deferred": 1.0}``.
         """
+        if self.check_every <= 1 or not self._pending:
+            # a checked step or a window opens: its spans (pin, launches,
+            # flush, a replay) and those of a dump at its boundary share
+            # this iteration
+            self.telemetry.iteration = self.iteration
         if self.check_every <= 1:
             return self._step_checked()
         if not self._pending:
@@ -1791,15 +1811,17 @@ class Simulation:
             # With donation active the window's first launch CONSUMES
             # self.state, so the pin must be a real copy — one copy per
             # window, amortized over check_every donated steps
-            pin = self.state
-            if self._donate_active:
-                pin = jax.tree.map(jnp.copy, self.state)
-            # aux slots (turb/chem/_bstate) are never donated, so the
-            # carry pin holds them by reference around the copied slab
-            self._window_prior = (
-                dataclasses.replace(self.sim_state, particles=pin),
-                self.iteration,
-            )
+            with self.telemetry.span("sphexa:pin",
+                                     copied=bool(self._donate_active)):
+                pin = self.state
+                if self._donate_active:
+                    pin = jax.tree.map(jnp.copy, self.state)
+                # aux slots (turb/chem/_bstate) are never donated, so the
+                # carry pin holds them by reference around the copied slab
+                self._window_prior = (
+                    dataclasses.replace(self.sim_state, particles=pin),
+                    self.iteration,
+                )
         out = self._launch(donate_ok=True)
         self._apply(out)
         self.iteration += 1
@@ -1818,53 +1840,67 @@ class Simulation:
         the synchronous checked path."""
         if not self._pending:
             return self._last_diag
-        pending, self._pending = self._pending, []
-        prior, self._window_prior = self._window_prior, None
-        t0, self._window_t0 = self._window_t0, None
-        with self.telemetry.annotate("sphexa:flush"):
-            fetched = jax.device_get([self._scalar_view(d) for d in pending])
-        # the batched fetch drains every launched program, so this host
-        # span IS the window's device time; per-step attribution is its
-        # mean (what "step time" means under deferral, docs/OBSERVABILITY)
-        window_wall = time.perf_counter() - t0 if t0 is not None else 0.0
-        bad = next(
-            (i for i, scal in enumerate(fetched) if self._overflowed(scal)),
-            None,
-        )
-        if bad is None:
-            self.telemetry.timing("step", window_wall)
-            self.telemetry.event(
-                "window", it=self.iteration, steps=len(pending),
-                wall_s=round(window_wall, 6),
-                per_step_s=round(window_wall / len(pending), 6),
+        with self.telemetry.span("sphexa:flush"):
+            pending, self._pending = self._pending, []
+            prior, self._window_prior = self._window_prior, None
+            t0, self._window_t0 = self._window_t0, None
+            with self.telemetry.span("sphexa:fetch"):
+                fetched = jax.device_get(
+                    [self._scalar_view(d) for d in pending])
+            # the batched fetch drains every launched program, so this
+            # host span IS the window's device time; per-step attribution
+            # is its mean (what "step time" means under deferral,
+            # docs/OBSERVABILITY)
+            window_wall = time.perf_counter() - t0 if t0 is not None else 0.0
+            bad = next(
+                (i for i, scal in enumerate(fetched)
+                 if self._overflowed(scal)),
+                None,
             )
-            # distributed telemetry rides the SAME fetch: per-shard
-            # load/exchange events + HBM snapshot, at window granularity
-            self._emit_distributed(fetched[-1], steps=len(pending))
-            # science ledger rides it too: one physics/numerics event +
-            # a constants row per step of the window (every step keeps
-            # its row even under --check-every N)
-            win_its = list(range(self.iteration - len(pending) + 1,
-                                 self.iteration + 1))
-            self._emit_science(fetched, win_its)
-            self._emit_blockdt(fetched, win_its)
-            self._emit_snapshot(fetched, win_its)
-            self._emit_memory("post-compile")
-            self._emit_memory("flush")
-            diagnostics = {**pending[-1], **fetched[-1]}
-            result = {
-                k: np.asarray(v) if getattr(v, "ndim", 0) else float(v)
-                for k, v in diagnostics.items()
-            }
-            result["reconfigured"] = 0.0
-            self._last_diag = result
-            if not self._config_still_valid(fetched[-1]):
-                self._configure(reason="stale-grid")
-                self._last_diag["reconfigured"] = 1.0
-            else:
-                self._maybe_rebuild_lists(fetched[-1])
-            return self._last_diag
-        # roll back to the window start and replay every window step
+            if bad is None:
+                with self.telemetry.span("sphexa:settle"):
+                    return self._settle(pending, fetched, window_wall)
+            with self.telemetry.span("sphexa:rollback"):
+                return self._rollback(pending, fetched, prior, bad)
+
+    def _settle(self, pending, fetched, window_wall) -> Dict[str, float]:
+        """A verified window's host work after the fetch: its events, the
+        validity check of the config, the age check of the lists."""
+        self.telemetry.event(
+            "window", it=self.iteration, steps=len(pending),
+            wall_s=round(window_wall, 6),
+            per_step_s=round(window_wall / len(pending), 6),
+        )
+        # distributed telemetry rides the SAME fetch: per-shard
+        # load/exchange events + HBM snapshot, at window granularity
+        self._emit_distributed(fetched[-1], steps=len(pending))
+        # science ledger rides it too: one physics/numerics event +
+        # a constants row per step of the window (every step keeps
+        # its row even under --check-every N)
+        win_its = list(range(self.iteration - len(pending) + 1,
+                             self.iteration + 1))
+        self._emit_science(fetched, win_its)
+        self._emit_blockdt(fetched, win_its)
+        self._emit_snapshot(fetched, win_its)
+        self._emit_memory("post-compile")
+        self._emit_memory("flush")
+        diagnostics = {**pending[-1], **fetched[-1]}
+        result = {
+            k: np.asarray(v) if getattr(v, "ndim", 0) else float(v)
+            for k, v in diagnostics.items()
+        }
+        result["reconfigured"] = 0.0
+        self._last_diag = result
+        if not self._config_still_valid(fetched[-1]):
+            self._configure(reason="stale-grid")
+            self._last_diag["reconfigured"] = 1.0
+        else:
+            self._maybe_rebuild_lists(fetched[-1])
+        return self._last_diag
+
+    def _rollback(self, pending, fetched, prior, bad) -> Dict[str, float]:
+        """Roll back to the window start and replay every window step
+        through the synchronous checked path."""
         diag_bad = fetched[bad]
         expiry_only = (
             not self._lists_fresh(diag_bad)

@@ -1,10 +1,9 @@
-"""The metrics registry: counters, gauges, phase timings, typed events.
+"""The metrics registry: counters, gauges, typed events, host spans.
 
 One ``Telemetry`` instance is shared by everything that measures a run —
-the Simulation driver, the app loop's ``Timer`` laps, bench.py — so every
-surface reports into the same place instead of three disconnected ones
-(the pre-telemetry state: util/timer.py wall laps, a one-shot
-substep_breakdown, and the per-step diagnostics dict).
+the Simulation driver, the app loop, bench.py — so every surface reports
+into the same place instead of disconnected ones (the pre-telemetry
+state: util/timer.py wall laps and the per-step diagnostics dict).
 
 Host-side only, by construction: nothing here touches device arrays.
 Callers hand in already-host scalars (floats, ints); the zero-sync
@@ -12,9 +11,10 @@ deferred-window contract lives in the CALLERS (Simulation.step/flush)
 and is pinned by tests/test_telemetry.py.
 """
 
-import contextlib
+import itertools
+import threading
 import time
-from collections import Counter, defaultdict
+from collections import Counter
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -32,12 +32,15 @@ import numpy as np
 #: kind (snapshot) — in-graph field-grid frames riding the flush
 #: boundary (observables/snapshot.py), rendered by ``sphexa-telemetry
 #: serve``. v8 only ADDS a kind, so v8 readers accept v1-v7 files
-#: strictly clean and v7 readers count ``snapshot`` under unknown_kinds.
-SCHEMA_VERSION = 8
+#: strictly clean and v7 readers count ``snapshot`` under unknown_kinds;
+#: v9 the host-span kind (span): ``Telemetry.span`` times the driver's
+#: and the dump's host work where it happens, on ``perf_counter_ns`` and
+#: in the profiler capture under the same name. v9 only ADDS a kind too.
+SCHEMA_VERSION = 9
 
 #: event schema versions this reader understands (older versions only
 #: ever ADD kinds, so the per-kind field table below covers them all)
-SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8)
+SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9)
 
 #: every event kind the schema admits, with its required payload fields
 #: (beyond the envelope ``v``/``seq``/``t``/``kind``). The CLI's --strict
@@ -117,6 +120,15 @@ EVENT_KINDS: Dict[str, tuple] = {
     # .npz ring with ``path`` as the pointer (null when no ring dir is
     # configured) — rendered by ``sphexa-telemetry serve``
     "snapshot": ("it", "fields", "grid"),
+    # -- v9: host-span kind (Telemetry.span) ------------------------------
+    # one closed host span: ``name`` ("sphexa:<what>"), per-process
+    # ``id``, ``parent`` (id of the innermost span open in the same
+    # thread, null at top level), ``it`` (the iteration at which the
+    # current check window or checked step opened: a window's and the
+    # following dump's spans share it), ``t0_ns``/``dur_ns`` on
+    # ``time.perf_counter_ns``, plus the span's own payload. Emitted at
+    # exit, so children precede their parent in the stream
+    "span": ("name", "id", "parent", "it", "t0_ns", "dur_ns"),
 }
 
 #: first schema version each kind appeared in (an older-versioned event
@@ -127,8 +139,10 @@ _V4_ONLY = frozenset({"phase_attr", "crash"})
 _V5_ONLY = frozenset({"sweep", "tuning"})
 _V6_ONLY = frozenset({"dt_bins"})
 _V8_ONLY = frozenset({"snapshot"})
+_V9_ONLY = frozenset({"span"})
 KIND_SINCE: Dict[str, int] = {
-    k: 8 if k in _V8_ONLY else 6 if k in _V6_ONLY else 5 if k in _V5_ONLY
+    k: 9 if k in _V9_ONLY else 8 if k in _V8_ONLY else 6 if k in _V6_ONLY
+    else 5 if k in _V5_ONLY
     else 4 if k in _V4_ONLY else 3 if k in _V3_ONLY
     else 2 if k in _V2_ONLY else 1
     for k in EVENT_KINDS
@@ -180,8 +194,88 @@ def validate_event(e: dict) -> List[str]:
     return problems
 
 
+#: per-process span ids: one sequence over every Telemetry instance, so
+#: an id names one span of a profiler capture whichever registry made it
+_SPAN_IDS = itertools.count(1)
+#: the spans open in each thread, innermost last
+_OPEN = threading.local()
+#: jax.profiler.TraceAnnotation, resolved on the first span (None = not
+#: tried yet, False = jax unavailable: the telemetry CLI never imports jax)
+_TRACE_ANNOTATION = None
+#: the registry that spans opened without a handle report to (``span``
+#: below): the latest Simulation's, else the one ``set_current`` named
+_CURRENT: Optional["Telemetry"] = None
+
+
+def _trace_annotation():
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _TRACE_ANNOTATION = TraceAnnotation
+        except Exception:
+            _TRACE_ANNOTATION = False
+    return _TRACE_ANNOTATION
+
+
+class Span:
+    """One open host span (``Telemetry.span``). Item assignment adds to
+    the payload of the event it emits on exit, for what is known only
+    once the work is done (``sp["bytes"] = n``)."""
+
+    __slots__ = ("_tel", "_name", "_payload", "_ann", "_t0", "_it",
+                 "id", "parent")
+
+    def __init__(self, tel, name, payload):
+        self._tel, self._name, self._payload = tel, name, payload
+
+    def __setitem__(self, key, value):
+        self._payload[key] = value
+
+    def __enter__(self):
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+        self.id = next(_SPAN_IDS)
+        self.parent = stack[-1] if stack else None
+        stack.append(self.id)
+        self._it = self._tel.iteration
+        ann = _trace_annotation()
+        self._ann = ann(self._name, id=self.id) if ann else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _OPEN.stack.pop()
+        self._tel.event("span", name=self._name, id=self.id,
+                        parent=self.parent, it=self._it, t0_ns=self._t0,
+                        dur_ns=dur, **self._payload)
+        return False
+
+
+class _NoSpan:
+    """What ``span`` hands out while no registry is current."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def __setitem__(self, key, value):
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
 class Telemetry:
-    """Counters + gauges + phase timings + an event stream over sinks.
+    """Counters + gauges + an event stream over sinks + host spans.
 
     With no sinks the registry still accumulates (bench.py uses that to
     report retrace/rollback counts without writing files); ``event()``
@@ -192,8 +286,9 @@ class Telemetry:
         self.sinks = list(sinks)
         self.counters: Counter = Counter()
         self.gauges: Dict[str, float] = {}
-        self.phase_totals: Dict[str, float] = defaultdict(float)
-        self.phase_counts: Counter = Counter()
+        #: the iteration at which the driver's current check window (or
+        #: checked step) opened; every span is stamped with it
+        self.iteration = 0
         self._seq = 0
 
     # -- scalar metrics ----------------------------------------------------
@@ -202,15 +297,6 @@ class Telemetry:
 
     def gauge(self, name: str, value: float) -> None:
         self.gauges[name] = float(value)
-
-    def timing(self, name: str, seconds: float) -> None:
-        """Accumulate one lap of a named phase (mean via timing_mean)."""
-        self.phase_totals[name] += float(seconds)
-        self.phase_counts[name] += 1
-
-    def timing_mean(self, name: str) -> float:
-        n = self.phase_counts[name]
-        return self.phase_totals[name] / n if n else float("nan")
 
     # -- event stream ------------------------------------------------------
     def event(self, kind: str, **payload) -> None:
@@ -230,30 +316,23 @@ class Telemetry:
             s.emit(e)
 
     def phases(self, it: int, laps: Dict[str, float]) -> None:
-        """Per-iteration host phase laps (the Timer's pop) as one event;
-        each lap also feeds the registry's phase accumulators."""
-        for k, v in laps.items():
-            self.timing(k, v)
+        """Per-iteration host phase laps (the Timer's pop) as one event."""
         self.event("phases",
                    it=int(it), **{k: round(float(v), 6)
                                   for k, v in laps.items()})
 
-    # -- profiler hooks ----------------------------------------------------
-    def annotate(self, name: str):
-        """Named scope for jax.profiler traces (TraceAnnotation): shows up
-        in a --trace-dir capture around launch/flush/reconfigure/rebuild.
-        Falls back to a no-op context when jax is unavailable (the CLI
-        never imports jax)."""
-        global _TRACE_ANNOTATION
-        if _TRACE_ANNOTATION is None:
-            try:
-                from jax.profiler import TraceAnnotation
-                _TRACE_ANNOTATION = TraceAnnotation
-            except Exception:
-                _TRACE_ANNOTATION = False
-        if not _TRACE_ANNOTATION:
-            return contextlib.nullcontext()
-        return _TRACE_ANNOTATION(name)
+    # -- host spans --------------------------------------------------------
+    def span(self, name: str, **payload) -> Span:
+        """Context manager timing one stretch of host work where it
+        happens. On exit it emits one ``span`` event (``t0_ns``/``dur_ns``
+        on ``time.perf_counter_ns``, the clock of a harness's own
+        ``perf_counter`` spans; ``id``, ``parent``, ``it``; the payload),
+        so the span lands in whatever sinks the run has and with none
+        costs the Counter bump of ``event``. For its whole extent it also
+        holds ``jax.profiler.TraceAnnotation(name, id=id)``, which puts
+        the same span, under the same name, on the clock of a profiler
+        capture. Host-only: a span never touches a device array."""
+        return Span(self, name, payload)
 
     # -- console routing ---------------------------------------------------
     def console_printer(self, fallback: Callable = print) -> Callable:
@@ -266,11 +345,28 @@ class Telemetry:
         return fallback
 
     def close(self) -> None:
+        global _CURRENT
+        if _CURRENT is self:
+            # a closed JsonlSink reopens (and truncates) on its next emit
+            _CURRENT = None
         for s in self.sinks:
             s.close()
 
 
-_TRACE_ANNOTATION = None  # resolved lazily by Telemetry.annotate
+def set_current(telemetry: Optional[Telemetry]) -> None:
+    """Name the registry that handle-less spans report to. Called where a
+    ``Simulation`` is constructed and by ``main()``; ``Telemetry.close``
+    un-names a registry that is current."""
+    global _CURRENT
+    _CURRENT = telemetry
+
+
+def span(name: str, **payload):
+    """``Telemetry.span`` on the process-current registry, for code that
+    is called without a telemetry handle (``analysis/compare.py``,
+    ``io/snapshot.py``); with none current, a no-op."""
+    tel = _CURRENT
+    return _NO_SPAN if tel is None else tel.span(name, **payload)
 
 
 # ---------------------------------------------------------------------------
@@ -281,10 +377,10 @@ _TRACE_ANNOTATION = None  # resolved lazily by Telemetry.annotate
 
 class LapTimer:
     """Accumulates named wall-clock laps within one iteration
-    (timer.hpp:46 semantics); each lap also feeds ``telemetry.timing``."""
+    (timer.hpp:46 semantics); ``pop`` hands them to whoever records the
+    iteration (``StepSeries.record`` / ``Telemetry.phases``)."""
 
-    def __init__(self, telemetry: Optional[Telemetry] = None):
-        self.telemetry = telemetry
+    def __init__(self):
         self.laps: Dict[str, float] = {}
         self._t = time.perf_counter()
 
@@ -297,8 +393,6 @@ class LapTimer:
         elapsed = now - self._t
         self.laps[name] = self.laps.get(name, 0.0) + elapsed
         self._t = now
-        if self.telemetry is not None:
-            self.telemetry.timing(name, elapsed)
         return elapsed
 
     # reference-parity alias (util/timer.hpp's Timer::step)
@@ -325,12 +419,11 @@ class StepSeries:
         if self.telemetry is not None:
             self.telemetry.phases(iteration, {**laps, **metrics})
 
-    def save(self, path: str, substeps=None) -> bool:
-        """Write the series (+ optional one-shot substep breakdown as
-        substep_<name> scalars). Returns whether a file was written —
-        with zero rows and no substeps nothing is, and the caller must
-        not report a series that doesn't exist (app/main.py --profile)."""
-        if not self.rows and not substeps:
+    def save(self, path: str) -> bool:
+        """Write the series. Returns whether a file was written — with
+        zero rows nothing is, and the caller must not report a series
+        that doesn't exist (app/main.py --profile)."""
+        if not self.rows:
             return False
         keys = sorted({k for row in self.rows for k in row})
         # ragged rows (a metric recorded only on some iterations) are
@@ -339,8 +432,6 @@ class StepSeries:
             k: np.array([row.get(k, np.nan) for row in self.rows])
             for k in keys
         }
-        for k, v in (substeps or {}).items():
-            arrays[f"substep_{k}"] = np.float64(v)
         np.savez(path, **arrays)
         return True
 

@@ -53,6 +53,7 @@ PHASES = (
     "ledger",           # in-graph conservation/numerics science ledger
     "snapshot",         # in-graph downsampled field-grid deposit
     "shard-metrics",    # per-shard telemetry pack + gather
+    "output-fields",    # a dump's recompute: keygen, sort/unsort permutes
 )
 
 _PREFIX = "sphexa/"

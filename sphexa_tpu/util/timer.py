@@ -8,10 +8,9 @@ measurable phases are coarser: step (device compute incl. any recompile),
 observables, output. The profile dump is an npz timeseries instead of the
 reference's HDF5 group.
 
-The implementations live on the registry (LapTimer / StepSeries) so that
-laps recorded here ALSO accumulate in a shared ``Telemetry`` instance
-when one is passed — the app loop, Simulation driver and bench then all
-report into the same place. These names stay for API stability.
+The implementations live on the registry (LapTimer / StepSeries): a
+``ProfileRecorder`` given a ``Telemetry`` also emits every row as a
+``phases`` event. These names stay for API stability.
 """
 
 from sphexa_tpu.telemetry.registry import LapTimer, StepSeries
@@ -19,8 +18,7 @@ from sphexa_tpu.telemetry.registry import LapTimer, StepSeries
 
 class Timer(LapTimer):
     """Accumulates named wall-clock laps within one iteration
-    (``step(name)`` records since the last mark, timer.hpp:46); pass
-    ``telemetry=`` to mirror every lap into a registry."""
+    (``step(name)`` records since the last mark, timer.hpp:46)."""
 
 
 class ProfileRecorder(StepSeries):

@@ -49,7 +49,7 @@ class TestSnapshotSchema:
         snaps = sink.of_kind("snapshot")
         assert [e["it"] for e in snaps] == [1, 2]
         for e in snaps:
-            assert e["v"] == SCHEMA_VERSION == 8
+            assert e["v"] == SCHEMA_VERSION == 9
             assert validate_event(e) == []
             assert e["fields"] == ["rho", "temp"] and e["grid"] == 8
             z = np.load(e["path"], allow_pickle=False)
@@ -67,7 +67,8 @@ class TestSnapshotSchema:
         the v8 reader (the fixture runs below re-check this end to
         end)."""
         assert KIND_SINCE["snapshot"] == 8
-        assert all(v < 8 for k, v in KIND_SINCE.items() if k != "snapshot")
+        assert all(v < 8 for k, v in KIND_SINCE.items()
+                   if k not in ("snapshot", "span"))
         # a v7 writer never emitted snapshots; its events validate as-is
         old = {"v": 7, "seq": 1, "t": 0.0, "kind": "step", "it": 1,
                "wall_s": 0.1, "dt": 1e-3, "reconfigured": False}

@@ -33,17 +33,18 @@ from sphexa_tpu.telemetry.registry import validate_event
 
 
 class TestRegistry:
-    def test_counters_gauges_timings(self):
+    def test_counters_gauges_spans(self):
         t = Telemetry()
         t.count("x")
         t.count("x", 2)
         t.gauge("g", 1.5)
-        t.timing("p", 0.5)
-        t.timing("p", 1.5)
+        with t.span("sphexa:p"):
+            pass
+        with t.span("sphexa:p"):
+            pass
         assert t.counters["x"] == 3
         assert t.gauges["g"] == 1.5
-        assert t.timing_mean("p") == 1.0
-        assert np.isnan(t.timing_mean("missing"))
+        assert t.counters["events.span"] == 2
 
     def test_event_envelope_and_seq(self):
         sink = MemorySink()
@@ -182,6 +183,11 @@ class TestSimulationTelemetry:
         kinds = [e["kind"] for e in events]
         # 7 launches (both windows), 2 window flushes, no rollbacks
         assert kinds.count("launch") == 7
+        # the spans were on all through the poisoned stretch: the guard
+        # holds with them, because a span only stamps the host's clock
+        names = [e["name"] for e in events if e["kind"] == "span"]
+        assert names.count("sphexa:launch") == 7
+        assert names.count("sphexa:pin") == names.count("sphexa:flush") == 2
         windows = [e for e in events if e["kind"] == "window"]
         assert len(windows) == 2
         assert windows[-1]["steps"] == 3
@@ -268,6 +274,277 @@ class TestSimulationTelemetry:
         sim = _sedov_sim(side=8)
         sim.run(1, log_every=1, printer=lines.append)
         assert len(lines) == 1 and "rho_max=" in lines[0]
+
+
+# ---------------------------------------------------------------------------
+# host spans (schema v9): the one recorder, and the spans the driver and
+# the dump open at their own boundaries
+# ---------------------------------------------------------------------------
+
+
+def _spans(sink, since=0):
+    return [e for e in sink.events[since:] if e["kind"] == "span"]
+
+
+def _tree(spans):
+    """Names of the outermost spans in opening order (ids rise with
+    opening), each with the names of its children, nested the same way."""
+    spans = sorted(spans, key=lambda e: e["id"])
+    known = {e["id"] for e in spans}
+
+    def walk(e):
+        below = [walk(c) for c in spans if c["parent"] == e["id"]]
+        return (e["name"], below) if below else e["name"]
+
+    return [walk(e) for e in spans if e["parent"] not in known]
+
+
+class TestSpans:
+    def test_span_event_fields_and_clock(self):
+        import time
+
+        sink = MemorySink()
+        t = Telemetry(sinks=[sink])
+        t.iteration = 12
+        before = time.perf_counter_ns()
+        with t.span("sphexa:x", reason="r") as sp:
+            sp["bytes"] = 3
+        after = time.perf_counter_ns()
+        (e,) = sink.events
+        assert e["kind"] == "span" and e["v"] == SCHEMA_VERSION == 9
+        assert validate_event(e) == []
+        assert (e["name"], e["parent"], e["it"]) == ("sphexa:x", None, 12)
+        assert (e["reason"], e["bytes"]) == ("r", 3)
+        # perf_counter_ns: subtracts directly from a harness's own spans
+        assert before <= e["t0_ns"] <= e["t0_ns"] + e["dur_ns"] <= after
+
+    @pytest.mark.parametrize("version", range(1, 9))
+    def test_older_versioned_span_is_flagged(self, version):
+        e = {"v": version, "seq": 0, "t": 1.0, "kind": "span",
+             "name": "sphexa:x", "id": 1, "parent": None, "it": 0,
+             "t0_ns": 1, "dur_ns": 1}
+        assert any("v9-only" in p for p in validate_event(e))
+        assert validate_event({**e, "v": 9}) == []
+        assert validate_event({k: v for k, v in {**e, "v": 9}.items()
+                               if k != "dur_ns"})
+
+    def test_nesting_gives_parent_siblings_do_not(self):
+        sink = MemorySink()
+        t = Telemetry(sinks=[sink])
+        with t.span("a") as a:
+            with t.span("b") as b:
+                with t.span("c") as c:
+                    pass
+            with t.span("d") as d:
+                pass
+        with t.span("e") as e:
+            pass
+        by = {x["name"]: x for x in sink.events}
+        assert by["a"]["parent"] is None and by["e"]["parent"] is None
+        assert by["b"]["parent"] == a.id and by["d"]["parent"] == a.id
+        assert by["c"]["parent"] == b.id
+        assert len({a.id, b.id, c.id, d.id, e.id}) == 5
+        # emitted at exit: children precede their parent in the stream
+        assert [x["name"] for x in sink.events] == ["c", "b", "d", "a", "e"]
+
+    def test_span_survives_an_exception_and_other_threads(self):
+        import threading
+
+        sink = MemorySink()
+        t = Telemetry(sinks=[sink])
+        with pytest.raises(ValueError):
+            with t.span("outer"):
+                with t.span("raises"):
+                    raise ValueError("x")
+        seen = {}
+
+        def worker():
+            with t.span("elsewhere") as sp:
+                seen["parent"] = sp.parent
+
+        with t.span("here"):
+            th = threading.Thread(target=worker)
+            th.start()
+            th.join(timeout=10)
+        assert not th.is_alive()
+        # the failed spans were recorded and closed: nothing leaked onto
+        # the stack, and a span of another thread has no parent here
+        assert [e["name"] for e in sink.events] == [
+            "raises", "outer", "elsewhere", "here"]
+        assert seen["parent"] is None
+        assert sink.events[-1]["parent"] is None
+
+    def test_sinkless_span_is_one_counter_bump(self):
+        t = Telemetry()
+        with t.span("sphexa:x"):
+            pass
+        assert dict(t.counters) == {"events.span": 1}
+        assert t._seq == 0  # no envelope built
+
+    def test_process_current_recorder(self):
+        from sphexa_tpu.telemetry import registry
+
+        registry.set_current(None)
+        with registry.span("sphexa:x") as sp:  # no-op, payload discarded
+            sp["bytes"] = 1
+        sink = MemorySink()
+        t = Telemetry(sinks=[sink])
+        registry.set_current(t)
+        with registry.span("sphexa:x"):
+            pass
+        assert [e["name"] for e in sink.events] == ["sphexa:x"]
+        # constructing a Simulation names its registry; closing un-names
+        sim = _sedov_sim()
+        with registry.span("sphexa:y"):
+            pass
+        assert sim.telemetry.counters["events.span"] >= 1
+        assert len(sink.events) == 1
+        sim.telemetry.close()
+        assert registry._CURRENT is None
+
+    def test_one_window_yields_its_spans(self):
+        sink = MemorySink()
+        sim = _sedov_sim(telemetry=Telemetry(sinks=[sink]), check_every=4)
+        for _ in range(4):
+            sim.step()  # first window: compiles
+        mark = len(sink.events)
+        for _ in range(4):
+            sim.step()
+        spans = _spans(sink, mark)
+        assert _tree(spans) == [
+            "sphexa:pin", "sphexa:launch", "sphexa:launch", "sphexa:launch",
+            "sphexa:launch",
+            ("sphexa:flush", ["sphexa:fetch", "sphexa:settle"])]
+        dur = {e["name"]: e["dur_ns"] for e in spans}
+        assert dur["sphexa:flush"] >= dur["sphexa:fetch"] + dur["sphexa:settle"]
+        # the spans of one window share the iteration it opened at; the
+        # window before had another
+        assert {e["it"] for e in spans} == {4}
+        assert {e["it"] for e in _spans(sink)[:3]} == {0}
+        launches = [e for e in spans if e["name"] == "sphexa:launch"]
+        assert all(e["donated"] is False and e["retrace"] == 0
+                   for e in launches)
+        # the window event's wall runs from before the pin to after the
+        # fetch: the spans inside it cannot exceed it
+        (w,) = [e for e in sink.events[mark:] if e["kind"] == "window"]
+        inside = (dur["sphexa:pin"] + sum(e["dur_ns"] for e in launches)
+                  + dur["sphexa:fetch"])
+        assert inside * 1e-9 <= w["wall_s"] + 1e-3
+
+    def test_checked_steps_have_launch_and_fetch_children(self):
+        sink = MemorySink()
+        sim = _sedov_sim(telemetry=Telemetry(sinks=[sink]))
+        sim.step()
+        mark = len(sink.events)
+        sim.step()
+        sim.step()
+        step = ("sphexa:step", ["sphexa:launch", "sphexa:fetch"])
+        assert _tree(_spans(sink, mark)) == [step, step]
+        assert [e["it"] for e in _spans(sink, mark)
+                if e["name"] == "sphexa:step"] == [1, 2]
+
+    def test_forced_rollback_yields_its_spans(self):
+        state, box, const = init_sedov(12)
+        sink = MemorySink()
+        sim = Simulation(state, box, const, prop="std", block=4096,
+                         check_every=3, telemetry=Telemetry(sinks=[sink]))
+        sim._cfg = dataclasses.replace(
+            sim._cfg, nbr=dataclasses.replace(sim._cfg.nbr, cap=8))
+        mark = len(sink.events)
+        for _ in range(3):
+            sim.step()
+        assert sink.of_kind("rollback")
+        spans = _spans(sink, mark)
+        (flush,) = [t for t in _tree(spans) if t[0] == "sphexa:flush"]
+        fetch, (name, inside) = flush[1]
+        assert fetch == "sphexa:fetch" and name == "sphexa:rollback"
+        assert inside[0][0] == "sphexa:reconfigure"
+        assert "sphexa:size-neighbors" in inside[0][1]
+        steps = [t for t in inside[1:]]
+        assert len(steps) == 3 and all(t[0] == "sphexa:step" for t in steps)
+        # the replay belongs to the window it replays
+        assert {e["it"] for e in spans} == {0}
+        (rc,) = [e for e in spans if e["name"] == "sphexa:reconfigure"]
+        assert rc["reason"] == "overflow"
+
+    @pytest.mark.parametrize("writer", ["h5", "npz", "sharded"])
+    def test_dump_yields_program_fetch_and_write(self, tmp_path, writer):
+        from sphexa_tpu.analysis import compute_output_fields
+        from sphexa_tpu.io import write_snapshot
+        from sphexa_tpu.io.snapshot import (
+            CONSERVED_FIELDS, write_snapshot_sharded)
+
+        sink = MemorySink()
+        state, box, const = init_sedov(8)
+        kw = {"num_devices": 2} if writer == "sharded" else {}
+        sim = Simulation(state, box, const, prop="std", block=4096,
+                         telemetry=Telemetry(sinks=[sink]), **kw)
+        sim.step()
+        mark = len(sink.events)
+        extra = compute_output_fields(sim.state, sim.box, sim.active_cfg)
+        path = str(tmp_path / ("dump.npz" if writer == "npz" else "dump.h5"))
+        write = write_snapshot_sharded if writer == "sharded" \
+            else write_snapshot
+        write(path, sim.state, sim.box, const, iteration=1,
+              extra_fields=extra)
+        spans = _spans(sink, mark)
+        names = [e["name"] for e in spans]
+        parts = 2 if writer == "sharded" else 1
+        assert names[0] == "sphexa:dump-program"
+        assert names.count("sphexa:dump-h5") == parts
+        n = int(sim.state.n)
+        written = (len(CONSERVED_FIELDS) + len(extra)) * n * 4
+        fetched = [e for e in spans if e["name"] == "sphexa:dump-fetch"]
+        assert sum(e["bytes"] for e in fetched) == written
+        assert sum(e["fields"] for e in fetched) \
+            == len(extra) + parts * len(CONSERVED_FIELDS)
+        h5 = [e for e in spans if e["name"] == "sphexa:dump-h5"]
+        assert sum(e["bytes"] for e in h5) == written
+        assert {e["format"] for e in h5} == {
+            "npz" if writer == "npz" else "h5"}
+        # outside main() a dump's spans have no program parent, and they
+        # share the iteration of the step they follow
+        assert all(e["parent"] is None for e in spans)
+        assert {e["it"] for e in spans} == {0}
+
+    def test_capture_holds_the_span_under_its_id(self, tmp_path):
+        """Under jax.profiler the same span is in the capture's host
+        plane, under the same name, with its id as a stat: the device
+        trace and the in-memory event name one stretch of host time."""
+        import glob
+
+        sink = MemorySink()
+        sim = _sedov_sim(telemetry=Telemetry(sinks=[sink]), check_every=2)
+        sim.step()
+        sim.step()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            mark = len(sink.events)
+            sim.step()
+            sim.step()
+        finally:
+            jax.profiler.stop_trace()
+        (pb,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+        captured = {}
+        for plane in jax.profiler.ProfileData.from_file(pb).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("sphexa:"):
+                        captured[dict(ev.stats)["id"]] = (
+                            ev.name, ev.duration_ns)
+        spans = _spans(sink, mark)
+        assert {e["name"] for e in spans} >= {
+            "sphexa:pin", "sphexa:launch", "sphexa:flush", "sphexa:fetch",
+            "sphexa:settle"}
+        for e in spans:
+            name, dur_ns = captured[e["id"]]
+            assert name == e["name"]
+            # two clocks read at slightly different instants
+            assert abs(dur_ns - e["dur_ns"]) < 2e6 + 0.05 * e["dur_ns"]
 
 
 # ---------------------------------------------------------------------------
@@ -415,7 +692,8 @@ class TestDistributedTelemetry:
         events = [json.loads(l) for l in open(tmp_path / "events.jsonl")]
         snaps = [e for e in events if e["kind"] == "snapshot"]
         assert [e["it"] for e in snaps] == [1, 2, 3, 4, 5]
-        assert all(e["v"] == 8 and validate_event(e) == [] for e in snaps)
+        assert all(e["v"] == SCHEMA_VERSION and validate_event(e) == []
+                   for e in snaps)
         for e in snaps:
             z = np.load(e["path"], allow_pickle=False)
             g = np.asarray(z["grid"])

@@ -1,6 +1,6 @@
 """util/timer.py coverage: Timer lap accumulation, ProfileRecorder
 save/summary NaN-padding for ragged rows, and the thin-adapter contract
-over the telemetry registry (laps mirrored into a shared Telemetry)."""
+over the telemetry registry (popped laps become one ``phases`` event)."""
 
 import os
 import time
@@ -37,15 +37,17 @@ class TestTimer:
         t.start()
         assert t.step("a") < 0.009
 
-    def test_laps_mirror_into_telemetry(self):
-        tel = Telemetry()
-        t = Timer(telemetry=tel)
+    def test_popped_laps_become_a_phases_event(self):
+        sink = MemorySink()
+        tel = Telemetry(sinks=[sink])
+        t = Timer()
         t.start()
         t.step("phase")
         t.step("phase")
-        assert tel.phase_counts["phase"] == 2
-        assert tel.phase_totals["phase"] >= 0.0
-        assert tel.timing_mean("phase") >= 0.0
+        tel.phases(7, t.pop())
+        (e,) = sink.of_kind("phases")
+        assert e["it"] == 7 and e["phase"] >= 0.0
+        assert tel.counters["events.phases"] == 1
 
 
 class TestProfileRecorder:
@@ -54,13 +56,6 @@ class TestProfileRecorder:
         path = str(tmp_path / "profile.npz")
         assert p.save(path) is False
         assert not os.path.exists(path)
-
-    def test_save_substeps_only_still_writes(self, tmp_path):
-        p = ProfileRecorder()
-        path = str(tmp_path / "profile.npz")
-        assert p.save(path, substeps={"density": 0.5}) is True
-        data = np.load(path)
-        assert float(data["substep_density"]) == 0.5
 
     def test_ragged_rows_nan_padded(self, tmp_path):
         p = ProfileRecorder()

@@ -367,9 +367,9 @@ class TestSchemaV6:
 class TestSchemaV7:
     def test_v7_keeps_no_kinds(self):
         # v7 adds the optional staged-exchange payload, no new kinds: no
-        # KIND_SINCE entry may claim 7 (v8 added the snapshot kind —
-        # tests/test_serve.py pins the current version)
-        assert SCHEMA_VERSION == 8
+        # KIND_SINCE entry may claim 7 (v8 added the snapshot kind, v9
+        # the span kind — tests/test_serve.py pins the current version)
+        assert SCHEMA_VERSION == 9
         assert 7 not in KIND_SINCE.values()
 
     def test_v7_staged_exchange_validates(self):
