@@ -477,7 +477,7 @@ def _pallas_p2p(x, y, z, m, h, shift, allow_self, cfg: GravityConfig,
         run_cap=max(cfg.leaf_cap, 1024), gap=0,
     )
     zero3 = jnp.zeros(starts.shape + (3,), jnp.float32)
-    rs, rl, sh3, nruns = pp._merge_runs(
+    rs, rl, sh3, nruns, _ = pp._merge_runs(
         starts, lens, lens > 0, zero3, nbr.run_cap, 0
     )
     ranges = pp.GroupRanges(

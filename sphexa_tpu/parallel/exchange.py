@@ -1,34 +1,49 @@
-"""Windowed halo exchange for the sharded Pallas fast path.
+"""Halo exchange for the sharded Pallas fast path: sparse by cells (what
+runs), or by one row window per peer (the fallback).
 
 TPU-native transposition of the reference's halo subsystem
 (cstone/halos/exchange_halos.hpp:43-119 pack-ranges -> p2p -> scatter,
 discovery cstone/traversal/collisions.hpp:26-106). The reference sends
-per-peer lists of octree-leaf row ranges; here each shard
+per-peer lists of octree-leaf row ranges. Here each shard runs the shared
+group-window prologue on its OWN slab against the GLOBAL cell-starts
+table (``global_cell_table``: an O(ncells) psum of per-shard histograms —
+the update_mpi.hpp allreduce analog, no key gather), so its candidate
+runs are global rows of the distributed array, and then one of two
+stages turns them into rows of [own slab | annex]:
 
-1. runs the shared group-window prologue on its OWN slab against the
-   GLOBAL cell-starts table (an O(ncells) psum of per-shard histograms —
-   the update_mpi.hpp allreduce analog, no key gather),
-2. derives, per source shard, the single row WINDOW [lo, hi) covering
-   every candidate run it needs from that shard (discovery),
-3. all_gathers the (P, P, 2) bounds matrix (the exchange_keys.hpp
-   negotiation analog — O(P^2) ints),
-4. receives the windows with ONE all_to_all of fixed (P, Wmax, nf)
-   buffers: shard j serves dynamic slices of its slab (pack), shard k
-   concatenates [own slab | annex] into the engine's j-buffer (scatter).
+**Sparse, cell-granular** (``shard_halo_stage_sparse``; the default the
+Simulation sizes, ``halo_mode="sparse"``, ``PropagatorConfig.halo_cells``):
 
-Comm volume per shard = (P-1) * Wmax rows per exchange stage — the
-MEASURED candidate need (sized at reconfiguration, guarded in-step), not
-an unconditional O(N) replication. At CI scale (1e6 particles / 8 shards,
-level-4 cells) windows still span most of a slab — the halo *is* the
-volume at that granularity — but Wmax shrinks relative to the shard size
-as particles-per-shard grow (deeper grids, smaller surface fraction),
-which is the reference's scaling property (SURVEY.md §2e P2).
+1. discovery: mark the grid cells the shard's runs touch
+   (``coverage_from_runs``). The prologue hands each run's first and last
+   cell along with it (group_cell_ranges ``with_cells``), so nothing is
+   searched per run; only the gravity near field, whose runs are leaf
+   ranges, still searches (``_cells_of_runs``);
+2. negotiation: ONE all_gather of the (P, ncells) coverage bitmaps. The
+   packed layout of every (dest, src) buffer is a pure function of a
+   bitmap and the replicated table (``_sparse_layout``), so sender and
+   receiver agree without exchanging an offset;
+3. serve (``serve_sparse``, once per group of fields a pair op reads):
+   P - 1 ``ppermute`` rounds, round r shipping each shard's packed rows to
+   its distance-r SFC successor in a buffer of STATIC size hmax[r-1] —
+   per-distance caps sized at reconfiguration
+   (sizing.device_sparse_halo), so the volume tracks the halo surface;
+4. ``localize_ranges_sparse`` rewrites the runs into j-buffer rows.
 
-A candidate run that escapes its source window (particle drift after the
-last sizing) zeroes itself and trips the step's occupancy sentinel; the
-CALLER owns recovery — discard the step and rebuild the sharded stepper
-with a larger ``halo_window`` (tests/test_parallel.py exercises both the
-sentinel and the resize), mirroring the neighbor-cap overflow contract.
+**Windowed** (``shard_halo_stage``; ``halo_mode="windowed"``, and the
+full-slab fallback of the retry loop): per source shard ONE row window
+[lo, hi) covering every run needed from it, an all_gather of the
+(P, P, 2) bounds matrix (the exchange_keys.hpp negotiation analog), and
+ONE all_to_all of fixed (P, Wmax, nf) buffers per serve. Comm volume
+(P-1) * Wmax rows; at CI scale the windows span most of a slab, which is
+why the sparse stage exists (docs/NEXT.md round 4).
+
+Either way a run that escapes what was sized (particle drift since the
+last sizing) zeroes itself and trips the step's occupancy sentinel
+(``fold_escape_sentinel``); the CALLER owns recovery — discard the step
+and rebuild the sharded stepper with larger caps (tests/test_parallel.py
+exercises both the sentinel and the resize), mirroring the neighbor-cap
+overflow contract. What the stage costs on four chips is in PERF.md §5.
 """
 
 from typing import Sequence, Tuple
@@ -105,7 +120,8 @@ def global_cell_table(local_keys, level: int, axis: str) -> jax.Array:
     ).astype(jnp.int32)
 
 
-def _split_runs(starts, lens, shifts3, S: int, extra: int = 8):
+def _split_runs(starts, lens, payloads, S: int, extra: int = 8,
+                rem_payloads=None):
     """Split candidate runs that cross shard-slab boundaries.
 
     A run's rows must come from ONE source shard so it maps into one
@@ -113,7 +129,12 @@ def _split_runs(starts, lens, shifts3, S: int, extra: int = 8):
     a multiple of S — at most P-1 cells globally) are clipped at the
     boundary and the remainder pieces are appended as fresh runs;
     everything is re-compacted front-first. Returns (starts, lens,
-    shifts3, nruns, overflow) with ``extra`` more slots per group.
+    payloads, nruns, overflow) with ``extra`` more slots per group.
+
+    ``payloads``: per-run arrays that ride the split (the three image
+    shifts; the sparse stage adds each run's first cell). A remainder
+    piece inherits its run's values, or those of ``rem_payloads`` where
+    it differs from the head piece (its first cell does).
 
     ``extra`` must scale with the mesh: one group can need up to P-1
     crossing remainders (callers pass max(8, P-1) — growing the halo
@@ -121,7 +142,6 @@ def _split_runs(starts, lens, shifts3, S: int, extra: int = 8):
     make the escape-sentinel retry loop diverge).
     """
     ng, w3 = starts.shape
-    shx, shy, shz = shifts3
     src0 = starts // S
     src1 = jnp.where(lens > 0, (starts + lens - 1) // S, src0)
     cross = (src1 > src0) & (lens > 0)
@@ -138,26 +158,50 @@ def _split_runs(starts, lens, shifts3, S: int, extra: int = 8):
     order = jnp.argsort(~(r_len > 0), axis=1, stable=True)[:, :extra]
     take = lambda a: jnp.take_along_axis(a, order, axis=1)
     e_start, e_len = take(r_start), take(r_len)
-    e_shx, e_shy, e_shz = take(shx), take(shy), take(shz)
     overflow = jnp.sum(r_len > 0, axis=1) > extra
 
     starts = jnp.concatenate([starts, e_start], axis=1)
     lens = jnp.concatenate([jnp.where(cross, len1, lens), e_len], axis=1)
-    shx = jnp.concatenate([shx, e_shx], axis=1)
-    shy = jnp.concatenate([shy, e_shy], axis=1)
-    shz = jnp.concatenate([shz, e_shz], axis=1)
+    payloads = [
+        jnp.concatenate([a, take(r)], axis=1)
+        for a, r in zip(payloads, rem_payloads or payloads)
+    ]
 
     # re-compact: active runs to the front (stable keeps SFC order)
     active = lens > 0
-    _, act_i, starts, lens, shx, shy, shz = jax.lax.sort(
+    _, act_i, starts, lens, *payloads = jax.lax.sort(
         ((~active).astype(jnp.int32), active.astype(jnp.int32),
-         starts, lens, shx, shy, shz),
+         starts, lens, *payloads),
         num_keys=1, dimension=1, is_stable=True,
     )
     lens = jnp.where(act_i.astype(bool), lens, 0)
     starts = jnp.where(act_i.astype(bool), starts, 0)
     nruns = jnp.sum(active, axis=1).astype(jnp.int32)
-    return starts, lens, (shx, shy, shz), nruns, jnp.any(overflow) | r_cross
+    return starts, lens, tuple(payloads), nruns, jnp.any(overflow) | r_cross
+
+
+def _split_runs_cells(ranges: GroupRanges, table, S: int, P: int, c0=None):
+    """``_split_runs`` of the sparse stage: also names the first cell of
+    every piece. Returns (starts, lens, shifts3, nruns, overflow, c0).
+
+    ``c0``: the unsplit runs' carried first cells. A head piece keeps its
+    run's; a remainder piece starts at row (src0 + 1) * S, and the cells
+    holding the P - 1 slab boundaries are all there is to search for.
+    ``None``: search the table once per piece (_cells_of_runs)."""
+    sh3 = (ranges.shift_x, ranges.shift_y, ranges.shift_z)
+    extra = max(8, P - 1)
+    if c0 is None:
+        starts, lens, sh3, nruns, ovf = _split_runs(
+            ranges.starts, ranges.lens, sh3, S, extra=extra)
+        return (starts, lens, sh3, nruns, ovf,
+                _cells_of_runs(starts, lens, table)[0])
+    bcell = _cells_of_rows(
+        jnp.arange(1, max(P, 2), dtype=jnp.int32) * S, table)
+    rem_c0 = bcell[jnp.clip(ranges.starts // S, 0, bcell.shape[0] - 1)]
+    starts, lens, (*sh3, c0), nruns, ovf = _split_runs(
+        ranges.starts, ranges.lens, sh3 + (c0,), S, extra=extra,
+        rem_payloads=sh3 + (rem_c0,))
+    return starts, lens, tuple(sh3), nruns, ovf, c0
 
 
 def window_bounds(starts, lens, S: int, P: int, k, axis: str):
@@ -260,25 +304,38 @@ def fold_escape_sentinel(occ, escaped, cap: int, axis: str):
     return jax.lax.pmax(occ, axis)
 
 
+def _cells_of_rows(rows, table):
+    """Index of the cell holding each global row: the last cell that
+    starts at or before it (empty cells share their successor's start and
+    sit below it). One binary search of the table per row."""
+    c = jnp.searchsorted(table, rows, side="right").astype(jnp.int32) - 1
+    return jnp.clip(c, 0, table.shape[0] - 2)
+
+
 def _cells_of_runs(starts, lens, table):
     """First/last cell index of every run: runs are unions of consecutive
     cells of the level grid, so [c0, c1] brackets exactly the run's rows.
-    Dead runs (len 0) return a harmless [c0, c0]."""
+    Dead runs (len 0) return a harmless [c0, c0].
+
+    Two searches PER RUN SLOT: for runs whose cells are not known — the
+    gravity near field's leaf ranges. Runs that group_cell_ranges made
+    from a table lookup carry their cells (``with_cells``); searching for
+    those was 477 of the four-chip Sedov step's 1468 ms (PERF.md, PR 24)."""
     ends = jnp.where(lens > 0, starts + lens - 1, starts)
-    c0 = jnp.searchsorted(table, starts, side="right").astype(jnp.int32) - 1
-    c1 = jnp.searchsorted(table, ends, side="right").astype(jnp.int32) - 1
-    ncells = table.shape[0] - 1
-    return jnp.clip(c0, 0, ncells - 1), jnp.clip(c1, 0, ncells - 1)
+    return _cells_of_rows(starts, table), _cells_of_rows(ends, table)
 
 
-def coverage_from_runs(starts, lens, table) -> jax.Array:
+def coverage_from_runs(starts, lens, table, cells=None) -> jax.Array:
     """(ncells,) bool: cells whose rows any ACTIVE candidate run touches —
     this shard's halo NEED at cell granularity (the collision-detection
     product of the reference's halo discovery, collisions.hpp:26-106,
     transposed to the replicated level grid). Interval-marked with one
     +1/-1 scatter + cumsum; gap-bridged cells inside a merged run are
-    covered too (their rows ride the run's DMA window)."""
-    c0, c1 = _cells_of_runs(starts, lens, table)
+    covered too (their rows ride the run's DMA window).
+
+    ``cells``: the runs' carried ``(c0, c1)`` (group_cell_ranges
+    ``with_cells``); searched for in the table when None."""
+    c0, c1 = _cells_of_runs(starts, lens, table) if cells is None else cells
     active = (lens > 0).astype(jnp.int32)
     ncells = table.shape[0] - 1
     diff = jnp.zeros(ncells + 1, jnp.int32)
@@ -401,22 +458,33 @@ def _sparse_layout_dest(covered_all, dest, table, S: int, k):
 @named_phase("halo-exchange")
 def localize_ranges_sparse(
     ranges: GroupRanges, table, S: int, P: int, hmax: Tuple[int, ...],
-    k, axis: str,
+    k, axis: str, cells=None,
 ) -> Tuple[GroupRanges, jax.Array, jax.Array, jax.Array]:
     """Sparse analog of ``localize_ranges``: rewrite global-row runs into
     j-buffer rows [own slab (S) | packed annex (sum(hmax))] using the
     cell-granular packed layout. Also computes and all_gathers this
     shard's coverage bitmap (the negotiation). Returns (localized
-    ranges, covered_all (P, ncells), escaped, coverage bitmap)."""
-    starts, lens, sh3, nruns, split_ovf = _split_runs(
-        ranges.starts, ranges.lens,
-        (ranges.shift_x, ranges.shift_y, ranges.shift_z), S,
-        extra=max(8, P - 1),
-    )
+    ranges, covered_all (P, ncells), escaped, coverage bitmap).
+
+    ``cells``: the ``(c0, c1)`` the prologue carried for ``ranges``
+    (group_cell_ranges ``with_cells``). With them nothing is searched per
+    run: coverage is marked from the unsplit runs (a run covers the same
+    cells cut or whole — except an EMPTY cell lying exactly on the slab
+    boundary that cuts it, which holds no rows and so has no entry in
+    any layout), a head piece keeps its run's first cell, and a
+    remainder piece starts at a slab boundary, whose cell is one of
+    P - 1. ``None`` searches the table for the split pieces' cells
+    (_cells_of_runs) and returns the same ranges, escapes and layouts.
+    """
     if len(hmax) != P - 1:
         raise ValueError(f"hmax needs P-1={P-1} per-distance caps, got "
                          f"{len(hmax)}")
-    covered = coverage_from_runs(starts, lens, table)
+    starts, lens, sh3, nruns, split_ovf, c0 = _split_runs_cells(
+        ranges, table, S, P, c0=None if cells is None else cells[0])
+    if cells is None:
+        covered = coverage_from_runs(starts, lens, table)
+    else:
+        covered = coverage_from_runs(ranges.starts, ranges.lens, table, cells)
     covered_all = jax.lax.all_gather(covered, axis)  # (P, ncells)
 
     clen, poff, need = _sparse_layout(covered, table, S, P)  # per src j
@@ -434,7 +502,6 @@ def localize_ranges_sparse(
     active = lens > 0
     src = jnp.clip(starts // S, 0, P - 1)
     own = src == k
-    c0, _ = _cells_of_runs(starts, lens, table)
     clip_lo = jnp.maximum(table[c0], src * S)
     packed = poff[src, c0] + (starts - clip_lo)
     r_run = (k - src) % P
@@ -472,9 +539,10 @@ def shard_halo_stage_sparse(x, y, z, h, keys, box, nbr, P: int,
     S = x.shape[0]
     k = jax.lax.axis_index(axis)
     table = global_cell_table(keys, nbr.level, axis)
-    granges = group_cell_ranges(x, y, z, h, None, box, nbr, table=table)
+    granges, cells = group_cell_ranges(x, y, z, h, None, box, nbr,
+                                       table=table, with_cells=True)
     ranges, covered_all, escaped, covered = localize_ranges_sparse(
-        granges, table, S, P, hmax, k, axis
+        granges, table, S, P, hmax, k, axis, cells=cells
     )
 
     # one total order over EVERY collective this stage issues, carried

@@ -195,7 +195,7 @@ def sparse_need_matrix(x, y, z, h, keys, box, nbr, P: int):
     telemetry ``shard_rows`` (exchange.exchange_metrics_sparse) must
     equal this matrix's off-diagonal row sums on an undrifted state
     (pinned by tests/test_parallel.py)."""
-    from sphexa_tpu.parallel.exchange import _cells_of_runs, _sparse_layout
+    from sphexa_tpu.parallel.exchange import _sparse_layout, coverage_from_runs
     from sphexa_tpu.sph.pallas_pairs import group_cell_ranges
 
     n = x.shape[0]
@@ -215,27 +215,19 @@ def sparse_need_matrix(x, y, z, h, keys, box, nbr, P: int):
     # per-SHARD group windows: the in-step prologue forms groups within
     # each slab (rows restart at k*S), so sizing over global group
     # boundaries would measure different bboxes whenever S % group != 0
-    # and could under-size a cap with zero drift
+    # and could under-size a cap with zero drift. Coverage is the in-step
+    # stage's own function of the prologue's own products (the runs and
+    # the cells they carry), so the two cannot drift apart
+    def need_of_shard(xk, yk, zk, hk):
+        ranges, cells = group_cell_ranges(xk, yk, zk, hk, None, box, nbr,
+                                          table=table, with_cells=True)
+        covered = coverage_from_runs(ranges.starts, ranges.lens, table, cells)
+        return _sparse_layout(covered, table, S, P)[2]
+
     shard = lambda a: a.reshape(P, S)
-    ranges = jax.vmap(
-        lambda a, b, c, d: group_cell_ranges(a, b, c, d, None, box, nbr,
-                                             table=table)
-    )(shard(xs), shard(ys), shard(zs), shard(hs))
-    starts, lens = ranges.starts, ranges.lens  # (P, NG_s, W3)
-
-    c0, c1 = _cells_of_runs(starts, lens, table)
-    active = (lens > 0).astype(jnp.int32)
-    dest = jnp.broadcast_to(
-        jnp.arange(P, dtype=jnp.int32)[:, None, None], starts.shape
-    )
-    diff = jnp.zeros((P, ncells + 1), jnp.int32)
-    diff = diff.at[dest.ravel(), c0.ravel()].add(active.ravel())
-    diff = diff.at[dest.ravel(), c1.ravel() + 1].add(-active.ravel())
-    covered = jnp.cumsum(diff, axis=1)[:, :ncells] > 0  # (P_dest, ncells)
-
-    return jax.vmap(
-        lambda cov: _sparse_layout(cov, table, S, P)[2]
-    )(covered)  # (P_dest, P_src)
+    return jax.vmap(need_of_shard)(
+        shard(xs), shard(ys), shard(zs), shard(hs)
+    )  # (P_dest, P_src)
 
 
 @functools.partial(jax.jit, static_argnames=("nbr", "P"))

@@ -581,7 +581,8 @@ def _shard_metrics(ranges, escaped, metrics, axis: str, token=None):
 
 def _std_forces_sharded(state, box, cfg: PropagatorConfig, keys):
     """std pair-op stage under shard_map: per-device Mosaic kernels on the
-    device's SFC slab, halos via the windowed all_to_all exchange.
+    device's SFC slab, halos via the stage ``_halo_stage_fn`` chooses
+    (sparse ppermute rounds by default, per-peer windows as the fallback).
 
     The arrays arrive GLOBALLY sorted and slab-sharded (the sort is the
     domain redistribution, parallel/mesh.py). The shared prologue runs on

@@ -124,8 +124,8 @@ def engine_fold(box: Box, cfg: NeighborConfig) -> bool:
 @named_phase("neighbors")
 def group_cell_ranges(
     x, y, z, h, sorted_keys, box: Box, cfg: NeighborConfig,
-    table=None, radius_pad=0.0,
-) -> GroupRanges:
+    table=None, radius_pad=0.0, with_cells: bool = False,
+):
     """Candidate cells of every group, culled and compacted.
 
     Vectorized over all groups (the jax-side prologue all pair ops
@@ -143,6 +143,16 @@ def group_cell_ranges(
     the returned ranges are then global rows of the distributed array.
     When given, ``sorted_keys`` may be None (the deep-grid searchsorted
     fallback needs keys and is unavailable).
+
+    ``with_cells`` (static; set by the callers that map runs back onto
+    the cell grid — the sparse halo stage and its sizing — never a user
+    option): also return ``(c0, c1)``, (NG, W3) int32 grid-cell indices of
+    each compacted run's first and last kept cell, 0 on dead slots. The
+    cell index is in hand when the table is read, and rides the sorts and
+    scans below as payload, so nobody has to search the table for it
+    again (exchange._cells_of_runs). Returns ``(GroupRanges, (c0, c1))``
+    then, a plain GroupRanges otherwise: without it the prologue lowers
+    exactly as it did before the payloads existed.
     """
     n = x.shape[0]
     level = cfg.level
@@ -245,17 +255,19 @@ def group_cell_ranges(
         img = jnp.floor_divide(cells, ncell).astype(jnp.float32)  # (NG, W3, 3)
         shifts = img * box.lengths[None, None, :]
 
+    cell = ckey.astype(jnp.int32) if with_cells else None
     if cfg.run_cap > 0:
         # merge SFC-adjacent survivors into long streamed runs (fewer,
         # fuller chunks; see _merge_runs)
-        starts_c, lens_c, sh, ncells = _merge_runs(
-            start, lens, keep, shifts, cfg.run_cap, cfg.gap
+        starts_c, lens_c, sh, ncells, run_cells = _merge_runs(
+            start, lens, keep, shifts, cfg.run_cap, cfg.gap, cell=cell
         )
     else:
         # compact survivors to the front (stable: preserves SFC cell order)
-        _, kc_i, starts_c, lens_s, shx_c, shy_c, shz_c = jax.lax.sort(
+        _, kc_i, starts_c, lens_s, shx_c, shy_c, shz_c, *cell_c = jax.lax.sort(
             ((~keep).astype(jnp.int32), keep.astype(jnp.int32), start, lens,
-             shifts[..., 0], shifts[..., 1], shifts[..., 2]),
+             shifts[..., 0], shifts[..., 1], shifts[..., 2])
+            + (() if cell is None else (cell,)),
             num_keys=1, dimension=1, is_stable=True,
         )
         keep_c = kc_i.astype(bool)
@@ -264,6 +276,8 @@ def group_cell_ranges(
         starts_c = jnp.where(keep_c, starts_c, 0)
         sh = [jnp.where(keep_c, a, 0.0) for a in (shx_c, shy_c, shz_c)]
         ncells = jnp.sum(keep, axis=1).astype(jnp.int32)
+        # an unmerged run IS one cell: first == last
+        run_cells = tuple(jnp.where(keep_c, c, 0) for c in cell_c) * 2
 
     # cap overflow only matters for cells the kernel will visit: a culled
     # cell's clipped length truncates nothing
@@ -277,14 +291,16 @@ def group_cell_ranges(
     # fold is a no-op there (only consumed in fold mode)
     boxl = jnp.where(box.periodic_mask, box.lengths, jnp.float32(1e30))
 
-    return GroupRanges(
+    ranges = GroupRanges(
         starts=starts_c, lens=lens_c,
         shift_x=sh[0], shift_y=sh[1], shift_z=sh[2],
         ncells=ncells, occupancy=occupancy, boxl=boxl.astype(jnp.float32),
     )
+    return (ranges, run_cells) if with_cells else ranges
 
 
-def _merge_runs(start, lens, keep, shifts, run_cap: int, gap: int):
+def _merge_runs(start, lens, keep, shifts, run_cap: int, gap: int,
+                cell=None):
     """Merge kept cells into contiguous streamed RUNS per group.
 
     The SFC sort makes spatially adjacent cells often key-adjacent, so
@@ -301,21 +317,30 @@ def _merge_runs(start, lens, keep, shifts, run_cap: int, gap: int):
     images and are clipped to ``run_cap`` slots (the engine's static DMA
     window, NeighborConfig.dma_cap).
 
-    Returns (starts, lens, [shift_x, shift_y, shift_z], nruns), shaped
-    like the unmerged compaction.
+    ``cell``: optional (ng, w3) int32 index of every slot's cell in the
+    table the starts were read from (ascending with ``start`` over kept
+    cells). When given, each run's first and last kept cell ride along as
+    two more int32 payloads of the sorts and the reverse scan.
+
+    Returns (starts, lens, [shift_x, shift_y, shift_z], nruns, cells),
+    shaped like the unmerged compaction; ``cells`` is ``(c0, c1)`` (0 on
+    dead slots), or ``()`` without ``cell``.
     """
     ng, w3 = start.shape
     INF = jnp.int32(2**30)
     # variadic sort carries every payload through the sorting network —
     # argsort + take_along_axis would pay ~6 full-array gathers instead
-    _, s, l, ki, shx, shy, shz = jax.lax.sort(
+    _, s, l, ki, shx, shy, shz, *c = jax.lax.sort(
         (jnp.where(keep, start, INF), start, lens, keep.astype(jnp.int32),
-         shifts[..., 0], shifts[..., 1], shifts[..., 2]),
+         shifts[..., 0], shifts[..., 1], shifts[..., 2])
+        + (() if cell is None else (cell,)),
         num_keys=1, dimension=1,
     )
     k = ki.astype(bool)
-    # unkept tail entries must not extend any run's end
-    end_eff = jnp.where(k, s + l, -1)
+    # unkept tail entries must not extend any run's end (nor its last
+    # cell: the table is monotone, so the maximum end and the maximum
+    # cell index of a run belong to the same kept cell)
+    tails = [jnp.where(k, a, -1) for a in [s + l] + c]
 
     # forward scan: mark run heads (kept cells that cannot join the
     # running span: image mismatch, gap too wide, or span over run_cap)
@@ -356,18 +381,23 @@ def _merge_runs(start, lens, keep, shifts, run_cap: int, gap: int):
         [is_head[:, 1:], jnp.ones((ng, 1), bool)], axis=1
     )
     def rstep(carry, inp):
-        e_w, hn_w = inp
-        r = jnp.maximum(e_w, jnp.where(hn_w, jnp.int32(-1), carry))
+        hn_w = inp[-1]
+        r = tuple(
+            jnp.maximum(t_w, jnp.where(hn_w, jnp.int32(-1), t_c))
+            for t_w, t_c in zip(inp[:-1], carry)
+        )
         return r, r
 
-    xs_r = (end_eff[:, ::-1].T, head_next[:, ::-1].T)
-    _, r_t = jax.lax.scan(rstep, jnp.full_like(end_eff[:, 0], -1), xs_r)
-    run_end = r_t.T[:, ::-1]
+    xs_r = tuple(a[:, ::-1].T for a in tails + [head_next])
+    _, r_t = jax.lax.scan(
+        rstep, tuple(jnp.full_like(a[:, 0], -1) for a in tails), xs_r
+    )
+    run_end, *run_c1 = (a.T[:, ::-1] for a in r_t)
 
     # compact heads to the front (stable: preserves key order)
-    _, hk_i, hs_r, hlen, cshx, cshy, cshz = jax.lax.sort(
+    _, hk_i, hs_r, hlen, cshx, cshy, cshz, *hc = jax.lax.sort(
         ((~is_head).astype(jnp.int32), is_head.astype(jnp.int32), s,
-         run_end - s, shx, shy, shz),
+         run_end - s, shx, shy, shz, *c, *run_c1),
         num_keys=1, dimension=1, is_stable=True,
     )
     hk = hk_i.astype(bool)
@@ -375,7 +405,7 @@ def _merge_runs(start, lens, keep, shifts, run_cap: int, gap: int):
     hl = jnp.where(hk, hlen, 0)
     sh = [jnp.where(hk, a, 0.0) for a in (cshx, cshy, cshz)]
     nruns = jnp.sum(is_head, axis=1).astype(jnp.int32)
-    return hs, hl, sh, nruns
+    return hs, hl, sh, nruns, tuple(jnp.where(hk, a, 0) for a in hc)
 
 
 def _round_up(v: int, q: int) -> int:
