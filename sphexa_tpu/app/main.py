@@ -21,7 +21,9 @@ def build_parser() -> argparse.ArgumentParser:
         prog="sphexa-tpu",
         description="TPU-native SPH simulation (Sedov, Noh, ... test cases)",
     )
-    p.add_argument("--init", default="sedov", help="test case name (sedov, ...)")
+    p.add_argument("--init", default="sedov",
+                   help="test case name (sedov, ...), case:settings.json, "
+                        "case+capability, or a snapshot file")
     p.add_argument("-n", type=int, default=50, dest="side",
                    help="particles per cube side (N = n^3)")
     p.add_argument("-s", type=float, default=10, dest="stop",
@@ -218,7 +220,11 @@ def main(argv=None) -> int:
     log = (lambda *a, **k: None) if args.quiet else print
     # 'case:settings.json' selects the case with overrides; observables key
     # on the bare case name (with the overrides applied to their thresholds)
-    case_name, settings_path = split_case_spec(args.init)
+    try:
+        case_name, settings_path = split_case_spec(args.init)
+    except ValueError as e:  # a capability this program lacks
+        print(e, file=sys.stderr)
+        return 2
     case_overrides = None
     if settings_path is not None:
         import json

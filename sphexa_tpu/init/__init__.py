@@ -45,15 +45,29 @@ CASES: Dict[str, Callable] = {
 }
 
 
+#: what a run spec may ask of the program by name ('noh+list-lifecycle'):
+#: a program from before the capability refuses the spec where it parses
+#: it, instead of failing deep in a run. ``list-lifecycle`` (PR 25): pair
+#: lists sized for the relaxed h and rebuilt with the outgoing list let go
+#: first; without it Noh at -n 128 runs out of device memory in the
+#: rebuild of its first rolled-back window.
+CAPABILITIES = frozenset({"list-lifecycle"})
+
+
 def split_case_spec(name: str):
-    """'case:settings.json' -> (case, settings_path); otherwise (name, None).
-    SINGLE source of the spec grammar — main.py keys observables/dump
-    metadata on the same parse."""
-    if ":" in name:
-        case, _, settings_path = name.partition(":")
-        if case in CASES:
-            return case, settings_path
-    return name, None
+    """'case[+need...][:settings.json]' -> (case, settings_path or None);
+    anything else -> (name, None). SINGLE source of the spec grammar —
+    main.py keys observables/dump metadata on the same parse. A ``need``
+    this program lacks (CAPABILITIES) raises ValueError."""
+    head, sep, settings_path = name.partition(":")
+    case, *needs = head.split("+")
+    if case not in CASES:
+        return name, None
+    missing = sorted(set(needs) - CAPABILITIES)
+    if missing:
+        raise ValueError(f"run spec '{name}' needs {missing}; this program "
+                         f"has {sorted(CAPABILITIES)}")
+    return case, settings_path if sep else None
 
 
 def make_initializer(name: str) -> Callable:
@@ -62,12 +76,11 @@ def make_initializer(name: str) -> Callable:
 
     ``case:settings.json`` appends a JSON settings file whose keys override
     the case defaults (the reference's ``--init sedov:my_settings`` path,
-    factory.hpp:47-48).
+    factory.hpp:47-48); ``case+need`` asks for a program capability.
     """
-    if name in CASES:
-        return CASES[name]
-
     case, settings_path = split_case_spec(name)
+    if case in CASES and settings_path is None:
+        return CASES[case]
     if settings_path is not None:
         import json
 
@@ -103,6 +116,7 @@ def make_initializer(name: str) -> Callable:
 
 
 __all__ = [
+    "CAPABILITIES",
     "CASES",
     "make_initializer",
     "split_case_spec",

@@ -12,6 +12,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from sphexa_tpu.init import split_case_spec
 from sphexa_tpu.init.wind_shock import wind_shock_constants
 from sphexa_tpu.observables.extras import (
     kh_growth_rate,
@@ -89,6 +90,7 @@ def make_observable(case: str, overrides: Optional[Dict[str, float]] = None):
     'kelvin-helmholtz', 'wind-shock', 'turbulence'). ``overrides`` are the
     case's settings-file overrides, so threshold-bearing observables match
     the actual setup."""
+    case = split_case_spec(case)[0]  # callers may hand the whole run spec
     if case == "kelvin-helmholtz":
         return TimeEnergyGrowth()
     if case == "wind-shock":

@@ -878,6 +878,11 @@ def _std_forces(
             rho, c, gdiag, aux)
 
 
+#: the std force stage under its public name: what evaluates it outside a
+#: step (a force check against a plain reference) imports
+std_forces = _std_forces
+
+
 def _step_hydro_std(
     state: ParticleState, box: Box, cfg: PropagatorConfig,
     gtree: Optional[GravityTree] = None, lists=None,

@@ -102,6 +102,7 @@ def make_propagator_config(
     use_lists: bool = False,
     list_skin_rel: Optional[float] = None,
     list_slot_margin: float = 1.3,
+    h_relax: float = 1.0,
     sizing_cache=None,
     obs_spec=None,
     snap_spec=None,
@@ -132,6 +133,12 @@ def make_propagator_config(
     ``sizing_cache``: optional precomputed (keys, order) device arrays
     for the device_sizing path, so a caller that also needs keys (the
     gravity reconfigure) computes them once.
+
+    ``h_relax`` (>= 1): how far ``h`` still is from the fixed point of
+    its update (kernels.h_fixed_point, read by the driver from a
+    verified step). Persistent lists outlive many steps, so their
+    window and slot budget are sized for ``h * h_relax``; 1 sizes for
+    ``h`` as it stands.
     """
     backend = resolve_backend(backend)
     # tuned knob resolution (docs/TUNING.md): the engine knobs default to
@@ -212,14 +219,23 @@ def make_propagator_config(
             cap = max(cap, pad_cap(min_cap))  # quantized so retry caps cache
         ncell = 1 << level
         ext = native.group_extents(xa, ya, za, order, group)
+    # The Mosaic engine only CLIPS a cell's run at ``cap``: every buffer it
+    # allocates is sized by NeighborConfig.dma_cap = max(cap, run_cap), so
+    # a cap under run_cap bounds nothing, and outgrowing it re-sizes into
+    # identical shapes (a recompile for no new buffer: Noh's central cells
+    # gain 4 % a step). A mesh clamps run_cap to the slab AFTER this
+    # sizing (parallel/sizing.py), so there the cap stays as measured.
+    if backend == "pallas" and not device_sizing:
+        cap = max(cap, run_cap)
+
     # 10% radius slack absorbs drift between reconfigurations; a whole
     # margin cell costs ~2x window cells (every cell is a kernel iteration),
     # and the window_ok guard reconfigures if the slack is ever outgrown.
-    def size_window(radius):
+    def size_window(radius, margin_cells=0):
         w = 1
         for e, edge in zip(ext, lengths / ncell):
             w = max(w, window_cells(e, radius, float(edge), ncell,
-                                    margin_cells=0))
+                                    margin_cells=margin_cells))
         return w
 
     def make_nbr(window):
@@ -231,7 +247,6 @@ def make_propagator_config(
 
     nbr = make_nbr(size_window(4.0 * h_max * 1.1))
     slot_cap = 0
-    skin = list_skin_rel * 2.0 * h_max
     if use_lists and backend == "pallas" and not device_sizing:
         from sphexa_tpu.sph.pair_lists import estimate_slot_cap
         from sphexa_tpu.sph.pallas_pairs import engine_fold
@@ -241,8 +256,24 @@ def make_propagator_config(
         if not engine_fold(box, nbr):
             import jax.numpy as _jnp
 
-            # in list mode the window must additionally cover the skin
-            nbr = make_nbr(size_window((4.0 * h_max + skin) * 1.1))
+            # a list is built once and walked for many steps, so it is
+            # sized for the h its particles are relaxing to (h_relax),
+            # and its window covers what the build inflates a group's
+            # bbox by: 2h + skin on EACH side
+            h_to = h_max * h_relax
+            skin = list_skin_rel * 2.0 * h_to
+            # Where no dimension is periodic the particles need not tile
+            # the grid: SFC-consecutive groups straddle stretches of the
+            # curve that lie outside them, and their extent moves with
+            # the flow across the fixed grid (Noh's sphere: the largest
+            # goes 0.20 -> 0.29 of the box in 30 steps), which no slack
+            # on the radius covers: one margin cell. Only the build's
+            # prologue walks a list's window (culled cells reach no
+            # kernel), so list mode pays nothing per step for it.
+            open_box = not any(b == BoundaryType.periodic
+                               for b in box.boundaries)
+            nbr = make_nbr(size_window((4.0 * h_to + 2.0 * skin) * 1.1,
+                                       margin_cells=int(open_box)))
             if engine_fold(box, nbr):
                 nbr = make_nbr(size_window(4.0 * h_max * 1.1))
             else:
@@ -252,7 +283,8 @@ def make_propagator_config(
                 skeys = _jnp.asarray(keys[order])
                 slot_cap = estimate_slot_cap(
                     _jnp.asarray(xa[order]), _jnp.asarray(ya[order]),
-                    _jnp.asarray(za[order]), _jnp.asarray(h[order]),
+                    _jnp.asarray(za[order]),
+                    _jnp.asarray(h[order] * np.float32(h_relax)),
                     skeys, box, nbr, skin, margin=list_slot_margin,
                 )
     return PropagatorConfig(
@@ -679,7 +711,20 @@ class Simulation:
         self._want_lists = use_lists
         self._list_skin_rel = list_skin_rel
         self._lists = None
+        # iteration at which the live (or last dropped) list was built;
+        # None until the run's first build
+        self._lists_built_it = None
+        # why the next launch finds no list: the run's first, a
+        # reconfigure dropped it, or a build that raised is retried
+        self._list_reason = "first"
         self._slot_margin = 1.3
+        # list mode sizes for the h its particles are relaxing to:
+        # (max h at the last configure, not yet checked against a
+        # verified step | None), the max h that configure sized for, and
+        # the newest estimate of fixed point / h (_lists_cover_h)
+        self._h_configured = None
+        self._h_sized = None
+        self._h_relax = 1.0
         self.iteration = 0
         # deferred cap-checking (check_every > 1): the happy path launches
         # steps without any device->host sync; diagnostics of the last
@@ -726,6 +771,12 @@ class Simulation:
                              engine=self._engine_facts())
 
     @property
+    def pair_lists(self):
+        """The live persistent pair lists the next step would walk, or
+        None: lists off, or dropped and not yet rebuilt."""
+        return self._lists if self._use_lists else None
+
+    @property
     def active_cfg(self) -> PropagatorConfig:
         """The config the launched step runs under: on a mesh the
         sharded stepper's (mesh, shard axis, sized halo caps), else the
@@ -758,6 +809,8 @@ class Simulation:
 
     def _configure_impl(self, min_cap: int = 0, grav_margin: float = 1.5):
         self._lists = None  # any static re-size invalidates the lists
+        if self._lists_built_it is not None:
+            self._list_reason = "reconfigure"
         if self._mesh is not None:
             # drain in-flight steps before dispatching the sizing jits:
             # those jits contain their own collectives, and on CPU meshes
@@ -802,6 +855,7 @@ class Simulation:
                 use_lists=self._lists_eligible,
                 list_skin_rel=self._list_skin_rel,
                 list_slot_margin=self._slot_margin,
+                h_relax=self._h_relax,
                 sizing_cache=sizing_cache[:2] if sizing_cache else None,
                 obs_spec=self._obs_spec,
                 snap_spec=self._snap_spec,
@@ -812,6 +866,9 @@ class Simulation:
                 # defaults
                 **self._nbr_knobs,
             )
+        if self._use_lists:
+            self._h_configured = float(jnp.max(self.state.h))
+            self._h_sized = self._h_configured * self._h_relax
         if self.gravity_on:
             with self.telemetry.span("sphexa:size-gravity"):
                 self._configure_gravity(grav_margin, keys_cache=sizing_cache)
@@ -1063,6 +1120,27 @@ class Simulation:
         cell_edge = float(np.min(np.asarray(self.box.lengths))) / (1 << nbr.level)
         return 2.0 * h_max <= cell_edge
 
+    def _lists_cover_h(self, first, last) -> bool:
+        """List mode, once after each configure: do the lists' window and
+        slot budget still cover the h the particles are heading for?
+
+        A configure sizes for the h it sees; the first verified step
+        after it (``first``; ``last`` ends the same verified stretch) is
+        the earliest the driver can know how far that h is from the
+        fixed point of its update (kernels.h_fixed_point, on the h_max
+        both fetches already carry). Past the sizing's 10 % radius slack
+        the lists would outgrow their caps rebuild by rebuild — an IC
+        whose rim has half its neighbours relaxes by 30 % — so the
+        driver re-sizes now, once, for where h is going."""
+        h0, self._h_configured = self._h_configured, None
+        if h0 is None or not self._use_lists:
+            return True
+        from sphexa_tpu.sph.kernels import h_fixed_point
+
+        h_to = h_fixed_point(h0, float(first["h_max"]))
+        self._h_relax = max(1.0, h_to / float(last["h_max"]))
+        return h_to <= 1.1 * self._h_sized
+
     @property
     def _use_lists(self) -> bool:
         # slot_cap == 0 also covers the fold-mode grids where lists are
@@ -1075,39 +1153,66 @@ class Simulation:
     _LIST_SLACK_REBUILD = 0.25
 
     def _maybe_rebuild_lists(self, diagnostics):
-        if self._use_lists and (
-            float(diagnostics.get("list_slack", 1.0))
-            < self._LIST_SLACK_REBUILD
-        ):
-            self._rebuild_lists()
+        slack = float(diagnostics.get("list_slack", 1.0))
+        if self._use_lists and slack < self._LIST_SLACK_REBUILD:
+            self._rebuild_lists("proactive", slack=slack)
 
-    def _rebuild_lists(self):
+    def _rebuild_lists(self, reason: str, slack: Optional[float] = None,
+                       served_to: Optional[int] = None):
         """(Re)build the persistent lists: one jitted sort + mark pass.
         Replaces the per-step rebuild the reference does
         (find_neighbors.cuh) — between rebuilds the steady steps run on
         the frozen order. A slot-cap overflow re-sizes the static budget
-        (recompile) and retries, like every other cap."""
+        (recompile) and retries, like every other cap.
+
+        One ``rebuild_lists`` event per call that built a list, with the
+        WHY: ``reason`` (first | proactive | expiry | rollback |
+        reconfigure), ``age_steps`` (verified steps the outgoing list
+        served, counted to ``served_to``, default the current
+        iteration), ``slack`` (the fetched ``list_slack`` that triggered
+        it, None where none did), ``slot_need``/``slot_cap`` and
+        ``attempts``. All of it is host state or rides the overflow
+        fetch the rebuild always made."""
         import jax as _jax
 
         from sphexa_tpu.propagator import rebuild_pair_lists
 
-        self.telemetry.event("rebuild_lists", it=self.iteration)
-        for _ in range(3):
+        self._list_reason = reason  # a build that raises retries as itself
+        if served_to is None:
+            served_to = self.iteration
+        age = (0 if self._lists_built_it is None
+               else served_to - self._lists_built_it)
+        for attempt in range(1, 4):
             if not self._use_lists:
                 # a reconfigure flipped the grid into fold mode or left
                 # list_slot_cap == 0: fall back to per-step streaming
-                # (self._lists stays None; steps run with lists=None)
+                # (self._lists stays None; steps run with lists=None,
+                # nothing was built and no event says otherwise)
                 return
             aux = self.chem if self.prop_name == "std-cooling" else None
+            # the outgoing list is dead weight from here on: let go of it
+            # before the build allocates its successor (at 1.1M Noh a
+            # list is 1.8-3.6 GiB, and a rollback's rebuild beside the
+            # old one and the pin ran a 16 GB chip out of memory)
+            self._lists = None
             with self.telemetry.span("sphexa:rebuild-lists"):
                 state, box, lists, aux = rebuild_pair_lists(
                     self.state, self.box, self._cfg, aux
                 )
-                overflow = int(_jax.device_get(lists.overflow))
+                overflow, need = (int(v) for v in _jax.device_get(
+                    (lists.overflow, lists.slot_need)))
             if not overflow:
                 self.state, self.box, self._lists = state, box, lists
                 if aux is not None:
                     self.chem = aux
+                self._lists_built_it = self.iteration
+                self.telemetry.event(
+                    "rebuild_lists", it=self.iteration, reason=reason,
+                    age_steps=age,
+                    slack=None if slack is None else round(slack, 6),
+                    slot_need=need, slot_cap=self._cfg.list_slot_cap,
+                    attempts=attempt,
+                )
                 return
             self._slot_margin *= 1.5
             self._configure(reason="list-slot")
@@ -1315,7 +1420,7 @@ class Simulation:
         kw = {}
         if self._use_lists:
             if self._lists is None:
-                self._rebuild_lists()
+                self._rebuild_lists(self._list_reason)
             kw["lists"] = self._lists
         aux_cfg = (self.turb_cfg if self.prop_name == "turb-ve"
                    else self.cooling_cfg if self.prop_name == "std-cooling"
@@ -1699,28 +1804,52 @@ class Simulation:
             grav_margin=grav_margin, reason="overflow",
         )
 
-    def _step_checked(self) -> Dict[str, float]:
+    def _step_checked(self, replay: bool = False) -> Dict[str, float]:
         """Advance one step synchronously; a step whose own diagnostics
         reveal a cell-cap overflow (truncated neighbor candidates) is
         discarded and re-run under a freshly sized config — overflow must
-        never corrupt state."""
-        with self.telemetry.span("sphexa:step"):
-            return self._step_checked_impl()
+        never corrupt state.
 
-    def _step_checked_impl(self) -> Dict[str, float]:
+        ``replay``: a rolled-back window's step. Where the window's
+        launches donate, the replay launches the same donated program
+        over a pinned copy: the undonated twin is a second executable
+        nothing has compiled until the run's first rollback, and a
+        recovery is no place for a first-use compile."""
+        with self.telemetry.span("sphexa:step"):
+            return self._step_checked_impl(replay and self._donate_active)
+
+    def _pin(self) -> SimState:
+        """The current carry, safe from a donated launch: with donation
+        active the launch CONSUMES self.state, so the particle slab is a
+        real copy. Aux slots (turb/chem/_bstate) are never donated and
+        ride by reference."""
+        with self.telemetry.span("sphexa:pin",
+                                 copied=bool(self._donate_active)):
+            pin = self.state
+            if self._donate_active:
+                pin = jax.tree.map(jnp.copy, self.state)
+            return dataclasses.replace(self.sim_state, particles=pin)
+
+    def _step_checked_impl(self, donate: bool = False) -> Dict[str, float]:
         reconfigured = False
         grav_margin = 1.5
         grav_blown_once = False
         t0 = time.perf_counter()
         for _attempt in range(4):
-            out = self._launch()
+            pin = self._pin() if donate else None
+            out = self._launch(donate_ok=donate)
             with self.telemetry.span("sphexa:fetch"):
                 diagnostics = {**out[1], **self._fetch_scalars(out[1])}
             if not self._overflowed(diagnostics):
                 break
+            if pin is not None:
+                # the discarded launch consumed its input: the retry
+                # (and the sizing before it) reads the pinned copy
+                self._set_sim_state(pin)
             if not self._lists_fresh(diagnostics):
                 # stale persistent lists: discard + rebuild (no re-size)
-                self._rebuild_lists()
+                self._rebuild_lists(
+                    "expiry", slack=float(diagnostics["list_slack"]))
                 continue
             if self._grav_window_blown(diagnostics):
                 # escaped sparse near-field runs (the cap+1 sentinel):
@@ -1753,6 +1882,9 @@ class Simulation:
             # config check FIRST: _configure() drops self._lists, so a
             # proactive rebuild before it would be wasted work
             self._configure(reason="stale-grid")
+            reconfigured = True
+        elif not self._lists_cover_h(diagnostics, diagnostics):
+            self._configure(reason="h-relax")
             reconfigured = True
         else:
             self._maybe_rebuild_lists(diagnostics)
@@ -1811,17 +1943,7 @@ class Simulation:
             # With donation active the window's first launch CONSUMES
             # self.state, so the pin must be a real copy — one copy per
             # window, amortized over check_every donated steps
-            with self.telemetry.span("sphexa:pin",
-                                     copied=bool(self._donate_active)):
-                pin = self.state
-                if self._donate_active:
-                    pin = jax.tree.map(jnp.copy, self.state)
-                # aux slots (turb/chem/_bstate) are never donated, so the
-                # carry pin holds them by reference around the copied slab
-                self._window_prior = (
-                    dataclasses.replace(self.sim_state, particles=pin),
-                    self.iteration,
-                )
+            self._window_prior = (self._pin(), self.iteration)
         out = self._launch(donate_ok=True)
         self._apply(out)
         self.iteration += 1
@@ -1894,6 +2016,9 @@ class Simulation:
         if not self._config_still_valid(fetched[-1]):
             self._configure(reason="stale-grid")
             self._last_diag["reconfigured"] = 1.0
+        elif not self._lists_cover_h(fetched[0], fetched[-1]):
+            self._configure(reason="h-relax")
+            self._last_diag["reconfigured"] = 1.0
         else:
             self._maybe_rebuild_lists(fetched[-1])
         return self._last_diag
@@ -1916,8 +2041,11 @@ class Simulation:
         self._set_sim_state(prior[0])
         self.iteration = prior[1]
         if expiry_only:
-            # expiry only: fresh lists on the rolled-back state suffice
-            self._rebuild_lists()
+            # expiry only: fresh lists on the rolled-back state suffice;
+            # the old list served the window's steps before the bad one
+            self._rebuild_lists(
+                "rollback", slack=float(diag_bad["list_slack"]),
+                served_to=prior[1] + bad)
         else:
             grav_margin = 1.5
             if self._grav_window_blown(diag_bad):
@@ -1931,7 +2059,7 @@ class Simulation:
                 grav_margin = 1.5 * 1.5
             self._reconfigure_after_overflow(diag_bad, grav_margin)
         for _ in range(len(pending)):
-            result = self._step_checked()
+            result = self._step_checked(replay=True)
         self.telemetry.event("replay", it=self.iteration, steps=len(pending))
         result["reconfigured"] = 1.0
         self._last_diag = result

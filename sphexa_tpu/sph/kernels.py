@@ -190,10 +190,28 @@ def ts_k_courant(maxvsignal, h, c, k_cour):
     return k_cour * h / v
 
 
+_H_C0 = 1023.0
+_H_EXP = 0.1
+
+
 def update_h(ng0: int, nc, h):
     """Nudge h so the neighbor count drifts toward ng0 (kernels.hpp:18-32).
 
     nc includes the particle itself, like the reference's usage.
     """
-    c0 = 1023.0
-    return h * 0.5 * (1.0 + c0 * ng0 / jnp.maximum(nc, 1)) ** 0.1
+    return h * 0.5 * (1.0 + _H_C0 * ng0 / jnp.maximum(nc, 1)) ** _H_EXP
+
+
+def h_fixed_point(h_before: float, h_after: float) -> float:
+    """Where one particle's ``update_h`` is heading, read back from one
+    application of it (host arithmetic on two fetched scalars).
+
+    ``update_h`` is a function of ``nc / ng0`` alone, so the step's
+    growth ``g = h_after / h_before`` gives ``ng0 / nc = ((2g)^(1/exp)
+    - 1) / c0``, and the neighbour count scales with h^3: the update
+    stops moving at ``h_before * cbrt(ng0 / nc)``. ``g = 1`` returns
+    ``h_before``. An estimate, not a bound: ``nc`` is a step function of
+    ``h`` on a lattice."""
+    g = h_after / h_before
+    ratio = ((2.0 * g) ** (1.0 / _H_EXP) - 1.0) / _H_C0
+    return h_before * max(ratio, 0.0) ** (1.0 / 3.0)

@@ -64,6 +64,9 @@ class PairLists(NamedTuple):
     #                       128-lane staging chunk
     tail: jax.Array       # (NG,) int32 — flush lanes after the last chunk
     overflow: jax.Array   # () int32 — 1 if any group needed > S_cap slots
+    slot_need: jax.Array  # () int32 — most chunk slots any group needed
+    #                       (overflow = slot_need > S_cap; the rebuild's
+    #                       event reports it beside the cap)
     lanes_total: jax.Array  # () int64-ish f32 — sum of cnt (diagnostics)
     xb: jax.Array         # build positions + smoothing lengths: the
     yb: jax.Array         # validity reduction compares current state
@@ -369,7 +372,8 @@ def build_pair_lists(
     fill = excl % 128
     emit = ((fill + cnt) >= 128).astype(jnp.int32)
     tail = csum[:, -1] % 128
-    overflow = jnp.max(total).astype(jnp.int32) > slot_cap
+    slot_need = jnp.max(total).astype(jnp.int32)
+    overflow = slot_need > slot_cap
 
     # PRE-ROTATED compaction indices in ONE batched 128-wide sort: lane
     # l's destination slot is (fill + rank-among-selected) % 128 when
@@ -392,6 +396,7 @@ def build_pair_lists(
     return PairLists(
         ranges=ranges, gidx=rot, cnt=cnt, fill=fill, emit=emit,
         tail=tail, overflow=overflow.astype(jnp.int32),
+        slot_need=slot_need,
         lanes_total=jnp.sum(csum[:, -1].astype(jnp.float32)),
         xb=x, yb=y, zb=z, hb=h,
         skin=jnp.asarray(skin, jnp.float32),

@@ -35,12 +35,17 @@ import numpy as np
 #: strictly clean and v7 readers count ``snapshot`` under unknown_kinds;
 #: v9 the host-span kind (span): ``Telemetry.span`` times the driver's
 #: and the dump's host work where it happens, on ``perf_counter_ns`` and
-#: in the profiler capture under the same name. v9 only ADDS a kind too.
-SCHEMA_VERSION = 9
+#: in the profiler capture under the same name. v9 only ADDS a kind too;
+#: v10 the optional WHY payload on ``rebuild_lists`` (``reason``,
+#: ``age_steps``, ``slack``, ``slot_need``, ``slot_cap``, ``attempts``),
+#: and the event is emitted once per rebuild that BUILT a list (before
+#: v10: once per call, built or not). Like v7 it adds no kind and no
+#: REQUIRED field, so v10 readers accept v1-v9 files strictly clean.
+SCHEMA_VERSION = 10
 
 #: event schema versions this reader understands (older versions only
 #: ever ADD kinds, so the per-kind field table below covers them all)
-SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9)
+SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10)
 
 #: every event kind the schema admits, with its required payload fields
 #: (beyond the envelope ``v``/``seq``/``t``/``kind``). The CLI's --strict
@@ -53,6 +58,8 @@ EVENT_KINDS: Dict[str, tuple] = {
     "rollback": ("it", "steps", "reason"),
     "replay": ("it", "steps"),
     "retrace": ("it", "delta"),   # jit cache grew on a launch (recompile)
+    # persistent pair lists (re)built; since v10 with the optional WHY
+    # payload: reason, age_steps, slack, slot_need, slot_cap, attempts
     "rebuild_lists": ("it",),
     "phases": ("it",),            # per-iteration host phase laps
     "trace": ("dir",),            # jax.profiler trace started

@@ -15,6 +15,7 @@ from sphexa_tpu.init import (
     init_noh,
     init_wind_shock,
     make_initializer,
+    split_case_spec,
 )
 from sphexa_tpu.sfc.box import BoundaryType
 from sphexa_tpu.simulation import Simulation
@@ -34,6 +35,48 @@ class TestFactory:
     def test_unknown_case_raises(self):
         with pytest.raises(ValueError):
             make_initializer("nope")
+
+    @pytest.mark.parametrize("spec,case,settings", [
+        ("noh", "noh", None),
+        ("noh+list-lifecycle", "noh", None),
+        ("sedov+list-lifecycle:s.json", "sedov", "s.json"),
+        ("sedov:s.json", "sedov", "s.json"),
+        ("dump.h5:3", "dump.h5:3", None),
+    ])
+    def test_run_spec_grammar(self, spec, case, settings):
+        """'case[+need...][:settings.json]': a capability this program has
+        (init.CAPABILITIES) leaves the case as it is."""
+        assert split_case_spec(spec) == (case, settings)
+        if settings is None and case in CASES:
+            assert make_initializer(spec) is CASES[case]
+
+    @pytest.mark.parametrize("spec", ["noh+warp-drive",
+                                      "noh+list-lifecycle+warp-drive",
+                                      "sedov+warp-drive:s.json"])
+    def test_missing_capability_is_refused_while_parsing(self, spec):
+        """A spec that asks for what the program lacks fails before any
+        particle is made, as a program from before ``list-lifecycle``
+        fails on benchmarks/configs/noh-std-1m.json's ``init``."""
+        with pytest.raises(ValueError, match="warp-drive"):
+            make_initializer(spec)
+
+    def test_benchmark_configs_name_capabilities_this_program_has(self):
+        """Every benchmark configuration's ``init`` resolves here, and
+        observables key on its bare case."""
+        import glob
+        import json
+        import os
+
+        from sphexa_tpu.observables import make_observable_spec
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        files = glob.glob(os.path.join(root, "benchmarks", "configs", "*.json"))
+        assert files
+        for f in files:
+            init = json.load(open(f))["init"]
+            case, _ = split_case_spec(init)
+            assert make_initializer(init) is CASES[case], f
+            assert make_observable_spec(init) == make_observable_spec(case)
 
     def test_settings_file_overrides(self, tmp_path):
         """'case:settings.json' applies JSON overrides to the case defaults

@@ -270,10 +270,10 @@ def test_simulation_list_rebuild_on_expiry():
     rebuilds = 0
     orig = sim._rebuild_lists
 
-    def counting():
+    def counting(*args, **kw):
         nonlocal rebuilds
         rebuilds += 1
-        orig()
+        orig(*args, **kw)
 
     sim._rebuild_lists = counting
     diags = [sim.step() for _ in range(12)]

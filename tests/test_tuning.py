@@ -368,9 +368,10 @@ class TestSchemaV7:
     def test_v7_keeps_no_kinds(self):
         # v7 adds the optional staged-exchange payload, no new kinds: no
         # KIND_SINCE entry may claim 7 (v8 added the snapshot kind, v9
-        # the span kind — tests/test_serve.py pins the current version)
-        assert SCHEMA_VERSION == 9
-        assert 7 not in KIND_SINCE.values()
+        # the span kind — tests/test_serve.py pins the current version);
+        # v10 did the same for ``rebuild_lists`` (optional WHY payload)
+        assert SCHEMA_VERSION == 10
+        assert not {7, 10} & set(KIND_SINCE.values())
 
     def test_v7_staged_exchange_validates(self):
         for stage in ("sph", "gravity"):
