@@ -98,7 +98,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "rehearsal path the multi-chip dry run uses")
     p.add_argument("--check-every", type=int, default=None,
                    dest="check_every",
-                   help="deferred cap-checking window: launch N steps "
+                   help="deferred cap-checking window: launch up to N "
+                        "steps (fewer where the pair list covers fewer) "
                         "with no device sync, fetch/verify diagnostics "
                         "in one batch at the window end (default 1 = "
                         "synchronous; unset, --tuned may resolve it "

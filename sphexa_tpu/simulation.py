@@ -10,7 +10,7 @@ when particle motion invalidates it — the rare recompile boundary — and
 import dataclasses
 import os
 import time
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 import jax
@@ -80,6 +80,92 @@ _PROPAGATORS_BLOCKDT_DONATED: Dict[str, Callable] = {
     "std": step_hydro_std_blockdt_donated,
     "ve": step_hydro_ve_blockdt_donated,
 }
+
+#: skin fraction a step must be predicted to FIND (its ``list_slack``)
+#: for the plan to launch it. The plan extrapolates the skin use of the
+#: last verified step over at most ``check_every`` further ones; what
+#: it cannot see is the flow changing inside that horizon. 0.05 is a
+#: third of the use of one step of the shortest-lived list measured
+#: (Noh at 1.1M, 0.14-0.17 per step): it absorbs a 10 % change of that
+#: use over three planned steps. A miss costs a rolled-back window (a
+#: rebuild and a window of replays); the margin costs a list whose
+#: last step would have found less than 0.05 one step of its life.
+_LIST_COVER_MARGIN = 0.05
+
+
+class WindowPlan(NamedTuple):
+    """The next check window, planned from the skin the list has left."""
+    #: skin fraction the next step is predicted to use (None: no trend)
+    rate: Optional[float]
+    #: further steps the list is predicted to serve (None: no trend)
+    cover: Optional[int]
+    #: steps to launch before the next fetch, 1..check_every
+    steps: int
+
+
+@dataclasses.dataclass(frozen=True)
+class SkinTrend:
+    """How fast the steps use a pair list's Verlet skin, read from the
+    ``list_slack`` and ``dt`` every verified step's diagnostics carry.
+
+    Step k of a list finds ``slack`` = the skin left after the k-1 steps
+    before it and then moves the particles for its ``dt``, so two
+    consecutive steps of one list give the use per unit of simulated
+    time, ``(slack[k-1] - slack[k]) / dt[k-1]``. The largest drift is a
+    maximum over particles of (nearly) linear functions of time, so the
+    newest difference is the best estimate and errs low only when the
+    flow itself speeds up. The rate survives a rebuild (the flow is
+    continuous; a fresh list has slack 1 and no history of its own)."""
+
+    #: skin fraction used per unit of simulated time (None: not seen yet)
+    rate: Optional[float] = None
+    #: the last verified step's dt, and by how much the next is taken to
+    #: exceed it: the last observed ratio, never under 1 (a falling dt
+    #: is not bet on), never over ``const.max_dt_increase``
+    dt: Optional[float] = None
+    growth: float = 1.0
+    #: ``list_slack`` of the last verified step ON THE LIVE LIST; None
+    #: where the next step is a list's first (its slack is exactly 1)
+    slack: Optional[float] = None
+
+    def observe(self, slack, dt, max_dt_increase: float) -> "SkinTrend":
+        """Fold in one verified step (oldest first). A step that carries
+        no ``list_slack`` ran without lists and says nothing."""
+        if slack is None:
+            return self
+        slack, dt = float(slack), float(dt)
+        rate, growth = self.rate, self.growth
+        if self.dt:
+            growth = min(max(dt / self.dt, 1.0), max_dt_increase)
+            if self.slack is not None:
+                rate = (self.slack - slack) / self.dt
+        return SkinTrend(rate, dt, growth, slack)
+
+    def rebuilt(self) -> "SkinTrend":
+        """The list was replaced: the next step finds slack 1."""
+        return dataclasses.replace(self, slack=None)
+
+    def plan(self, check_every: int) -> WindowPlan:
+        """``cover`` = how many further steps are predicted to find at
+        least ``_LIST_COVER_MARGIN`` of the skin, the j-th of them
+        ``now - use * (1 + g + ... + g**(j-2))``; the window is that
+        many steps and never more than ``check_every``. With no trend
+        (a run's first list, a flow at rest) it is the whole window and
+        the rollback is the net."""
+        if self.rate is None or self.rate <= 0.0:
+            return WindowPlan(None, None, check_every)
+        use = self.rate * self.dt * self.growth
+        # the last verified step's own use is not in any fetched slack
+        now = 1.0 if self.slack is None else self.slack - self.rate * self.dt
+        room = (now - _LIST_COVER_MARGIN) / use
+        if room < 0.0:
+            cover = 0
+        elif self.growth > 1.0:
+            g = self.growth
+            cover = 1 + int(np.log1p(room * (g - 1.0)) / np.log(g))
+        else:
+            cover = 1 + int(room)
+        return WindowPlan(use, cover, max(1, min(check_every, cover)))
 
 
 def make_propagator_config(
@@ -717,6 +803,12 @@ class Simulation:
         # why the next launch finds no list: the run's first, a
         # reconfigure dropped it, or a build that raised is retried
         self._list_reason = "first"
+        # how fast the verified steps use a list's skin, the check window
+        # planned from it (whole windows until a trend is seen), and the
+        # life that plan gave the live list when it was fresh
+        self._trend = SkinTrend()
+        self._plan = WindowPlan(None, None, max(1, check_every))
+        self._list_cover = None
         self._slot_margin = 1.3
         # list mode sizes for the h its particles are relaxing to:
         # (max h at the last configure, not yet checked against a
@@ -809,6 +901,7 @@ class Simulation:
 
     def _configure_impl(self, min_cap: int = 0, grav_margin: float = 1.5):
         self._lists = None  # any static re-size invalidates the lists
+        self._trend = self._trend.rebuilt()  # the next launch builds one
         if self._lists_built_it is not None:
             self._list_reason = "reconfigure"
         if self._mesh is not None:
@@ -1147,15 +1240,31 @@ class Simulation:
         # structurally unavailable (make_propagator_config leaves it 0)
         return self._lists_eligible and self._cfg.list_slot_cap > 0
 
-    # rebuild proactively below this remaining-skin fraction: the next
-    # step would likely expire mid-flight and be discarded — rebuilding
-    # now costs one sort+mark, not a wasted step
-    _LIST_SLACK_REBUILD = 0.25
+    def _observe_lists(self, verified) -> None:
+        """Fold the ``list_slack`` and ``dt`` of steps just verified
+        (oldest first) into the skin trend: values the fetch that
+        verified them already brought."""
+        for d in verified:
+            self._trend = self._trend.observe(
+                d.get("list_slack"), d.get("dt"),
+                self.const.max_dt_increase)
 
-    def _maybe_rebuild_lists(self, diagnostics):
-        slack = float(diagnostics.get("list_slack", 1.0))
-        if self._use_lists and slack < self._LIST_SLACK_REBUILD:
-            self._rebuild_lists("proactive", slack=slack)
+    def _plan_lists(self) -> None:
+        """At a verified boundary: plan the next check window from the
+        skin the live list has left (SkinTrend.plan). A list predicted
+        to serve no further step is rebuilt now, at the boundary,
+        instead of expiring inside the next window and rolling it back;
+        the window is then planned from the fresh list. One rule for
+        ``check_every`` 1 (a horizon of one step) and N; inert where
+        the steps run without lists."""
+        if not self._use_lists:
+            self._plan = WindowPlan(None, None, self.check_every)
+            return
+        self._plan = self._trend.plan(self.check_every)
+        if self._plan.cover == 0 and self._trend.slack is not None:
+            # (a fresh list that covers no step cannot be bettered)
+            self._rebuild_lists("proactive", slack=self._trend.slack)
+            self._plan = self._trend.plan(self.check_every)
 
     def _rebuild_lists(self, reason: str, slack: Optional[float] = None,
                        served_to: Optional[int] = None):
@@ -1206,12 +1315,18 @@ class Simulation:
                 if aux is not None:
                     self.chem = aux
                 self._lists_built_it = self.iteration
+                self._trend = self._trend.rebuilt()
+                cover, self._list_cover = (
+                    self._list_cover, self._trend.plan(self.check_every).cover)
+                rate = self._plan.rate
                 self.telemetry.event(
                     "rebuild_lists", it=self.iteration, reason=reason,
                     age_steps=age,
                     slack=None if slack is None else round(slack, 6),
                     slot_need=need, slot_cap=self._cfg.list_slot_cap,
                     attempts=attempt,
+                    rate=None if rate is None else round(rate, 6),
+                    cover_steps=cover,
                 )
                 return
             self._slot_margin *= 1.5
@@ -1878,16 +1993,16 @@ class Simulation:
         wall = time.perf_counter() - t0
         self._apply(out)
         self.iteration += 1
+        self._observe_lists([diagnostics])
+        # config check FIRST: _configure() drops self._lists, so a
+        # proactive rebuild before it would be wasted work
         if not self._config_still_valid(diagnostics):
-            # config check FIRST: _configure() drops self._lists, so a
-            # proactive rebuild before it would be wasted work
             self._configure(reason="stale-grid")
             reconfigured = True
         elif not self._lists_cover_h(diagnostics, diagnostics):
             self._configure(reason="h-relax")
             reconfigured = True
-        else:
-            self._maybe_rebuild_lists(diagnostics)
+        self._plan_lists()
         result = {
             k: np.asarray(v) if getattr(v, "ndim", 0) else float(v)
             for k, v in diagnostics.items()
@@ -1919,12 +2034,13 @@ class Simulation:
         With ``check_every == 1`` (default) the step is checked
         synchronously. With ``check_every > 1`` steps are launched with NO
         device->host sync on the happy path; every ``check_every`` steps
-        the accumulated diagnostics are fetched in one transfer and, if an
-        overflow is found, the simulation rolls back to the last verified
-        state and replays the lost steps under a fresh config (the same
-        discard-and-retry semantics, checked late). Diagnostics returned
-        between check boundaries are the last verified ones, marked
-        ``{"deferred": 1.0}``.
+        (sooner where the pair list is predicted to cover fewer:
+        ``_plan_lists``) the accumulated diagnostics are fetched in one
+        transfer and, if an overflow is found, the simulation rolls back
+        to the last verified state and replays the lost steps under a
+        fresh config (the same discard-and-retry semantics, checked
+        late). Diagnostics returned between check boundaries are the last
+        verified ones, marked ``{"deferred": 1.0}``.
         """
         if self.check_every <= 1 or not self._pending:
             # a checked step or a window opens: its spans (pin, launches,
@@ -1951,7 +2067,7 @@ class Simulation:
         # device, timestamps are host-side — zero added transfers
         self.telemetry.event("launch", it=self.iteration)
         self._pending.append(out[1])
-        if len(self._pending) >= self.check_every:
+        if len(self._pending) >= self._plan.steps:
             return self.flush()
         return {**self._last_diag, "deferred": 1.0}
 
@@ -1992,6 +2108,7 @@ class Simulation:
             "window", it=self.iteration, steps=len(pending),
             wall_s=round(window_wall, 6),
             per_step_s=round(window_wall / len(pending), 6),
+            planned_steps=self._plan.steps,
         )
         # distributed telemetry rides the SAME fetch: per-shard
         # load/exchange events + HBM snapshot, at window granularity
@@ -2013,14 +2130,14 @@ class Simulation:
         }
         result["reconfigured"] = 0.0
         self._last_diag = result
+        self._observe_lists(fetched)
         if not self._config_still_valid(fetched[-1]):
             self._configure(reason="stale-grid")
             self._last_diag["reconfigured"] = 1.0
         elif not self._lists_cover_h(fetched[0], fetched[-1]):
             self._configure(reason="h-relax")
             self._last_diag["reconfigured"] = 1.0
-        else:
-            self._maybe_rebuild_lists(fetched[-1])
+        self._plan_lists()
         return self._last_diag
 
     def _rollback(self, pending, fetched, prior, bad) -> Dict[str, float]:

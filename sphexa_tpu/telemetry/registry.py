@@ -40,12 +40,18 @@ import numpy as np
 #: ``age_steps``, ``slack``, ``slot_need``, ``slot_cap``, ``attempts``),
 #: and the event is emitted once per rebuild that BUILT a list (before
 #: v10: once per call, built or not). Like v7 it adds no kind and no
-#: REQUIRED field, so v10 readers accept v1-v9 files strictly clean.
-SCHEMA_VERSION = 10
+#: REQUIRED field, so v10 readers accept v1-v9 files strictly clean;
+#: v11 the planned check window: optional ``planned_steps`` on ``window``
+#: (what the driver's plan allowed, <= ``check_every``; a window may now
+#: be shorter than ``check_every``) and optional ``rate`` /
+#: ``cover_steps`` on ``rebuild_lists`` (the skin fraction per step the
+#: plan was made with, and the life predicted for the outgoing list).
+#: No kind, no REQUIRED field: v11 readers accept v1-v10 files clean.
+SCHEMA_VERSION = 11
 
 #: event schema versions this reader understands (older versions only
 #: ever ADD kinds, so the per-kind field table below covers them all)
-SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10)
+SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11)
 
 #: every event kind the schema admits, with its required payload fields
 #: (beyond the envelope ``v``/``seq``/``t``/``kind``). The CLI's --strict
@@ -53,13 +59,15 @@ SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10)
 EVENT_KINDS: Dict[str, tuple] = {
     "launch": ("it",),            # one deferred-window step dispatched
     "step": ("it", "wall_s"),     # one synchronously checked step done
-    "window": ("it", "steps", "wall_s", "per_step_s"),  # deferred flush
+    # deferred flush; since v11 with the optional ``planned_steps``
+    "window": ("it", "steps", "wall_s", "per_step_s"),
     "reconfigure": ("it", "reason"),
     "rollback": ("it", "steps", "reason"),
     "replay": ("it", "steps"),
     "retrace": ("it", "delta"),   # jit cache grew on a launch (recompile)
     # persistent pair lists (re)built; since v10 with the optional WHY
-    # payload: reason, age_steps, slack, slot_need, slot_cap, attempts
+    # payload: reason, age_steps, slack, slot_need, slot_cap, attempts;
+    # since v11 also rate, cover_steps
     "rebuild_lists": ("it",),
     "phases": ("it",),            # per-iteration host phase laps
     "trace": ("dir",),            # jax.profiler trace started

@@ -369,9 +369,11 @@ class TestSchemaV7:
         # v7 adds the optional staged-exchange payload, no new kinds: no
         # KIND_SINCE entry may claim 7 (v8 added the snapshot kind, v9
         # the span kind — tests/test_serve.py pins the current version);
-        # v10 did the same for ``rebuild_lists`` (optional WHY payload)
-        assert SCHEMA_VERSION == 10
-        assert not {7, 10} & set(KIND_SINCE.values())
+        # v10 did the same for ``rebuild_lists`` (optional WHY payload),
+        # v11 for the planned window (``window.planned_steps``,
+        # ``rebuild_lists.rate`` / ``cover_steps``)
+        assert SCHEMA_VERSION == 11
+        assert not {7, 10, 11} & set(KIND_SINCE.values())
 
     def test_v7_staged_exchange_validates(self):
         for stage in ("sph", "gravity"):
