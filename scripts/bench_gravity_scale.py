@@ -76,8 +76,7 @@ def main():
     args = (xs, ys, zs, ms, hs, skeys, box, gtree, meta)
     results = {}
     compaction = os.environ.get("COMPACT", "sort")  # sort | bitmask
-    # hierarchical pre-pass factor: the SAME env name the sibling
-    # profile_gravity_phases.py reads; 0 keeps the flat sweep
+    # hierarchical pre-pass factor; 0 keeps the flat sweep
     sf_env = SUPER if compaction == "bitmask" else 0
     for tb in (64, 128, 256, 512):
         base = GravityConfig(theta=THETA, bucket_size=BUCKET, G=1.0,

@@ -38,7 +38,6 @@ from typing import (
     Any,
     Callable,
     Dict,
-    Iterable,
     List,
     Optional,
     Sequence,
@@ -66,8 +65,6 @@ __all__ = [
     "register",
     "all_rules",
     "Auditor",
-    "subjaxprs",
-    "all_closed_jaxprs",
 ]
 
 _DISABLE_RE = make_disable_re("jaxaudit")
@@ -260,54 +257,6 @@ def entries_from_namespace(ns: Dict[str, Any]) -> List[EntryPoint]:
     if dupes:
         raise ValueError(f"duplicate audit entry name(s): {sorted(dupes)}")
     return sorted(entries, key=lambda e: (e.path, e.line))
-
-
-# ---------------------------------------------------------------------------
-# jaxpr walking helpers
-# ---------------------------------------------------------------------------
-
-
-def subjaxprs(jaxpr) -> Iterable:
-    """Yield every eqn of ``jaxpr`` and of all nested sub-jaxprs (pjit
-    bodies, scan/while/cond branches, shard_map bodies, custom_* calls)."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for v in eqn.params.values():
-            for w in (v if isinstance(v, (list, tuple)) else (v,)):
-                if hasattr(w, "eqns"):            # raw Jaxpr
-                    yield from subjaxprs(w)
-                elif hasattr(w, "jaxpr") and hasattr(
-                        getattr(w, "jaxpr"), "eqns"):  # ClosedJaxpr
-                    yield from subjaxprs(w.jaxpr)
-
-
-def all_closed_jaxprs(closed) -> Iterable:
-    """Yield ``closed`` and every nested ClosedJaxpr (their ``consts``
-    are where pjit-internal constants hide)."""
-    seen = set()
-
-    def walk(cj):
-        if id(cj) in seen:
-            return
-        seen.add(id(cj))
-        yield cj
-        for eqn in cj.jaxpr.eqns:
-            for v in eqn.params.values():
-                for w in (v if isinstance(v, (list, tuple)) else (v,)):
-                    if hasattr(w, "jaxpr") and hasattr(w, "consts"):
-                        yield from walk(w)
-                    elif hasattr(w, "eqns"):
-                        # raw Jaxpr: constvars but no const VALUES; the
-                        # values live on an enclosing ClosedJaxpr
-                        for eq2 in subjaxprs(w):
-                            for v2 in eq2.params.values():
-                                for w2 in (v2 if isinstance(v2, (list, tuple))
-                                           else (v2,)):
-                                    if hasattr(w2, "jaxpr") and hasattr(
-                                            w2, "consts"):
-                                        yield from walk(w2)
-
-    yield from walk(closed)
 
 
 # ---------------------------------------------------------------------------

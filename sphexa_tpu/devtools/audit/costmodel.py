@@ -23,7 +23,7 @@ Per eqn the model accumulates:
   eqn reads/writes HBM — no fusion) and a lower bound with a same-phase
   fusion discount (each value is charged once per phase — perfect
   intra-phase fusion, the XLA-on-TPU asymptote).
-- **ICI bytes** for collective primitives (``spmd.COLLECTIVE_PRIMS``),
+- **ICI bytes** for collective primitives (``primitives.COLLECTIVE_PRIMS``),
   per-shard result volume — the same accounting JXA203 gates.
 
 ``predict`` divides the tallies by a ``devices.py`` model into a
@@ -51,12 +51,8 @@ import os
 from typing import Any, Dict, List, Optional, Tuple
 
 from sphexa_tpu.devtools.audit.devices import DeviceModel, get_device
-from sphexa_tpu.devtools.audit.spmd import (
-    COLLECTIVE_PRIMS,
-    _is_var,
-    _sub_jaxprs,
-    aval_bytes,
-)
+from sphexa_tpu.devtools.audit.spmd import _is_var, aval_bytes
+from sphexa_tpu.devtools.primitives import COLLECTIVE_PRIMS, sub_jaxprs
 from sphexa_tpu.telemetry.traceview import PHASE_RE
 
 __all__ = [
@@ -314,7 +310,7 @@ def _pallas_leaf(eqn, phase: str, mult: float, acc: _Acc) -> None:
         inner = getattr(body, "jaxpr", body)
         if inner is not None and hasattr(inner, "eqns"):
             flops = sum(eqn_flops(e) for e in inner.eqns
-                        if not _sub_jaxprs(e)) * steps
+                        if not sub_jaxprs(e)) * steps
         out0 = next((v for v in eqn.outvars if hasattr(v, "aval")), None)
         if out0 is not None:
             dtype = _dtype_name(out0.aval)
@@ -345,7 +341,7 @@ def _walk(jaxpr, inherited: str, mult: float, acc: _Acc) -> None:
                 acc.merge(max(branch_accs, key=lambda a: a.total_flops()))
                 continue
 
-        subs = _sub_jaxprs(eqn)
+        subs = sub_jaxprs(eqn)
         if subs:
             submult = mult
             if prim == "scan":

@@ -46,8 +46,11 @@ import sys
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from sphexa_tpu.devtools.audit.core import all_closed_jaxprs
-from sphexa_tpu.devtools.audit.spmd import COLLECTIVE_PRIMS
+from sphexa_tpu.devtools.primitives import (
+    COLLECTIVE_PRIMS,
+    sub_jaxprs,
+    walk_consts,
+)
 
 __all__ = [
     "LOCK_VERSION",
@@ -97,21 +100,6 @@ def _is_jaxprish(v) -> bool:
         hasattr(v, "jaxpr") and hasattr(getattr(v, "jaxpr"), "eqns"))
 
 
-def _eqn_subjaxprs(eqn) -> List[Any]:
-    """Raw sub-jaxprs of one eqn, in param order (params sorted by key
-    so the inline expansion order is canonical)."""
-    subs: List[Any] = []
-    for key in sorted(eqn.params, key=str):
-        v = eqn.params[key]
-        for w in (v if isinstance(v, (list, tuple)) else (v,)):
-            # ClosedJaxpr forwards .eqns, so unwrap it FIRST
-            if hasattr(w, "jaxpr") and hasattr(getattr(w, "jaxpr"), "eqns"):
-                subs.append(w.jaxpr)
-            elif hasattr(w, "eqns"):
-                subs.append(w)
-    return subs
-
-
 def _aux_jaxpr_digest(v) -> str:
     """Alpha-invariant digest of a jaxpr buried inside a non-jaxpr param
     (e.g. a pallas GridMapping's index_map_jaxpr). These are NOT
@@ -131,7 +119,7 @@ def _canon_value(v, inline: bool = False) -> str:
     """Render one param value position-independently: no object
     addresses, dicts sorted, arrays by shape/dtype/value-digest.
 
-    ``inline`` is True exactly where ``_eqn_subjaxprs`` expands jaxpr
+    ``inline`` is True exactly where ``sub_jaxprs`` expands jaxpr
     values after the call eqn (direct param values and items of
     list/tuple params) — there a jaxpr renders as a marker; everywhere
     else (dict values, dataclass fields) it renders as an
@@ -174,7 +162,7 @@ class _Canonicalizer:
 
     Variables are renamed ``v0, v1, ...`` in traversal order (binders
     first: constvars/invars at jaxpr entry, outvars at their defining
-    eqn), so the digest is alpha-invariant. Nested jaxprs (pjit bodies,
+    eqn), so the digest is alpha-invariant. Nested jaxprs (jit bodies,
     scan/while/cond branches, shard_map bodies) expand inline
     depth-first after their call eqn's own line, inheriting its phase —
     the costmodel._walk convention, so the per-phase sub-digests group
@@ -214,11 +202,9 @@ class _Canonicalizer:
             self.lines.append(self._eqn_line(eqn, phase))
             self.line_phases.append(phase or UNATTRIBUTED)
             prim = eqn.primitive.name
-            # count shard_map's rebound variants too (psum -> psum2)
-            if prim in COLLECTIVE_PRIMS or (
-                    prim.endswith("2") and prim[:-1] in COLLECTIVE_PRIMS):
+            if prim in COLLECTIVE_PRIMS:
                 self.collectives += 1
-            for sub in _eqn_subjaxprs(eqn):
+            for sub in sub_jaxprs(eqn):
                 self.walk(sub, phase)
 
 
@@ -281,17 +267,16 @@ def _consts_fingerprint(closed) -> Tuple[str, int]:
 
     h = hashlib.sha256()
     total = 0
-    for cj in all_closed_jaxprs(closed):
-        for c in cj.consts:
-            try:
-                a = np.asarray(c)
-                if a.dtype == np.dtype(object):  # address bytes — no
-                    raise TypeError("object const")
-                h.update(f"{a.shape}:{a.dtype}:".encode())
-                h.update(a.tobytes())
-                total += a.nbytes
-            except Exception:  # noqa: BLE001 - non-array const
-                h.update(_canon_value(c).encode())
+    for c in walk_consts(closed):
+        try:
+            a = np.asarray(c)
+            if a.dtype == np.dtype(object):  # address bytes — no
+                raise TypeError("object const")
+            h.update(f"{a.shape}:{a.dtype}:".encode())
+            h.update(a.tobytes())
+            total += a.nbytes
+        except Exception:  # noqa: BLE001 - non-array const
+            h.update(_canon_value(c).encode())
     return h.hexdigest()[:32], total
 
 
@@ -634,7 +619,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "sharded entries trace (default: "
                          "$SPHEXA_AUDIT_DEVICES or 2; 0 = ambient "
                          "backend). The committed lock is written at "
-                         "the default mesh.")
+                         "--cpu-devices 8.")
     return ap
 
 

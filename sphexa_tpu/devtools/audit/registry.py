@@ -564,9 +564,6 @@ def step_std_blockdt_sharded():
 # ---------------------------------------------------------------------------
 
 
-# jaxaudit: disable=JXA502 -- the ledger's optimization_barrier (pinned
-# summation-order fence, JXA401) has no vmap batching rule in this jax;
-# ensembles reduce observables per member OUTSIDE the batched step
 @entrypoint("observable_ledger")
 def observable_ledger():
     import jax.numpy as jnp
@@ -627,10 +624,6 @@ def observable_ledger_sharded():
 # ---------------------------------------------------------------------------
 
 
-# jaxaudit: disable=JXA502 -- the snapshot's chain_after (the same
-# collective-order fence as the ledger's, JXA401) has no vmap batching
-# rule in this jax; ensembles snapshot per member OUTSIDE the batched
-# step
 # jaxaudit: disable=JXA401 -- the deposit is a colliding histogram
 # scatter BY DESIGN (many particles per cell); the grid is a viz/
 # monitoring surface whose contract is the cell sum up to rounding,
@@ -657,7 +650,6 @@ def observable_snapshot():
     return EntryCase(fn=fn, args=(s, box, rho))
 
 
-# jaxaudit: disable=JXA502 -- same optimization_barrier fence as above
 # jaxaudit: disable=JXA401 -- same deliberate histogram scatter as the
 # single-device entry above
 @entrypoint("observable_snapshot_sharded", mesh_axes=("p",))

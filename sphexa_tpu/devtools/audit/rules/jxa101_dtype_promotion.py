@@ -22,12 +22,9 @@ from __future__ import annotations
 
 from typing import Dict, List, Tuple
 
-from sphexa_tpu.devtools.audit.core import (
-    EntryTrace,
-    register,
-    subjaxprs,
-)
+from sphexa_tpu.devtools.audit.core import EntryTrace, register
 from sphexa_tpu.devtools.common import Finding
+from sphexa_tpu.devtools.primitives import walk_eqns
 
 _MAX_ITEMSIZE = 4  # the dtypes.py policy is 32-bit device values
 
@@ -61,7 +58,7 @@ def check(trace: EntryTrace) -> List[Finding]:
         _scan_aval(aval, "entry input", hits)
     for c in closed.consts:
         _scan_aval(c, "jaxpr constant", hits)
-    for eqn in subjaxprs(closed.jaxpr):
+    for eqn in walk_eqns(closed.jaxpr):
         for var in eqn.outvars:
             _scan_aval(getattr(var, "aval", None),
                        f"`{eqn.primitive.name}` output", hits)

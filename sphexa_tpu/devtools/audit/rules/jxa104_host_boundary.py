@@ -5,7 +5,7 @@ step round-trips to the host (or re-places a buffer) EVERY iteration —
 the per-step analog of the JXL002 host-sync class, but visible only after
 tracing (the AST pass cannot see a callback smuggled in through a helper
 in another module). Debug prints count too: ``jax.debug.print`` lowers to
-``debug_callback`` and serializes the device stream.
+a callback primitive of its own and serializes the device stream.
 
 ``with_sharding_constraint``/collectives are NOT flagged — they are
 device-side. ``jax.named_scope`` (the sphexa/<phase> attribution
@@ -13,9 +13,9 @@ scopes, util/phases.py) never appears here at all: it pushes a
 tracing-time name stack and lowers to NO primitive, so the phase
 taxonomy is invisible to this rule by construction (pinned by the
 audit gate staying at zero findings with every step entry scoped).
-The deny set is the callback/transfer family. ``device_put``
-needs care: jax stages ``jnp.asarray(np_constant)`` inside a traced body
-as a device_put eqn with no target (``devices=[None]``, alias
+The deny set is ``primitives.HOST_BOUNDARY_PRIMS``, the
+callback/transfer family. ``device_put`` needs care: jax stages
+``jnp.asarray(np_constant)`` inside a traced body as a device_put eqn with no target (``devices=[None]``, alias
 semantics) — that is constant staging, not a transfer (JXA105 budgets
 its size instead). Only device_put with an EXPLICIT placement target is
 a re-placement inside the hot body and gets flagged.
@@ -26,22 +26,9 @@ from __future__ import annotations
 from collections import Counter
 from typing import List
 
-from sphexa_tpu.devtools.audit.core import (
-    EntryTrace,
-    register,
-    subjaxprs,
-)
+from sphexa_tpu.devtools.audit.core import EntryTrace, register
 from sphexa_tpu.devtools.common import Finding
-
-_DENY = {
-    "pure_callback": "host callback per step",
-    "io_callback": "host IO callback per step",
-    "debug_callback": "debug print/callback serializes the device stream",
-    "callback": "host callback per step",
-    "infeed": "host infeed per step",
-    "outfeed": "host outfeed per step",
-    "device_put": "explicitly re-places a buffer inside the traced body",
-}
+from sphexa_tpu.devtools.primitives import HOST_BOUNDARY_PRIMS, walk_eqns
 
 
 def _is_constant_staging(eqn) -> bool:
@@ -58,18 +45,18 @@ def _is_constant_staging(eqn) -> bool:
 )
 def check(trace: EntryTrace) -> List[Finding]:
     counts: Counter = Counter()
-    for eqn in subjaxprs(trace.closed_jaxpr.jaxpr):
+    for eqn in walk_eqns(trace.closed_jaxpr.jaxpr):
         name = eqn.primitive.name
-        if name in _DENY:
+        if name in HOST_BOUNDARY_PRIMS:
             if name == "device_put" and _is_constant_staging(eqn):
                 continue
             counts[name] += 1
     return [
         trace.finding(
             "JXA104",
-            f"`{name}` x{n} in the traced body — {_DENY[name]}. Move it "
-            f"to the driver loop (Simulation host code) or behind a "
-            f"debug-only flag.",
+            f"`{name}` x{n} in the traced body — "
+            f"{HOST_BOUNDARY_PRIMS[name]}. Move it to the driver loop "
+            f"(Simulation host code) or behind a debug-only flag.",
         )
         for name, n in sorted(counts.items())
     ]

@@ -9,7 +9,7 @@ into every later step. Entries budget constants via
 ``const_bytes_limit`` (default 1 MiB: lookup tables are legal, particle
 arrays are not).
 
-Constants of nested pjit bodies are walked too — that is where closure
+Constants of nested jit bodies are walked too — that is where closure
 captures of inner jitted helpers land.
 """
 
@@ -17,12 +17,10 @@ from __future__ import annotations
 
 from typing import List
 
-from sphexa_tpu.devtools.audit.core import (
-    EntryTrace,
-    all_closed_jaxprs,
-    register,
-)
+from sphexa_tpu.devtools.audit.core import EntryTrace, register
+from sphexa_tpu.devtools.audit.spmd import aval_bytes
 from sphexa_tpu.devtools.common import Finding
+from sphexa_tpu.devtools.primitives import walk_consts
 
 
 @register(
@@ -34,19 +32,19 @@ def check(trace: EntryTrace) -> List[Finding]:
     limit = trace.entry.const_bytes_limit
     out: List[Finding] = []
     seen = set()
-    for cj in all_closed_jaxprs(trace.closed_jaxpr):
-        for c in cj.consts:
-            if id(c) in seen:
-                continue
-            seen.add(id(c))
-            nbytes = getattr(c, "nbytes", 0)
-            if nbytes > limit:
-                out.append(trace.finding(
-                    "JXA105",
-                    f"constant {getattr(c, 'dtype', '?')}"
-                    f"{tuple(getattr(c, 'shape', ()))} of {nbytes} bytes "
-                    f"baked into the jaxpr (budget {limit}). Pass it as "
-                    f"an argument (pytree leaf) instead of closing over "
-                    f"it.",
-                ))
+    for c in walk_consts(trace.closed_jaxpr):
+        if id(c) in seen:
+            continue
+        seen.add(id(c))
+        # shape x itemsize: jax's own constant wrapper has no .nbytes
+        nbytes = aval_bytes(c)
+        if nbytes > limit:
+            out.append(trace.finding(
+                "JXA105",
+                f"constant {getattr(c, 'dtype', '?')}"
+                f"{tuple(getattr(c, 'shape', ()))} of {nbytes} bytes "
+                f"baked into the jaxpr (budget {limit}). Pass it as "
+                f"an argument (pytree leaf) instead of closing over "
+                f"it.",
+            ))
     return out

@@ -1,6 +1,6 @@
 """JXA106: collective-axis audit against the entry's declared sharding.
 
-Every psum/ppermute/all_gather/... in the traced body names a mesh axis;
+Every collective in the traced body names a mesh axis;
 the registry entry declares which axes its sharding provides
 (``mesh_axes=("p",)``). An axis outside the declaration means the code
 and the registry disagree about the mesh — either a renamed axis that a
@@ -15,19 +15,9 @@ from __future__ import annotations
 
 from typing import Dict, List
 
-from sphexa_tpu.devtools.audit.core import (
-    EntryTrace,
-    register,
-    subjaxprs,
-)
+from sphexa_tpu.devtools.audit.core import EntryTrace, register
 from sphexa_tpu.devtools.common import Finding
-
-_AXIS_PARAM_KEYS = ("axes", "axis_name")
-
-
-def _string_axes(value) -> List[str]:
-    vals = value if isinstance(value, (tuple, list)) else (value,)
-    return [v for v in vals if isinstance(v, str)]
+from sphexa_tpu.devtools.primitives import collective_axes, walk_eqns
 
 
 @register(
@@ -38,14 +28,11 @@ def _string_axes(value) -> List[str]:
 def check(trace: EntryTrace) -> List[Finding]:
     declared = set(trace.entry.mesh_axes)
     unknown: Dict[str, str] = {}  # axis -> first primitive
-    for eqn in subjaxprs(trace.closed_jaxpr.jaxpr):
-        names: List[str] = []
-        for key in _AXIS_PARAM_KEYS:
-            if key in eqn.params:
-                names += _string_axes(eqn.params[key])
+    for eqn in walk_eqns(trace.closed_jaxpr.jaxpr):
+        names = list(collective_axes(eqn))
         mesh = eqn.params.get("mesh")
         if mesh is not None and hasattr(mesh, "axis_names"):
-            names += _string_axes(tuple(mesh.axis_names))
+            names += [a for a in mesh.axis_names if isinstance(a, str)]
         for name in names:
             if name not in declared and name not in unknown:
                 unknown[name] = eqn.primitive.name

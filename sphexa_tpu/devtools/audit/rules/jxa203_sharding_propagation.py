@@ -4,7 +4,7 @@ volume beyond the analytic expectation.
 Two ways sharding propagation goes wrong land here:
 
 - a **particle-shaped operand enters a shard_map fully replicated**
-  (empty ``in_names``): the partitioner materializes all N rows on
+  (no dim sharded): the partitioner materializes all N rows on
   every device — the implicit all-gather the Warren-Salmon LET program
   exists to avoid. Flagged when the operand's campaign-rescaled bytes
   clear the AuditContext threshold; small replicated tables and the

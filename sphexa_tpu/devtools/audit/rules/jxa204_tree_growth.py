@@ -27,18 +27,9 @@ from sphexa_tpu.devtools.audit.core import (
     audit_context,
     register,
 )
-from sphexa_tpu.devtools.audit.spmd import _sub_jaxprs, aval_bytes, format_bytes
+from sphexa_tpu.devtools.audit.spmd import aval_bytes, format_bytes, slab_rows
 from sphexa_tpu.devtools.common import Finding
-
-
-def _slab_rows(jaxpr) -> int:
-    """Largest leading dim over entry invars (the spmd_report anchor)."""
-    s = 0
-    for v in jaxpr.invars:
-        shape = getattr(v.aval, "shape", ())
-        if shape:
-            s = max(s, int(shape[0]))
-    return s
+from sphexa_tpu.devtools.primitives import sub_jaxprs
 
 
 def _exempt_bytes(jaxpr, s_toy: int) -> int:
@@ -79,7 +70,7 @@ def _exempt_bytes(jaxpr, s_toy: int) -> int:
                 visit(ov)
             if eqn.primitive.name == "pallas_call":
                 continue
-            for sj in _sub_jaxprs(eqn):
+            for sj in sub_jaxprs(eqn):
                 walk(sj)
 
     walk(jaxpr)
@@ -100,8 +91,8 @@ def check(trace: EntryTrace) -> List[Finding]:
 
     jx1 = trace.closed_jaxpr.jaxpr
     jx2 = grown.closed_jaxpr.jaxpr
-    e1 = _exempt_bytes(jx1, _slab_rows(jx1))
-    e2 = _exempt_bytes(jx2, _slab_rows(jx2))
+    e1 = _exempt_bytes(jx1, slab_rows(jx1))
+    e2 = _exempt_bytes(jx2, slab_rows(jx2))
     if e1 <= 0:
         return []
     growth = e2 / e1

@@ -15,8 +15,8 @@ shapes break replay even with an identical jaxpr digest:
 - a ``reduce_precision`` eqn: the deliberate-precision-drop escape hatch
   is banned from audited entries (dtype policy lives in util/dtypes.py,
   not in per-eqn rounding).
-- a float-REDUCTION collective (psum/pmean/psum_scatter/reduce_scatter —
-  not pmax/pmin, whose results are order-insensitive) that participates
+- a float-REDUCTION collective (``primitives.FLOAT_REDUCTION_COLLECTIVES``
+  — not pmax/pmin, whose results are order-insensitive) that participates
   in a JXA201 unordered pair: with no proven total order the reduction
   tree may associate differently per run. Chained collectives
   (exchange.chain_after) are already excluded by the spmd dependency
@@ -33,17 +33,16 @@ from sphexa_tpu.devtools.audit.core import (
     EntryTrace,
     audit_context,
     register,
-    subjaxprs,
 )
 from sphexa_tpu.devtools.audit.spmd import spmd_report
 from sphexa_tpu.devtools.common import Finding
+from sphexa_tpu.devtools.primitives import (
+    FLOAT_REDUCTION_COLLECTIVES,
+    walk_eqns,
+)
 
 #: scatter variants whose combiner is order-sensitive on floats
 _UNORDERED_SCATTERS = ("scatter-add", "scatter-mul")
-
-#: collectives whose cross-device combiner is order-sensitive on floats
-_FLOAT_REDUCTIONS = frozenset(
-    {"psum", "pmean", "psum_scatter", "reduce_scatter"})
 
 
 def _is_float(aval) -> bool:
@@ -62,7 +61,7 @@ def check(trace: EntryTrace) -> List[Finding]:
     scatters = 0
     scatter_example = ""
     precisions = 0
-    for eqn in subjaxprs(trace.closed_jaxpr.jaxpr):
+    for eqn in walk_eqns(trace.closed_jaxpr.jaxpr):
         prim = eqn.primitive.name
         if prim in _UNORDERED_SCATTERS:
             if (not eqn.params.get("unique_indices", False)
@@ -102,7 +101,7 @@ def check(trace: EntryTrace) -> List[Finding]:
             f"{rep.collectives[cid].prim}#{cid}"
             f"[{rep.collectives[cid].where}]"
             for pair in rep.unordered_pairs for cid in pair
-            if rep.collectives[cid].prim in _FLOAT_REDUCTIONS})
+            if rep.collectives[cid].prim in FLOAT_REDUCTION_COLLECTIVES})
         if hazard:
             findings.append(trace.finding(
                 "JXA401",

@@ -4,8 +4,8 @@ One walk over an entry's closed jaxpr produces everything the three
 rules and the ``sphexa-audit preflight`` table read:
 
 - **Collective order graph** (JXA201): every named-axis collective
-  (psum/ppermute/all_gather/all_to_all/... at any nesting depth,
-  shard_map bodies included) with its set of collective *ancestors*
+  (``primitives.COLLECTIVE_PRIMS`` at any nesting depth, shard_map
+  bodies included) with its set of collective *ancestors*
   through the data-dependency graph. ``optimization_barrier`` — the
   ``exchange.chain_after`` primitive — is an ordinary eqn here, so a
   chained collective inherits its predecessor as an ancestor for free.
@@ -19,7 +19,7 @@ rules and the ``sphexa-audit preflight`` table read:
   shard_map-interior avals are already per-shard. Donated entry args
   (the property JXA103 verifies actually lowers to input-output
   aliasing) credit their matched output buffer as zero bytes. Nested
-  jaxprs (pjit/scan/cond bodies) contribute their own internal excess
+  jaxprs (jit/scan/cond bodies) contribute their own internal excess
   over their operand/result footprint at the call site. The same sweep
   carries a *campaign rescale*: every buffer holding a whole number of
   per-device slabs ("extensive" — particle fields, (S,3) vectors, halo
@@ -29,7 +29,7 @@ rules and the ``sphexa-audit preflight`` table read:
   arrays) stay at traced size. Full-slab halo windows rescale as full
   campaign slabs, so the bound is deliberately above the real Wmax.
 - **Sharding-propagation facts** (JXA203): particle-shaped operands
-  entering a shard_map fully replicated (empty ``in_names`` — the
+  entering a shard_map fully replicated (no sharded dim — the
   partitioner will materialize N rows per device), and the summed
   output bytes of all collectives (the measured cross-shard volume the
   rule gates against the analytic ``sizing``-derived budget a registry
@@ -44,8 +44,14 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, FrozenSet, List, Optional, Set, Tuple
 
+from sphexa_tpu.devtools.primitives import (
+    COLLECTIVE_PRIMS,
+    collective_axes,
+    shard_map_operand_axes,
+    sub_jaxprs,
+)
+
 __all__ = [
-    "COLLECTIVE_PRIMS",
     "Collective",
     "ReplicatedOperand",
     "SpmdReport",
@@ -53,15 +59,6 @@ __all__ = [
     "format_bytes",
 ]
 
-# jax.lax collective primitives that synchronize over a NAMED mesh axis.
-# axis_index is deliberately absent: it reads the coordinate, no comm.
-COLLECTIVE_PRIMS = frozenset({
-    "psum", "pmax", "pmin", "pmean", "ppermute", "pshuffle",
-    "all_gather", "all_gather_invariant", "all_to_all",
-    "psum_scatter", "reduce_scatter", "pgather",
-})
-
-_AXIS_PARAM_KEYS = ("axes", "axis_name")
 _EMPTY: FrozenSet[int] = frozenset()
 
 
@@ -71,7 +68,7 @@ class Collective:
     prim: str
     axes: Tuple[str, ...]
     out_bytes: int       # per-shard result bytes (shard_map-interior aval)
-    where: str           # nesting path, e.g. "pjit/shard_map"
+    where: str           # nesting path, e.g. "jit/shard_map"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,29 +115,11 @@ def _is_var(v) -> bool:
     return not hasattr(v, "val")
 
 
-def _named_axes(eqn) -> Tuple[str, ...]:
-    names: List[str] = []
-    for key in _AXIS_PARAM_KEYS:
-        if key in eqn.params:
-            v = eqn.params[key]
-            vals = v if isinstance(v, (tuple, list)) else (v,)
-            names += [a for a in vals if isinstance(a, str)]
-    return tuple(names)
-
-
-def _sub_jaxprs(eqn) -> List[Any]:
-    """Raw sub-jaxprs in an eqn's params (pjit ClosedJaxpr bodies,
-    scan/while/cond branches, shard_map bodies, custom_* calls)."""
-    subs: List[Any] = []
-    for v in eqn.params.values():
-        for w in (v if isinstance(v, (list, tuple)) else (v,)):
-            # ClosedJaxpr forwards .eqns, so require .invars to pick the
-            # RAW jaxpr (positional invar mapping needs it)
-            if hasattr(w, "eqns") and hasattr(w, "invars"):
-                subs.append(w)
-            elif hasattr(w, "jaxpr") and hasattr(getattr(w, "jaxpr"), "eqns"):
-                subs.append(w.jaxpr)
-    return subs
+def slab_rows(jaxpr) -> int:
+    """Largest leading dim over entry invars: the N that the JXA2xx slab
+    arithmetic, JXA204 and the schema's axis polynomials anchor on."""
+    return max((int(v.aval.shape[0]) for v in jaxpr.invars
+                if getattr(v.aval, "shape", ())), default=0)
 
 
 def aval_bytes(aval) -> int:
@@ -166,7 +145,7 @@ def _collective_order(jaxpr) -> Tuple[List[Collective], List[FrozenSet[int]],
     Dataflow abstract interpretation: each var maps to the set of
     collective ids on some path to it. Sub-jaxpr invars/outvars are
     mapped positionally to the call eqn's when the arities line up
-    (pjit, scan, shard_map, cond modulo the predicate); otherwise the
+    (jit, scan, shard_map, cond modulo the predicate); otherwise the
     call is treated as a unit (all inner collectives become ancestors of
     all eqn outputs) — optimistic only across a call boundary, which is
     where XLA schedules calls as units anyway."""
@@ -183,7 +162,7 @@ def _collective_order(jaxpr) -> Tuple[List[Collective], List[FrozenSet[int]],
             for v in eqn.invars:
                 if _is_var(v):
                     in_a |= env.get(v, _EMPTY)
-            subs = _sub_jaxprs(eqn)
+            subs = sub_jaxprs(eqn)
             if subs:
                 inner_all: Set[int] = set()
                 out_accum: Optional[List[Set[int]]] = None
@@ -222,10 +201,10 @@ def _collective_order(jaxpr) -> Tuple[List[Collective], List[FrozenSet[int]],
                 else:
                     for ov in eqn.outvars:
                         env[ov] = in_a | inner_all
-            elif prim in COLLECTIVE_PRIMS and _named_axes(eqn):
+            elif prim in COLLECTIVE_PRIMS and collective_axes(eqn):
                 cid = len(infos)
                 infos.append(Collective(
-                    cid=cid, prim=prim, axes=_named_axes(eqn),
+                    cid=cid, prim=prim, axes=collective_axes(eqn),
                     out_bytes=sum(aval_bytes(ov.aval) for ov in eqn.outvars),
                     where=where or "jit",
                 ))
@@ -361,7 +340,7 @@ def _peak_liveness(jaxpr, P: int, s_toy: int, ratio: float,
                 # buffers — the call's HBM footprint is its operands and
                 # results, already counted at this level
                 continue
-            subs = _sub_jaxprs(eqn)
+            subs = sub_jaxprs(eqn)
             if not subs:
                 continue
             sub_scaled = scaled and eqn.primitive.name != "shard_map"
@@ -400,9 +379,8 @@ def _replicated_operands(jaxpr, n_global: int, campaign_n: int
         for eqn in jx.eqns:
             prim = eqn.primitive.name
             if prim == "shard_map":
-                in_names = eqn.params.get("in_names", ())
-                for pos, names in enumerate(in_names):
-                    if names or pos >= len(eqn.invars):
+                for pos, axes in enumerate(shard_map_operand_axes(eqn)):
+                    if axes or pos >= len(eqn.invars):
                         continue       # some dim is sharded, or arity drift
                     v = eqn.invars[pos]
                     aval = getattr(v, "aval", None)
@@ -417,7 +395,7 @@ def _replicated_operands(jaxpr, n_global: int, campaign_n: int
                         dtype=str(getattr(aval, "dtype", "?")),
                         toy_bytes=tb, campaign_bytes=cb,
                     ))
-            for sj in _sub_jaxprs(eqn):
+            for sj in sub_jaxprs(eqn):
                 walk(sj, f"{where}/{prim}" if where else prim)
 
     walk(jaxpr, "")
@@ -439,7 +417,7 @@ def _mesh_size(jaxpr) -> int:
                         size *= int(d)
                 if size:
                     best = max(best, int(size))
-            for sj in _sub_jaxprs(eqn):
+            for sj in sub_jaxprs(eqn):
                 walk(sj)
 
     walk(jaxpr)
@@ -471,13 +449,12 @@ def spmd_report(trace, ctx) -> SpmdReport:
             if p < len(spans):
                 donated |= set(range(offsets[p], offsets[p] + spans[p]))
 
-    n_global = 0
+    n_global = slab_rows(jx)
     s_toy = 0
     for v in jx.invars:
         shape = getattr(v.aval, "shape", ())
         if shape:
             d0 = int(shape[0])
-            n_global = max(n_global, d0)
             rows = d0 // P if (P > 1 and d0 >= P and d0 % P == 0) else d0
             s_toy = max(s_toy, rows)
 

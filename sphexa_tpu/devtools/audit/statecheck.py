@@ -41,6 +41,9 @@ from fractions import Fraction
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
 
+from sphexa_tpu.devtools.audit.spmd import slab_rows
+from sphexa_tpu.devtools.primitives import HOST_CALLBACK_PRIMS, walk_eqns
+
 __all__ = [
     "SCHEMA_VERSION",
     "DEFAULT_SCHEMA_PATH",
@@ -68,17 +71,6 @@ class LockError(ValueError):
 # ---------------------------------------------------------------------------
 # symbolic schema inference
 # ---------------------------------------------------------------------------
-
-
-def _slab_rows(jaxpr) -> int:
-    """Largest leading dim over entry invars — the same N anchor JXA204
-    and the JXA2xx spmd report key their slab arithmetic on."""
-    s = 0
-    for v in jaxpr.invars:
-        shape = getattr(v.aval, "shape", ())
-        if shape:
-            s = max(s, int(shape[0]))
-    return s
 
 
 def _fit_axes(dims1, dims2, n1: int, n2: int) -> List[Dict[str, Any]]:
@@ -156,7 +148,7 @@ def entry_schema(trace) -> Dict[str, Any]:
     from sphexa_tpu.devtools.audit.core import EntryTrace, audit_context
 
     base = _flat_leaves(trace)
-    n1 = _slab_rows(trace.closed_jaxpr.jaxpr)
+    n1 = slab_rows(trace.closed_jaxpr.jaxpr)
     row: Dict[str, Any] = {
         "mesh": audit_context().mesh_size,
         "n_base": n1 or None,
@@ -169,7 +161,7 @@ def entry_schema(trace) -> Dict[str, Any]:
         grown_case, _ratio = trace.case.grow()
         gtrace = EntryTrace(trace.entry, grown_case)
         grown = _flat_leaves(gtrace)
-        n2 = _slab_rows(gtrace.closed_jaxpr.jaxpr)
+        n2 = slab_rows(gtrace.closed_jaxpr.jaxpr)
         if len(grown) != len(base) or n2 == n1:
             raise ValueError(
                 f"entry {trace.entry.name}: grow probe changed the output "
@@ -200,15 +192,9 @@ def entry_schema(trace) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
-def _is_callback_prim(name: str) -> bool:
-    return "callback" in name or name in ("infeed", "outfeed")
-
-
 def _loop_count(closed) -> int:
-    from sphexa_tpu.devtools.audit.core import subjaxprs
-
     return sum(
-        1 for eqn in subjaxprs(closed.jaxpr)
+        1 for eqn in walk_eqns(closed.jaxpr)
         if eqn.primitive.name in ("while", "scan")
     )
 
@@ -243,11 +229,9 @@ def vmap_probe(trace, members: int) -> Dict[str, Any]:
         report["error"] = f"{e.__class__.__name__}: {e}"
         trace._vmap = report
         return report
-    from sphexa_tpu.devtools.audit.core import subjaxprs
-
     callbacks: Dict[str, int] = {}
-    for eqn in subjaxprs(closed.jaxpr):
-        if _is_callback_prim(eqn.primitive.name):
+    for eqn in walk_eqns(closed.jaxpr):
+        if eqn.primitive.name in HOST_CALLBACK_PRIMS:
             callbacks[eqn.primitive.name] = \
                 callbacks.get(eqn.primitive.name, 0) + 1
     report["callbacks"] = sorted(callbacks.items())

@@ -23,7 +23,7 @@ import ast
 from pathlib import PurePosixPath
 from typing import Dict, List
 
-from sphexa_tpu.devtools.audit.spmd import COLLECTIVE_PRIMS
+from sphexa_tpu.devtools.primitives import COLLECTIVE_PRIMS
 from sphexa_tpu.devtools.lint.core import Finding, ModuleInfo, register
 from sphexa_tpu.devtools.lint.trace_scope import build_parent_map
 

@@ -103,9 +103,8 @@ class GravityConfig:
 
 
 def gravity_tuning(n: int, use_pallas: bool, telemetry=None) -> dict:
-    """Scale-dependent gravity-solver shape, shared by
-    Simulation._configure_gravity and bench.py so the benchmarked config
-    IS the production config.
+    """Scale-dependent gravity-solver shape
+    (Simulation._configure_gravity).
 
     Coarser classification blocks amortize the MAC sweep at large N
     (measured 1.86x at 1M Plummer: tb=256 975 ms vs tb=64 1810 ms,
@@ -537,8 +536,7 @@ def _monotone_mac_geometry(box, tree, meta, node_com, valid, theta):
     to the node's GEO BOX. Since child boxes nest and the radius is
     non-increasing down the tree, accept(parent) => accept(child) — so
     "first accepted ancestor" collapses to ONE parent lookup (no
-    per-level downsweep, the 210 ms phase at 1M,
-    scripts/profile_gravity_phases.py) and p2p = leaf & ~accept needs no
+    per-level downsweep, the 210 ms phase at 1M) and p2p = leaf & ~accept needs no
     ancestor chain at all. Validity: the true com distance >= box
     distance (com inside the box) and the monotone radius >= the node's
     own l/theta + s_off, so every acceptance satisfies the original
@@ -1005,8 +1003,8 @@ def compute_gravity(
             # P2P list is a dynamic slice at the M2P count. The class and the
             # node index ride in one PACKED int32 key (class in the top bits,
             # index below) — a single single-operand sort where a stable
-            # argsort + sort pair cost ~2x (the 208 ms phase at 1M,
-            # scripts/profile_gravity_phases.py); unique keys make it
+            # argsort + sort pair cost ~2x (the 208 ms phase at 1M);
+            # unique keys make it
             # order-preserving within a class by construction
             cls = jnp.where(m2p_mask, 0, jnp.where(p2p_mask, 1, 2))
             cls_len = cls.shape[0]
@@ -1187,7 +1185,7 @@ def compute_gravity(
         "let_max": let_n if use_let else jnp.int32(0),
         # compaction complexity proxy: candidate slots each block's list
         # materialization scans (the interpret-mode op-count stand-in for
-        # chip timings; bench.py records it in the phase breakdown)
+        # chip timings)
         "compact_width": jnp.int32(compact_width),
         # accepted-to-evaluated MAC work (VERDICT r2 #4 diagnostic): the
         # hierarchical path shrinks the denominator by ~num_n/super_cap

@@ -1,7 +1,7 @@
 """Synthetic Plummer-sphere sample: the centrally concentrated mass
 distribution that stresses Barnes-Hut MAC classification (deep,
 strongly non-uniform trees). Not a reference init case — a gravity
-benchmark/test IC shared by bench.py and scripts/bench_gravity_scale.py.
+benchmark/test IC (scripts/bench_gravity_scale.py).
 """
 
 import numpy as np

@@ -133,10 +133,6 @@ class PropagatorConfig:
     # include the per-particle accelerations in the step diagnostics (the
     # gravitational-wave observable consumes them, gravitational_waves.hpp)
     keep_accels: bool = False
-    # include per-particle rho and sound speed c in the diagnostics (the
-    # field-consuming observables read them, avoiding a second full
-    # density/EOS pass per step); arrays are in the post-step state order
-    keep_fields: bool = False
     # 'pallas': fused search+op TPU kernels for the std pipeline
     # (sph/pallas_pairs.py); 'xla': portable gather-based path
     backend: str = "xla"
@@ -480,7 +476,7 @@ def _integrate_and_finish(
     # state (the pairing the app's eager recompute used: new positions/
     # velocities/temp with the force stage's rho/c); egrav is the force
     # stage's value, like the reference adds it to etot in-sweep.
-    # Conditional like SHARD_DIAG_KEYS/keep_fields: cfg.obs = None skips
+    # Conditional like SHARD_DIAG_KEYS: cfg.obs = None skips
     # it (bare library steps stay ledger-free and compile leaner); the
     # app/bench always configure a spec, so every science-facing run
     # carries the full ledger
@@ -511,9 +507,6 @@ def _integrate_and_finish(
         diagnostics["dt_limiter"] = dt_limiter
     if cfg.keep_accels:
         diagnostics.update({"ax": ax, "ay": ay, "az": az})
-    if cfg.keep_fields:
-        diagnostics["rho"] = rho
-        diagnostics["c"] = c if c is not None else jnp.zeros_like(rho)
     diagnostics.update(extra_diag or {})
     return new_state, box, diagnostics
 
@@ -1198,8 +1191,7 @@ def _integrate_and_finish_blockdt(
     const = cfg.const
     with phase_scope("integrate"):
         # bins>0 gate: at k=0 the rebase term is exactly zero, but
-        # a - 0.0 is not a bitwise identity for a = -0.0 and dt_bins=1
-        # pins bitwise equality with the global path
+        # a - 0.0 is not a bitwise identity for a = -0.0
         rebase = due & (bins > 0)
         dr = dt_eff - dt_min
         bx = jnp.where(rebase, state.x - state.vx * dr, state.x)
@@ -1262,9 +1254,6 @@ def _integrate_and_finish_blockdt(
         diagnostics["dt_limiter"] = dt_limiter
     if cfg.keep_accels:
         diagnostics.update({"ax": ax, "ay": ay, "az": az})
-    if cfg.keep_fields:
-        diagnostics["rho"] = rho
-        diagnostics["c"] = c if c is not None else jnp.zeros_like(rho)
     diagnostics.update(extra_diag or {})
     return new_state, box, diagnostics
 
@@ -1331,17 +1320,22 @@ def _blockdt_tail(state, box, cfg: PropagatorConfig, ax, ay, az, du,
             dt_min=dt_min)
     extra = None if alpha is None else {
         "alpha": jnp.where(due, alpha, state.alpha)}
-    # B == 1: feed compute_positions the SCALARS the global path feeds it
-    # — a broadcast (n,) operand changes XLA's FMA formation and would
-    # break the bitwise dt_bins=1 pin even at identical values
+    extra_diag = {**(gdiag or {}), **bdiag}
     if B == 1:
-        cp_dt, cp_dtm1 = dt_min, state.min_dt
+        # one bin: every row is due every substep with dt_eff == dt_min,
+        # so the step IS the global step. It goes through the global
+        # tail, not through a twin fed the same values: the due-selects
+        # and per-row dt operands of the twin change how XLA contracts
+        # the Press update's multiply-adds, and dt_bins=1 is pinned
+        # bitwise against the global path
+        new_state, box, diag = _integrate_and_finish(
+            state, box, cfg, ax, ay, az, du, dt_min, nc, occ, rho,
+            extra=extra, extra_diag=extra_diag, c=c, dt_limiter=dt_limiter)
     else:
-        cp_dt, cp_dtm1 = dt_eff, bst.dt_prev
-    new_state, box, diag = _integrate_and_finish_blockdt(
-        state, box, cfg, ax, ay, az, du, dt_min, cp_dtm1, due, bins,
-        cp_dt, nc, occ, rho, extra=extra,
-        extra_diag={**(gdiag or {}), **bdiag}, c=c, dt_limiter=dt_limiter)
+        new_state, box, diag = _integrate_and_finish_blockdt(
+            state, box, cfg, ax, ay, az, du, dt_min, bst.dt_prev, due,
+            bins, dt_eff, nc, occ, rho, extra=extra,
+            extra_diag=extra_diag, c=c, dt_limiter=dt_limiter)
     return new_state, box, diag, new_bst
 
 

@@ -178,7 +178,6 @@ def make_propagator_config(
     min_cap: int = 0,
     av_clean: bool = False,
     keep_accels: bool = False,
-    keep_fields: bool = False,
     backend: str = "auto",
     cell_target: Optional[int] = None,
     run_cap: Optional[int] = None,
@@ -204,10 +203,10 @@ def make_propagator_config(
 
     ``cell_target`` picks the grid level by mean cell occupancy;
     ``run_cap``/``gap`` control the pallas engine's merged-run streaming
-    (cell_list.NeighborConfig). Defaults tuned on v5e (scripts/
-    sweep_engine.py): ~128-per-cell grids beat finer levels (fragmented
-    short runs waste 128-lane chunks), and aggressive run merging cuts
-    the per-group DMA count ~3x.
+    (cell_list.NeighborConfig). Defaults tuned on v5e: ~128-per-cell
+    grids beat finer levels (fragmented short runs waste 128-lane
+    chunks), and aggressive run merging cuts the per-group DMA count
+    ~3x.
 
     ``device_sizing``: compute every sizing statistic with jitted
     reductions on the (possibly sharded) device arrays and fetch only
@@ -375,7 +374,7 @@ def make_propagator_config(
                 )
     return PropagatorConfig(
         const=const, nbr=nbr, curve=curve, block=block, av_clean=av_clean,
-        keep_accels=keep_accels, keep_fields=keep_fields, backend=backend,
+        keep_accels=keep_accels, backend=backend,
         list_slot_cap=slot_cap, list_skin_rel=list_skin_rel, obs=obs_spec,
         snap=snap_spec,
         dt_bins=dt_bins, bin_sync_every=bin_sync_every,
@@ -427,6 +426,9 @@ class Simulation:
     cell grid no longer covers the interaction radius or a cell overflows
     its candidate cap."""
 
+    #: leaf bucket size of the gravity tree
+    grav_bucket = 64
+
     def __init__(
         self,
         state: ParticleState,
@@ -438,9 +440,7 @@ class Simulation:
         curve: str = "hilbert",
         av_clean: bool = False,
         theta: float = 0.5,
-        grav_bucket: int = 64,
         keep_accels: bool = False,
-        keep_fields: bool = False,
         backend: str = "auto",
         turb_cfg=None,
         turb_state=None,
@@ -646,11 +646,9 @@ class Simulation:
         self.curve = curve
         self.av_clean = av_clean
         self.keep_accels = keep_accels
-        self.keep_fields = keep_fields
         self.backend = backend
         self.ngmax = ngmax or const.ngmax
         self.theta = theta
-        self.grav_bucket = grav_bucket
         self.m2p_cap_margin = m2p_cap_margin
         # multi-chip: shard the state over a device mesh and drive the
         # sharded step (parallel/mesh.py) through the SAME loop —
@@ -943,7 +941,7 @@ class Simulation:
                 ngmax=self.ngmax, block=self.block, curve=self.curve,
                 min_cap=min_cap,
                 av_clean=self.av_clean, keep_accels=self.keep_accels,
-                keep_fields=self.keep_fields, backend=self.backend,
+                backend=self.backend,
                 device_sizing=self._mesh is not None,
                 use_lists=self._lists_eligible,
                 list_skin_rel=self._list_skin_rel,
@@ -1103,8 +1101,7 @@ class Simulation:
         skeys = keys_d[order]
         xs, ys, zs, ms = s.x[order], s.y[order], s.z[order], s.m[order]
         # scale-dependent solver shape (target_block / hierarchical
-        # bitmask compaction at >= 500k, gravity_tuning) — bench.py uses
-        # the same helper so the benchmarked config IS this one
+        # bitmask compaction at >= 500k, gravity_tuning)
         from sphexa_tpu.gravity.traversal import gravity_tuning
 
         shape = gravity_tuning(self.state.n,
@@ -1553,7 +1550,7 @@ class Simulation:
         (SHARD_DIAG_KEYS), (B,) bin populations (BLOCKDT_DIAG_KEYS) and
         the (F, G, G)-sized snapshot grids (SNAP_DIAG_KEYS) —
         everything the flush boundary fetches in one batch. Per-particle
-        arrays (keep_fields/keep_accels) stay on device."""
+        arrays (keep_accels) stay on device."""
         from sphexa_tpu.propagator import (
             BLOCKDT_DIAG_KEYS, GRAV_SHARD_DIAG_KEYS, SHARD_DIAG_KEYS,
             SNAP_DIAG_KEYS)

@@ -64,17 +64,8 @@ GROUP = 128  # default targets per group (NeighborConfig.group overrides)
 # the std Sedov 100^3 pipeline): the per-field lane concats cost more than
 # the halved loop overhead saves — the per-chunk overhead is accumulator
 # read-modify-write + field loads, which pairing cannot reduce. Kept for
-# future hardware; configured via NeighborConfig.chunk_pair (0 = take the
-# SPHEXA_CHUNK_PAIR env default, read at engine build so late env changes
-# take effect). (docs/NEXT.md round-4 notes.)
-import os as _os
-
-
-def _chunk_pair(cfg) -> int:
-    cp = getattr(cfg, "chunk_pair", 0)
-    if not cp:
-        cp = int(_os.environ.get("SPHEXA_CHUNK_PAIR", "1"))
-    return max(1, cp)
+# future hardware; configured via NeighborConfig.chunk_pair (0 = 1).
+# (docs/NEXT.md round-4 notes.)
 
 
 class PairGeom(NamedTuple):
@@ -532,7 +523,7 @@ def group_pair_engine(
     """
     R = _dma_rows(cfg.dma_cap)
     nf_pad = _round_up(num_j, 8)
-    CW = _chunk_pair(cfg)  # chunks per inner-loop trip
+    CW = max(1, cfg.chunk_pair)  # chunks per inner-loop trip
     LW = 128 * CW            # lane width of the pair-math tiles
     SKIP = skip_slots > 0
     if SKIP:

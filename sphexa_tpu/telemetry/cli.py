@@ -35,8 +35,8 @@ and the run's max |Δetot|/|etot0| exceeds it, or (without ``--budget``)
 when a drift/field-health watchdog fired during the run — so CI can
 gate on conservation the way it already gates on step time.
 
-``diff`` compares two run directories, two bench JSONs (``bench.py``
-output, the ``BENCH_r*.json`` driver wrapper, or the
+``diff`` compares two run directories, two bench JSONs (a
+metric/value line, the ``BENCH_r*.json`` driver wrapper, or the
 ``MULTICHIP_r*.json`` wrapper whose tail carries
 ``scripts/measure_multichip.py --json``'s line), or a run against a
 bench baseline (throughput derived as particles / p50 step time). Exit
@@ -556,7 +556,7 @@ def diff_sides(base: Dict, cand: Dict, threshold: float,
     elif base["type"] == "bench" and cand["type"] == "bench":
         a, b = base["bench"], cand["bench"]
         # the headline is whatever the bench line's metric is: throughput
-        # for bench.py, a saving ratio for measure_multichip --json —
+        # for a BENCH round, a saving ratio for measure_multichip --json —
         # both higher-is-better by construction
         label = ("saving" if "saving" in str(a.get("metric", ""))
                  else "updates_per_sec")

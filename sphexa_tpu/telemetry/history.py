@@ -6,7 +6,7 @@ The repo's performance record lives in loose committed files — eleven
 dirs — with no trend view and nothing stopping a chip-less PR from
 quietly regressing a chip-measured number. This module gives both:
 
-- ``load_history`` ingests any mix of bench JSONs (bench.py output, the
+- ``load_history`` ingests any mix of bench JSONs (a metric/value line, the
   ``BENCH_r*.json`` driver wrapper, ``MULTICHIP_r*.json``) and telemetry
   run directories into one row-per-round trend table
   (``sphexa-telemetry history``);
@@ -43,7 +43,7 @@ class HistoryError(Exception):
 
 
 def parse_bench_json(path: str) -> Dict:
-    """bench.py's JSON line, or a driver wrapper (``BENCH_r*.json`` /
+    """A bench JSON line (``metric``/``value``), or a driver wrapper (``BENCH_r*.json`` /
     ``MULTICHIP_r*.json``) whose ``tail`` buries a metric/value line in
     captured output (measure_multichip.py --json emits the same shape,
     so multi-chip comm-volume rounds parse exactly like bench rounds)."""

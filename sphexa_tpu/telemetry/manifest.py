@@ -1,7 +1,7 @@
 """Run manifest: the who/what/where stamp that makes runs comparable.
 
 Every telemetry-enabled run writes ``manifest.json`` next to
-``events.jsonl``; bench.py stamps the same structure into its JSON line.
+``events.jsonl``.
 ``sphexa-telemetry diff`` refuses nothing but warns on mismatched
 environments — a regression across different jax versions or mesh shapes
 is a different conversation than one on identical setups.

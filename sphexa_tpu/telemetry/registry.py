@@ -1,7 +1,7 @@
 """The metrics registry: counters, gauges, typed events, host spans.
 
 One ``Telemetry`` instance is shared by everything that measures a run —
-the Simulation driver, the app loop, bench.py — so every surface reports
+the Simulation driver, the app loop, the benchmark — so every surface reports
 into the same place instead of disconnected ones (the pre-telemetry
 state: util/timer.py wall laps and the per-step diagnostics dict).
 
@@ -292,8 +292,8 @@ _NO_SPAN = _NoSpan()
 class Telemetry:
     """Counters + gauges + an event stream over sinks + host spans.
 
-    With no sinks the registry still accumulates (bench.py uses that to
-    report retrace/rollback counts without writing files); ``event()``
+    With no sinks the registry still accumulates (retrace/rollback
+    counts can be read without writing files); ``event()``
     then costs one Counter bump — cheap enough for the hot loop.
     """
 

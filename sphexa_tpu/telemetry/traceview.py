@@ -495,10 +495,8 @@ def summarize_trace(trace_dir: str, top: int = 8) -> Dict:
 
 
 def phase_attr_digest(summary: Dict) -> Dict:
-    """The compact per-capture digest persisted into the run record —
-    bench.py stamps it as ``extra.phase_attr`` and the app as the
-    ``phase_attr`` event payload. One shape, built in one place, so the
-    two records cannot silently diverge."""
+    """The compact per-capture digest persisted into the run record:
+    the app's ``phase_attr`` event payload."""
     return {
         "phases": {p["phase"]: round(p["us"], 1)
                    for p in summary["phases"]},

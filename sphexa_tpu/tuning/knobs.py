@@ -67,9 +67,8 @@ class KnobSpec:
 
 
 #: every registered knob, keyed by name. Domains are the measured
-#: candidate sets from the past sweeps (scripts/sweep_engine.py /
-#: profile_grid.py, docs/NEXT.md rounds 4-6) — the staged search seeds
-#: from them, it does not invent values.
+#: candidate sets from the past sweeps (docs/NEXT.md rounds 4-6) — the
+#: staged search seeds from them, it does not invent values.
 KNOBS: Dict[str, KnobSpec] = {
     spec.name: spec
     for spec in (

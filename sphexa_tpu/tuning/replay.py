@@ -13,8 +13,7 @@ already trusts:
 * timing is the existing sync-free deferred-window clock — the
   candidate runs as one (or more) ``check_every`` windows and the
   objective is the ``window`` event's ``per_step_s``, not a fresh
-  ad-hoc ``time.time()`` loop (the scripts/sweep_engine.py pattern
-  this module retires);
+  ad-hoc ``time.time()`` loop;
 * optionally the objective is one PHASE of the per-phase device-time
   table (``objective="phase:gravity-mac"``): the measured window runs
   under a jax.profiler trace and traceview's ``summarize_trace``

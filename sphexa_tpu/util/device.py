@@ -3,13 +3,13 @@
 Every platform-dependent choice in the package — the ``backend="auto"``
 engine pick, Mosaic interpret mode, buffer donation, the CPU-mesh drain —
 asks this module, and so do the entry points that must not run without a
-chip (chip_smoke.py, bench.py: ``require_tpu``). On a CPU host the answers
+chip (chip_smoke.py: ``require_tpu``). On a CPU host the answers
 select the portable paths the tests want (XLA gather engine, interpret-mode
 kernels); nothing here ever falls back silently on a path that demanded a
 chip.
 
 Also home of ``enable_compile_cache``: the persistent compilation cache
-placement shared by the CLI, the bench and the smoke.
+placement shared by the CLI and the smoke.
 """
 
 import functools

@@ -1,9 +1,11 @@
 """JXA502 fixtures: entries that break or degrade under jax.vmap.
 
-``vmap_trace_break``: an optimization_barrier fence has no batching
-rule in this jax — the vmapped trace raises, captured as a finding.
-``vmap_callback``: a debug print lowers to debug_callback, which under
-vmap serializes per member. ``vmap_serialized``: a sequential_vmap
+``vmap_trace_break``: a primitive with no batching rule — the vmapped
+trace raises, captured as a finding. (A fixture-owned primitive, so the
+case does not lean on which of jax's own primitives lack a rule this
+release: optimization_barrier had none once and has one now.)
+``vmap_callback``: a debug print lowers to a host-callback primitive,
+which under vmap serializes per member. ``vmap_serialized``: a sequential_vmap
 custom-batched inner fn — the batch rule is an explicit member loop, so
 the vmapped jaxpr gains a scan the base jaxpr does not have.
 ``vmap_clean`` is the honest twin: plain elementwise math batches into
@@ -16,14 +18,18 @@ these entries are invisible to the package gate).
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Primitive
 
 from sphexa_tpu.devtools.audit.core import EntryCase, entrypoint
+
+_unbatchable_p = Primitive("fixture_unbatchable")
+_unbatchable_p.def_abstract_eval(lambda x: x)
 
 
 @entrypoint("vmap_trace_break", phase_coverage_min=0.0)  # expect: JXA502
 def vmap_trace_break():
     def fn(x):
-        return jax.lax.optimization_barrier(x * 2.0)
+        return _unbatchable_p.bind(x * 2.0)
 
     return EntryCase(fn=fn, args=(jnp.zeros(8, jnp.float32),))
 

@@ -1,5 +1,5 @@
 """Device resolver, compile-cache placement and the chip-only entry points
-(util/device.py, chip_smoke.py, bench.py) as seen from a CPU host: the
+(util/device.py, chip_smoke.py) as seen from a CPU host: the
 portable paths resolve, every demand-a-chip call fails and names what it
 found, and nothing writes a number.
 """
@@ -75,11 +75,11 @@ class TestCompileCache:
 
 
 class TestChipOnlyEntryPoints:
-    @pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
-    def test_refuses_without_a_chip(self, script, tmp_path):
+    def test_refuses_without_a_chip(self, tmp_path):
         """Exits non-zero before compiling anything, names the platform
         it found, prints no result line."""
-        r = _run(os.path.join(ROOT, script), cwd=str(tmp_path), script=True)
+        r = _run(os.path.join(ROOT, "chip_smoke.py"), cwd=str(tmp_path),
+                 script=True)
         assert r.returncode != 0
         assert "platform='cpu'" in r.stderr
         assert '"ok"' not in r.stdout and '"value"' not in r.stdout

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sphexa_tpu.devtools.audit.core import subjaxprs
+from sphexa_tpu.devtools.primitives import CALL_PRIMS, walk_eqns
 from sphexa_tpu.dtypes import KEY_BITS, KEY_DTYPE
 from sphexa_tpu.neighbors.cell_list import NeighborConfig
 from sphexa_tpu.parallel import exchange as ex
@@ -239,14 +239,14 @@ def _search_queries(jaxpr):
     """Query counts of every jnp.searchsorted in the program."""
     return [
         int(np.prod(eqn.invars[1].aval.shape, dtype=np.int64))
-        for eqn in subjaxprs(jaxpr)
-        if eqn.primitive.name in ("pjit", "jit")
+        for eqn in walk_eqns(jaxpr)
+        if eqn.primitive.name in CALL_PRIMS
         and eqn.params.get("name") == "searchsorted"
     ]
 
 
 def _sort_operands(jaxpr):
-    return [len(e.invars) for e in subjaxprs(jaxpr) if e.primitive.name == "sort"]
+    return [len(e.invars) for e in walk_eqns(jaxpr) if e.primitive.name == "sort"]
 
 
 @pytest.mark.parametrize("P", [4, 8])

@@ -12,6 +12,7 @@ process boundary every run.
 """
 
 import json
+import re
 from pathlib import Path
 
 import jax
@@ -32,6 +33,7 @@ from sphexa_tpu.devtools.audit.lowerdiff import (
     structural_diff,
     write_lock,
 )
+from sphexa_tpu.devtools.primitives import CALL_PRIMS
 from sphexa_tpu.util.phases import phase_scope
 
 REPO_ROOT = Path(__file__).resolve().parent.parent
@@ -61,11 +63,12 @@ class TestFingerprint:
         assert fp_a.digest != fp_c.digest
 
     def test_jitted_and_inner_jaxprs(self):
-        # a jitted callable traces to one pjit eqn whose body the walk
+        # a jitted callable traces to one call eqn whose body the walk
         # expands inline — the eqn count must see the body, not the call
         fp = fingerprint_callable(jax.jit(_double), jnp.ones(4))
-        assert fp.eqns >= 2  # the pjit call + at least the mul
-        assert any("pjit" in ln for ln in fp.lines)
+        assert fp.eqns >= 2  # the jit call + at least the mul
+        prims = [re.search(r" = (\w+)\[", ln).group(1) for ln in fp.lines]
+        assert prims[0] in CALL_PRIMS and "mul" in prims[1:]
 
     def test_phase_attribution(self):
         def fn(x):
