@@ -21,7 +21,7 @@ from sphexa_tpu.propagator import _sort_by_keys
 from sphexa_tpu.simulation import make_propagator_config
 from sphexa_tpu.sph import pallas_pairs as pp
 from sphexa_tpu.sph.hydro_std import compute_eos_std
-from sphexa_tpu.sph.pair_lists import build_pair_lists, estimate_slot_cap
+from sphexa_tpu.sph.pair_lists import build_pair_lists, estimate_list_caps
 
 
 def _barrier(out):
@@ -62,10 +62,12 @@ def main():
 
     h_max = float(jnp.max(h))
     skin = args.skin_rel * 2.0 * h_max
-    scap = estimate_slot_cap(x, y, z, h, keys, box, nbr, skin)
-    print(f"skin={skin:.5f} ({args.skin_rel} x 2h_max)  slot_cap={scap}")
+    scap, rows = estimate_list_caps(x, y, z, h, keys, box, nbr, skin)
+    print(f"skin={skin:.5f} ({args.skin_rel} x 2h_max)  slot_cap={scap}  "
+          f"slots_cap={rows}")
 
-    build = jax.jit(lambda *a: build_pair_lists(*a, box, nbr, skin, scap))
+    build = jax.jit(
+        lambda *a: build_pair_lists(*a, box, nbr, skin, scap, rows))
     t_build, lists = timed(build, x, y, z, h, keys)
     assert int(lists.overflow) == 0
     lanes = float(lists.lanes_total) / state.n

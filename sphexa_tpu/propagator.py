@@ -161,6 +161,9 @@ class PropagatorConfig:
     # global sort AND the candidate prologue, momentum ops lane-compact,
     # cheap ops chunk-skip. Sized at configure time like every cap.
     list_slot_cap: int = 0
+    # rows of the lists' flat lane table (one per kept chunk, all groups
+    # together), sized with list_slot_cap from the same host pass
+    list_slots_cap: int = 0
     # case observable computed in-graph alongside the conservation
     # ledger (observables/ledger.py); None = energies only
     obs: Optional[ObservableSpec] = None
@@ -279,7 +282,8 @@ def rebuild_pair_lists(state: ParticleState, box: Box,
         skin = jnp.float32(cfg.list_skin_rel) * 2.0 * jnp.max(state.h)
         lists = build_pair_lists(
             state.x, state.y, state.z, state.h, keys, box, cfg.nbr,
-            skin, cfg.list_slot_cap, interpret=_pallas_interpret(),
+            skin, cfg.list_slot_cap, cfg.list_slots_cap,
+            interpret=_pallas_interpret(),
         )
     return state, box, lists, aux
 

@@ -331,9 +331,9 @@ def make_propagator_config(
         )
 
     nbr = make_nbr(size_window(4.0 * h_max * 1.1))
-    slot_cap = 0
+    slot_cap = slots_cap = 0
     if use_lists and backend == "pallas" and not device_sizing:
-        from sphexa_tpu.sph.pair_lists import estimate_slot_cap
+        from sphexa_tpu.sph.pair_lists import estimate_list_caps
         from sphexa_tpu.sph.pallas_pairs import engine_fold
 
         # fold-mode eligibility is checked on the UNinflated window: the
@@ -366,7 +366,7 @@ def make_propagator_config(
                 # device keygen+argsort at 1M costs tens of ms per
                 # reconfigure for nothing)
                 skeys = _jnp.asarray(keys[order])
-                slot_cap = estimate_slot_cap(
+                slot_cap, slots_cap = estimate_list_caps(
                     _jnp.asarray(xa[order]), _jnp.asarray(ya[order]),
                     _jnp.asarray(za[order]),
                     _jnp.asarray(h[order] * np.float32(h_relax)),
@@ -375,7 +375,8 @@ def make_propagator_config(
     return PropagatorConfig(
         const=const, nbr=nbr, curve=curve, block=block, av_clean=av_clean,
         keep_accels=keep_accels, backend=backend,
-        list_slot_cap=slot_cap, list_skin_rel=list_skin_rel, obs=obs_spec,
+        list_slot_cap=slot_cap, list_slots_cap=slots_cap,
+        list_skin_rel=list_skin_rel, obs=obs_spec,
         snap=snap_spec,
         dt_bins=dt_bins, bin_sync_every=bin_sync_every,
         bin_resort_drift=bin_resort_drift,
@@ -1276,9 +1277,12 @@ class Simulation:
         reconfigure), ``age_steps`` (verified steps the outgoing list
         served, counted to ``served_to``, default the current
         iteration), ``slack`` (the fetched ``list_slack`` that triggered
-        it, None where none did), ``slot_need``/``slot_cap`` and
-        ``attempts``. All of it is host state or rides the overflow
-        fetch the rebuild always made."""
+        it, None where none did), ``slot_need``/``slot_cap`` (chunk slots
+        of the fullest group against the per-group budget),
+        ``slots_live``/``slots_cap`` (rows of the flat lane table in use
+        against its budget: the occupancy) and ``attempts``. All of it
+        is host state or rides the overflow fetch the rebuild always
+        made."""
         import jax as _jax
 
         from sphexa_tpu.propagator import rebuild_pair_lists
@@ -1298,15 +1302,16 @@ class Simulation:
             aux = self.chem if self.prop_name == "std-cooling" else None
             # the outgoing list is dead weight from here on: let go of it
             # before the build allocates its successor (at 1.1M Noh a
-            # list is 1.8-3.6 GiB, and a rollback's rebuild beside the
-            # old one and the pin ran a 16 GB chip out of memory)
+            # list was 1.8-3.6 GiB with a dense lane table, and a
+            # rollback's rebuild beside the old one and the pin ran a
+            # 16 GB chip out of memory; the flat table is 1.3 GB)
             self._lists = None
             with self.telemetry.span("sphexa:rebuild-lists"):
                 state, box, lists, aux = rebuild_pair_lists(
                     self.state, self.box, self._cfg, aux
                 )
-                overflow, need = (int(v) for v in _jax.device_get(
-                    (lists.overflow, lists.slot_need)))
+                overflow, need, live = (int(v) for v in _jax.device_get(
+                    (lists.overflow, lists.slot_need, lists.slots_live)))
             if not overflow:
                 self.state, self.box, self._lists = state, box, lists
                 if aux is not None:
@@ -1321,6 +1326,7 @@ class Simulation:
                     age_steps=age,
                     slack=None if slack is None else round(slack, 6),
                     slot_need=need, slot_cap=self._cfg.list_slot_cap,
+                    slots_live=live, slots_cap=self._cfg.list_slots_cap,
                     attempts=attempt,
                     rate=None if rate is None else round(rate, 6),
                     cover_steps=cover,

@@ -403,6 +403,13 @@ def _round_up(v: int, q: int) -> int:
     return -(-v // q) * q
 
 
+#: rows of one int32 sublane tile: a group's segment of the persistent
+#: lists' flat lane table (sph/pair_lists.py) starts on one and is a whole
+#: number of them, so the build's tile DMAs and the walk's element-offset
+#: fetch both stay aligned
+LIST_ROW_TILE = 8
+
+
 def _dma_rows(cap: int) -> int:
     """Rows of 128 covering any cell range [s, s+len<=cap): the range
     starts at lane offset s%128 inside row s//128 and extends at most
@@ -874,6 +881,10 @@ def group_pair_engine_lists(
     Contract differences from the streaming engine:
     - call(lists, i_fields, j_packed, i_offset, allow_self) — runs come
       from lists.ranges (build-time, skin-inflated);
+    - the gather indices come from the lists' FLAT table (one row per
+      kept chunk, sized by the sum over groups): a group's rows are a
+      window of slot_cap rows from its segment's first row, so chunk k
+      of the group is row k of the block;
     - no fold mode (lists are disabled on tiny grids), no chunk pairing,
       no AABB chunk-skip (the cnt>0 test replaces it at zero DMA cost);
     - the candidate's GLOBAL sorted-array index is staged as an f32 row
@@ -885,12 +896,14 @@ def group_pair_engine_lists(
     IDXR = num_j                       # sublane row of the staged index
 
     def kernel(*refs):
+        # refs[0]: the scalar-prefetched segment offsets, read by the
+        # lane table's index map alone
         (starts, lens, shx_r, shy_r, shz_r, ncells, ioff, aself,
-         cnt_r, fill_r, emit_r, tail_r) = refs[:12]
-        i_refs = refs[12 : 12 + num_i]
-        jref = refs[12 + num_i]
-        gidx_ref = refs[13 + num_i]
-        out_refs = refs[14 + num_i : -2]
+         cnt_r, fill_r, emit_r, tail_r) = refs[1:13]
+        i_refs = refs[13 : 13 + num_i]
+        jref = refs[13 + num_i]
+        gidx_ref = refs[14 + num_i]
+        out_refs = refs[15 + num_i : -2]
         nc_ref = refs[-2]
         (buf, sems, acc_refs, ncacc_ref, stage) = refs[-1]
 
@@ -969,7 +982,7 @@ def group_pair_engine_lists(
                 def _():
                     # gidx arrives PRE-ROTATED by the staging fill, so
                     # the compaction + rotation is ONE lane gather
-                    gi_row = gidx_ref[0, si][None, :]  # (1, 128) int32
+                    gi_row = gidx_ref[si][None, :]  # (1, 128) int32
                     rolled = jnp.take_along_axis(
                         buf[slot, t],
                         jnp.broadcast_to(gi_row, (nf_pad, 128)), axis=1,
@@ -1048,13 +1061,14 @@ def group_pair_engine_lists(
             )
         )
         smem_spec = lambda shape: pl.BlockSpec(
-            shape, lambda g: (g, 0, 0), memory_space=pltpu.SMEM
+            shape, lambda g, seg: (g, 0, 0), memory_space=pltpu.SMEM
         )
         rep_spec = lambda shape: pl.BlockSpec(
-            shape, lambda g: (0, 0, 0), memory_space=pltpu.SMEM
+            shape, lambda g, seg: (0, 0, 0), memory_space=pltpu.SMEM
         )
+        vmem_spec = lambda: pl.BlockSpec((1, 1, G), lambda g, seg: (g, 0, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0,
+            num_scalar_prefetch=1,         # lists.seg
             grid=(num_groups,),
             in_specs=[
                 smem_spec((1, 1, w3)),     # starts
@@ -1070,19 +1084,19 @@ def group_pair_engine_lists(
                 smem_spec((1, 1, S_cap)),  # emit
                 smem_spec((1, 1, 1)),      # tail
             ]
-            + [
-                pl.BlockSpec((1, 1, G), lambda g: (g, 0, 0))
-                for _ in range(num_i)
-            ]
+            + [vmem_spec() for _ in range(num_i)]
             + [
                 pl.BlockSpec(memory_space=pl.ANY),             # j_packed
-                pl.BlockSpec((1, S_cap, 128), lambda g: (g, 0, 0)),  # gidx
+                # gidx: the group's rows of the flat table, an element-
+                # offset window of slot_cap rows from its segment's
+                # first row (auto-pipelined across grid steps like any
+                # block; Mosaic must see the offset divide the tile)
+                pl.BlockSpec(
+                    (pl.Element(_round_up(S_cap, LIST_ROW_TILE)),
+                     pl.Element(128)),
+                    lambda g, seg: (seg[g] * LIST_ROW_TILE, 0)),
             ],
-            out_specs=[
-                pl.BlockSpec((1, 1, G), lambda g: (g, 0, 0))
-                for _ in range(num_out_arrays)
-            ]
-            + [pl.BlockSpec((1, 1, G), lambda g: (g, 0, 0))],
+            out_specs=[vmem_spec() for _ in range(num_out_arrays + 1)],
             scratch_shapes=[
                 pltpu.VMEM((2, R, nf_pad, 128), jnp.float32),
                 pltpu.SemaphoreType.DMA((2,)),
@@ -1096,6 +1110,7 @@ def group_pair_engine_lists(
             for _ in range(num_out_arrays)
         ] + [jax.ShapeDtypeStruct((num_groups, 1, G), jnp.int32)]
         args = (
+            lists.seg,
             smem3(ranges.starts), smem3(ranges.lens),
             smem3(ranges.shift_x), smem3(ranges.shift_y),
             smem3(ranges.shift_z),

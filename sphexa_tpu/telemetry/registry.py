@@ -46,12 +46,17 @@ import numpy as np
 #: be shorter than ``check_every``) and optional ``rate`` /
 #: ``cover_steps`` on ``rebuild_lists`` (the skin fraction per step the
 #: plan was made with, and the life predicted for the outgoing list).
-#: No kind, no REQUIRED field: v11 readers accept v1-v10 files clean.
-SCHEMA_VERSION = 11
+#: No kind, no REQUIRED field: v11 readers accept v1-v10 files clean;
+#: v12 the flat lane table's occupancy: optional ``slots_live`` /
+#: ``slots_cap`` on ``rebuild_lists`` (rows of the pair lists' flat
+#: lane table in use, each group's kept chunks rounded up to the 8-row
+#: tile, and the static row budget they were built into). No kind, no
+#: REQUIRED field: v12 readers accept v1-v11 files clean.
+SCHEMA_VERSION = 12
 
 #: event schema versions this reader understands (older versions only
 #: ever ADD kinds, so the per-kind field table below covers them all)
-SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11)
+SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12)
 
 #: every event kind the schema admits, with its required payload fields
 #: (beyond the envelope ``v``/``seq``/``t``/``kind``). The CLI's --strict
@@ -67,7 +72,7 @@ EVENT_KINDS: Dict[str, tuple] = {
     "retrace": ("it", "delta"),   # jit cache grew on a launch (recompile)
     # persistent pair lists (re)built; since v10 with the optional WHY
     # payload: reason, age_steps, slack, slot_need, slot_cap, attempts;
-    # since v11 also rate, cover_steps
+    # since v11 also rate, cover_steps; since v12 slots_live, slots_cap
     "rebuild_lists": ("it",),
     "phases": ("it",),            # per-iteration host phase laps
     "trace": ("dir",),            # jax.profiler trace started

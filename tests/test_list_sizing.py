@@ -202,3 +202,33 @@ def test_failed_build_is_retried_under_its_own_reason(monkeypatch):
     last = sink.of_kind("rebuild_lists")[-1]
     assert last["reason"] == "proactive" and last["attempts"] == 1
     assert sim.pair_lists is not None
+
+
+def test_flat_table_overflow_resizes_and_retries_once(monkeypatch):
+    """The flat lane table's budget (rows for the SUM of kept chunks) is a
+    static cap like the per-group one: a build that needs more rows than
+    it has raises the same sentinel, the driver re-sizes under
+    ``list-slot`` and the second attempt fits."""
+    import sphexa_tpu.sph.pair_lists as pair_lists
+
+    # (the budget is taken up to a post-pass tile: at a row tile here,
+    # so a table can be short at this size at all)
+    monkeypatch.setattr(pair_lists, "LIST_TABLE_TILE", 8)
+    real, sized = pair_lists.estimate_list_caps, []
+
+    def short_table_first(*args, **kw):
+        slot_cap, slots_cap = real(*args, **kw)
+        sized.append(slots_cap)
+        return slot_cap, (64 if len(sized) == 1 else slots_cap)
+
+    monkeypatch.setattr(pair_lists, "estimate_list_caps", short_table_first)
+    sim, sink, _ = _drive(init_noh, 14, 2, flushed=2)
+    first = sink.of_kind("rebuild_lists")[0]
+    assert first["reason"] == "first" and first["attempts"] == 2
+    assert 64 < first["slots_live"] <= first["slots_cap"]
+    assert first["slot_need"] <= first["slot_cap"]
+    resized = [e for e in sink.of_kind("reconfigure")
+               if e["reason"] == "list-slot"]
+    assert len(resized) == 1
+    assert sim.pair_lists is not None
+    assert sim.pair_lists.slots_cap == sim.active_cfg.list_slots_cap

@@ -174,10 +174,13 @@ def test_rebuild_event_says_why(driven):
     _, _, sink, _, _ = driven
     events = sink.of_kind("rebuild_lists")
     for e in events:
-        assert e["v"] == SCHEMA_VERSION == 11 and validate_event(e) == []
+        assert e["v"] == SCHEMA_VERSION == 12 and validate_event(e) == []
         assert e["reason"] in ("first", "proactive", "expiry", "rollback",
                                "reconfigure")
         assert 0 < e["slot_need"] <= e["slot_cap"] and e["attempts"] >= 1
+        # v12: the flat lane table's occupancy, whole 8-row tiles
+        assert 0 < e["slots_live"] <= e["slots_cap"]
+        assert e["slots_live"] % 8 == 0
         triggered = e["reason"] in ("proactive", "expiry", "rollback")
         assert (e["slack"] is not None) == triggered
         if e["reason"] == "proactive":
@@ -292,20 +295,21 @@ def test_kicked_trajectory_matches_streaming(kicked, streamed_12, key):
 
 class TestSchemaV10:
     def test_v10_and_v11_add_no_kind_and_no_required_field(self):
-        assert SCHEMA_VERSION == 11
-        assert not {10, 11} & set(KIND_SINCE.values())
+        # (nor does v12: ``rebuild_lists.slots_live`` / ``slots_cap``)
+        assert SCHEMA_VERSION == 12
+        assert not {10, 11, 12} & set(KIND_SINCE.values())
         assert EVENT_KINDS["rebuild_lists"] == ("it",)
         assert EVENT_KINDS["window"] == ("it", "steps", "wall_s",
                                          "per_step_s")
 
-    @pytest.mark.parametrize("version", range(1, 12))
+    @pytest.mark.parametrize("version", range(1, 13))
     def test_bare_rebuild_event_validates_at_every_version(self, version):
         # a v1-v9 writer's event carries ``it`` alone; later readers take it
         e = {"v": version, "seq": 0, "t": 1.0, "kind": "rebuild_lists",
              "it": 3}
         assert validate_event(e) == []
 
-    @pytest.mark.parametrize("version", range(1, 12))
+    @pytest.mark.parametrize("version", range(1, 13))
     def test_window_without_planned_steps_validates(self, version):
         # a v1-v10 writer's window has no ``planned_steps``
         e = {"v": version, "seq": 0, "t": 1.0, "kind": "window", "it": 8,
@@ -322,6 +326,14 @@ class TestSchemaV10:
              "steps": 3, "wall_s": 0.9, "per_step_s": 0.3,
              "planned_steps": 3}
         assert validate_event(w) == []
+
+    def test_v12_payload_validates(self):
+        e = {"v": 12, "seq": 0, "t": 1.0, "kind": "rebuild_lists", "it": 10,
+             "reason": "proactive", "age_steps": 7, "slack": 0.1,
+             "slot_need": 12, "slot_cap": 16, "slots_live": 4096,
+             "slots_cap": 8192, "attempts": 1, "rate": 0.15,
+             "cover_steps": 7}
+        assert validate_event(e) == []
 
     def test_v10_payload_validates(self):
         e = {"v": 10, "seq": 0, "t": 1.0, "kind": "rebuild_lists", "it": 8,

@@ -20,7 +20,7 @@ from sphexa_tpu.simulation import make_propagator_config
 from sphexa_tpu.sph import pallas_pairs as pp
 from sphexa_tpu.sph.pair_lists import (
     build_pair_lists,
-    estimate_slot_cap,
+    estimate_list_caps,
     lists_valid,
 )
 
@@ -48,9 +48,11 @@ def case(request):
 def built(case):
     ss, keys, box, const, nbr = case
     skin = 0.2 * float(jnp.max(ss.h))
-    scap = estimate_slot_cap(ss.x, ss.y, ss.z, ss.h, keys, box, nbr, skin)
+    scap, rows = estimate_list_caps(ss.x, ss.y, ss.z, ss.h, keys, box, nbr,
+                                    skin)
     lists = build_pair_lists(
-        ss.x, ss.y, ss.z, ss.h, keys, box, nbr, skin, scap, interpret=True
+        ss.x, ss.y, ss.z, ss.h, keys, box, nbr, skin, scap, rows,
+        interpret=True,
     )
     return lists, skin, scap
 
@@ -295,6 +297,262 @@ def test_slot_cap_overflow_sentinel(case):
     skin = 0.2 * float(jnp.max(ss.h))
     lists = build_pair_lists(
         ss.x, ss.y, ss.z, ss.h, keys, box, nbr, skin, slot_cap=2,
-        interpret=True,
+        slots_cap=1 << 16, interpret=True,
     )
     assert int(lists.overflow) == 1
+
+
+# -- the flat lane table (PR 28): one row per KEPT chunk, each group's rows
+# contiguous from an 8-row tile boundary, sized by the sum over groups ------
+
+
+def _dense_table(ss, nbr, lists, skin):
+    """The dense ``(groups, slot_cap, 128)`` table the flat one replaced,
+    in numpy: the mark test redone over the list's (pruned) runs, then the
+    old post-passes verbatim (cnt, fill, cumsum, dst, 128-wide sort)."""
+    f32 = np.float32
+    x, y, z, h = (np.asarray(a, f32) for a in (ss.x, ss.y, ss.z, ss.h))
+    n, G = x.shape[0], nbr.group
+    rg = lists.ranges
+    starts, lens, ncells = (np.asarray(a) for a in
+                            (rg.starts, rg.lens, rg.ncells))
+    shifts = [np.asarray(a, f32) for a in
+              (rg.shift_x, rg.shift_y, rg.shift_z)]
+    ng, scap = np.asarray(lists.cnt).shape
+    bits = np.zeros((ng, scap, 128), np.int32)
+    pad = lambda a: np.concatenate([a, np.zeros(128 + nbr.dma_cap, f32)])
+    xp, yp, zp = pad(x), pad(y), pad(z)
+    lane = np.arange(128)
+    for g in range(ng):
+        ii = np.minimum(np.arange(g * G, (g + 1) * G), n - 1)
+        r = f32(2.0) * h[ii].max() + f32(skin)
+        lo = [a[ii].min() - r for a in (x, y, z)]
+        hi = [a[ii].max() + r for a in (x, y, z)]
+        slot = 0
+        for w in range(ncells[g]):
+            s, ln = starts[g, w], lens[g, w]
+            row0 = s // 128
+            for t in range((s - row0 * 128 + ln + 127) // 128):
+                cand = (row0 + t) * 128 + lane
+                m = (cand >= s) & (cand < s + ln)
+                for jp, sh, a, b in zip((xp, yp, zp), shifts, lo, hi):
+                    j = jp[cand] + sh[g, w]
+                    m &= (j >= a) & (j <= b)
+                if slot < scap:
+                    bits[g, slot] = m
+                slot += 1
+    cnt = bits.sum(-1)
+    csum = np.cumsum(cnt, axis=1)
+    fill = (csum - cnt) % 128
+    lanes = np.broadcast_to(lane, bits.shape)
+    rank1 = np.cumsum(bits, axis=2) - bits
+    dst = np.where(bits > 0, fill[:, :, None] + rank1,
+                   fill[:, :, None] + cnt[:, :, None] + lanes - rank1) % 128
+    return np.argsort(dst, axis=2, kind="stable").astype(np.int32), cnt, fill
+
+
+def _assert_flat_is_dense(ss, nbr, lists, skin):
+    rot, cnt, fill = _dense_table(ss, nbr, lists, skin)
+    np.testing.assert_array_equal(np.asarray(lists.cnt), cnt)
+    np.testing.assert_array_equal(np.asarray(lists.fill), fill)
+    gidx, seg = np.asarray(lists.gidx), np.asarray(lists.seg)
+    kept = (cnt > 0).sum(1)
+    tiles = (kept + 7) // 8
+    # segments: whole tiles, contiguous, in group order, none shared
+    np.testing.assert_array_equal(seg, np.cumsum(tiles) - tiles)
+    assert int(lists.slots_live) == 8 * tiles.sum() <= lists.slots_cap
+    assert gidx.shape == (lists.slots_cap + -(-lists.slot_cap // 8) * 8, 128)
+    for g in range(len(kept)):
+        # kept chunks are compacted to the front of the dense arrays, so
+        # row k of the group's segment is dense slot k, lane for lane
+        assert (cnt[g, :kept[g]] > 0).all()
+        np.testing.assert_array_equal(
+            gidx[8 * seg[g]:8 * seg[g] + kept[g]], rot[g, :kept[g]],
+            err_msg=f"group {g}")
+    return kept
+
+
+def test_flat_table_reproduces_the_dense_one_row_for_row(case, built):
+    ss, keys, box, const, nbr = case
+    lists, skin, _ = built
+    kept = _assert_flat_is_dense(ss, nbr, lists, skin)
+    # what the layout is for: it stores the sum, not groups x slot_cap
+    assert int(lists.slots_live) < kept.shape[0] * lists.slot_cap
+
+
+def test_table_segments_zero_kept_and_the_tables_end():
+    from sphexa_tpu.sph.pair_lists import _table_segments
+
+    # kept chunks 3, 0, 8, 9: tiles 1, 0, 1, 2; a group that keeps
+    # nothing takes no row and shares its offset with its successor
+    cnt = jnp.asarray([[5, 1, 7] + [0] * 9, [0] * 12, [1] * 8 + [0] * 4,
+                       [2] * 9 + [0] * 3], jnp.int32)
+    seg, ntile, live = _table_segments(cnt, 32)
+    assert (list(map(int, seg)), list(map(int, ntile)), int(live)) == (
+        [0, 1, 1, 2], [1, 0, 1, 2], 32)   # the last segment ends the table
+    # one tile short: the last group writes what fits, nothing past the
+    # budget, and the rows needed say so (the caller's sentinel)
+    seg, ntile, live = _table_segments(cnt, 24)
+    assert (list(map(int, seg)), list(map(int, ntile)), int(live)) == (
+        [0, 1, 1, 2], [1, 0, 1, 1], 32)
+    seg, ntile, live = _table_segments(cnt, 8)
+    assert (list(map(int, seg)), list(map(int, ntile)), int(live)) == (
+        [0, 1, 1, 1], [1, 0, 0, 0], 32)
+
+
+def _skewed_noh():
+    """Noh 12^3 with groups of 4 and a quarter of h: most groups keep two
+    chunks, the fullest six, as the SFC-straddler groups of the 1.1M
+    sphere keep 3-5 x the median."""
+    import dataclasses
+
+    state, box, const = init_noh(12)
+    state = dataclasses.replace(state, h=state.h * 0.25)
+    cfg = make_propagator_config(state, box, const, block=4096,
+                                 backend="pallas", group=4, use_lists=True,
+                                 list_skin_rel=0.2)
+    ss, keys, _ = _sort_by_keys(state, box, "hilbert")
+    return ss, keys, box, const, cfg
+
+
+def test_skewed_group_builds_at_the_estimated_caps(monkeypatch):
+    """One group keeps >= 3 x the median: the table holds the SUM, so
+    neither cap is raised for it; with the post-pass tile at a row tile
+    the table is exactly as long as its rows, so the last group's
+    segment ends it and its fetch reads the window pad."""
+    import sphexa_tpu.sph.pair_lists as pair_lists
+
+    ss, keys, box, const, cfg = _skewed_noh()
+    skin = 0.2 * 2.0 * float(jnp.max(ss.h))
+    args = (ss.x, ss.y, ss.z, ss.h, keys, box, cfg.nbr, skin)
+    lists = build_pair_lists(*args, cfg.list_slot_cap, cfg.list_slots_cap,
+                             interpret=True)
+    assert int(lists.overflow) == 0
+    kept = _assert_flat_is_dense(ss, cfg.nbr, lists, skin)
+    assert kept.max() >= 3 * np.median(kept)
+    assert int(lists.slot_need) <= cfg.list_slot_cap
+
+    monkeypatch.setattr(pair_lists, "LIST_TABLE_TILE", 8)
+    live = int(lists.slots_live)
+    tight = build_pair_lists(*args, cfg.list_slot_cap, live, interpret=True)
+    assert int(tight.overflow) == 0 and tight.slots_cap == live
+    assert 8 * int(tight.seg[-1]) + 8 * ((kept[-1] + 7) // 8) == live
+    np.testing.assert_array_equal(np.asarray(tight.gidx)[:live],
+                                  np.asarray(lists.gidx)[:live])
+    short = build_pair_lists(*args, cfg.list_slot_cap, live - 8,
+                             interpret=True)
+    assert int(short.overflow) == 1 and int(short.slots_live) == live
+    assert 8 * int(short.seg.max()) <= short.slots_cap
+
+    # the walk engine on the skewed and on the tight table vs streaming
+    x, y, z, h, m = ss.x, ss.y, ss.z, ss.h, ss.m
+    nbr = cfg.nbr
+    rho, nc0, _ = pp.pallas_density(x, y, z, h, m, keys, box, const, nbr,
+                                    interpret=True)
+    from sphexa_tpu.sph.hydro_std import compute_eos_std
+
+    p, c = compute_eos_std(ss.temp, rho, const)
+    cs, _ = pp.pallas_iad(x, y, z, h, m / rho, keys, box, const, nbr,
+                          interpret=True)
+    margs = (x, y, z, ss.vx, ss.vy, ss.vz, h, m, rho, p, c, *cs)
+    ref = pp.pallas_momentum_energy_std(*margs, keys, box, const, nbr,
+                                        interpret=True)
+    outs = [pp.pallas_momentum_energy_std(*margs, None, box, const, nbr,
+                                          interpret=True, lists=ls)
+            for ls in (lists, tight)]
+    scale = float(jnp.max(jnp.abs(ref[0])))
+    for out in outs:
+        for a, b in zip(out[:3], ref[:3]):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-5 * scale)
+    for a, b in zip(*outs):  # same rows, same order: bit for bit
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_slots_cap_overflow_sentinel(case):
+    ss, keys, box, const, nbr = case
+    skin = 0.2 * float(jnp.max(ss.h))
+    scap, _ = estimate_list_caps(ss.x, ss.y, ss.z, ss.h, keys, box, nbr,
+                                 skin)
+    lists = build_pair_lists(
+        ss.x, ss.y, ss.z, ss.h, keys, box, nbr, skin, scap, slots_cap=8,
+        interpret=True,
+    )
+    # the budget is taken up to one post-pass tile; sedov 30^3 needs more
+    need = int(lists.slots_live)
+    assert int(lists.overflow) == int(need > lists.slots_cap)
+    assert int(lists.slot_need) <= scap
+
+
+def _prune_reference(starts, lens, shifts, ncells, cnt):
+    """``_prune_empty_chunks`` in plain loops: maximal stretches of kept
+    chunks within one candidate run, with exact particle bounds."""
+    ng, scap = cnt.shape
+    out = {k: np.zeros((ng, scap), starts.dtype if k in "sl" else np.float32)
+           for k in ("s", "l", "x", "y", "z")}
+    heads = np.zeros(ng, np.int32)
+    perm = np.zeros((ng, scap), np.int32)
+    for g in range(ng):
+        slot, runs, kept_slots = 0, [], []
+        for w in range(starts.shape[1]):
+            s, ln = int(starts[g, w]), int(lens[g, w])
+            if ln <= 0:
+                continue
+            row0, cur = s // 128, None
+            for c in range((s % 128 + ln + 127) // 128):
+                row = row0 + c
+                if slot < scap and cnt[g, slot] > 0:
+                    kept_slots.append(slot)
+                    hi = min(s + ln, (row + 1) * 128)
+                    if cur is None:
+                        cur = [max(s, row * 128), hi, w]
+                        runs.append(cur)
+                    else:
+                        cur[1] = hi
+                else:
+                    cur = None
+                slot += 1
+        heads[g] = len(runs)
+        for k, (lo, hi, w) in enumerate(runs):
+            out["s"][g, k], out["l"][g, k] = lo, hi - lo
+            for key, sh in zip("xyz", shifts):
+                out[key][g, k] = sh[g, w]
+        rest = [s for s in range(scap) if s not in set(kept_slots)]
+        perm[g] = kept_slots + rest
+    return out, heads, perm
+
+
+@pytest.mark.parametrize("thin", [False, True], ids=["marked", "thinned"])
+def test_prune_matches_a_plain_loop(case, built, thin):
+    """The prune's slot -> run lookups (masked sums over the runs since
+    PR 28, gathers before) against loops over runs and chunks; ``thinned``
+    also drops every third kept chunk, so stretches break inside runs."""
+    from sphexa_tpu.sph.pair_lists import _prune_empty_chunks
+
+    ss, keys, box, const, nbr = case
+    _, skin, scap = built
+    ranges = pp.group_cell_ranges(ss.x, ss.y, ss.z, ss.h, keys, box, nbr,
+                                  radius_pad=skin)
+    starts, lens = np.asarray(ranges.starts), np.asarray(ranges.lens)
+    nch = np.where(lens > 0, (starts % 128 + lens + 127) // 128, 0).sum(1)
+    rng = np.random.default_rng(7)
+    cnt = rng.integers(0, 3, (starts.shape[0], scap)).astype(np.int32)
+    cnt *= (np.arange(scap)[None, :] < nch[:, None])
+    if thin:
+        cnt[:, ::3] = 0
+    new, packed = _prune_empty_chunks(ranges, jnp.asarray(cnt), scap)
+    shifts = [np.asarray(a) for a in
+              (ranges.shift_x, ranges.shift_y, ranges.shift_z)]
+    ref, heads, ref_perm = _prune_reference(
+        starts, lens, shifts, np.asarray(ranges.ncells), cnt)
+    np.testing.assert_array_equal(np.asarray(new.ncells), heads)
+    np.testing.assert_array_equal(np.asarray(new.starts), ref["s"])
+    np.testing.assert_array_equal(np.asarray(new.lens), ref["l"])
+    for got, key in zip((new.shift_x, new.shift_y, new.shift_z), "xyz"):
+        np.testing.assert_array_equal(np.asarray(got), ref[key])
+    # the kept slots' counts, compacted to the front in their order
+    kept_first = np.take_along_axis(cnt, ref_perm, axis=1)
+    live = (kept_first > 0).sum(1)
+    assert (np.diff((kept_first > 0).astype(int), axis=1) <= 0).all()
+    assert live.max() > 0
+    np.testing.assert_array_equal(np.asarray(packed), kept_first)
