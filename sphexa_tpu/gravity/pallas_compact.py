@@ -59,8 +59,9 @@ IDX_MASK = (1 << IDX_BITS) - 1
 DEAD = 2 << IDX_BITS
 
 
-def _kernel(pk_ref, out0_ref, out1_ref, cnt_ref, stage_ref):
-    T = pk_ref.shape[1]
+def _kernel(pk_ref, out0_ref, out1_ref, cnt_ref, stage_ref, chunks=None):
+    """``chunks``: how many of the row's T chunks to walk (None = all)."""
+    T = pk_ref.shape[1] if chunks is None else chunks
     out_rows = (out0_ref.shape[1], out1_ref.shape[1])
 
     out0_ref[0] = jnp.zeros((out_rows[0], 128), jnp.int32)
@@ -137,9 +138,16 @@ def _kernel(pk_ref, out0_ref, out1_ref, cnt_ref, stage_ref):
         lane1 == 0, done[0], jnp.where(lane1 == 1, done[1], 0))
 
 
+def _kernel_live(live_ref, pk_ref, out0_ref, out1_ref, cnt_ref, stage_ref):
+    """The same walk over the row's first ``ceil(live / 128)`` chunks."""
+    live = live_ref[pl.program_id(0)]
+    chunks = jnp.clip((live + 127) // 128, 0, pk_ref.shape[1])
+    _kernel(pk_ref, out0_ref, out1_ref, cnt_ref, stage_ref, chunks=chunks)
+
+
 @functools.partial(jax.jit, static_argnames=("cap0", "cap1", "interpret"))
 def compact_class_lists(packed, cap0: int, cap1: int,
-                        interpret: bool = False):
+                        interpret: bool = False, live=None):
     """Compact each row's class-0 and class-1 slots into fixed-cap lists.
 
     ``packed``: (B, C) int32, ``(cls << IDX_BITS) | value`` with value in
@@ -148,6 +156,13 @@ def compact_class_lists(packed, cap0: int, cap1: int,
     i32, n1 (B,) i32)`` — values in candidate order, UNCLIPPED true counts
     (entries beyond a cap are truncated; slots beyond a count are 0 and
     must be masked by the caller).
+
+    ``live``: optional (B,) int32, the caller's promise that row b holds
+    nothing but dropped slots from slot ``live[b]`` on. The chunk walk of
+    row b then ends at ``ceil(live[b] / 128)`` (a scalar-prefetch operand
+    bounds the kernel's loop) and never reads the slots past that chunk,
+    so they need not even be written; lists and counts are those of the
+    full walk, bit for bit. Without it every chunk is walked.
     """
     B, C = packed.shape
     T = max(1, -(-C // 128))
@@ -158,23 +173,32 @@ def compact_class_lists(packed, cap0: int, cap1: int,
     pk = packed.reshape(B, T, 128)
     r0 = max(1, -(-cap0 // 128))
     r1 = max(1, -(-cap1 // 128))
-    outs = pl.pallas_call(
-        _kernel,
+    # index maps take the prefetched scalars after the grid indices
+    row = lambda b, *_: (b, 0, 0)
+    spec = dict(
         grid=(B,),
-        in_specs=[pl.BlockSpec((1, T, 128), lambda b: (b, 0, 0))],
+        in_specs=[pl.BlockSpec((1, T, 128), row)],
         out_specs=[
-            pl.BlockSpec((1, r0, 128), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, r1, 128), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1, 128), lambda b: (b, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, r0, 128), jnp.int32),
-            jax.ShapeDtypeStruct((B, r1, 128), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1, 128), jnp.int32),
+            pl.BlockSpec((1, r0, 128), row),
+            pl.BlockSpec((1, r1, 128), row),
+            pl.BlockSpec((1, 1, 128), row),
         ],
         scratch_shapes=[pltpu.VMEM((2, 8, 256), jnp.float32)],
-        interpret=interpret,
-    )(pk)
+    )
+    out_shape = [
+        jax.ShapeDtypeStruct((B, r0, 128), jnp.int32),
+        jax.ShapeDtypeStruct((B, r1, 128), jnp.int32),
+        jax.ShapeDtypeStruct((B, 1, 128), jnp.int32),
+    ]
+    if live is None:
+        outs = pl.pallas_call(_kernel, out_shape=out_shape,
+                              interpret=interpret, **spec)(pk)
+    else:
+        outs = pl.pallas_call(
+            _kernel_live, out_shape=out_shape, interpret=interpret,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, **spec),
+        )(live.astype(jnp.int32), pk)
     list0 = outs[0].reshape(B, r0 * 128)[:, :cap0]
     list1 = outs[1].reshape(B, r1 * 128)[:, :cap1]
     return list0, outs[2][:, 0, 0], list1, outs[2][:, 0, 1]

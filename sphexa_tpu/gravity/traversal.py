@@ -95,9 +95,11 @@ class GravityConfig:
     # tests/test_gravity.py), and the shape the hierarchical superblock
     # path needs to pay. The dense sort stays selectable everywhere.
     compaction: str = "sort"
-    # m2p cap sizing margin: M2P eval cost is linear in m2p_cap, and the
+    # m2p cap sizing margin: M2P eval cost WAS linear in m2p_cap, and the
     # generic 1.5-1.6 sizing margin left ~35 ms of eval slack at 1M
-    # (docs/NEXT.md round 5). Applied by estimate_gravity_caps to the m2p
+    # (docs/NEXT.md round 5); since the evaluation runs in tiles up to the
+    # lists' own counts (m2p_tiled) the cap only sizes storage, and this
+    # knob can go (ROADMAP D3). Applied by estimate_gravity_caps to the m2p
     # cap only; overflow is guarded by the m2p_max diagnostic exactly
     # like let_max (Simulation regrows the margin and re-sizes on
     # overflow, so a too-tight cap costs a retry, never dropped nodes).
@@ -678,6 +680,61 @@ def _monotone_mac_geometry(box, tree, meta, node_com, valid, theta):
     return ccenter, chalf, mac2
 
 
+#: slots per tile of the block loop's width-following stages: a stage
+#: walks its list in tiles of this many slots and stops after the last
+#: tile that holds a live one, so its work follows the iteration's own
+#: list lengths while the caps stay the lists' storage sizes and overflow
+#: guards. Sized on the v5e at Evrard 1.1M (PERF.md, PR 30): small enough
+#: that the rounding to whole tiles wastes little, large enough that a
+#: tile's gather and fusion launches amortize.
+_CAND_TILE = 2048  # candidates of one superblock (gather + classify)
+_M2P_TILE = 512  # accepted nodes (packed-row gather + quadrupole terms)
+_P2P_TILE = 128  # near-field leaves (row-range lookup)
+
+
+def _live_tiles(live, tile: int, cap: int):
+    """How many ``tile``-slot tiles of a ``cap``-slot list hold one of
+    its first ``live`` slots."""
+    return jnp.clip((live + (tile - 1)) // tile, 0, -(-cap // tile))
+
+
+def _pad_slots(a, tile: int, fill):
+    """``a`` with its last axis padded by ``fill`` to whole tiles."""
+    pad = -a.shape[-1] % tile
+    if pad == 0:
+        return a
+    return jnp.concatenate(
+        [a, jnp.full(a.shape[:-1] + (pad,), fill, a.dtype)], axis=-1)
+
+
+def m2p_tiled(eval_tile, rows, order_m, m2p_ok, live, zero, tile=None):
+    """Far field of one fixed-cap M2P list, evaluated tile by tile.
+
+    ``order_m`` (cap,) row indices into ``rows`` (one packed payload row
+    per node), ``m2p_ok`` (cap,) the list's live slots,
+    ``eval_tile(rows_of_tile, ok_of_tile)`` the masked partial sums of
+    one tile as a tuple of arrays shaped like ``zero``. Tiles are
+    accumulated in list order and only those below ``live`` run (a traced
+    scalar: under ``vmap`` over blocks the fullest block's count, passed
+    unbatched, so the blocks share one trip count). A tile past the
+    list's own count adds exact zeros, so the result does not depend on
+    how many such tiles run; which is what keeps the sort and the bitmask
+    compactions bitwise equal under different ``live``.
+    """
+    cap = order_m.shape[0]
+    tile = min(tile or _M2P_TILE, cap)
+    order_m = _pad_slots(order_m, tile, 0)
+    m2p_ok = _pad_slots(m2p_ok, tile, False)
+
+    def body(t, acc):
+        om = jax.lax.dynamic_slice(order_m, (t * tile,), (tile,))
+        ok = jax.lax.dynamic_slice(m2p_ok, (t * tile,), (tile,))
+        return tuple(a + o for a, o in zip(acc, eval_tile(rows[om], ok)))
+
+    return jax.lax.fori_loop(0, _live_tiles(live, tile, cap), body,
+                             (zero,) * 4)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("meta", "cfg", "with_phi", "shard"))
 def compute_gravity(
@@ -842,19 +899,31 @@ def compute_gravity(
             let_n = jnp.sum(cand_s)
 
     @named_phase("gravity-m2p")
-    def _m2p_eval(tx, ty, tz, order_m, m2p_ok):
-        """Far-field eval of one block's fixed-cap M2P list. Shared by
-        the sort and bitmask compactions: identical masked sums over
-        identical slot layouts keep the two paths bitwise equal."""
-        nd = node_packed[jnp.minimum(order_m, num_n - 1)]  # one row gather
+    def _m2p_eval(tx, ty, tz, order_m, m2p_ok, live):
+        """Far-field eval of one block's fixed-cap M2P list, in tiles up
+        to ``live`` slots (m2p_tiled; the fullest list of the blocks
+        evaluated together, unbatched under their vmap). Shared by the
+        sort and bitmask compactions: identical masked sums over
+        identical tiles keep the two paths bitwise equal."""
         if cfg.multipole_order > 0:
             from sphexa_tpu.gravity import spherical as sp
 
             nc_ = sp.ncoef(cfg.multipole_order)
-            coeffs = jax.lax.complex(nd[:, 4 : 4 + nc_], nd[:, 4 + nc_ :])
-            return sp.m2p(tx, ty, tz, nd[:, 0:3], coeffs, m2p_ok,
-                          cfg.multipole_order)
-        return mp.m2p(tx, ty, tz, nd[:, 0:3], nd[:, 3:10], nd[:, 10], m2p_ok)
+
+            def eval_tile(nd, ok):
+                coeffs = jax.lax.complex(nd[:, 4 : 4 + nc_],
+                                         nd[:, 4 + nc_ :])
+                return sp.m2p(tx, ty, tz, nd[:, 0:3], coeffs, ok,
+                              cfg.multipole_order)
+        else:
+            def eval_tile(nd, ok):
+                return mp.m2p(tx, ty, tz, nd[:, 0:3], nd[:, 3:10],
+                              nd[:, 10], ok)
+
+        # one packed-row gather per tile
+        return m2p_tiled(eval_tile, node_packed,
+                         jnp.minimum(order_m, num_n - 1), m2p_ok, live,
+                         jnp.zeros_like(tx))
 
     # the sparse near-field exchange serves by leaf (``edges`` is its
     # cell table): each range is one leaf's rows, so the leaf it was made
@@ -862,18 +931,38 @@ def compute_gravity(
     # (exchange.localize_ranges_sparse ``cells``)
     carry_leaf = shard is not None and isinstance(shard[2], tuple)
 
+    # (start, length, leaf) of every node's row range, looked up once
+    # per solve, so that a list slot costs ONE row gather (a TPU gather
+    # pays per index, 7 ns on the v5e, whatever the row holds)
+    with phase_scope("gravity-p2p"):
+        lidx_n = jnp.clip(tree.leaf_of_node, 0, meta.num_leaves - 1)
+        node_rows = jnp.stack(
+            [edges[lidx_n], edges[lidx_n + 1] - edges[lidx_n], lidx_n],
+            axis=1)
+
     @named_phase("gravity-p2p")
-    def _p2p_leaf_ranges(order_p, p2p_ok):
+    def _p2p_leaf_ranges(order_p, p2p_ok, live):
         """Sorted-array row ranges of one block's near-field leaves, as
         a tuple (start, length[, leaf]): ``leaf`` only for the sparse
-        sharded serve (``carry_leaf``)."""
-        order_p = jnp.minimum(order_p, num_n - 1)
-        lidx = tree.leaf_of_node[order_p]  # (P,)
-        start = jnp.where(p2p_ok, edges[lidx], 0)
-        length = jnp.where(p2p_ok, edges[lidx + 1] - edges[lidx], 0)
-        if carry_leaf:
-            return start, length, jnp.where(p2p_ok, lidx, 0)
-        return start, length
+        sharded serve (``carry_leaf``). Looked up in tiles up to ``live``
+        slots (as _m2p_eval's); slots past a list's count read 0."""
+        cap = order_p.shape[0]
+        tile = min(_P2P_TILE, cap)
+        order_p = _pad_slots(jnp.minimum(order_p, num_n - 1), tile, 0)
+        p2p_ok = _pad_slots(p2p_ok, tile, False)
+
+        def body(t, out):
+            at = (t * tile,)
+            rows = node_rows[jax.lax.dynamic_slice(order_p, at, (tile,))]
+            rows = jnp.where(
+                jax.lax.dynamic_slice(p2p_ok, at, (tile,))[:, None],
+                rows, 0)
+            return jax.lax.dynamic_update_slice(out, rows, (t * tile, 0))
+
+        out = jax.lax.fori_loop(
+            0, _live_tiles(live, tile, cap), body,
+            jnp.zeros((order_p.shape[0], 3), node_rows.dtype))[:cap]
+        return tuple(out[:, k] for k in range(3 if carry_leaf else 2))
 
     @named_phase("gravity-p2p")
     def _p2p_xla(tx, ty, tz, th, bi, start, length, p2p_ok):
@@ -888,6 +977,45 @@ def compute_gravity(
             tx, ty, tz, th,
             x[cand], y[cand], z[cand], m[cand], h[cand], pair_ok,
         )
+
+    def _target_blocks(*shape):
+        """(x, y, z, h) of the shifted targets in SFC-consecutive
+        blocks of ``shape``, the tail padded with the last particle:
+        what ``a[minimum(arange, n - 1)]`` gathers, as slices (a TPU
+        gather pays per index: 111 ms of an Evrard 1.1M solve went to
+        these identity gathers, PERF.md PR 30)."""
+        pad = int(np.prod(shape)) - n
+
+        def blocked(a):
+            if pad:
+                a = jnp.concatenate([a, jnp.broadcast_to(a[-1:], (pad,))])
+            return a.reshape(shape)
+
+        return tuple(blocked(a) for a in (
+            x + shift[0], y + shift[1], z + shift[2], h))
+
+    def _eval_bm(bi, tgt, om, mn, op, pn, m_live, p_live):
+        """One block's far field and near-field ranges from its two
+        compacted lists; ``m_live`` / ``p_live`` the fullest lists of
+        the blocks evaluated together (unbatched under their vmap)."""
+        tx, ty, tz, th = tgt
+        m2p_ok = jnp.arange(cfg.m2p_cap, dtype=jnp.int32) < mn
+        ax, ay, az, phi = _m2p_eval(tx, ty, tz, om, m2p_ok, m_live)
+        p2p_ok = jnp.arange(cfg.p2p_cap, dtype=jnp.int32) < pn
+        start, length, *leaf = _p2p_leaf_ranges(op, p2p_ok, p_live)
+        if cfg.use_pallas:
+            return (ax, ay, az, phi, mn, pn, start, length, *leaf)
+        pax, pay, paz, pphi = _p2p_xla(tx, ty, tz, th, bi, start,
+                                       length, p2p_ok)
+        return ax + pax, ay + pay, az + paz, phi + pphi, mn, pn
+
+    def _eval_blocks(bidx, tgt, om, mn, op, pn):
+        """_eval_bm over the blocks of one loop iteration, each stage
+        as wide as their fullest list."""
+        m_live = jnp.minimum(jnp.max(mn), cfg.m2p_cap)
+        p_live = jnp.minimum(jnp.max(pn), cfg.p2p_cap)
+        return jax.vmap(_eval_bm, in_axes=(0,) * 6 + (None, None))(
+            bidx, tgt, om, mn, op, pn, m_live, p_live)
 
     if use_bitmask:
         from sphexa_tpu.gravity import pallas_compact as pcmp
@@ -910,15 +1038,25 @@ def compute_gravity(
         dense_geo = (ccenter, chalf, mac2, pcc, pch, pmac2, anc_ok,
                      leaf_ok, valid, jnp.ones((num_n,), bool), iota_n)
 
+        # the same eleven, one row per node: a list slot costs ONE row
+        # gather instead of nine lookups (the three flags ride as a
+        # small integer, exact in f32)
+        geo_rows = jnp.concatenate(
+            [ccenter, chalf, mac2[:, None], pcc, pch, pmac2[:, None],
+             (anc_ok + 2 * leaf_ok + 4 * valid).astype(
+                 ccenter.dtype)[:, None]], axis=1)
+
         def _gather_geo(cidx, ok):
             """Candidate-space MAC arrays of one node list (gathered ONCE
             per list and shared by every block classifying against it —
             the per-block candidate gathers are what sank the round-4
             superblock formulation)."""
             ci = jnp.minimum(cidx, num_n - 1)
-            return (ccenter[ci], chalf[ci], mac2[ci], pcc[ci], pch[ci],
-                    pmac2[ci], anc_ok[ci] & ok, leaf_ok[ci] & ok,
-                    valid[ci] & ok, ok, ci)
+            g = geo_rows[ci]
+            flags = g[:, 14].astype(jnp.int32)
+            return (g[:, 0:3], g[:, 3:6], g[:, 6], g[:, 7:10], g[:, 10:13],
+                    g[:, 13], ((flags & 1) > 0) & ok,
+                    ((flags & 2) > 0) & ok, ((flags & 4) > 0) & ok, ok, ci)
 
         def _packed_cls(bc, bs, geo):
             """Per-candidate M2P/P2P/pruned class, packed with the node
@@ -939,27 +1077,6 @@ def compute_gravity(
             cls = jnp.where(ok & ~anc, 0, 2)
             return (cls.astype(jnp.int32) << pcmp.IDX_BITS) | idxs
 
-        @named_phase("gravity-mac")
-        def _block_bm(bi, geo):
-            bc, bs = _bbox(x[bi] + shift[0], y[bi] + shift[1],
-                           z[bi] + shift[2])
-            return _packed_cls(bc, bs, geo)
-
-        def _eval_bm(bi, om, mn, op, pn):
-            tx = x[bi] + shift[0]
-            ty = y[bi] + shift[1]
-            tz = z[bi] + shift[2]
-            th = h[bi]
-            m2p_ok = jnp.arange(cfg.m2p_cap, dtype=jnp.int32) < mn
-            ax, ay, az, phi = _m2p_eval(tx, ty, tz, om, m2p_ok)
-            p2p_ok = jnp.arange(cfg.p2p_cap, dtype=jnp.int32) < pn
-            start, length, *leaf = _p2p_leaf_ranges(op, p2p_ok)
-            if cfg.use_pallas:
-                return (ax, ay, az, phi, mn, pn, start, length, *leaf)
-            pax, pay, paz, pphi = _p2p_xla(tx, ty, tz, th, bi, start,
-                                           length, p2p_ok)
-            return ax + pax, ay + pay, az + paz, phi + pphi, mn, pn
-
         if use_let:
             let_geo = _gather_geo(jnp.minimum(lidx_, num_n - 1), lok)
 
@@ -971,33 +1088,34 @@ def compute_gravity(
             # data gathered once per super, never per block.
             sblk = sf * blk
             num_super = -(-n // sblk)
-            sidx = jnp.arange(num_super * sblk, dtype=jnp.int32)
-            sidx = jnp.minimum(sidx, n - 1).reshape(num_super, sblk)
+            tgtb = _target_blocks(num_super, sf, blk)
             pre_geo = let_geo if use_let else dense_geo
 
             @named_phase("gravity-mac")
-            def one_super_pre(si):
-                bc, bs = _bbox(x[si] + shift[0], y[si] + shift[1],
-                               z[si] + shift[2])
-                return _packed_cand(bc, bs, pre_geo)
+            def one_super_pre(tx, ty, tz):
+                return _packed_cand(*_bbox(tx, ty, tz), pre_geo)
 
             spc = max(1, min(num_super, chunk))
             nsc = -(-num_super // spc)
-            sidx_p = jnp.concatenate(
-                [sidx, jnp.broadcast_to(sidx[-1:],
-                                        (nsc * spc - num_super, sblk))]
-            ) if nsc * spc > num_super else sidx
+
+            def pre_chunks(a):
+                # whole chunks: the tail repeats the last superblock
+                a = a.reshape(num_super, sblk)
+                if nsc * spc > num_super:
+                    a = jnp.concatenate([a, jnp.broadcast_to(
+                        a[-1:], (nsc * spc - num_super, sblk))])
+                return a.reshape(nsc, spc, sblk)
 
             @named_phase("gravity-mac")
-            def pre_chunk(sx):
-                pk = jax.vmap(one_super_pre)(sx)
+            def pre_chunk(tgt):
+                pk = jax.vmap(one_super_pre)(*tgt)
                 sc, sn, _, _ = pcmp.compact_class_lists(
                     pk, scap, 128, interpret=interp)
                 return sc, sn
 
             with phase_scope("gravity-mac"):
                 scand, scand_n = jax.lax.map(
-                    pre_chunk, sidx_p.reshape(nsc, spc, sblk))
+                    pre_chunk, tuple(pre_chunks(a) for a in tgtb[:3]))
             scand = scand.reshape(-1, scap)[:num_super]
             scand_n = scand_n.reshape(-1)[:num_super]
             c_max = jnp.max(scand_n)
@@ -1005,16 +1123,37 @@ def compute_gravity(
             idxb = jnp.arange(num_super * sf * blk, dtype=jnp.int32)
             idxb = jnp.minimum(idxb, n - 1).reshape(num_super, sf, blk)
 
+            ctile = min(_CAND_TILE, scap)
+            scand = _pad_slots(scand, ctile, num_n)
+
             def one_super_main(args):
-                sc, sn, bidx = args
+                sc, sn, bidx, tgt = args
                 with phase_scope("gravity-mac"):
-                    ok = jnp.arange(scap, dtype=jnp.int32) < jnp.minimum(
-                        sn, scap)
-                    geo = _gather_geo(sc, ok)
-                    pk = jax.vmap(lambda bi: _block_bm(bi, geo))(bidx)
+                    live = jnp.minimum(sn, scap)
+                    bc, bs = jax.vmap(_bbox)(*tgt[:3])
+
+                    def classify_tile(t, pk):
+                        # gather + classify the super's candidates a tile
+                        # at a time, up to its own count: the slots past
+                        # the last tile run stay DEAD, and the kernel
+                        # stops at the same count
+                        ci = jax.lax.dynamic_slice(sc, (t * ctile,),
+                                                   (ctile,))
+                        ok = t * ctile + jnp.arange(
+                            ctile, dtype=jnp.int32) < live
+                        geo = _gather_geo(ci, ok)
+                        return jax.lax.dynamic_update_slice(
+                            pk, jax.vmap(
+                                lambda c, s_: _packed_cls(c, s_, geo))(
+                                    bc, bs), (0, t * ctile))
+
+                    pk = jax.lax.fori_loop(
+                        0, _live_tiles(live, ctile, scap), classify_tile,
+                        jnp.full((sf, sc.shape[0]), pcmp.DEAD, jnp.int32))
                     om, mn, op, pn = pcmp.compact_class_lists(
-                        pk, cfg.m2p_cap, cfg.p2p_cap, interpret=interp)
-                return jax.vmap(_eval_bm)(bidx, om, mn, op, pn)
+                        pk, cfg.m2p_cap, cfg.p2p_cap, interpret=interp,
+                        live=jnp.broadcast_to(live, (sf,)))
+                return _eval_blocks(bidx, tgt, om, mn, op, pn)
 
             # the block loops carry the MAC's scope: the loop op, its
             # per-iteration slicing and stacking and the copies XLA adds
@@ -1025,19 +1164,25 @@ def compute_gravity(
             # a reader that takes the innermost scope sees them, one
             # that takes the outermost sees the loop whole
             with phase_scope("gravity-mac"):
-                out = jax.lax.map(one_super_main, (scand, scand_n, idxb))
+                out = jax.lax.map(one_super_main,
+                                  (scand, scand_n, idxb, tgtb))
         else:
             geo0 = let_geo if use_let else dense_geo
 
-            def one_chunk_bm(bidx):
+            def one_chunk_bm(args):
+                bidx, tgt = args
                 with phase_scope("gravity-mac"):
-                    pk = jax.vmap(lambda bi: _block_bm(bi, geo0))(bidx)
+                    bc, bs = jax.vmap(_bbox)(*tgt[:3])
+                    pk = jax.vmap(
+                        lambda c, s_: _packed_cls(c, s_, geo0))(bc, bs)
                     om, mn, op, pn = pcmp.compact_class_lists(
                         pk, cfg.m2p_cap, cfg.p2p_cap, interpret=interp)
-                return jax.vmap(_eval_bm)(bidx, om, mn, op, pn)
+                return _eval_blocks(bidx, tgt, om, mn, op, pn)
 
             with phase_scope("gravity-mac"):
-                out = jax.lax.map(one_chunk_bm, idx)
+                out = jax.lax.map(
+                    one_chunk_bm,
+                    (idx, _target_blocks(num_chunks, chunk, blk)))
 
     if not use_bitmask and sf > 0:
         # superblock pre-pass (the two-level hierarchical classification):
@@ -1074,111 +1219,100 @@ def compute_gravity(
         scand = scand.reshape(-1, scap)
         scand_ok = scand_ok.reshape(-1, scap)
         spar = spar.reshape(-1, scap)
+        scand_n = scand_n.reshape(-1)[:num_super]
         c_max = jnp.max(scand_n)
 
-    def one_block(bi, bnum):
-        """bi: (blk,) particle indices of one target group; bnum: its
-        block index (selects the superblock candidate list)."""
-        tx, ty, tz, th = x[bi] + shift[0], y[bi] + shift[1], z[bi] + shift[2], h[bi]
-        with phase_scope("gravity-mac"):
-            bc, bs = _bbox(tx, ty, tz)
+    @named_phase("gravity-mac")
+    def one_block_lists(tgt, bnum):
+        """The two interaction lists of one target group, by the sort
+        compaction: (order_m, m2p_n, order_p, p2p_n), the UNCLIPPED counts
+        as the bitmask kernel returns them. tgt: its (blk,) targets; bnum:
+        its block index (selects the superblock candidate list)."""
+        bc, bs = _bbox(*tgt[:3])
 
-            if sf > 0 or use_let:
-                if sf > 0:
-                    sid = bnum // sf
-                    cidx = jnp.minimum(scand[sid], num_n - 1)
-                    cok = scand_ok[sid]
-                    ppos = spar[sid]
-                else:
-                    # LET: the shard-wide essential list, shared by blocks
-                    cidx = jnp.minimum(lidx_, num_n - 1)
-                    cok = lok
-                    ppos = lpar
-                accept = cok & valid[cidx] & _accept(
-                    bc, bs, ccenter[cidx], chalf[cidx], mac2[cidx]
-                )
-                # monotone MAC: the first accepted ancestor IS the parent.
-                # The root's parent is ITSELF — mask self-parents or an
-                # accepted root (far replica shifts) would mark itself as its
-                # own accepted ancestor and zero the whole interaction
-                not_self = cidx[ppos] != cidx
-                anc = accept[ppos] & not_self
-                m2p_mask = accept & ~anc
-                p2p_mask = cok & tree.is_leaf[cidx] & valid[cidx] & ~accept
+        if sf > 0 or use_let:
+            if sf > 0:
+                sid = bnum // sf
+                cidx = jnp.minimum(scand[sid], num_n - 1)
+                cok = scand_ok[sid]
+                ppos = spar[sid]
             else:
-                cidx = None
-                accept = valid & _accept(bc, bs, ccenter, chalf, mac2)
-                # monotone MAC (see mac2 above): one parent gather replaces
-                # the per-level first-accepted-ancestor downsweep, and
-                # ~accept already implies no accepted ancestor for leaves
-                anc = jnp.where(self_parent, False, accept[tree.parent])
-                m2p_mask = accept & ~anc
-                p2p_mask = tree.is_leaf & valid & ~accept
-            m2p_n = jnp.sum(m2p_mask)
-            p2p_n = jnp.sum(p2p_mask)
-
-            # ONE 3-class sort compacts both interaction lists: class-0 nodes
-            # (M2P) land first, class-1 (P2P leaves) directly after, so the
-            # P2P list is a dynamic slice at the M2P count. The class and the
-            # node index ride in one PACKED int32 key (class in the top bits,
-            # index below) — a single single-operand sort where a stable
-            # argsort + sort pair cost ~2x (the 208 ms phase at 1M);
-            # unique keys make it
-            # order-preserving within a class by construction
-            cls = jnp.where(m2p_mask, 0, jnp.where(p2p_mask, 1, 2))
-            cls_len = cls.shape[0]
-            nbits = max(1, int(np.ceil(np.log2(max(cls_len, 2)))))
-            iota_k = jnp.arange(cls_len, dtype=jnp.int32)
-            # measured equals: lax.top_k(k = m2p_cap + p2p_cap) on the
-            # negated keys costs the SAME as the full sort at 1M/58k nodes
-            # (803.8 vs 798.7 ms end-to-end) — XLA's TPU top_k is not a
-            # partial sort win at k/N ~ 13%; keep the simpler full sort
-            ks = jnp.sort((cls.astype(jnp.int32) << nbits) | iota_k)
-            order_all = ks & jnp.int32((1 << nbits) - 1)
-            cls_sorted = ks >> nbits
-            if cidx is not None:
-                order_all = cidx[order_all]
-            # sentinel-pad so the fixed-cap slices below stay in range when
-            # the candidate list is shorter than a cap (tiny trees / small
-            # super lists)
-            padn = max(cfg.m2p_cap, cfg.p2p_cap)
-            order_all = jnp.concatenate(
-                [order_all, jnp.full((padn,), num_n - 1, order_all.dtype)]
+                # LET: the shard-wide essential list, shared by blocks
+                cidx = jnp.minimum(lidx_, num_n - 1)
+                cok = lok
+                ppos = lpar
+            accept = cok & valid[cidx] & _accept(
+                bc, bs, ccenter[cidx], chalf[cidx], mac2[cidx]
             )
-            cls_sorted = jnp.concatenate(
-                [cls_sorted, jnp.full((padn,), 2, cls_sorted.dtype)]
-            )
-            order_m = jnp.minimum(order_all[: cfg.m2p_cap], num_n - 1)
-            m2p_ok = cls_sorted[: cfg.m2p_cap] == 0
-        ax, ay, az, phi = _m2p_eval(tx, ty, tz, order_m, m2p_ok)
+            # monotone MAC: the first accepted ancestor IS the parent.
+            # The root's parent is ITSELF — mask self-parents or an
+            # accepted root (far replica shifts) would mark itself as its
+            # own accepted ancestor and zero the whole interaction
+            not_self = cidx[ppos] != cidx
+            anc = accept[ppos] & not_self
+            m2p_mask = accept & ~anc
+            p2p_mask = cok & tree.is_leaf[cidx] & valid[cidx] & ~accept
+        else:
+            cidx = None
+            accept = valid & _accept(bc, bs, ccenter, chalf, mac2)
+            # monotone MAC (see mac2 above): one parent gather replaces
+            # the per-level first-accepted-ancestor downsweep, and
+            # ~accept already implies no accepted ancestor for leaves
+            anc = jnp.where(self_parent, False, accept[tree.parent])
+            m2p_mask = accept & ~anc
+            p2p_mask = tree.is_leaf & valid & ~accept
+        m2p_n = jnp.sum(m2p_mask)
+        p2p_n = jnp.sum(p2p_mask)
 
-        # dynamic_slice clamps the start when m2p_n is near the array
-        # end; the slice then still covers the whole class-1 block and
-        # stray class-0/2 entries are masked
-        order_p = jax.lax.dynamic_slice(order_all, (m2p_n,), (cfg.p2p_cap,))
-        p2p_ok = jax.lax.dynamic_slice(
-            cls_sorted, (m2p_n,), (cfg.p2p_cap,)
-        ) == 1
-        start, length, *leaf = _p2p_leaf_ranges(order_p, p2p_ok)
-
-        if cfg.use_pallas:
-            # defer the near field to the streamed engine (below)
-            return (ax, ay, az, phi, m2p_n, p2p_n, start, length, *leaf)
-
-        pax, pay, paz, pphi = _p2p_xla(tx, ty, tz, th, bi, start, length,
-                                       p2p_ok)
-        return ax + pax, ay + pay, az + paz, phi + pphi, m2p_n, p2p_n
+        # ONE 3-class sort compacts both interaction lists: class-0 nodes
+        # (M2P) land first, class-1 (P2P leaves) directly after, so the
+        # P2P list is a dynamic slice at the M2P count. The class and the
+        # node index ride in one PACKED int32 key (class in the top bits,
+        # index below) — a single single-operand sort where a stable
+        # argsort + sort pair cost ~2x (the 208 ms phase at 1M);
+        # unique keys make it
+        # order-preserving within a class by construction
+        cls = jnp.where(m2p_mask, 0, jnp.where(p2p_mask, 1, 2))
+        cls_len = cls.shape[0]
+        nbits = max(1, int(np.ceil(np.log2(max(cls_len, 2)))))
+        iota_k = jnp.arange(cls_len, dtype=jnp.int32)
+        # measured equals: lax.top_k(k = m2p_cap + p2p_cap) on the
+        # negated keys costs the SAME as the full sort at 1M/58k nodes
+        # (803.8 vs 798.7 ms end-to-end) — XLA's TPU top_k is not a
+        # partial sort win at k/N ~ 13%; keep the simpler full sort
+        ks = jnp.sort((cls.astype(jnp.int32) << nbits) | iota_k)
+        order_all = ks & jnp.int32((1 << nbits) - 1)
+        if cidx is not None:
+            order_all = cidx[order_all]
+        # sentinel-pad so the fixed-cap slices below stay in range when
+        # the candidate list is shorter than a cap (tiny trees / small
+        # super lists)
+        padn = max(cfg.m2p_cap, cfg.p2p_cap)
+        order_all = jnp.concatenate(
+            [order_all, jnp.full((padn,), num_n - 1, order_all.dtype)]
+        )
+        # class 0 sorts first and class 1 directly after it, so both
+        # lists are prefixes: slot k of the M2P list is live iff k <
+        # m2p_n, of the P2P list (a slice at the M2P count; the
+        # sentinel pad keeps it in range) iff k < p2p_n. _eval_bm
+        # masks by the counts, as for the kernel's lists.
+        return (order_all[: cfg.m2p_cap], m2p_n,
+                jax.lax.dynamic_slice(order_all, (m2p_n,),
+                                      (cfg.p2p_cap,)), p2p_n)
 
     if not use_bitmask:
         bnum = jnp.arange(num_chunks * chunk, dtype=jnp.int32)
         bnum = jnp.minimum(bnum, num_blocks - 1).reshape(num_chunks, chunk)
 
         def one_chunk(args):
-            bidx, bn = args
-            return jax.vmap(one_block)(bidx, bn)
+            bidx, bn, tgt = args
+            return _eval_blocks(bidx, tgt,
+                                *jax.vmap(one_block_lists)(tgt, bn))
 
         with phase_scope("gravity-mac"):
-            out = jax.lax.map(one_chunk, (idx, bnum))
+            out = jax.lax.map(
+                one_chunk,
+                (idx, bnum, _target_blocks(num_chunks, chunk, blk)))
     escaped = jnp.asarray(False)
     grav_halo_metrics = None
     if cfg.use_pallas:
@@ -1303,6 +1437,15 @@ def compute_gravity(
             # JXA201 gates)
             p2p_hw = chain_after(p2p_hw, jd[0])
         p2p_hw = fold_escape_sentinel(p2p_hw, escaped, cfg.p2p_cap, shard[0])
+
+    def fill(counts, cap, lists):
+        """Live slots of ``lists`` real lists over their ``cap`` slots
+        each: how far the block loop's width-following stages engage
+        (their work goes with the numerator, the storage with the
+        denominator)."""
+        return (jnp.sum(jnp.minimum(counts, cap)).astype(jnp.float32)
+                / jnp.float32(lists * cap))
+
     diagnostics = {
         "m2p_max": jnp.max(m2p_n),
         "p2p_max": p2p_hw,
@@ -1321,6 +1464,12 @@ def compute_gravity(
             (jnp.sum(m2p_n) + jnp.sum(p2p_n)).astype(jnp.float32)
             / jnp.float32(evals)
         ),
+        # list occupancies (0 = no superblock lists); on a mesh the
+        # fullest shard's, like every diagnostic here
+        "cand_fill": (fill(scand_n, scap, num_super) if sf > 0
+                      else jnp.float32(0)),
+        "m2p_fill": fill(m2p_n, cfg.m2p_cap, num_blocks),
+        "p2p_fill": fill(p2p_n, cfg.p2p_cap, num_blocks),
     }
     if grav_halo_metrics is not None:
         # sparse MAC-window mode only (the windowed / grav_window=0
@@ -1443,7 +1592,8 @@ def compute_gravity_on_mesh(x, y, z, m, h, sorted_keys, box: Box,
 
     dspec = sharded_diag_specs(win, (
         "m2p_max", "p2p_max", "leaf_occ", "c_max", "let_max",
-        "compact_width", "mac_work_ratio"))
+        "compact_width", "mac_work_ratio", "cand_fill", "m2p_fill",
+        "p2p_fill"))
     Pp, Pr = PartitionSpec(axis), PartitionSpec()
     return shard_map(
         stage,

@@ -1604,6 +1604,19 @@ class Simulation:
             or not self._lists_fresh(diagnostics)
         )
 
+    @staticmethod
+    def _list_fills(fetched) -> Dict[str, float]:
+        """Schema-v13 payload of a verified window's (or checked step's)
+        event where the steps solved gravity: ``cand_fill`` / ``m2p_fill``
+        / ``p2p_fill``, the tree solve's list occupancies (live slots ÷
+        lists x cap, traversal.compute_gravity) averaged over the steps.
+        ``fetched`` is already on the host: no transfer is added."""
+        return {
+            k: round(float(np.mean([float(d[k]) for d in fetched])), 6)
+            for k in ("cand_fill", "m2p_fill", "p2p_fill")
+            if all(k in d for d in fetched)
+        }
+
     def _emit_distributed(self, diagnostics, steps: int) -> None:
         """Schema-v2 distributed telemetry at the fetch boundary: one
         ``shard_load`` + one ``exchange`` event per checked step / clean
@@ -2038,6 +2051,7 @@ class Simulation:
             "step", it=self.iteration, wall_s=round(wall, 6),
             dt=float(result["dt"]) if "dt" in result else None,
             reconfigured=bool(reconfigured),
+            **self._list_fills([diagnostics]),
         )
         self._emit_distributed(diagnostics, steps=1)
         self._emit_science([diagnostics], [self.iteration])
@@ -2135,6 +2149,7 @@ class Simulation:
             wall_s=round(window_wall, 6),
             per_step_s=round(window_wall / len(pending), 6),
             planned_steps=self._plan.steps,
+            **self._list_fills(fetched),
         )
         # distributed telemetry rides the SAME fetch: per-shard
         # load/exchange events + HBM snapshot, at window granularity

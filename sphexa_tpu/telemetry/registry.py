@@ -51,12 +51,18 @@ import numpy as np
 #: ``slots_cap`` on ``rebuild_lists`` (rows of the pair lists' flat
 #: lane table in use, each group's kept chunks rounded up to the 8-row
 #: tile, and the static row budget they were built into). No kind, no
-#: REQUIRED field: v12 readers accept v1-v11 files clean.
-SCHEMA_VERSION = 12
+#: REQUIRED field: v12 readers accept v1-v11 files clean;
+#: v13 the tree solve's list occupancies: optional ``cand_fill`` /
+#: ``m2p_fill`` / ``p2p_fill`` on ``window`` and ``step`` where the
+#: steps solved gravity (live slots over lists x cap of the superblock
+#: candidate lists and of the blocks' M2P and P2P lists, averaged over
+#: the window's steps: how far the block loop's width-following stages
+#: engage). No kind, no REQUIRED field: v13 readers accept v1-v12 files.
+SCHEMA_VERSION = 13
 
 #: event schema versions this reader understands (older versions only
 #: ever ADD kinds, so the per-kind field table below covers them all)
-SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12)
+SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13)
 
 #: every event kind the schema admits, with its required payload fields
 #: (beyond the envelope ``v``/``seq``/``t``/``kind``). The CLI's --strict
@@ -64,7 +70,8 @@ SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12)
 EVENT_KINDS: Dict[str, tuple] = {
     "launch": ("it",),            # one deferred-window step dispatched
     "step": ("it", "wall_s"),     # one synchronously checked step done
-    # deferred flush; since v11 with the optional ``planned_steps``
+    # deferred flush; since v11 with the optional ``planned_steps``;
+    # since v13 (as "step") with the optional list fills of a gravity run
     "window": ("it", "steps", "wall_s", "per_step_s"),
     "reconfigure": ("it", "reason"),
     "rollback": ("it", "steps", "reason"),

@@ -267,6 +267,89 @@ class TestBitmaskCompaction:
         assert int(out_b[4]["m2p_max"]) == int(out_s[4]["m2p_max"]) >= 1
 
 
+class TestWidthFollowsTheLists:
+    """The block loop's stages walk their lists in tiles up to the
+    iteration's own counts (traversal.m2p_tiled and its siblings); the
+    caps stay storage sizes and overflow guards."""
+
+    TILE, CAP = 128, 416  # the cap is no whole number of tiles
+
+    @pytest.mark.parametrize("length", [0, 1, 128, 129, 416])
+    def test_tiled_m2p_is_m2p_and_ignores_dead_tiles(self, length):
+        from sphexa_tpu.gravity import multipole as mp
+        from sphexa_tpu.gravity.traversal import m2p_tiled
+
+        rng = np.random.default_rng(length)
+        n_nodes, B = 900, 64
+        rows = jnp.asarray(np.concatenate(
+            [rng.normal(size=(n_nodes, 3)) * 3.0 + 20.0,       # com, far
+             rng.normal(size=(n_nodes, 7)) * 0.1,              # quadrupole
+             rng.uniform(0.5, 2.0, size=(n_nodes, 1)),         # mass
+             np.zeros((n_nodes, 1))], axis=1), jnp.float32)
+        tx, ty, tz = (jnp.asarray(rng.uniform(-1, 1, B), jnp.float32)
+                      for _ in range(3))
+        order = jnp.asarray(rng.integers(0, n_nodes, self.CAP), jnp.int32)
+        ok = jnp.arange(self.CAP) < length
+
+        def eval_tile(nd, okt):
+            return mp.m2p(tx, ty, tz, nd[:, 0:3], nd[:, 3:10], nd[:, 10],
+                          okt)
+
+        def tiled(live):
+            return m2p_tiled(eval_tile, rows, order, ok, jnp.int32(live),
+                             jnp.zeros_like(tx), tile=self.TILE)
+
+        got = tiled(length)
+        want = eval_tile(rows[order], ok)
+        for name, a, b in zip(("ax", "ay", "az", "phi"), got, want):
+            scale = float(jnp.max(jnp.abs(b))) or 1.0
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=0, atol=2e-6 * scale,
+                                       err_msg=name)
+        # dead tiles add exact zeros: any trip count past the list's own
+        for live in (min(length + self.TILE, self.CAP), self.CAP):
+            for name, a, b in zip(("ax", "ay", "az", "phi"), got,
+                                  tiled(live)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                              err_msg=name)
+        if length == 0:
+            assert all(not np.any(np.asarray(a)) for a in got)
+
+    @pytest.mark.parametrize("shape", ["sort", "bitmask-supers"])
+    def test_fill_diagnostics_are_the_counts(self, shape):
+        """cand_fill / m2p_fill / p2p_fill against every block and
+        superblock of a small Evrard sphere classified in numpy."""
+        import dataclasses
+
+        import jax
+        from gravity_counts import counted_fills
+
+        from sphexa_tpu.init import init_evrard
+        from sphexa_tpu.sfc.box import make_global_box
+
+        state, box, _ = init_evrard(20)
+        gbox = make_global_box(state.x, state.y, state.z, box)
+        keys = compute_sfc_keys(state.x, state.y, state.z, gbox)
+        o = jnp.argsort(keys)
+        x, y, z, m, h = (a[o] for a in (state.x, state.y, state.z, state.m,
+                                        state.h))
+        keys = keys[o]
+        cfg = GravityConfig(theta=0.5, bucket_size=64)
+        if shape != "sort":
+            cfg = dataclasses.replace(cfg, target_block=32, super_factor=4,
+                                      compaction="bitmask")
+        tree, meta = build_gravity_tree(np.asarray(keys), cfg.bucket_size)
+        cfg = estimate_gravity_caps(x, y, z, m, keys, gbox, tree, meta, cfg)
+        diag = jax.device_get(compute_gravity(
+            x, y, z, m, h, keys, gbox, tree, meta, cfg)[-1])
+        assert int(diag["m2p_max"]) <= cfg.m2p_cap
+        want = counted_fills(x, y, z, m, keys, gbox, tree, meta, cfg)
+        got = [float(diag[k]) for k in ("cand_fill", "m2p_fill", "p2p_fill")]
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+        assert (got[0] > 0) == (shape != "sort")
+        assert 0 < got[1] < 1 and 0 < got[2] < 1
+
+
 @pytest.mark.slow
 def test_hierarchical_mac_matches_dense():
     """The two-level superblock classification must reproduce the dense

@@ -145,6 +145,31 @@ RUNNER = """
                           p99=rec["gravity_rel_p99"],
                           limits=[stated["gravity_rel_rms_max"],
                                   stated["gravity_rel_p99_max"]])
+    # the stage's three list fills on the live state, and the same counted
+    # in numpy over every block and superblock each slab forms (geometry
+    # from a one-device upsweep of the same rows); and the verified
+    # windows' events, which carry the steps' fills
+    import dataclasses
+    sys.path[:0] = [{tests!r}]
+    from gravity_counts import counted_fills
+    from sphexa_tpu.gravity import traversal as tv
+    s1 = sim.state
+    gb = make_global_box(s1.x, s1.y, s1.z, sim.box)
+    k1 = compute_sfc_keys(s1.x, s1.y, s1.z, gb, curve=sim.curve)
+    o1 = jnp.argsort(k1)
+    srt = [a[o1] for a in (s1.x, s1.y, s1.z, s1.m, s1.h)] + [k1[o1]]
+    g = sim._cfg.gravity
+    meta = sim._cfg.grav_meta
+    diag = jax.device_get(tv.compute_gravity(
+        *srt, gb, sim._gtree, meta, dataclasses.replace(g, G=const.g))[-1])
+    one = [jnp.asarray(np.asarray(a)) for a in srt]
+    fill_keys = ("cand_fill", "m2p_fill", "p2p_fill")
+    out["fills"] = dict(
+        stage=[float(diag[k]) for k in fill_keys],
+        counted=[float(v) for v in counted_fills(
+            *one[:4], one[5], gb, sim._gtree, meta, g, shards=4)],
+        windows=[[e.get(k) for k in fill_keys]
+                 for e in sink.events[mark:] if e["kind"] == "window"])
     # the scopes of the step this run launched, from its lowered IR
     import io, re
     ss = sim.sim_state
@@ -188,7 +213,8 @@ RUNNER = """
 def mesh_run(request):
     from conftest import run_mesh_subprocess
 
-    code = RUNNER.format(bench=BENCH, case=request.param, side=SIDE,
+    code = RUNNER.format(bench=BENCH, tests=TESTS, case=request.param,
+                         side=SIDE,
                          steps=STEPS, seed=SEED, targets=TARGETS,
                          trip_margin=TRIP_MARGIN)
     out = run_mesh_subprocess(code, timeout=900)
@@ -281,6 +307,23 @@ def test_benchmark_gravity_check_runs_the_mesh_solve(mesh_run):
     for reading in ("theta_control", "bf16_ref"):
         *_, rms, p99 = r[reading]
         assert rms > rms_max or p99 > p99_max, (reading, rms, p99)
+
+
+def test_list_fills_are_the_fullest_slabs_counts(mesh_run):
+    """cand_fill / m2p_fill / p2p_fill of the sharded stage (pmax over
+    shards) against numpy counts over the slabs' own blocks; and every
+    verified window's event carries the steps' fills (schema v13)."""
+    case, r = mesh_run
+    f = r["fills"]
+    # the stage's multipoles are a psum of per-slab sums: a node at the
+    # MAC's edge may flip against the one-device geometry counted here
+    np.testing.assert_allclose(f["stage"], f["counted"], rtol=2e-3,
+                               atol=1e-7)
+    assert (f["stage"][0] > 0) == (case == "bitmask")
+    assert 0 < f["stage"][1] < 1 and 0 < f["stage"][2] < 1
+    assert len(f["windows"]) >= 1
+    for got in f["windows"]:
+        np.testing.assert_allclose(got, f["stage"], rtol=0.1, atol=1e-7)
 
 
 def test_limits_refuse_looser_mac_and_lower_precision(mesh_run):

@@ -239,3 +239,37 @@ def test_gravity_p2p_pallas_matches_xla_interpret():
         scale = np.max(np.abs(sb)) + 1e-12
         np.testing.assert_allclose(sa, sb, atol=1e-6 * scale, rtol=1e-4,
                                    err_msg=name)
+
+
+@pytest.mark.parametrize("C", [1000, 1024])
+@pytest.mark.parametrize("live", [0, 1, 127, 128, 129, None])
+def test_gravity_compact_kernel_live_bound_is_the_full_scan(C, live):
+    """``compact_class_lists(live=...)``: the chunk walk bounded by each
+    row's live count gives the full scan's lists and UNCLIPPED counts bit
+    for bit, with overflow past a cap, for a width that is no multiple of
+    128 too; and it reads nothing past the last live chunk (garbage
+    there, which the full scan would compact, changes nothing)."""
+    from sphexa_tpu.gravity import pallas_compact as pc
+
+    live = C if live is None else live
+    rng = np.random.default_rng(100 * C + live)
+    B, cap0, cap1 = 3, 192, 64  # live = C overflows both (~C/3 a class)
+    cls = rng.integers(0, 3, size=(B, C))
+    cls[:, live:] = 2
+    vals = rng.integers(0, 1 << 20, size=(B, C))
+    packed = (cls << pc.IDX_BITS) | vals
+    full = pc.compact_class_lists(jnp.asarray(packed, jnp.int32), cap0, cap1,
+                                  interpret=True)
+    past = -(-live // 128) * 128
+    packed[:, past:] = 7  # class 0, value 7: never read
+    bounded = pc.compact_class_lists(
+        jnp.asarray(packed, jnp.int32), cap0, cap1, interpret=True,
+        live=jnp.full((B,), live, jnp.int32))
+    for name, a, b in zip(("list0", "n0", "list1", "n1"), full, bounded):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+    if live == C:
+        assert int(full[1].min()) > cap0 and int(full[3].min()) > cap1
+    for b in range(B):
+        assert int(full[1][b]) == int((cls[b] == 0).sum())
+        assert int(full[3][b]) == int((cls[b] == 1).sum())

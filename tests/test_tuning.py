@@ -372,9 +372,10 @@ class TestSchemaV7:
         # v10 did the same for ``rebuild_lists`` (optional WHY payload),
         # v11 for the planned window (``window.planned_steps``,
         # ``rebuild_lists.rate`` / ``cover_steps``), v12 for the flat
-        # lane table (``rebuild_lists.slots_live`` / ``slots_cap``)
-        assert SCHEMA_VERSION == 12
-        assert not {7, 10, 11, 12} & set(KIND_SINCE.values())
+        # lane table (``rebuild_lists.slots_live`` / ``slots_cap``), v13
+        # for the tree solve's list fills on ``window`` / ``step``
+        assert SCHEMA_VERSION == 13
+        assert not {7, 10, 11, 12, 13} & set(KIND_SINCE.values())
 
     def test_v7_staged_exchange_validates(self):
         for stage in ("sph", "gravity"):
