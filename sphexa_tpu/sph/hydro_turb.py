@@ -10,9 +10,17 @@ Differences from the reference by design:
 - the OU random stream is a jax PRNG key carried in the (checkpointable)
   TurbulenceState pytree instead of a host mt19937, so the whole update
   runs inside the jitted step;
-- the per-particle stirring sum over modes is phrased as two (N,M) x (M,3)
-  matmuls (cos/sin of the phase matrix), which XLA tiles onto the MXU
-  instead of the reference's per-particle mode loop (stirring.hpp:42-78).
+- the per-particle stirring sum (stirring.hpp:42-78, one particle's loop
+  over the modes) runs with the loops exchanged: a loop over the modes,
+  ``MODES_PER_TURN`` a turn, each turn an elementwise sweep that adds its
+  modes' terms to three (N,) f32 accumulators. Nothing of N x M elements
+  exists at any N, and every product and sum is f32 on the VPU. It is NOT a
+  matmul: a contraction over M modes with three output columns fills 3 of
+  the MXU's 128 lanes, and an f32 matmul at a TPU's default precision
+  rounds cosines, sines and weights to bf16 (measured on a v5e at 8.0M:
+  relative error 2.3e-3 where this form reads 2.3e-7;
+  benchmarks/reference_stirring.py is the plain reference and
+  benchmarks/check_stirring.py the comparison on the chip).
 """
 
 import dataclasses
@@ -182,8 +190,14 @@ def update_noise(
 ) -> TurbulenceState:
     """One OU step: x' = f x + sigma sqrt(1 - f^2) z, f = exp(-dt/ts)
     (driver.hpp:43-91, Bartosch 2001)."""
-    damping_a = jnp.exp(-dt / cfg.decay_time)
-    damping_b = jnp.sqrt(1.0 - damping_a**2)
+    # f - 1 = expm1(-dt/ts), and 1 - f^2 = (1 - f)(1 + f) from it. A step
+    # is dt/ts ~ 1e-5..1e-3, so f sits within 1e-3 of 1: on a v5e
+    # exp(-1.6e-4) is 1.5e-6 off (1 % of 1 - f, the damping a step
+    # applies), and sqrt(1 - f*f) of it is 0.2 % off there and 5 % off at
+    # dt/ts = 6e-6; this form reads 2e-8 and 1e-8 (PERF.md, PR 31)
+    f_minus_1 = jnp.expm1(-dt / cfg.decay_time)
+    damping_a = 1.0 + f_minus_1
+    damping_b = jnp.sqrt(-f_minus_1 * (2.0 + f_minus_1))
     key, sub = jax.random.split(turb.key)
     z = jax.random.normal(sub, turb.phases.shape, dtype=turb.phases.dtype)
     phases = turb.phases * damping_a + cfg.variance * damping_b * z
@@ -208,23 +222,78 @@ def compute_phases(turb: TurbulenceState, cfg: TurbulenceConfig):
     return sw * curla + (1.0 - sw) * divb, sw * curlb + (1.0 - sw) * diva
 
 
+#: modes summed per turn of the stirring loop: each turn is one elementwise
+#: sweep over the particles that adds its modes' terms to the accumulators,
+#: so they cross HBM M / MODES_PER_TURN times. 8 and 16 time alike on a v5e
+#: (25.0 / 25.3 ms at 8.0M; PERF.md, PR 31); XLA keeps one (N,) temporary a
+#: mode of the turn
+MODES_PER_TURN = 8
+
+# pi/2 in three parts with trailing zero bits, so q * _PIO2[0] and
+# q * _PIO2[1] are exact for |q| < 2^15 (Cody-Waite; cephes sinf.c has the
+# same parts of pi/4)
+_PIO2 = (1.5703125, 4.837512969970703125e-4, 7.54978995489188e-8)
+# cephes sinf.c / cosf.c: minimax polynomials on [-pi/4, pi/4]
+_SIN_POLY = (-1.6666654611e-1, 8.3321608736e-3, -1.9515295891e-4)
+_COS_POLY = (4.166664568298827e-2, -1.388731625493765e-3,
+             2.443315711809948e-5)
+
+
+def _sincos(a):
+    """(sin a, cos a) of f32 ``a``, both from one argument reduction, in
+    adds, multiplies and selects: |error| < 2e-7 for |a| < 1e4 (the
+    stirring's |k.x| is under 17). ``jnp.sin`` and ``jnp.cos`` are as
+    accurate, but XLA's TPU pipeline will not fuse them into the sum that
+    consumes them: the stirring sweep with them took 75.6 ms at 8.0M on a
+    v5e where this takes 25.0 (PERF.md, PR 31)."""
+    q = jnp.round(a * (2.0 / np.pi))
+    r = ((a - q * _PIO2[0]) - q * _PIO2[1]) - q * _PIO2[2]
+    r2 = r * r
+    s = r + r * r2 * (_SIN_POLY[0] + r2 * (_SIN_POLY[1] + r2 * _SIN_POLY[2]))
+    c = (1.0 - 0.5 * r2) + r2 * r2 * (
+        _COS_POLY[0] + r2 * (_COS_POLY[1] + r2 * _COS_POLY[2]))
+    # a = q pi/2 + r: the quadrant swaps and signs the pair
+    quadrant = q.astype(jnp.int32)
+    odd = (quadrant & 1) == 1
+    sin_a = jnp.where(odd, c, s)
+    cos_a = jnp.where(odd, s, c)
+    sin_a = jnp.where((quadrant & 2) == 2, -sin_a, sin_a)
+    cos_a = jnp.where(((quadrant + 1) & 2) == 2, -cos_a, cos_a)
+    return sin_a, cos_a
+
+
 def st_calc_accel(
     x, y, z, turb: TurbulenceState, cfg: TurbulenceConfig,
     phases_real, phases_imag,
 ):
-    """Stirring accelerations: a_i += norm * sum_m amp_m Re[(P_m) e^{i k_m x_i}]
-    (stirring.hpp stirParticle), phrased as (N,M)@(M,3) matmuls."""
-    kdotx = (
-        x[:, None] * turb.modes[None, :, 0]
-        + y[:, None] * turb.modes[None, :, 1]
-        + z[:, None] * turb.modes[None, :, 2]
-    )                                    # (N, M)
-    ck = jnp.cos(kdotx)
-    sk = jnp.sin(kdotx)
-    amp_pr = turb.amplitudes[:, None] * phases_real   # (M, 3)
-    amp_pi = turb.amplitudes[:, None] * phases_imag
-    acc = cfg.sol_weight_norm * (ck @ amp_pr - sk @ amp_pi)  # (N, 3)
-    return acc[:, 0], acc[:, 1], acc[:, 2]
+    """Stirring accelerations: a_i = norm * sum_m amp_m Re[(P_m) e^{i k_m x_i}]
+    (stirring.hpp stirParticle), as a loop over the modes on three (N,)
+    f32 accumulators: no array of N x M elements at any N or M."""
+    num_modes = turb.modes.shape[0]
+    turns = -(-num_modes // MODES_PER_TURN)
+    weight = (cfg.sol_weight_norm * turb.amplitudes)[:, None]
+    # one row a mode: k (3), weighted real phases (3), imaginary (3). A
+    # table that is no multiple of the turn is filled up with rows of
+    # zeros, which add cos(0) * 0
+    rows = jnp.pad(
+        jnp.concatenate(
+            [turb.modes, weight * phases_real, weight * phases_imag], axis=1),
+        ((0, turns * MODES_PER_TURN - num_modes), (0, 0)),
+    )
+
+    def turn(t, acc):
+        row = jax.lax.dynamic_slice_in_dim(
+            rows, t * MODES_PER_TURN, MODES_PER_TURN)
+        for j in range(MODES_PER_TURN):
+            sk, ck = _sincos(row[j, 0] * x + row[j, 1] * y + row[j, 2] * z)
+            acc = tuple(
+                a + (row[j, 3 + c] * ck - row[j, 6 + c] * sk)
+                for c, a in enumerate(acc)
+            )
+        return acc
+
+    zero = jnp.zeros_like(x)
+    return jax.lax.fori_loop(0, turns, turn, (zero, zero, zero))
 
 
 def drive_turbulence(
