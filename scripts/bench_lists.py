@@ -131,20 +131,34 @@ def main():
     (kx, gradh), _ = pp.pallas_ve_def_gradh(x, y, z, h, m, xm, None, box,
                                             const, nbr, lists=lists)
     prho, cve, rhove, pve = compute_eos_ve(ss.temp, m, kx, xm, gradh, const)
-    dv_args = (x, y, z, ss.vx, ss.vy, ss.vz, h, kx, xm, *cs0)
-    f_w = jax.jit(lambda ls, *a: pp.pallas_iad_divv_curlv(
-        *a, None, box, const, nbr, lists=ls, list_walk=True))
-    f_k = jax.jit(lambda ls, *a: pp.pallas_iad_divv_curlv(
-        *a, None, box, const, nbr, lists=ls, list_walk=False))
-    tw, ow = timed(f_w, lists, *dv_args)
-    tk, ok_ = timed(f_k, lists, *dv_args)
-    dd = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(ow[0], ok_[0]))
-    print(f"divv_curlv: skip   {tk*1e3:7.1f} ms  walk  {tw*1e3:7.1f} ms  "
-          f"x{tk/tw:.2f}  d={dd:.2e}")
+    # the fused IAD + divv/curlv op (one pass where there were two): skip
+    # vs walk on lists, and the streamed engine the Evrard cells run; under
+    # a velocity field with a gradient, so the outputs compare on something
+    two_pi = 2.0 * np.pi / box.lengths
+    v = (jnp.sin(two_pi[0] * x), jnp.sin(two_pi[1] * y + two_pi[0] * x),
+         jnp.cos(two_pi[2] * z))
+    dv_args = (x, y, z, *v, h, kx, xm)
+    for gv in (False, True):
+        f_w = jax.jit(lambda ls, *a: pp.pallas_iad_divv_curlv(
+            *a, None, box, const, nbr, lists=ls, list_walk=True,
+            with_gradv=gv))
+        f_k = jax.jit(lambda ls, *a: pp.pallas_iad_divv_curlv(
+            *a, None, box, const, nbr, lists=ls, list_walk=False,
+            with_gradv=gv))
+        f_s = jax.jit(lambda rng, *a: pp.pallas_iad_divv_curlv(
+            *a, keys, box, const, nbr, ranges=rng, with_gradv=gv))
+        tw, ow = timed(f_w, lists, *dv_args)
+        tk, ok_ = timed(f_k, lists, *dv_args)
+        ts, os_ = timed(f_s, ranges, *dv_args)
+        dd = max(float(jnp.max(jnp.abs(a - b)))
+                 for a, b in zip(ow[1] + os_[1], ok_[1] + ok_[1]))
+        print(f"iad+divv{'+gradv' if gv else '      '}: skip {tk*1e3:7.1f} ms"
+              f"  walk {tw*1e3:7.1f} ms  x{tk/tw:.2f}  stream {ts*1e3:7.1f}"
+              f" ms  d={dd:.2e} of {float(jnp.max(jnp.abs(ok_[1][0]))):.2f}")
+    cs0 = ok_[0]
 
-    divv, curlv = ow[0][0], ow[0][1]
-    av_args = (x, y, z, ss.vx, ss.vy, ss.vz, h, cve, kx, xm, divv,
-               ss.alpha, *cs0)
+    divv = ok_[1][0]
+    av_args = (x, y, z, *v, h, cve, kx, xm, divv, ss.alpha, *cs0)
     f_w = jax.jit(lambda ls, *a: pp.pallas_av_switches(
         *a, None, box, 1e-5, const, nbr, lists=ls, list_walk=True))
     f_k = jax.jit(lambda ls, *a: pp.pallas_av_switches(

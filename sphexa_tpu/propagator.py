@@ -626,18 +626,12 @@ def _ve_forces_sharded(state, box, cfg: PropagatorConfig, keys):
         )
         prho, c, rho, p = hydro_ve.compute_eos_ve(temp, m, kx, xm, gradh, const)
         hkx, hprho, hc, hvx, hvy, hvz = serve((kx, prho, c, vx, vy, vz))
-        cs, _ = pp.pallas_iad(
-            x, y, z, h, xm / kx, None, box, const, nbr, ranges=ranges,
-            jdata=jbuf((x, y, z, xm / kx), (hx, hy, hz, hxm / hkx)),
-            interpret=interpret,
-        )
-        c11, c12, c13, c22, c23, c33 = cs
-        dvout, _ = pp.pallas_iad_divv_curlv(
-            x, y, z, vx, vy, vz, h, kx, xm, *cs,
+        cs, dvout, _ = pp.pallas_iad_divv_curlv(
+            x, y, z, vx, vy, vz, h, kx, xm,
             None, box, const, nbr, ranges=ranges,
             with_gradv=cfg.av_clean,
-            jdata=jbuf((x, y, z, xm, vx, vy, vz),
-                       (hx, hy, hz, hxm, hvx, hvy, hvz)),
+            jdata=jbuf((x, y, z, xm / kx, xm, vx, vy, vz),
+                       (hx, hy, hz, hxm / hkx, hxm, hvx, hvy, hvz)),
             interpret=interpret,
         )
         divv, curlv, gradv = _split_dvout(dvout, cfg.av_clean)
@@ -916,7 +910,8 @@ def _ve_forces(
     elif cfg.backend == "pallas":
         # fused search+op TPU engine for the full VE sequence — the
         # reference's flagship propagator (ve_hydro.hpp:131-208) on the
-        # fast path, sharing one cell-range prologue across all six ops
+        # fast path, sharing one cell-range prologue across all five ops
+        # (IAD and divv/curlv are one neighbour pass)
         from sphexa_tpu.sph import pallas_pairs as pp
 
         interp = _pallas_interpret()
@@ -937,13 +932,8 @@ def _ve_forces(
         prho, c, rho, p = hydro_ve.compute_eos_ve(
             state.temp, m, kx, xm, gradh, const
         )
-        (c11, c12, c13, c22, c23, c33), _ = pp.pallas_iad(
-            x, y, z, h, xm / kx, keys, box, const, cfg.nbr, ranges=ranges,
-            interpret=interp, lists=lists,
-        )
-        dvout, _ = pp.pallas_iad_divv_curlv(
+        (c11, c12, c13, c22, c23, c33), dvout, _ = pp.pallas_iad_divv_curlv(
             x, y, z, vx, vy, vz, h, kx, xm,
-            c11, c12, c13, c22, c23, c33,
             keys, box, const, cfg.nbr, ranges=ranges,
             with_gradv=cfg.av_clean, interpret=interp, lists=lists,
         )

@@ -104,7 +104,9 @@ def compute_iad_divv_curlv(
     the avClean momentum correction. The reference fuses IAD+divv+curlv in
     one pass (iad_divv_curlv.hpp); here IAD comes from hydro_std.compute_iad
     and this op consumes its output — XLA's fusion takes the place of the
-    hand-fused kernel.
+    hand-fused kernel. The pair engine's op IS fused
+    (pallas_pairs.pallas_iad_divv_curlv, one neighbour pass); this two-op
+    form stays as the independent reference its tests compare against.
     """
     n = x.shape[0]
 

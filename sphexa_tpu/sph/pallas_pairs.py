@@ -1223,6 +1223,42 @@ def pallas_density(
     return rho.reshape(-1)[:n], nc.reshape(-1)[:n], ranges.occupancy
 
 
+def _iad_tau_terms(geom):
+    """The six r (x) r products of the IAD moment matrix, in the order
+    (11, 12, 13, 22, 23, 33)."""
+    return (
+        geom.rx * geom.rx, geom.rx * geom.ry, geom.rx * geom.rz,
+        geom.ry * geom.ry, geom.ry * geom.rz, geom.rz * geom.rz,
+    )
+
+
+def _iad_invert(hi, K: float, t11, t12, t13, t22, t23, t33):
+    """(G, 1) reduced moments tau -> the six components of its inverse
+    scaled by h^3/K (shared epilogue of pallas_iad and the fused
+    pallas_iad_divv_curlv)."""
+    # exponent renormalization (iad_kern.hpp ilogb/ldexp trick) via
+    # exp2/log2 — exact because the factor cancels in adj/det
+    exp_of = lambda v: jnp.where(
+        v != 0.0, jnp.floor(jnp.log2(jnp.abs(v) + 1e-45)), 0.0
+    )
+    esum = (exp_of(t11) + exp_of(t12) + exp_of(t13)
+            + exp_of(t22) + exp_of(t23) + exp_of(t33))
+    norm = jnp.exp2(-jnp.floor(esum / 6.0))
+    t11, t12, t13 = t11 * norm, t12 * norm, t13 * norm
+    t22, t23, t33 = t22 * norm, t23 * norm, t33 * norm
+    det = (t11 * t22 * t33 + 2.0 * t12 * t23 * t13
+           - t11 * t23 * t23 - t22 * t13 * t13 - t33 * t12 * t12)
+    factor = norm * (hi * hi * hi) / (det * K)
+    return (
+        (t22 * t33 - t23 * t23) * factor,
+        (t13 * t23 - t33 * t12) * factor,
+        (t12 * t23 - t22 * t13) * factor,
+        (t11 * t33 - t13 * t13) * factor,
+        (t13 * t12 - t11 * t23) * factor,
+        (t11 * t22 - t12 * t12) * factor,
+    )
+
+
 @named_phase("iad")
 def pallas_iad(
     x, y, z, h, vol, sorted_keys, box: Box, const, cfg: NeighborConfig,
@@ -1250,41 +1286,15 @@ def pallas_iad(
         vj = j_fields[3]
         w = _w_poly(geom.d2 * inv_h2, coeffs)
         vw = jnp.where(geom.mask, vj * w, 0.0)
-        terms = (
-            geom.rx * geom.rx, geom.rx * geom.ry, geom.rx * geom.rz,
-            geom.ry * geom.ry, geom.ry * geom.rz, geom.rz * geom.rz,
+        return tuple(
+            acc + t * vw for acc, t in zip(accs, _iad_tau_terms(geom))
         )
-        return tuple(acc + t * vw for acc, t in zip(accs, terms))
 
     def finalize(i_fields, accs, nc):
         t11, t12, t13, t22, t23, t33 = (
             jnp.sum(a, axis=1, keepdims=True) for a in accs
         )
-        return _invert(i_fields, t11, t12, t13, t22, t23, t33)
-
-    def _invert(i_fields, t11, t12, t13, t22, t23, t33):
-        hi = i_fields[3]
-        # exponent renormalization (iad_kern.hpp ilogb/ldexp trick) via
-        # exp2/log2 — exact because the factor cancels in adj/det
-        exp_of = lambda v: jnp.where(
-            v != 0.0, jnp.floor(jnp.log2(jnp.abs(v) + 1e-45)), 0.0
-        )
-        esum = (exp_of(t11) + exp_of(t12) + exp_of(t13)
-                + exp_of(t22) + exp_of(t23) + exp_of(t33))
-        norm = jnp.exp2(-jnp.floor(esum / 6.0))
-        t11, t12, t13 = t11 * norm, t12 * norm, t13 * norm
-        t22, t23, t33 = t22 * norm, t23 * norm, t33 * norm
-        det = (t11 * t22 * t33 + 2.0 * t12 * t23 * t13
-               - t11 * t23 * t23 - t22 * t13 * t13 - t33 * t12 * t12)
-        factor = norm * (hi * hi * hi) / (det * K)
-        return (
-            (t22 * t33 - t23 * t23) * factor,
-            (t13 * t23 - t33 * t12) * factor,
-            (t12 * t23 - t22 * t13) * factor,
-            (t11 * t33 - t13 * t13) * factor,
-            (t13 * t12 - t11 * t23) * factor,
-            (t11 * t22 - t12 * t12) * factor,
-        )
+        return _iad_invert(i_fields[3], K, t11, t12, t13, t22, t23, t33)
 
     # NOTE: an MXU variant (second moments around the group center via one
     # (G,128)x(128,16) dot_general per chunk, engine commit 42af8de)
@@ -1545,21 +1555,44 @@ def pallas_ve_def_gradh(
     return (f(kx), f(gradh)), ranges.occupancy
 
 
+#: engine of the fused IAD + divv/curlv op on persistent lists: True =
+#: list-walk (lane compaction), False = mark-bit chunk skip. Measured on a
+#: v5e at Sedov 160^3 = 4.1M (scripts/bench_lists.py --ve -n 160, PR 32):
+#: walk 505.3 ms vs skip 542.3 (gradv outputs: 513.4 vs 548.1) — the
+#: 15 read-modify-write accumulators a visit weigh more than the ninth
+#: staged row's second sublane tile; the two ops this one replaces were
+#: 420.6 (iad, skip) + 471.0 (divv/curlv, skip). Streamed (no lists,
+#: AABB chunk cull): 541.2 on the same data. In sedov-ve-4m.steady's
+#: traced step: 499.6 (walk) vs 536.1 (skip).
+IAD_DIVV_LIST_WALK = True
+
+
 @named_phase("divv-curlv")
 def pallas_iad_divv_curlv(
     x, y, z, vx, vy, vz, h, kx, xm,
-    c11, c12, c13, c22, c23, c33,
     sorted_keys, box: Box, const, cfg: NeighborConfig,
     ranges=None, with_gradv: bool = False, interpret: bool = False,
     jdata=None, i_offset=0, lists=None, list_walk=None,
 ):
-    """Velocity divergence/curl through the IAD gradient
-    (divv_curlv_kern.hpp:43-120), optionally the full symmetrized
-    velocity-gradient tensor for avClean. Returns (outs, occupancy) with
-    outs = (divv, curlv[, dv11..dv33]).
+    """IAD tensor AND velocity divergence/curl through the IAD gradient in
+    ONE neighbour pass (iad_kern.hpp + divv_curlv_kern.hpp:43-120; the
+    reference launches them as one kernel too, iad_divv_curlv.hpp),
+    optionally the full symmetrized velocity-gradient tensor for avClean.
 
-    Under shard_map, ``jdata = (x, y, z, xm, vx, vy, vz)`` supplies the
-    j-side candidate arrays — same contract as pallas_density."""
+    The gradient reads the TARGET's matrix only, and linearly:
+    dv_ab = sum_j xm_j v_ji,a tA_b with tA = -(C_i r_ij) W, so
+    dv_ab = -sum_c C_i,bc M_ac with M_ac = sum_j xm_j W v_ji,a r_ij,c.
+    The pass accumulates the six IAD moments tau and the nine raw
+    moments M over the same geometry and W; the epilogue inverts tau
+    into C once per target and contracts it with M.
+
+    Returns ((c11..c33), outs, occupancy), outs = (divv, curlv[,
+    dv11..dv33]); ``with_gradv`` changes the outputs only.
+
+    Under shard_map, ``jdata = (x, y, z, xm/kx, xm, vx, vy, vz)`` supplies
+    the j-side candidate arrays — same contract as pallas_density.
+    ``list_walk``: the by-hand bench's and the tests' engine override on
+    lists (None = IAD_DIVV_LIST_WALK)."""
     n = x.shape[0]
     wc = kernel_poly_coeffs(float(const.sinc_index), const.kernel_choice)
     K = float(const.K)
@@ -1568,106 +1601,80 @@ def pallas_iad_divv_curlv(
         ranges = group_cell_ranges(x, y, z, h, sorted_keys, box, cfg)
 
     def pair_body(geom, i_fields, j_fields, accs):
-        (xi, yi, zi, hi, inv_h2,
-         c11i, c12i, c13i, c22i, c23i, c33i, _knorm) = i_fields[:12]
-        (cx, cy, cz, xmj, vxj, vyj, vzj) = j_fields[:7]
-        vxi, vyi, vzi = i_fields[12], i_fields[13], i_fields[14]
-
-        # negated projection: the VE kernels use tA = -(C r) W
-        # (iad_project sign=-1, divv_curlv_kern.hpp)
-        w = -_w_poly(geom.d2 * inv_h2, wc)
-        tA1 = (c11i * geom.rx + c12i * geom.ry + c13i * geom.rz) * w
-        tA2 = (c12i * geom.rx + c22i * geom.ry + c23i * geom.rz) * w
-        tA3 = (c13i * geom.rx + c23i * geom.ry + c33i * geom.rz) * w
-        vx_ji = vxj - vxi
-        vy_ji = vyj - vyi
-        vz_ji = vzj - vzi
-        mm = geom.mask
-        mw = jnp.where(mm, xmj, 0.0)
-        if with_gradv:
-            dvx1, dvx2, dvx3, dvy1, dvy2, dvy3, dvz1, dvz2, dvz3 = accs
-            dvx1 = dvx1 + mw * vx_ji * tA1
-            dvx2 = dvx2 + mw * vx_ji * tA2
-            dvx3 = dvx3 + mw * vx_ji * tA3
-            dvy1 = dvy1 + mw * vy_ji * tA1
-            dvy2 = dvy2 + mw * vy_ji * tA2
-            dvy3 = dvy3 + mw * vy_ji * tA3
-            dvz1 = dvz1 + mw * vz_ji * tA1
-            dvz2 = dvz2 + mw * vz_ji * tA2
-            dvz3 = dvz3 + mw * vz_ji * tA3
-            return dvx1, dvx2, dvx3, dvy1, dvy2, dvy3, dvz1, dvz2, dvz3
-        adiv, acx, acy, acz = accs
-        adiv = adiv + mw * (vx_ji * tA1 + vy_ji * tA2 + vz_ji * tA3)
-        acx = acx + mw * (vz_ji * tA2 - vy_ji * tA3)
-        acy = acy + mw * (vx_ji * tA3 - vz_ji * tA1)
-        acz = acz + mw * (vy_ji * tA1 - vx_ji * tA2)
-        return adiv, acx, acy, acz
+        inv_h2 = i_fields[4]
+        vxi, vyi, vzi = i_fields[6], i_fields[7], i_fields[8]
+        volj, xmj, vxj, vyj, vzj = j_fields[3:8]
+        w = _w_poly(geom.d2 * inv_h2, wc)
+        # tau exactly as pallas_iad forms it (bitwise the same C)
+        vw = jnp.where(geom.mask, volj * w, 0.0)
+        taus = tuple(
+            acc + t * vw for acc, t in zip(accs[:6], _iad_tau_terms(geom))
+        )
+        mw = jnp.where(geom.mask, xmj, 0.0) * w
+        qs = (mw * (vxj - vxi), mw * (vyj - vyi), mw * (vzj - vzi))
+        # M_ac in the order (x1, x2, x3, y1, ..., z3)
+        qr = [q * r for q in qs for r in (geom.rx, geom.ry, geom.rz)]
+        return taus + tuple(acc + t for acc, t in zip(accs[6:], qr))
 
     def finalize(i_fields, accs, nc):
-        knorm = i_fields[11]
+        hi, knorm = i_fields[3], i_fields[5]
         red = lambda a: jnp.sum(a, axis=1, keepdims=True)
-        if with_gradv:
-            dvx1, dvx2, dvx3, dvy1, dvy2, dvy3, dvz1, dvz2, dvz3 = (
-                red(a) for a in accs
-            )
-            divv = knorm * (dvx1 + dvy2 + dvz3)
-            cx_ = dvz2 - dvy3
-            cy_ = dvx3 - dvz1
-            cz_ = dvy1 - dvx2
-            curlv = knorm * jnp.sqrt(cx_ * cx_ + cy_ * cy_ + cz_ * cz_)
-            return (
-                divv, curlv,
-                knorm * dvx1, knorm * (dvx2 + dvy1), knorm * (dvx3 + dvz1),
-                knorm * dvy2, knorm * (dvy3 + dvz2), knorm * dvz3,
-            )
-        adiv, acx, acy, acz = (red(a) for a in accs)
-        divv = knorm * adiv
-        curlv = knorm * jnp.sqrt(acx * acx + acy * acy + acz * acz)
-        return (divv, curlv)
+        cs = _iad_invert(hi, K, *(red(a) for a in accs[:6]))
+        c11, c12, c13, c22, c23, c33 = cs
+        crows = ((c11, c12, c13), (c12, c22, c23), (c13, c23, c33))
+        # negated projection: the VE kernels use tA = -(C r) W
+        # (iad_project sign=-1, divv_curlv_kern.hpp)
+        m = [red(a) for a in accs[6:]]
+        (dvx1, dvx2, dvx3), (dvy1, dvy2, dvy3), (dvz1, dvz2, dvz3) = (
+            [-(cb[0] * m[a] + cb[1] * m[a + 1] + cb[2] * m[a + 2])
+             for cb in crows]
+            for a in (0, 3, 6)
+        )
+        divv = knorm * (dvx1 + dvy2 + dvz3)
+        cx_ = dvz2 - dvy3
+        cy_ = dvx3 - dvz1
+        cz_ = dvy1 - dvx2
+        curlv = knorm * jnp.sqrt(cx_ * cx_ + cy_ * cy_ + cz_ * cz_)
+        if not with_gradv:
+            return cs + (divv, curlv)
+        return cs + (
+            divv, curlv,
+            knorm * dvx1, knorm * (dvx2 + dvy1), knorm * (dvx3 + dvz1),
+            knorm * dvy2, knorm * (dvy3 + dvz2), knorm * dvz3,
+        )
 
     knorm = K / (h * h * h * kx)
     i_fields = _prep_i(
-        x, y, z, h,
-        (1.0 / (h * h), c11, c12, c13, c22, c23, c33, knorm, vx, vy, vz),
-        cfg.group,
+        x, y, z, h, (1.0 / (h * h), knorm, vx, vy, vz), cfg.group
     )
-    jf = jdata or (x, y, z, xm, vx, vy, vz)
-    f = lambda a: a.reshape(-1)[:n]
-    if lists is not None:
-        if list_walk is None:
-            # measured at 80^3: divv/curlv body is a WASH vs chunk-skip
-            # (59.1 vs 58.2 ms) but the 9-accumulator gradv (avClean)
-            # body pays for lane compaction (60.3 vs 71.3 ms) — default
-            # per body weight
-            list_walk = with_gradv
-        if list_walk:
-            engine = group_pair_engine_lists(
-                pair_body, finalize, num_i=15, num_j=7,
-                num_acc=9 if with_gradv else 4, cfg=cfg,
-                interpret=interpret, want_nc=False,
-            )
-            jp = pack_j_fields(jf, cfg.dma_cap)
-            *outs, _nc = engine(lists, i_fields, jp, i_offset)
-            return tuple(f(a) for a in outs), lists.ranges.occupancy
+    jf = jdata or (x, y, z, xm / kx, xm, vx, vy, vz)
+    dims = dict(num_i=9, num_j=8, num_acc=15, cfg=cfg, interpret=interpret,
+                want_nc=False)
+    walk = IAD_DIVV_LIST_WALK if list_walk is None else list_walk
+    if lists is None:
         engine = group_pair_engine(
-            pair_body, finalize, num_i=15, num_j=7,
-            num_acc=9 if with_gradv else 4, cfg=cfg,
-            fold=False, interpret=interpret, chunk_skip=False,
-            want_nc=False, skip_slots=lists.slot_cap,
+            pair_body, finalize, fold=engine_fold(box, cfg), **dims
         )
-        jp = pack_j_fields(jf, cfg.dma_cap)
-        *outs, _nc = engine(lists.ranges, i_fields, jp, i_offset,
+        *outs, _nc = engine(ranges, i_fields, pack_j_fields(jf, cfg.dma_cap),
+                            i_offset, aabb=_op_aabb(jf, box, cfg))
+        occ = ranges.occupancy
+    elif walk:
+        engine = group_pair_engine_lists(pair_body, finalize, **dims)
+        *outs, _nc = engine(
+            lists, i_fields, pack_j_fields(jf, cfg.dma_cap, nf_min=9),
+            i_offset)
+        occ = lists.ranges.occupancy
+    else:
+        engine = group_pair_engine(
+            pair_body, finalize, fold=False, chunk_skip=False,
+            skip_slots=lists.slot_cap, **dims
+        )
+        *outs, _nc = engine(lists.ranges, i_fields,
+                            pack_j_fields(jf, cfg.dma_cap), i_offset,
                             skip=lists)
-        return tuple(f(a) for a in outs), lists.ranges.occupancy
-    engine = group_pair_engine(
-        pair_body, finalize, num_i=15, num_j=7,
-        num_acc=9 if with_gradv else 4, cfg=cfg,
-        fold=engine_fold(box, cfg), interpret=interpret, want_nc=False,
-    )
-    jp = pack_j_fields(jf, cfg.dma_cap)
-    *outs, _nc = engine(ranges, i_fields, jp, i_offset,
-                        aabb=_op_aabb(jf, box, cfg))
-    return tuple(f(a) for a in outs), ranges.occupancy
+        occ = lists.ranges.occupancy
+    outs = tuple(a.reshape(-1)[:n] for a in outs)
+    return outs[:6], outs[6:], occ
 
 
 @named_phase("av-switches")

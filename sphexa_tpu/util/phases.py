@@ -37,8 +37,9 @@ PHASES = (
     "xmass",            # VE generalized volume elements
     "gradh",            # VE kx / gradh pair op
     "eos",              # equation of state
-    "iad",              # integral-approximation-of-derivatives tensor
-    "divv-curlv",       # VE velocity divergence / curl (+gradv)
+    "iad",              # IAD tensor pass (std; VE on the xla backend)
+    "divv-curlv",       # VE divergence / curl (+gradv); on the pair engine
+                        # the IAD moments ride this pass (no iad scope)
     "av-switches",      # VE artificial-viscosity switches
     "momentum-energy",  # momentum + energy pair op
     "gravity-upsweep",  # multipole upsweep (psum-reduced when sharded)

@@ -152,17 +152,24 @@ def test_momentum_ve_lists_match_streaming(case, built):
     np.testing.assert_allclose(np.asarray(kx1), np.asarray(kx), rtol=2e-5)
     np.testing.assert_allclose(np.asarray(gradh1), np.asarray(gradh),
                                rtol=2e-4, atol=2e-6)
-    dv0, _ = pp.pallas_iad_divv_curlv(
-        x, y, z, ss.vx, ss.vy, ss.vz, h, kx, xm, *cs, keys, box, const,
+    cs0, dv0, _ = pp.pallas_iad_divv_curlv(
+        x, y, z, ss.vx, ss.vy, ss.vz, h, kx, xm, keys, box, const,
         nbr, interpret=True,
     )
-    dv1, _ = pp.pallas_iad_divv_curlv(
-        x, y, z, ss.vx, ss.vy, ss.vz, h, kx, xm, *cs, None, box, const,
+    cs1, dv1, _ = pp.pallas_iad_divv_curlv(
+        x, y, z, ss.vx, ss.vy, ss.vz, h, kx, xm, None, box, const,
         nbr, interpret=True, lists=lists,
     )
     sc = float(jnp.max(jnp.abs(dv0[0])))
     np.testing.assert_allclose(np.asarray(dv1[0]), np.asarray(dv0[0]),
                                rtol=1e-4, atol=1e-5 * sc)
+    # the fused op's C is pallas_iad's: the same moments in the same
+    # order, the same inverse (to the bit on one engine)
+    csc = max(float(np.abs(np.asarray(b)).max()) for b in cs)
+    for a0, a1, b in zip(cs0, cs1, cs):
+        np.testing.assert_array_equal(np.asarray(a0), np.asarray(b))
+        np.testing.assert_allclose(np.asarray(a1), np.asarray(b),
+                                   rtol=2e-5, atol=1e-6 * csc)
     a0, _ = pp.pallas_av_switches(
         x, y, z, ss.vx, ss.vy, ss.vz, h, c, kx, xm, dv0[0], alpha, *cs,
         keys, box, ss.min_dt, const, nbr, interpret=True,
@@ -182,6 +189,61 @@ def test_momentum_ve_lists_match_streaming(case, built):
                                    rtol=1e-4, atol=1e-5 * scale)
     np.testing.assert_allclose(np.asarray(du1), np.asarray(du0), rtol=1e-4,
                                atol=1e-6 * float(jnp.max(jnp.abs(du0))))
+
+
+@pytest.fixture(scope="module")
+def ve_reference(case):
+    """The XLA reference of the fused op's two halves (hydro_std.compute_iad
+    + hydro_ve.compute_iad_divv_curlv) under a smooth velocity field: the
+    initial conditions' own give divv = 0 (Sedov) by construction."""
+    from sphexa_tpu.neighbors.cell_list import find_neighbors
+    from sphexa_tpu.sph import hydro_std, hydro_ve
+
+    ss, keys, box, const, nbr = case
+    x, y, z, h, m = ss.x, ss.y, ss.z, ss.h, ss.m
+    tau = 2.0 * np.pi / box.lengths
+    px, py, pz = tau[0] * x, tau[1] * y, tau[2] * z
+    v = (jnp.sin(px) + 0.5 * jnp.sin(py),
+         0.7 * jnp.cos(pz) + 0.3 * jnp.sin(py),
+         jnp.sin(px + pz))
+    nidx, nmask, _, _ = find_neighbors(x, y, z, h, keys, box, nbr)
+    xm = hydro_ve.compute_xmass(x, y, z, h, m, nidx, nmask, box, const, 4096)
+    kx, _ = hydro_ve.compute_ve_def_gradh(x, y, z, h, m, xm, nidx, nmask,
+                                          box, const, 4096)
+    cs = hydro_std.compute_iad(x, y, z, h, xm / kx, nidx, nmask, box, const,
+                               4096)
+    dv = hydro_ve.compute_iad_divv_curlv(
+        x, y, z, *v, h, kx, xm, *cs, nidx, nmask, box, const, 4096,
+        with_gradv=True,
+    )
+    return v, kx, xm, cs, dv
+
+
+@pytest.mark.parametrize("with_gradv", [False, True], ids=["plain", "gradv"])
+@pytest.mark.parametrize("engine", ["streamed", "skip", "walk"])
+def test_fused_iad_divv_matches_xla(case, built, ve_reference, engine,
+                                    with_gradv):
+    """c11..c33, divv, curlv and the six gradv of the ONE-pass op against
+    the two XLA reference ops, on each engine the op is built on."""
+    ss, keys, box, const, nbr = case
+    lists, _, _ = built
+    v, kx, xm, cs0, dv0 = ve_reference
+    kw = {} if engine == "streamed" else dict(
+        lists=lists, list_walk=engine == "walk")
+    cs1, dv1, _ = pp.pallas_iad_divv_curlv(
+        ss.x, ss.y, ss.z, *v, ss.h, kx, xm,
+        keys if engine == "streamed" else None, box, const, nbr,
+        with_gradv=with_gradv, interpret=True, **kw,
+    )
+    assert len(dv1) == (8 if with_gradv else 2)
+    scale = float(jnp.max(jnp.abs(cs0[0])))
+    for a, b in zip(cs1, cs0):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5 * scale)
+    assert float(jnp.max(jnp.abs(dv0[0]))) > 1.0  # a field with a gradient
+    for a, b in zip(dv1, dv0):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=5e-4)
 
 
 def test_stale_lists_cover_drifted_positions(case, built):

@@ -130,10 +130,16 @@ def test_ve_pipeline_matches_xla_interpret(case, av_clean):
         ss.x, ss.y, ss.z, ss.vx, ss.vy, ss.vz, ss.h, kx0, xm0, *cs,
         nidx, nmask, box, const, 4096, with_gradv=av_clean,
     )
-    dv1, _ = pp.pallas_iad_divv_curlv(
-        ss.x, ss.y, ss.z, ss.vx, ss.vy, ss.vz, ss.h, kx0, xm0, *cs,
+    cs1, dv1, _ = pp.pallas_iad_divv_curlv(
+        ss.x, ss.y, ss.z, ss.vx, ss.vy, ss.vz, ss.h, kx0, xm0,
         keys, box, const, nbr, with_gradv=av_clean, interpret=True,
     )
+    # the fused op's IAD, on the diagonal scale like the std pipeline's
+    scale = float(jnp.max(jnp.abs(cs[0])))
+    for a, b in zip(cs1, cs):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5 * scale
+        )
     # divv/curlv are ~0 on the initial lattice (cancellation): absolute
     # tolerance on the kernel-sum scale
     for a, b in zip(dv1, dv0):

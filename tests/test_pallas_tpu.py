@@ -92,10 +92,10 @@ def test_full_pipeline_matches_xla(case):
 
 @pytest.mark.parametrize("av_clean", [False, True], ids=["plain", "avclean"])
 def test_ve_pipeline_matches_xla_tpu(case, av_clean):
-    """Mosaic-lowering check for the six VE engine ops (the interpret tier
+    """Mosaic-lowering check for the five VE engine ops (the interpret tier
     covers the logic; this tier covers the TPU compile + execution),
-    including the avClean variant's bigger kernel (9 accumulators,
-    nf_pad=32 packing)."""
+    including the fused IAD + divv/curlv op's 15 accumulators and the
+    avClean momentum kernel's nf_pad=32 packing."""
     from sphexa_tpu.sph import hydro_ve
     from sphexa_tpu.sph.pallas_pairs import (
         pallas_av_switches,
@@ -134,10 +134,15 @@ def test_ve_pipeline_matches_xla_tpu(case, av_clean):
         ss.x, ss.y, ss.z, ss.vx, ss.vy, ss.vz, ss.h, kx0, xm0, *cs,
         nidx, nmask, box, const, 4096, with_gradv=av_clean,
     )
-    dv1, _ = pallas_iad_divv_curlv(
-        ss.x, ss.y, ss.z, ss.vx, ss.vy, ss.vz, ss.h, kx0, xm0, *cs,
+    cs1, dv1, _ = pallas_iad_divv_curlv(
+        ss.x, ss.y, ss.z, ss.vx, ss.vy, ss.vz, ss.h, kx0, xm0,
         keys, box, const, nbr, with_gradv=av_clean,
     )
+    scale = float(jnp.max(jnp.abs(cs[0])))
+    for a, b in zip(cs1, cs):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5 * scale
+        )
     for a, b in zip(dv1, dv0):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-4, atol=5e-4

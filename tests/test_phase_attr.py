@@ -70,7 +70,7 @@ _EXPECT = {
 }
 
 
-def _lowered_ir(prop):
+def _lowered_ir(prop, backend="auto"):
     """Debug-info StableHLO text of one lowered (NOT compiled) step of
     ``prop`` at audit scale (side 6), built through the real Simulation
     machinery so the lowered program IS the production one."""
@@ -84,7 +84,7 @@ def _lowered_ir(prop):
     if prop == "nbody":
         const = dc.replace(const, g=1.0)
     sim = Simulation(state, box, const, prop=prop, block=512,
-                     obs_spec=ObservableSpec())
+                     obs_spec=ObservableSpec(), backend=backend)
     fn = _PROPAGATORS[prop]
     if prop == "turb-ve":
         aux = (sim.turb_state, sim.turb_cfg)
@@ -116,6 +116,19 @@ class TestNamedScopePins:
         seen = set(re.findall(r"sphexa/([A-Za-z0-9_.:+-]+?)[/\"]", ir))
         assert seen <= set(PHASES), f"unknown phases stamped: " \
                                     f"{seen - set(PHASES)}"
+
+    @pytest.mark.parametrize("prop", ["ve", "turb-ve"])
+    def test_engine_ve_step_opens_five_pair_scopes(self, prop):
+        """On the pair engine a VE step is FIVE neighbour passes: the IAD
+        moments ride the divv/curlv pass (pallas_iad_divv_curlv), so no
+        ``sphexa/iad`` scope is opened; std keeps its own IAD pass."""
+        ir = _lowered_ir(prop, backend="pallas")
+        pair = ("xmass", "gradh", "divv-curlv", "av-switches",
+                "momentum-energy")
+        assert not [p for p in pair if f"sphexa/{p}" not in ir]
+        assert "sphexa/iad" not in ir
+        assert "sphexa/iad" in _lowered_ir("std", backend="pallas")
+
 
 
 # ---------------------------------------------------------------------------
