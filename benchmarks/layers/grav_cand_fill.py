@@ -1,0 +1,12 @@
+"""Median ``cand_fill`` over the window's ``window`` / ``step`` events: live
+slots of the tree solve's superblock candidate lists over real lists x cap, the
+fullest shard's (``compute_gravity``'s diagnostics, schema v13): how far the
+block loop's width-following stages engage. A count, never a speed."""
+
+import windows
+
+
+def read(run):
+    return windows.median([e["cand_fill"] for e in run["events"]
+                           if e["kind"] in ("window", "step")
+                           and "cand_fill" in e])

@@ -1,5 +1,5 @@
 """Trace ONE forced pair-list rebuild of a list cell on the chip and break
-it down by device op and by scope.
+it down by (phase, stage) and by device op (benchmarks/stage_times.py).
 
     python3 scripts/trace_rebuild.py --workload noh-std-1m.steady \
         [--cycles 6] [--out chiprun_out/rebuild.json]
@@ -34,6 +34,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import run  # benchmarks/run.py; puts the checkout's root on sys.path
+    import stage_times
     import trace_reduce
     if args.side:
         os.environ["JAX_PLATFORMS"] = "cpu"
@@ -71,33 +72,22 @@ def main(argv=None) -> int:
     capture = trace_reduce.load_capture(trace_dir)
     span = [a for a in capture["annotations"]
             if a[0] == "sphexa:rebuild-lists"][-1]
-    w0, w1 = span[1], span[1] + span[2]
-    res = {"workload": args.workload, "span_ms": span[2] * 1e-6,
-           "event": sink.of_kind("rebuild_lists")[-1]}
-    for events in capture["devices"].values():
-        events = [e for e in events if w0 <= e[1] < w1]
-        self_ns, _ = trace_reduce._self_times(events)
-        busy = trace_reduce._union([[e[1], e[1] + e[2]] for e in events])
-        ops, scopes = {}, {}
-        for i, e in enumerate(events):
-            t = max(self_ns[i], 0.0) * 1e-6
-            ops[e[0]] = ops.get(e[0], 0.0) + t
-            scope = "/".join(e[3].split("/")[:6])
-            scopes[scope] = scopes.get(scope, 0.0) + t
-        top = lambda table, n: sorted(table.items(), key=lambda kv: -kv[1])[:n]
-        res.update(busy_ms=sum(e - s for s, e in busy) * 1e-6,
-                   ops_ms=top(ops, 40), scopes_ms=top(scopes, 30))
-        break  # one chip: list cells run on one device
-    print(f"# event {res['event']}")
+    print(f"# event {sink.of_kind('rebuild_lists')[-1]}")
     if args.side:
         print("# CPU rehearsal: control flow only, no time is printed")
         return 0
-    print(f"# rebuild span {res['span_ms']:.1f} ms, device busy "
-          f"{res['busy_ms']:.1f} ms")
-    for name, ms in res.get("ops_ms", [])[:25]:
-        print(f"  {ms:9.2f} ms  {name}")
-    for name, ms in res.get("scopes_ms", [])[:12]:
-        print(f"  {ms:9.2f} ms  {name}")
+    # the rebuild's span by (first phase, last scope token) and device op:
+    # the benchmark's own reader, clipped to the span (one step = the span)
+    table = stage_times.table_of_capture(
+        capture, steps=1, with_ops=True, window=(span[1], span[1] + span[2]))
+    print(f"# rebuild span {span[2] * 1e-6:.1f} ms")
+    stage_times.print_table(table, floor_ms=0.5, ops=12)
+    res = {"workload": args.workload, "span_ms": span[2] * 1e-6,
+           "event": sink.of_kind("rebuild_lists")[-1],
+           "ops_ms": {dev: sorted(
+               ([ns * 1e-6, first, last, name]
+                for (first, last, name), ns in d["ops"].items()),
+               reverse=True)[:60] for dev, d in table["devices"].items()}}
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
         with open(args.out, "w") as f:

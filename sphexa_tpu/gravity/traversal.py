@@ -38,7 +38,8 @@ from jax.sharding import PartitionSpec
 from sphexa_tpu.gravity import multipole as mp
 from sphexa_tpu.gravity.tree import GravityTree, GravityTreeMeta
 from sphexa_tpu.sfc.box import Box
-from sphexa_tpu.util.phases import named_phase, phase_scope
+from sphexa_tpu.util.phases import (named_phase, named_stage, phase_scope,
+                                    stage_scope)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -496,11 +497,13 @@ def compute_multipoles_sharded(
     psum runs on the complex leaf payloads) with GLOBAL row edges.
 
     The arithmetic carries the ``gravity-upsweep`` scope and each psum
-    the ``gravity-exchange`` scope (an all-reduce is an op of its own on
-    the device timeline), so a capture tells the wire from the sums.
+    the ``gravity-exchange`` scope with its ``psum`` stage (an all-reduce
+    is an op of its own on the device timeline), so a capture tells the
+    wire from the sums.
     """
     upsweep = lambda: phase_scope("gravity-upsweep")
     wire = lambda: phase_scope("gravity-exchange")
+    psum = lambda: stage_scope("gravity-exchange", "psum")
     lk = tree.leaf_keys
     num_l, num_n = meta.num_leaves, meta.num_nodes
     S = x.shape[0]
@@ -508,7 +511,7 @@ def compute_multipoles_sharded(
     with upsweep():
         pos_local = jnp.searchsorted(
             local_keys, lk, side="left").astype(jnp.int32)
-    with wire():
+    with wire(), psum():
         # jaxlint: disable=JXL006 -- data-chained upsweep: every later
         # psum consumes the previous psum's result (edges -> leaf_w ->
         # leaf_q/c), so program order is already total (JXA201 proves it
@@ -523,7 +526,7 @@ def compute_multipoles_sharded(
         pleaf = _pleaf_from_edges(e_clip, S)
         w = jnp.stack([m, m * x, m * y, m * z], axis=1)
         leaf_w = mp.edge_segment_sum(w, e_clip)  # (L, 4), this slab's
-    with wire():
+    with wire(), psum():
         # jaxlint: disable=JXL006 -- data-chained on edges (via e_clip)
         leaf_w = jax.lax.psum(leaf_w, axis)
     with upsweep():
@@ -535,7 +538,7 @@ def compute_multipoles_sharded(
         with upsweep():
             leaf_c = sp.p2m(x, y, z, m, leaf_com, e_clip, order,
                             pleaf=pleaf)
-        with wire():
+        with wire(), psum():
             # jaxlint: disable=JXL006 -- data-chained on leaf_w (leaf_com)
             leaf_c = jax.lax.psum(leaf_c, axis)
         with upsweep():
@@ -545,7 +548,7 @@ def compute_multipoles_sharded(
     with upsweep():
         leaf_q = mp.p2m_leaf(x, y, z, m, pleaf, leaf_com, num_l,
                              edges=e_clip)
-    with wire():
+    with wire(), psum():
         # jaxlint: disable=JXL006 -- data-chained on leaf_w (via leaf_com)
         leaf_q = jax.lax.psum(leaf_q, axis)
     with upsweep():
@@ -581,9 +584,10 @@ def _pallas_p2p(x, y, z, m, h, shift, allow_self, cfg: GravityConfig,
         run_cap=max(cfg.leaf_cap, 1024), gap=0,
     )
     zero3 = jnp.zeros(starts.shape + (3,), jnp.float32)
-    rs, rl, sh3, nruns, _ = pp._merge_runs(
-        starts, lens, lens > 0, zero3, nbr.run_cap, 0
-    )
+    with stage_scope("gravity-p2p", "merge-runs"):
+        rs, rl, sh3, nruns, _ = pp._merge_runs(
+            starts, lens, lens > 0, zero3, nbr.run_cap, 0
+        )
     ranges = pp.GroupRanges(
         starts=rs, lens=rl, shift_x=sh3[0], shift_y=sh3[1], shift_z=sh3[2],
         ncells=nruns, occupancy=jnp.int32(0),
@@ -625,15 +629,18 @@ def _pallas_p2p(x, y, z, m, h, shift, allow_self, cfg: GravityConfig,
         ) if npad > n else a
         return a.reshape(nb, blk)
 
-    i_fields = [blocked(x, shift[0]), blocked(y, shift[1]),
-                blocked(z, shift[2]), blocked(h, 0.0)]
-    jp = pp.pack_j_fields(jdata or (x, y, z, m, h), nbr.dma_cap)
-    ax, ay, az, phi, _nc = engine(ranges, i_fields, jp, i_offset, allow_self)
+    with stage_scope("gravity-p2p", "kernel"):
+        i_fields = [blocked(x, shift[0]), blocked(y, shift[1]),
+                    blocked(z, shift[2]), blocked(h, 0.0)]
+        jp = pp.pack_j_fields(jdata or (x, y, z, m, h), nbr.dma_cap)
+        ax, ay, az, phi, _nc = engine(ranges, i_fields, jp, i_offset,
+                                      allow_self)
     f = lambda a: a.reshape(-1)
     return f(ax), f(ay), f(az), f(phi)
 
 
 @named_phase("gravity-mac")
+@named_stage("gravity-mac", "geometry")
 def _monotone_mac_geometry(box, tree, meta, node_com, valid, theta):
     """MONOTONE vector-MAC acceptance geometry (macs.hpp computeVecMacR2
     role, made hierarchy-monotone): radius l/theta +
@@ -890,7 +897,7 @@ def compute_gravity(
         # bboxes are subsets of the slab bbox computed from the same
         # live positions, so the superblock containment argument applies
         # with zero staleness).
-        with phase_scope("gravity-mac"):
+        with phase_scope("gravity-mac"), stage_scope("gravity-mac", "let"):
             bc_s, bs_s = _bbox(x + shift[0], y + shift[1], z + shift[2])
             accept_s = valid & _accept(bc_s, bs_s, ccenter, chalf, mac2)
             anc_s = jnp.where(self_parent, False, accept_s[tree.parent])
@@ -941,6 +948,7 @@ def compute_gravity(
             axis=1)
 
     @named_phase("gravity-p2p")
+    @named_stage("gravity-p2p", "leaf-ranges")
     def _p2p_leaf_ranges(order_p, p2p_ok, live):
         """Sorted-array row ranges of one block's near-field leaves, as
         a tuple (start, length[, leaf]): ``leaf`` only for the sparse
@@ -1092,6 +1100,7 @@ def compute_gravity(
             pre_geo = let_geo if use_let else dense_geo
 
             @named_phase("gravity-mac")
+            @named_stage("gravity-mac", "prepass")
             def one_super_pre(tx, ty, tz):
                 return _packed_cand(*_bbox(tx, ty, tz), pre_geo)
 
@@ -1107,13 +1116,15 @@ def compute_gravity(
                 return a.reshape(nsc, spc, sblk)
 
             @named_phase("gravity-mac")
+            @named_stage("gravity-mac", "prepass")
             def pre_chunk(tgt):
                 pk = jax.vmap(one_super_pre)(*tgt)
                 sc, sn, _, _ = pcmp.compact_class_lists(
                     pk, scap, 128, interpret=interp)
                 return sc, sn
 
-            with phase_scope("gravity-mac"):
+            with phase_scope("gravity-mac"), \
+                    stage_scope("gravity-mac", "prepass"):
                 scand, scand_n = jax.lax.map(
                     pre_chunk, tuple(pre_chunks(a) for a in tgtb[:3]))
             scand = scand.reshape(-1, scap)[:num_super]
@@ -1128,7 +1139,8 @@ def compute_gravity(
 
             def one_super_main(args):
                 sc, sn, bidx, tgt = args
-                with phase_scope("gravity-mac"):
+                with phase_scope("gravity-mac"), \
+                        stage_scope("gravity-mac", "classify"):
                     live = jnp.minimum(sn, scap)
                     bc, bs = jax.vmap(_bbox)(*tgt[:3])
 
@@ -1150,19 +1162,16 @@ def compute_gravity(
                     pk = jax.lax.fori_loop(
                         0, _live_tiles(live, ctile, scap), classify_tile,
                         jnp.full((sf, sc.shape[0]), pcmp.DEAD, jnp.int32))
+                with phase_scope("gravity-mac"), \
+                        stage_scope("gravity-mac", "compact"):
                     om, mn, op, pn = pcmp.compact_class_lists(
                         pk, cfg.m2p_cap, cfg.p2p_cap, interpret=interp,
                         live=jnp.broadcast_to(live, (sf,)))
                 return _eval_blocks(bidx, tgt, om, mn, op, pn)
 
-            # the block loops carry the MAC's scope: the loop op, its
-            # per-iteration slicing and stacking and the copies XLA adds
-            # round its carry belong to no stage of the body (on the v5e
-            # 151 ms of an Evrard 1.1M step, PERF.md PR 23). The body's
-            # stages keep their scopes further down the op's path
-            # (.../sphexa/gravity-mac/while/body/.../sphexa/gravity-m2p/):
-            # a reader that takes the innermost scope sees them, one
-            # that takes the outermost sees the loop whole
+            # the block loops carry the MAC's scope and no stage: what
+            # reads as (gravity-mac, gravity-mac) is the loop's carry and
+            # slicing; the body by stage: benchmarks/stage_times.py
             with phase_scope("gravity-mac"):
                 out = jax.lax.map(one_super_main,
                                   (scand, scand_n, idxb, tgtb))
@@ -1171,10 +1180,13 @@ def compute_gravity(
 
             def one_chunk_bm(args):
                 bidx, tgt = args
-                with phase_scope("gravity-mac"):
+                with phase_scope("gravity-mac"), \
+                        stage_scope("gravity-mac", "classify"):
                     bc, bs = jax.vmap(_bbox)(*tgt[:3])
                     pk = jax.vmap(
                         lambda c, s_: _packed_cls(c, s_, geo0))(bc, bs)
+                with phase_scope("gravity-mac"), \
+                        stage_scope("gravity-mac", "compact"):
                     om, mn, op, pn = pcmp.compact_class_lists(
                         pk, cfg.m2p_cap, cfg.p2p_cap, interpret=interp)
                 return _eval_blocks(bidx, tgt, om, mn, op, pn)
@@ -1198,6 +1210,7 @@ def compute_gravity(
         sidx = jnp.minimum(sidx, n - 1).reshape(num_super, sblk)
 
         @named_phase("gravity-mac")
+        @named_stage("gravity-mac", "prepass")
         def one_super(si):
             bc, bs = _bbox(x[si] + shift[0], y[si] + shift[1],
                            z[si] + shift[2])
@@ -1212,7 +1225,8 @@ def compute_gravity(
         sidx_p = jnp.concatenate(
             [sidx, jnp.broadcast_to(sidx[-1:], (nsc * chunk - num_super, sblk))]
         ) if nsc * chunk > num_super else sidx
-        with phase_scope("gravity-mac"):
+        with phase_scope("gravity-mac"), \
+                stage_scope("gravity-mac", "prepass"):
             scand, scand_ok, spar, scand_n = jax.lax.map(
                 jax.vmap(one_super), sidx_p.reshape(nsc, chunk, sblk)
             )
@@ -1222,12 +1236,11 @@ def compute_gravity(
         scand_n = scand_n.reshape(-1)[:num_super]
         c_max = jnp.max(scand_n)
 
-    @named_phase("gravity-mac")
-    def one_block_lists(tgt, bnum):
-        """The two interaction lists of one target group, by the sort
-        compaction: (order_m, m2p_n, order_p, p2p_n), the UNCLIPPED counts
-        as the bitmask kernel returns them. tgt: its (blk,) targets; bnum:
-        its block index (selects the superblock candidate list)."""
+    @named_stage("gravity-mac", "classify")
+    def _block_classes(tgt, bnum):
+        """(m2p_mask, p2p_mask, cidx) of one target group against its
+        candidate list (``cidx`` None: the full tree). tgt: its (blk,)
+        targets; bnum: its block index (selects the superblock list)."""
         bc, bs = _bbox(*tgt[:3])
 
         if sf > 0 or use_let:
@@ -1261,6 +1274,13 @@ def compute_gravity(
             anc = jnp.where(self_parent, False, accept[tree.parent])
             m2p_mask = accept & ~anc
             p2p_mask = tree.is_leaf & valid & ~accept
+        return m2p_mask, p2p_mask, cidx
+
+    @named_stage("gravity-mac", "compact")
+    def _block_compact(m2p_mask, p2p_mask, cidx):
+        """The two interaction lists of one target group, by the sort
+        compaction: (order_m, m2p_n, order_p, p2p_n), the UNCLIPPED counts
+        as the bitmask kernel returns them."""
         m2p_n = jnp.sum(m2p_mask)
         p2p_n = jnp.sum(p2p_mask)
 
@@ -1299,6 +1319,10 @@ def compute_gravity(
         return (order_all[: cfg.m2p_cap], m2p_n,
                 jax.lax.dynamic_slice(order_all, (m2p_n,),
                                       (cfg.p2p_cap,)), p2p_n)
+
+    @named_phase("gravity-mac")
+    def one_block_lists(tgt, bnum):
+        return _block_compact(*_block_classes(tgt, bnum))
 
     if not use_bitmask:
         bnum = jnp.arange(num_chunks * chunk, dtype=jnp.int32)
@@ -1371,7 +1395,8 @@ def compute_gravity(
                     )
                     halo = ex.serve_windows((x, y, z, m, h), bounds, n,
                                             win, P_, kk, axis)
-            with phase_scope("gravity-exchange"):
+            with phase_scope("gravity-exchange"), \
+                    stage_scope("gravity-exchange", "jbuf"):
                 jd = tuple(
                     jnp.concatenate([o, a])
                     for o, a in zip((x, y, z, m, h), halo)

@@ -39,7 +39,7 @@ from sphexa_tpu.sfc.box import Box, apply_pbc_xyz
 from sphexa_tpu.sfc.hilbert import hilbert_encode
 from sphexa_tpu.sfc.keys import coords_to_igrid
 from sphexa_tpu.sfc.morton import morton_encode
-from sphexa_tpu.util.phases import named_phase
+from sphexa_tpu.util.phases import named_phase, stage_scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -191,47 +191,49 @@ def find_neighbors(
         idx = jnp.minimum(idx, n - 1)  # padded tail re-processes the last row
         gx, gy, gz, gh = x[idx], y[idx], z[idx], h[idx]
 
-        lo = jnp.stack([jnp.min(gx), jnp.min(gy), jnp.min(gz)])
-        hi = jnp.stack([jnp.max(gx), jnp.max(gy), jnp.max(gz)])
-        radius = 2.0 * jnp.max(gh)
-        # first cell of the window block: floor((lo - 2h) / edge)
-        box_lo = jnp.stack([box.lo[0], box.lo[1], box.lo[2]])
-        base = jnp.floor((lo - radius - box_lo) / edge).astype(jnp.int32)
-        # window must cover hi + radius: last needed cell index
-        need = jnp.floor((hi + radius - box_lo) / edge).astype(jnp.int32)
-        # open dims: slide the window inside the existing grid (coverage
-        # is never lost — cells outside [0, ncell) don't exist); a window
-        # spanning the whole grid always covers
-        base = jnp.where(
-            periodic, base, jnp.clip(base, 0, max(0, ncell - cfg.window))
-        )
-        need_eff = jnp.where(periodic, need, jnp.minimum(need, ncell - 1))
-        window_ok = jnp.all(
-            (need_eff - base + 1 <= cfg.window) | (cfg.window >= ncell)
-        )
+        with stage_scope("neighbors", "windows"):
+            lo = jnp.stack([jnp.min(gx), jnp.min(gy), jnp.min(gz)])
+            hi = jnp.stack([jnp.max(gx), jnp.max(gy), jnp.max(gz)])
+            radius = 2.0 * jnp.max(gh)
+            # first cell of the window block: floor((lo - 2h) / edge)
+            box_lo = jnp.stack([box.lo[0], box.lo[1], box.lo[2]])
+            base = jnp.floor((lo - radius - box_lo) / edge).astype(jnp.int32)
+            # window must cover hi + radius: last needed cell index
+            need = jnp.floor((hi + radius - box_lo) / edge).astype(jnp.int32)
+            # open dims: slide the window inside the existing grid (coverage
+            # is never lost — cells outside [0, ncell) don't exist); a window
+            # spanning the whole grid always covers
+            base = jnp.where(
+                periodic, base, jnp.clip(base, 0, max(0, ncell - cfg.window))
+            )
+            need_eff = jnp.where(periodic, need, jnp.minimum(need, ncell - 1))
+            window_ok = jnp.all(
+                (need_eff - base + 1 <= cfg.window) | (cfg.window >= ncell)
+            )
 
-        cells = base[None, :] + offsets  # (W3, 3)
-        wrapped = jnp.mod(cells, ncell)
-        in_range = (cells >= 0) & (cells < ncell)
-        # periodic dims wrap but must not alias (offsets beyond the grid
-        # revisit the same cells — drop them); open dims clip-and-exclude
-        unique = offsets < ncell
-        cell_ok = jnp.all(
-            jnp.where(periodic[None, :], unique, in_range), axis=-1
-        )  # (W3,)
-        cells = jnp.where(periodic[None, :], wrapped, jnp.clip(cells, 0, ncell - 1))
+            cells = base[None, :] + offsets  # (W3, 3)
+            wrapped = jnp.mod(cells, ncell)
+            in_range = (cells >= 0) & (cells < ncell)
+            # periodic dims wrap but must not alias (offsets beyond the grid
+            # revisit the same cells — drop them); open dims clip-and-exclude
+            unique = offsets < ncell
+            cell_ok = jnp.all(
+                jnp.where(periodic[None, :], unique, in_range), axis=-1
+            )  # (W3,)
+            cells = jnp.where(periodic[None, :], wrapped, jnp.clip(cells, 0, ncell - 1))
 
-        ckey = encode(
-            cells[:, 0].astype(KEY_DTYPE),
-            cells[:, 1].astype(KEY_DTYPE),
-            cells[:, 2].astype(KEY_DTYPE),
-            bits=level,
-        )
-        start = jnp.searchsorted(sorted_keys, ckey << shift).astype(jnp.int32)
-        end = jnp.searchsorted(sorted_keys, (ckey + KEY_DTYPE(1)) << shift).astype(
-            jnp.int32
-        )
-        occupancy = jnp.max(end - start)
+            ckey = encode(
+                cells[:, 0].astype(KEY_DTYPE),
+                cells[:, 1].astype(KEY_DTYPE),
+                cells[:, 2].astype(KEY_DTYPE),
+                bits=level,
+            )
+        with stage_scope("neighbors", "cell-ranges"):
+            start = jnp.searchsorted(sorted_keys, ckey << shift).astype(jnp.int32)
+            end = jnp.searchsorted(sorted_keys, (ckey + KEY_DTYPE(1)) << shift).astype(
+                jnp.int32
+            )
+            occupancy = jnp.max(end - start)
 
         cand = start[:, None] + jnp.arange(cfg.cap, dtype=jnp.int32)  # (W3, cap)
         cand_ok = (cand < end[:, None]) & cell_ok[:, None]

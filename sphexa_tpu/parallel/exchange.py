@@ -55,7 +55,7 @@ import jax.numpy as jnp
 
 from sphexa_tpu.dtypes import KEY_BITS, KEY_DTYPE
 from sphexa_tpu.sph.pallas_pairs import GroupRanges
-from sphexa_tpu.util.phases import named_phase
+from sphexa_tpu.util.phases import named_phase, stage_scope
 
 # numpy, NOT jnp: this module is first imported INSIDE jitted stage
 # functions, and a module-level jnp constant created under an active
@@ -63,6 +63,14 @@ from sphexa_tpu.util.phases import named_phase
 # in dryrun_multichip once the shard_map import shim let the pallas
 # steps run). A numpy scalar weak-types identically in every jnp op.
 INF32 = np.int32(2**30)
+
+
+def _stage(stage: str):
+    """Stage scope of this layer (util/phases.STAGES): the functions below
+    serve the SPH halo and the gravity near field alike; the caller's
+    first scope tells the two apart, the stage is the same word in both.
+    ``wire`` goes round every collective and nothing else."""
+    return stage_scope("halo-exchange", stage)
 
 
 def estimate_halo_window(
@@ -113,12 +121,15 @@ def global_cell_table(local_keys, level: int, axis: str) -> jax.Array:
     O(ncells) comm; replicated result (update_mpi.hpp:26-106 role)."""
     shift = KEY_DTYPE(3 * (KEY_BITS - level))
     ncells = (1 << level) ** 3
-    cid = (local_keys >> shift).astype(jnp.int32)
-    hist = jnp.zeros(ncells, jnp.int32).at[cid].add(1)
-    hist = jax.lax.psum(hist, axis)
-    return jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(hist)]
-    ).astype(jnp.int32)
+    with _stage("table"):
+        cid = (local_keys >> shift).astype(jnp.int32)
+        hist = jnp.zeros(ncells, jnp.int32).at[cid].add(1)
+    with _stage("wire"):
+        hist = jax.lax.psum(hist, axis)
+    with _stage("table"):
+        return jnp.concatenate(
+            [jnp.zeros(1, jnp.int32), jnp.cumsum(hist)]
+        ).astype(jnp.int32)
 
 
 def _split_runs(starts, lens, payloads, S: int, extra: int = 8,
@@ -219,7 +230,9 @@ def window_bounds(starts, lens, S: int, P: int, k, axis: str):
     lo = lo.at[k].set(INF32)
     hi = hi.at[k].set(0)
     mine = jnp.stack([lo, hi], axis=1)  # (P, 2)
-    return mine, jax.lax.all_gather(mine, axis)  # (P, P, 2)
+    with _stage("wire"):
+        bounds_all = jax.lax.all_gather(mine, axis)  # (P, P, 2)
+    return mine, bounds_all
 
 
 def _effective_lo(bounds_all, S: int, Wmax: int, P: int):
@@ -237,18 +250,22 @@ def serve_windows(fields: Sequence, bounds_all, S: int, Wmax: int,
     """One all_to_all exchange round: this shard serves every
     destination's window out of its slab; returns the annex — (P, Wmax)
     per field, row (j, i) holding global row lo_eff[k, j] + i."""
-    lo_eff = _effective_lo(bounds_all, S, Wmax, P)  # (P_dest, P_src)
-    local = jnp.stack(fields, axis=1)  # (S, nf)
-    nf = local.shape[1]
+    with _stage("pack"):
+        lo_eff = _effective_lo(bounds_all, S, Wmax, P)  # (P_dest, P_src)
+        local = jnp.stack(fields, axis=1)  # (S, nf)
+        nf = local.shape[1]
 
-    def serve_one(dest):
-        off = lo_eff[dest, k] - k * S
-        return jax.lax.dynamic_slice(local, (off, 0), (Wmax, nf))
+        def serve_one(dest):
+            off = lo_eff[dest, k] - k * S
+            return jax.lax.dynamic_slice(local, (off, 0), (Wmax, nf))
 
-    send = jax.vmap(serve_one)(jnp.arange(P, dtype=jnp.int32))  # (P, Wmax, nf)
-    annex = jax.lax.all_to_all(send, axis, 0, 0, tiled=False)
-    annex = annex.reshape(P * Wmax, nf)
-    return [annex[:, f] for f in range(nf)]
+        send = jax.vmap(serve_one)(
+            jnp.arange(P, dtype=jnp.int32))  # (P, Wmax, nf)
+    with _stage("wire"):
+        annex = jax.lax.all_to_all(send, axis, 0, 0, tiled=False)
+    with _stage("jbuf"):
+        annex = annex.reshape(P * Wmax, nf)
+        return [annex[:, f] for f in range(nf)]
 
 
 def shard_halo_stage(x, y, z, h, keys, box, nbr, P: int, Wmax: int,
@@ -424,21 +441,25 @@ def serve_sparse(fields: Sequence, covered_all, table, S: int,
     from the PREVIOUS serve; the rounds chain on it (and on each other)
     through ``chain_after`` so the P-1 independent ppermutes execute in
     one total order on every device (rendezvous-race guard)."""
-    local = jnp.stack(fields, axis=1)  # (S, nf)
+    with _stage("pack"):
+        local = jnp.stack(fields, axis=1)  # (S, nf)
     nf = local.shape[1]
     parts = []
     for r in range(1, P):
-        dest = (k + r) % P
-        clen, poff = _sparse_layout_dest(covered_all, dest, table, S, k)
-        ridx = _pack_rows(clen, poff, table, S, k, hmax[r - 1])
-        send = local[ridx]  # (Hmax_r, nf)
-        if token is not None:
-            send = chain_after(send, token)
+        with _stage("pack"):
+            dest = (k + r) % P
+            clen, poff = _sparse_layout_dest(covered_all, dest, table, S, k)
+            ridx = _pack_rows(clen, poff, table, S, k, hmax[r - 1])
+            send = local[ridx]  # (Hmax_r, nf)
+            if token is not None:
+                send = chain_after(send, token)
         perm = [(i, (i + r) % P) for i in range(P)]
-        parts.append(jax.lax.ppermute(send, axis, perm))
+        with _stage("wire"):
+            parts.append(jax.lax.ppermute(send, axis, perm))
         token = parts[-1]
-    annex = jnp.concatenate(parts, axis=0) if parts else local[:0]
-    return [annex[:, f] for f in range(nf)], token
+    with _stage("jbuf"):
+        annex = jnp.concatenate(parts, axis=0) if parts else local[:0]
+        return [annex[:, f] for f in range(nf)], token
 
 
 def _sparse_layout_dest(covered_all, dest, table, S: int, k):
@@ -482,14 +503,29 @@ def localize_ranges_sparse(
     if len(hmax) != P - 1:
         raise ValueError(f"hmax needs P-1={P-1} per-distance caps, got "
                          f"{len(hmax)}")
-    starts, lens, sh3, nruns, split_ovf, c0 = _split_runs_cells(
-        ranges, table, S, P, c0=None if cells is None else cells[0])
-    if cells is None:
-        covered = coverage_from_runs(starts, lens, table)
-    else:
-        covered = coverage_from_runs(ranges.starts, ranges.lens, table, cells)
-    covered_all = jax.lax.all_gather(covered, axis)  # (P, ncells)
+    with _stage("localize"):
+        starts, lens, sh3, nruns, split_ovf, c0 = _split_runs_cells(
+            ranges, table, S, P, c0=None if cells is None else cells[0])
+    with _stage("cover"):
+        if cells is None:
+            covered = coverage_from_runs(starts, lens, table)
+        else:
+            covered = coverage_from_runs(ranges.starts, ranges.lens, table,
+                                         cells)
+    with _stage("wire"):
+        covered_all = jax.lax.all_gather(covered, axis)  # (P, ncells)
+    with _stage("localize"):
+        out, escaped = _localize_sparse(
+            ranges, starts, lens, sh3, nruns, split_ovf, c0, covered, table,
+            S, P, hmax, k)
+    return out, covered_all, escaped, covered
 
+
+def _localize_sparse(ranges, starts, lens, sh3, nruns, split_ovf, c0,
+                     covered, table, S: int, P: int, hmax, k):
+    """The rewrite of ``localize_ranges_sparse``: the split runs into rows
+    of [own slab | packed annex] under this shard's own coverage, and the
+    escape flag. Returns (localized ranges, escaped)."""
     clen, poff, need = _sparse_layout(covered, table, S, P)  # per src j
     # static per-distance caps: need from src j rides round (k - j) % P
     hmax_arr = jnp.asarray((0,) + tuple(hmax), jnp.int32)  # index by r
@@ -525,7 +561,7 @@ def localize_ranges_sparse(
         shift_x=sh3[0], shift_y=sh3[1], shift_z=sh3[2],
         ncells=nruns, occupancy=ranges.occupancy, boxl=ranges.boxl,
     )
-    return out, covered_all, escaped, covered
+    return out, escaped
 
 
 def shard_halo_stage_sparse(x, y, z, h, keys, box, nbr, P: int,
@@ -600,12 +636,25 @@ def localize_ranges(
     Wmax sizing) zero out and flip ``escaped``, which the caller folds
     into the occupancy sentinel.
     """
-    starts, lens, sh3, nruns, split_ovf = _split_runs(
-        ranges.starts, ranges.lens,
-        (ranges.shift_x, ranges.shift_y, ranges.shift_z), S,
-        extra=max(8, P - 1),
-    )
-    mine, bounds_all = window_bounds(starts, lens, S, P, k, axis)
+    with _stage("localize"):
+        starts, lens, sh3, nruns, split_ovf = _split_runs(
+            ranges.starts, ranges.lens,
+            (ranges.shift_x, ranges.shift_y, ranges.shift_z), S,
+            extra=max(8, P - 1),
+        )
+    with _stage("cover"):
+        mine, bounds_all = window_bounds(starts, lens, S, P, k, axis)
+    with _stage("localize"):
+        out, escaped = _localize_windows(
+            ranges, starts, lens, sh3, nruns, split_ovf, bounds_all, S, P,
+            Wmax, k)
+    return out, bounds_all, escaped
+
+
+def _localize_windows(ranges, starts, lens, sh3, nruns, split_ovf,
+                      bounds_all, S: int, P: int, Wmax: int, k):
+    """The rewrite of ``localize_ranges``: the split runs into rows of
+    [own slab | annex], and the escape flag."""
     lo_eff = _effective_lo(bounds_all, S, Wmax, P)[k]  # (P_src,)
 
     src = jnp.clip(starts // S, 0, P - 1)
@@ -628,4 +677,4 @@ def localize_ranges(
         shift_x=sh3[0], shift_y=sh3[1], shift_z=sh3[2],
         ncells=nruns, occupancy=ranges.occupancy, boxl=ranges.boxl,
     )
-    return out, bounds_all, escaped
+    return out, escaped

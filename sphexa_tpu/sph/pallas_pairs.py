@@ -47,7 +47,7 @@ from sphexa_tpu.dtypes import KEY_BITS, KEY_DTYPE
 from sphexa_tpu.neighbors.cell_list import NeighborConfig, _window_offsets
 from sphexa_tpu.sfc.box import BoundaryType, Box
 from sphexa_tpu.util.device import on_tpu
-from sphexa_tpu.util.phases import named_phase
+from sphexa_tpu.util.phases import named_phase, stage_scope
 from sphexa_tpu.sfc.hilbert import hilbert_encode
 from sphexa_tpu.sfc.morton import morton_encode
 from sphexa_tpu.sph.kernels import (
@@ -153,130 +153,132 @@ def group_cell_ranges(
     edge = box.lengths / ncell
     periodic = box.periodic_mask
 
-    g = cfg.group
-    num_groups = -(-n // g)
-    pad = num_groups * g - n
-    gather_pad = lambda a: jnp.concatenate([a, jnp.broadcast_to(a[-1:], (pad,))]) if pad else a
-    xg = gather_pad(x).reshape(num_groups, g)
-    yg = gather_pad(y).reshape(num_groups, g)
-    zg = gather_pad(z).reshape(num_groups, g)
-    hg = gather_pad(h).reshape(num_groups, g)
+    with stage_scope("neighbors", "windows"):
+        g = cfg.group
+        num_groups = -(-n // g)
+        pad = num_groups * g - n
+        gather_pad = lambda a: jnp.concatenate([a, jnp.broadcast_to(a[-1:], (pad,))]) if pad else a
+        xg = gather_pad(x).reshape(num_groups, g)
+        yg = gather_pad(y).reshape(num_groups, g)
+        zg = gather_pad(z).reshape(num_groups, g)
+        hg = gather_pad(h).reshape(num_groups, g)
 
-    lo = jnp.stack([xg.min(1), yg.min(1), zg.min(1)], axis=1)  # (NG, 3)
-    hi = jnp.stack([xg.max(1), yg.max(1), zg.max(1)], axis=1)
-    # radius_pad: extra coverage slack (the list-build skin) so candidate
-    # runs stay valid while particles drift between list rebuilds
-    radius = 2.0 * hg.max(1) + radius_pad  # (NG,)
-    box_lo = jnp.stack([box.lo[0], box.lo[1], box.lo[2]])
-    base = jnp.floor((lo - radius[:, None] - box_lo) / edge).astype(jnp.int32)
-    need = jnp.floor((hi + radius[:, None] - box_lo) / edge).astype(jnp.int32)
-    # open dims: cells outside [0, ncell) don't exist — slide the window
-    # inside the grid (never loses coverage); a window spanning the whole
-    # grid always covers
-    base = jnp.where(
-        periodic[None, :], base,
-        jnp.clip(base, 0, max(0, ncell - cfg.window)),
-    )
-    need_eff = jnp.where(periodic[None, :], need, jnp.minimum(need, ncell - 1))
-    window_ok = jnp.all((need_eff - base + 1 <= cfg.window) | (cfg.window >= ncell))
-
-    offsets = jnp.asarray(_window_offsets(cfg.window))  # (W3, 3)
-    cells = base[:, None, :] + offsets[None, :, :]  # (NG, W3, 3) unwrapped
-    wrapped = jnp.mod(cells, ncell)
-    in_range = (cells >= 0) & (cells < ncell)
-    unique = offsets[None, :, :] < ncell
-    cell_ok = jnp.all(
-        jnp.where(periodic[None, None, :], unique, in_range), axis=-1
-    )  # (NG, W3)
-    lookup = jnp.where(
-        periodic[None, None, :], wrapped, jnp.clip(cells, 0, ncell - 1)
-    )
-
-    ckey = encode(
-        lookup[..., 0].astype(KEY_DTYPE),
-        lookup[..., 1].astype(KEY_DTYPE),
-        lookup[..., 2].astype(KEY_DTYPE),
-        bits=level,
-    )
-    if table is not None or ncell**3 <= 4 * max(n, 1024):
-        # ONE cell-starts table for the whole grid, then per-(group, cell)
-        # range lookups are gathers from it — a binary search per window
-        # cell into the N-element u64 key array costs ~20 emulated-u64
-        # gathers each and dominated the prologue
-        if table is None:
-            cid = (sorted_keys >> shift).astype(jnp.int32)  # ascending
-            table = jnp.searchsorted(
-                cid, jnp.arange(ncell**3 + 1, dtype=jnp.int32)
-            ).astype(jnp.int32)
-        ck32 = ckey.astype(jnp.int32)
-        start = table[ck32]
-        end = table[ck32 + 1]
-    else:
-        # deep grids (possible when a caller bypasses the occupancy-driven
-        # level heuristic): the table would be O(8^level) — search instead
-        start = jnp.searchsorted(sorted_keys, ckey << shift).astype(jnp.int32)
-        end = jnp.searchsorted(
-            sorted_keys, (ckey + KEY_DTYPE(1)) << shift
-        ).astype(jnp.int32)
-    raw_len = end - start
-    lens = jnp.where(cell_ok, jnp.minimum(raw_len, cfg.cap), 0)
-
-    if engine_fold(box, cfg):
-        # tiny-grid fallback: the kernel min-image-folds every pair, so
-        # image-position culling is meaningless — keep all non-empty cells
-        keep = cell_ok & (lens > 0)
-        shifts = jnp.zeros(cells.shape, jnp.float32)
-    else:
-        # cull: drop cells whose AABB (at their image position) cannot
-        # contain any neighbor of the group — exact box-vs-box distance
-        # test against the group bbox inflated by its search radius
-        cell_lo = (
-            box_lo[None, None, :] + cells.astype(jnp.float32) * edge[None, None, :]
+        lo = jnp.stack([xg.min(1), yg.min(1), zg.min(1)], axis=1)  # (NG, 3)
+        hi = jnp.stack([xg.max(1), yg.max(1), zg.max(1)], axis=1)
+        # radius_pad: extra coverage slack (the list-build skin) so candidate
+        # runs stay valid while particles drift between list rebuilds
+        radius = 2.0 * hg.max(1) + radius_pad  # (NG,)
+        box_lo = jnp.stack([box.lo[0], box.lo[1], box.lo[2]])
+        base = jnp.floor((lo - radius[:, None] - box_lo) / edge).astype(jnp.int32)
+        need = jnp.floor((hi + radius[:, None] - box_lo) / edge).astype(jnp.int32)
+        # open dims: cells outside [0, ncell) don't exist — slide the window
+        # inside the grid (never loses coverage); a window spanning the whole
+        # grid always covers
+        base = jnp.where(
+            periodic[None, :], base,
+            jnp.clip(base, 0, max(0, ncell - cfg.window)),
         )
-        cell_hi = cell_lo + edge[None, None, :]
-        r = radius[:, None, None]
-        overlap = jnp.all(
-            (cell_hi >= lo[:, None, :] - r) & (cell_lo <= hi[:, None, :] + r),
-            axis=-1,
+        need_eff = jnp.where(periodic[None, :], need, jnp.minimum(need, ncell - 1))
+        window_ok = jnp.all((need_eff - base + 1 <= cfg.window) | (cfg.window >= ncell))
+
+        offsets = jnp.asarray(_window_offsets(cfg.window))  # (W3, 3)
+        cells = base[:, None, :] + offsets[None, :, :]  # (NG, W3, 3) unwrapped
+        wrapped = jnp.mod(cells, ncell)
+        in_range = (cells >= 0) & (cells < ncell)
+        unique = offsets[None, :, :] < ncell
+        cell_ok = jnp.all(
+            jnp.where(periodic[None, None, :], unique, in_range), axis=-1
         )  # (NG, W3)
-        keep = cell_ok & overlap & (lens > 0)
-
-        # each window cell corresponds to exactly ONE box image: its offset
-        # resolves periodicity for every pair in the cell (no per-pair fold)
-        img = jnp.floor_divide(cells, ncell).astype(jnp.float32)  # (NG, W3, 3)
-        shifts = img * box.lengths[None, None, :]
-
-    cell = ckey.astype(jnp.int32) if with_cells else None
-    if cfg.run_cap > 0:
-        # merge SFC-adjacent survivors into long streamed runs (fewer,
-        # fuller chunks; see _merge_runs)
-        starts_c, lens_c, sh, ncells, run_cells = _merge_runs(
-            start, lens, keep, shifts, cfg.run_cap, cfg.gap, cell=cell
+        lookup = jnp.where(
+            periodic[None, None, :], wrapped, jnp.clip(cells, 0, ncell - 1)
         )
-    else:
-        # compact survivors to the front (stable: preserves SFC cell order)
-        _, kc_i, starts_c, lens_s, shx_c, shy_c, shz_c, *cell_c = jax.lax.sort(
-            ((~keep).astype(jnp.int32), keep.astype(jnp.int32), start, lens,
-             shifts[..., 0], shifts[..., 1], shifts[..., 2])
-            + (() if cell is None else (cell,)),
-            num_keys=1, dimension=1, is_stable=True,
-        )
-        keep_c = kc_i.astype(bool)
-        lens_c = jnp.where(keep_c, lens_s, 0)
-        # dead slots DMA row 0 harmlessly (len 0 masks every pair)
-        starts_c = jnp.where(keep_c, starts_c, 0)
-        sh = [jnp.where(keep_c, a, 0.0) for a in (shx_c, shy_c, shz_c)]
-        ncells = jnp.sum(keep, axis=1).astype(jnp.int32)
-        # an unmerged run IS one cell: first == last
-        run_cells = tuple(jnp.where(keep_c, c, 0) for c in cell_c) * 2
 
-    # cap overflow only matters for cells the kernel will visit: a culled
-    # cell's clipped length truncates nothing
-    occupancy = jnp.where(
-        window_ok,
-        jnp.max(jnp.where(keep, raw_len, 0)),
-        jnp.int32(cfg.cap + 1),
-    )
+        ckey = encode(
+            lookup[..., 0].astype(KEY_DTYPE),
+            lookup[..., 1].astype(KEY_DTYPE),
+            lookup[..., 2].astype(KEY_DTYPE),
+            bits=level,
+        )
+    with stage_scope("neighbors", "cell-ranges"):
+        if table is not None or ncell**3 <= 4 * max(n, 1024):
+            # ONE cell-starts table for the whole grid, then per-(group, cell)
+            # range lookups are gathers from it — a binary search per window
+            # cell into the N-element u64 key array costs ~20 emulated-u64
+            # gathers each and dominated the prologue
+            if table is None:
+                cid = (sorted_keys >> shift).astype(jnp.int32)  # ascending
+                table = jnp.searchsorted(
+                    cid, jnp.arange(ncell**3 + 1, dtype=jnp.int32)
+                ).astype(jnp.int32)
+            ck32 = ckey.astype(jnp.int32)
+            start = table[ck32]
+            end = table[ck32 + 1]
+        else:
+            # deep grids (possible when a caller bypasses the occupancy-driven
+            # level heuristic): the table would be O(8^level) — search instead
+            start = jnp.searchsorted(sorted_keys, ckey << shift).astype(jnp.int32)
+            end = jnp.searchsorted(
+                sorted_keys, (ckey + KEY_DTYPE(1)) << shift
+            ).astype(jnp.int32)
+        raw_len = end - start
+        lens = jnp.where(cell_ok, jnp.minimum(raw_len, cfg.cap), 0)
+
+        if engine_fold(box, cfg):
+            # tiny-grid fallback: the kernel min-image-folds every pair, so
+            # image-position culling is meaningless — keep all non-empty cells
+            keep = cell_ok & (lens > 0)
+            shifts = jnp.zeros(cells.shape, jnp.float32)
+        else:
+            # cull: drop cells whose AABB (at their image position) cannot
+            # contain any neighbor of the group — exact box-vs-box distance
+            # test against the group bbox inflated by its search radius
+            cell_lo = (
+                box_lo[None, None, :] + cells.astype(jnp.float32) * edge[None, None, :]
+            )
+            cell_hi = cell_lo + edge[None, None, :]
+            r = radius[:, None, None]
+            overlap = jnp.all(
+                (cell_hi >= lo[:, None, :] - r) & (cell_lo <= hi[:, None, :] + r),
+                axis=-1,
+            )  # (NG, W3)
+            keep = cell_ok & overlap & (lens > 0)
+
+            # each window cell corresponds to exactly ONE box image: its offset
+            # resolves periodicity for every pair in the cell (no per-pair fold)
+            img = jnp.floor_divide(cells, ncell).astype(jnp.float32)  # (NG, W3, 3)
+            shifts = img * box.lengths[None, None, :]
+
+        cell = ckey.astype(jnp.int32) if with_cells else None
+        if cfg.run_cap > 0:
+            # merge SFC-adjacent survivors into long streamed runs (fewer,
+            # fuller chunks; see _merge_runs)
+            starts_c, lens_c, sh, ncells, run_cells = _merge_runs(
+                start, lens, keep, shifts, cfg.run_cap, cfg.gap, cell=cell
+            )
+        else:
+            # compact survivors to the front (stable: preserves SFC cell order)
+            _, kc_i, starts_c, lens_s, shx_c, shy_c, shz_c, *cell_c = jax.lax.sort(
+                ((~keep).astype(jnp.int32), keep.astype(jnp.int32), start, lens,
+                 shifts[..., 0], shifts[..., 1], shifts[..., 2])
+                + (() if cell is None else (cell,)),
+                num_keys=1, dimension=1, is_stable=True,
+            )
+            keep_c = kc_i.astype(bool)
+            lens_c = jnp.where(keep_c, lens_s, 0)
+            # dead slots DMA row 0 harmlessly (len 0 masks every pair)
+            starts_c = jnp.where(keep_c, starts_c, 0)
+            sh = [jnp.where(keep_c, a, 0.0) for a in (shx_c, shy_c, shz_c)]
+            ncells = jnp.sum(keep, axis=1).astype(jnp.int32)
+            # an unmerged run IS one cell: first == last
+            run_cells = tuple(jnp.where(keep_c, c, 0) for c in cell_c) * 2
+
+        # cap overflow only matters for cells the kernel will visit: a culled
+        # cell's clipped length truncates nothing
+        occupancy = jnp.where(
+            window_ok,
+            jnp.max(jnp.where(keep, raw_len, 0)),
+            jnp.int32(cfg.cap + 1),
+        )
 
     # fold periods: open dims get an effectively-infinite period so the
     # fold is a no-op there (only consumed in fold mode)

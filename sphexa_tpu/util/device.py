@@ -76,19 +76,26 @@ DEFAULT_COMPILE_CACHE = os.path.join(
 def enable_compile_cache() -> Optional[str]:
     """Place jax's persistent compilation cache; call before the first
     jit. Where ``JAX_COMPILATION_CACHE_DIR`` is set jax already reads it
-    and nothing is set in code; otherwise, on a TPU, the cache goes to the
-    fixed ``<checkout>/.jax_cache``. Returns the directory in effect.
+    and no directory is set in code; otherwise, on a TPU, the cache goes to
+    the fixed ``<checkout>/.jax_cache``. Returns the directory in effect.
+
+    Wherever a cache is in effect its key covers the program's metadata
+    too. By default jax takes the key after ``strip-debuginfo``, so a
+    change of scope names alone (util/phases.py: what a capture is read
+    by) would be handed the executable an older checkout left there,
+    with that checkout's names on its ops.
 
     Off-TPU no directory is set (returns None): the cache exists to save
     chip compiles, and XLA:CPU reloads its cached AOT results with an
     error-level warning per entry ("machine type ... doesn't match ...
     could lead to SIGILL") that the CPU test tier has no use for."""
-    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env:
-        return env
-    if not on_tpu():
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir and not on_tpu():
         return None
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE)
-    return DEFAULT_COMPILE_CACHE
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    if not cache_dir:
+        cache_dir = DEFAULT_COMPILE_CACHE
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir

@@ -17,6 +17,17 @@ SURVEY §6) transposed to the fused one-program step: phases are trace
 METADATA here, not host-timed barriers — zero runtime cost, visible
 only in a profiler capture.
 
+A STAGE is a second name inside a phase: ``sphexa/<phase>~<stage>``
+(``stage_scope`` / ``@named_stage``, the ``STAGES`` table). ``~`` lies
+outside the character class every reader's phase pattern takes
+(``sphexa/([A-Za-z0-9_.:+-]+)``, first match of the path), so an op reads
+under the phase it read under before the stage existed, wherever the
+stage scope is opened; a reader that wants the stage takes the LAST
+``sphexa/`` token of the same path (benchmarks/stage_times.py,
+benchmarks/STAGES.md). Not ``@``: this jax's lowering cuts an
+``op_name`` there. A stage scope is opened only inside a phase scope
+(its own phase's or the caller's): the first phase of no op changes.
+
 ``named_scope`` is pure tracing machinery (it pushes a name onto jax's
 name stack; no primitive, no callback, no host boundary), so the
 jaxaudit JXA104 host-boundary rule has nothing to flag — pinned by the
@@ -58,7 +69,45 @@ PHASES = (
     "output-fields",    # a dump's recompute: keygen, sort/unsort permutes
 )
 
+#: the stages of a phase, ``{phase: (stage, ...)}``: what the records put
+#: at 10 ms a step or more in some cell, and every collective
+STAGES = {
+    "neighbors": (
+        "windows",      # group bboxes, window cells and their curve keys
+        "cell-ranges",  # table[cell] / table[cell + 1] lookups, cull,
+                        # compaction sorts and run merge
+    ),
+    "halo-exchange": (
+        "table",     # global cell table: slab histogram + cumsum
+        "cover",     # coverage bitmap of the runs' cells (+-1 scatters)
+        "localize",  # slab-boundary split, runs -> j-buffer rows, bounds
+        "pack",      # packed layout of a serve, row indices, row gather
+        "wire",      # every collective of the exchange, and nothing else
+        "jbuf",      # own + annex concatenates
+    ),
+    "gravity-mac": (
+        "geometry",  # per-solve node MAC geometry and packed node rows
+        "let",       # the slab's essential (LET) node list
+        "prepass",   # superblock candidate cut: class + compaction
+        "classify",  # a super's candidates: row gather + per-block class
+        "compact",   # the two interaction lists of every block
+    ),
+    "gravity-p2p": (
+        "leaf-ranges",  # row range of every near-field leaf of a list
+        "merge-runs",   # the near field's run merge (its two sorts)
+        "kernel",       # the streamed pair kernel and its blocked inputs
+    ),
+    "gravity-exchange": (
+        "psum",  # the sharded upsweep's all-reduces
+        "jbuf",  # own + annex concatenates of the near field
+    ),
+}
+assert set(STAGES) <= set(PHASES)
+assert all(len(set(v)) == len(v) for v in STAGES.values())
+
 _PREFIX = "sphexa/"
+#: between phase and stage; outside the readers' phase pattern
+STAGE_SEP = "~"
 
 
 def phase_scope(phase: str):
@@ -68,12 +117,10 @@ def phase_scope(phase: str):
     return jax.named_scope(_PREFIX + phase)
 
 
-def named_phase(phase: str):
-    """Decorator form: every op the wrapped function traces carries the
-    phase. Zero runtime cost outside tracing — the context manager only
-    runs while jax is building the jaxpr."""
-    assert phase in PHASES, f"unknown phase {phase!r} (util/phases.PHASES)"
-    name = _PREFIX + phase
+def _scoped(name: str):
+    """Decorator: every op the wrapped function traces carries ``name``.
+    Zero runtime cost outside tracing: the context manager only runs
+    while jax is building the jaxpr."""
 
     def deco(fn):
         @functools.wraps(fn)
@@ -84,3 +131,26 @@ def named_phase(phase: str):
         return wrapper
 
     return deco
+
+
+def named_phase(phase: str):
+    """Decorator form of ``phase_scope``."""
+    assert phase in PHASES, f"unknown phase {phase!r} (util/phases.PHASES)"
+    return _scoped(_PREFIX + phase)
+
+
+def _stage_name(phase: str, stage: str) -> str:
+    assert stage in STAGES.get(phase, ()), (
+        f"unknown stage {stage!r} of phase {phase!r} (util/phases.STAGES)")
+    return _PREFIX + phase + STAGE_SEP + stage
+
+
+def stage_scope(phase: str, stage: str):
+    """``jax.named_scope`` context for one stage of a phase. Open it only
+    where a phase scope already is (module docstring)."""
+    return jax.named_scope(_stage_name(phase, stage))
+
+
+def named_stage(phase: str, stage: str):
+    """Decorator form of ``stage_scope``."""
+    return _scoped(_stage_name(phase, stage))

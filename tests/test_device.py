@@ -47,6 +47,7 @@ from sphexa_tpu.util import device
 {patch}
 print(repr(device.enable_compile_cache()))
 print(repr(jax.config.jax_compilation_cache_dir))
+print(jax.config.jax_compilation_cache_include_metadata_in_key)
 """
 
 
@@ -54,16 +55,17 @@ class TestCompileCache:
     def test_env_set_leaves_config_untouched(self, tmp_path):
         r = _run(_CACHE_PROBE.format(patch="device.on_tpu = lambda: True"),
                  env_extra={"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
-        helper, config = r.stdout.strip().splitlines()
-        # jax itself read the variable; the helper set nothing else
+        helper, config, metadata_in_key = r.stdout.strip().splitlines()
+        # jax itself read the variable; the helper placed no other
         assert helper == config == repr(str(tmp_path)), r.stderr[-2000:]
+        assert metadata_in_key == "True"
 
     def test_unset_places_checkout_cache_from_any_cwd(self, tmp_path):
         want = repr(os.path.join(ROOT, ".jax_cache"))
         for cwd in (ROOT, str(tmp_path)):
             r = _run(_CACHE_PROBE.format(
                 patch="device.on_tpu = lambda: True"), cwd=cwd)
-            assert r.stdout.strip().splitlines() == [want, want], \
+            assert r.stdout.strip().splitlines() == [want, want, "True"], \
                 r.stderr[-2000:]
 
     def test_unset_off_tpu_places_nothing(self, monkeypatch):
@@ -72,6 +74,34 @@ class TestCompileCache:
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         assert device.enable_compile_cache() is None
         assert jax.config.jax_compilation_cache_dir is None
+
+    def test_scope_names_are_part_of_the_cache_key(self):
+        """Two programs that differ in a scope name alone get two keys
+        under the setting the helper makes, one key under jax's default
+        (a capture would then read an older checkout's names on a new
+        checkout's ops)."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        from jax._src import cache_key, compiler, config
+
+        def key_of(scope):
+            def f(x):
+                with jax.named_scope(scope):
+                    return jnp.sin(x)
+
+            return cache_key.get(
+                jax.jit(f).lower(jnp.ones(4)).compiler_ir(),
+                np.array(jax.devices()[:1]),
+                compiler.get_compile_options(1, 1), jax.devices()[0].client)
+
+        scopes = ("sphexa/neighbors", "sphexa/neighbors~windows",
+                  "sphexa/neighbors")
+        a, b, a2 = [key_of(s) for s in scopes]
+        assert a == b == a2
+        with config.compilation_cache_include_metadata_in_key(True):
+            a, b, a2 = [key_of(s) for s in scopes]
+        assert a == a2 and a != b
 
 
 class TestChipOnlyEntryPoints:

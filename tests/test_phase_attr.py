@@ -26,7 +26,8 @@ import time
 
 import pytest
 
-from sphexa_tpu.util.phases import PHASES, named_phase, phase_scope
+from sphexa_tpu.util.phases import (PHASES, STAGE_SEP, STAGES, named_phase,
+                                    named_stage, phase_scope, stage_scope)
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "trace_fixture")
 
@@ -129,6 +130,201 @@ class TestNamedScopePins:
         assert "sphexa/iad" not in ir
         assert "sphexa/iad" in _lowered_ir("std", backend="pallas")
 
+
+
+# ---------------------------------------------------------------------------
+# stages: a second name inside a phase (sphexa/<phase>~<stage>)
+# ---------------------------------------------------------------------------
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks")
+_COLLECTIVES = ("psum", "pmax", "pmin", "all_gather", "all_to_all",
+                "ppermute")
+_GRAVITY_LOOP = {
+    "neighbors": ("windows", "cell-ranges"),
+    "gravity-mac": ("geometry", "prepass", "classify", "compact"),
+    "gravity-p2p": ("leaf-ranges", "merge-runs", "kernel"),
+}
+_EXCHANGE = ("table", "cover", "localize", "pack", "wire", "jbuf")
+#: the stages each lowered program must open
+_STAGED = {
+    # (a) the one-chip gravity step, on both sides of the 500k switch
+    "chip-bitmask": _GRAVITY_LOOP,
+    "chip-sort": _GRAVITY_LOOP,
+    # (b) + (c): the mesh step holds the sparse SPH halo stage and the
+    # sharded gravity stage (bitmask compaction over the LET list)
+    "mesh-sparse": dict(_GRAVITY_LOOP, **{
+        "gravity-mac": ("geometry", "let", "prepass", "classify", "compact"),
+        "gravity-exchange": ("psum", "jbuf"), "halo-exchange": _EXCHANGE}),
+    # the windowed serves (the retry ceiling) under the sort compaction,
+    # no superblocks: blocks classify against the LET list
+    "mesh-windowed": dict(_GRAVITY_LOOP, **{
+        "gravity-mac": ("geometry", "let", "classify", "compact"),
+        "gravity-exchange": ("psum", "jbuf"), "halo-exchange": _EXCHANGE}),
+}
+
+
+def _phase_re():
+    """The pattern the benchmark's readers take an op's phase with."""
+    if BENCH not in sys.path:
+        sys.path.insert(0, BENCH)
+    import trace_reduce
+
+    return trace_reduce.PHASE_RE
+
+
+def _tokens(path):
+    """Every ``<phase>`` / ``<phase>~<stage>`` of a path, as the stage
+    reader (benchmarks/stage_times.py) finds them."""
+    _phase_re()
+    import stage_times
+
+    return [a + b for a, b in stage_times.TOKEN_RE.findall(path)]
+
+
+def _ir_text(lowered):
+    buf = io.StringIO()
+    lowered.compiler_ir(dialect="stablehlo").operation.print(
+        file=buf, enable_debug_info=True)
+    return buf.getvalue()
+
+
+@pytest.fixture(scope="module")
+def staged_paths():
+    """{program: scope paths of every op} of four lowered (NOT compiled)
+    Evrard VE steps at audit scale, built through the real Simulation."""
+    import dataclasses as dc
+    import re
+
+    import jax
+
+    from sphexa_tpu.init import init_evrard
+    from sphexa_tpu.observables import ObservableSpec
+    from sphexa_tpu.parallel.mesh import make_sharded_step
+    from sphexa_tpu.propagator import step_hydro_ve
+    from sphexa_tpu.simulation import _PROPAGATORS, Simulation
+
+    state, box, const = init_evrard(10)
+    n4 = (state.n // 4) * 4
+    state = jax.tree.map(
+        lambda a: a[:n4] if getattr(a, "ndim", 0) == 1 else a, state)
+    kw = dict(prop="ve", block=512, backend="pallas",
+              obs_spec=ObservableSpec())
+
+    def with_gravity(sim, **changes):
+        nodes = sim._cfg.grav_meta.num_nodes
+        return dc.replace(sim._cfg, gravity=dc.replace(
+            sim._cfg.gravity, super_cap=nodes, **changes))
+
+    one = Simulation(state, box, const, **kw)
+    lowered = {
+        name: _PROPAGATORS["ve"].lower(
+            one.state, one.box,
+            with_gravity(one, compaction=mode, super_factor=2), one._gtree)
+        for name, mode in (("chip-bitmask", "bitmask"), ("chip-sort", "sort"))}
+
+    mesh = Simulation(state, box, const, num_devices=4, **kw)
+    ss = mesh.sim_state
+    nodes = mesh._cfg.grav_meta.num_nodes
+    sparse = make_sharded_step(
+        mesh._mesh,
+        with_gravity(mesh, compaction="bitmask", super_factor=2,
+                     let_cap=nodes),
+        step_hydro_ve, halo_cells=mesh._halo_info["caps"],
+        grav_cells=mesh._grav_cells)
+    windowed = make_sharded_step(
+        mesh._mesh, with_gravity(mesh, compaction="sort", let_cap=nodes),
+        step_hydro_ve, halo_window=n4 // 4)
+    for name, step in (("mesh-sparse", sparse), ("mesh-windowed", windowed)):
+        lowered[name] = step._jitted.lower(ss.particles, ss.box,
+                                           mesh._gtree, None)
+    return {name: sorted(p for p in set(re.findall(
+        r'loc\("([^"]*)"', _ir_text(low))) if "sphexa/" in p)
+        for name, low in lowered.items()}
+
+
+class TestStages:
+    def test_stages_wellformed(self):
+        """Every reader the repo has reads a staged scope as its phase,
+        and the stage comes back from the path alone."""
+        import re
+
+        from sphexa_tpu.telemetry.traceview import PHASE_RE as traceview_re
+
+        assert set(STAGES) <= set(PHASES)
+        for phase, stages in STAGES.items():
+            assert len(set(stages)) == len(stages)
+            for stage in stages:
+                assert re.fullmatch(r"[A-Za-z0-9_.:+-]+", stage)
+                path = (f"jit(step)/sphexa/{phase}/while/body/"
+                        f"sphexa/{phase}{STAGE_SEP}{stage}/gather")
+                for pattern in (_phase_re(), traceview_re):
+                    assert pattern.search(path).group(1) == phase
+                    assert pattern.search(
+                        path.split("/", 3)[3]).group(1) == phase
+                assert _tokens(path)[-1] == f"{phase}{STAGE_SEP}{stage}"
+
+    def test_unknown_stage_rejected(self):
+        with pytest.raises(AssertionError):
+            stage_scope("gravity-mac", "not-a-stage")
+        with pytest.raises(AssertionError):
+            named_stage("density", "wire")  # a phase without stages
+        with pytest.raises(AssertionError):
+            stage_scope("not-a-phase", "wire")
+
+    @pytest.mark.parametrize("program", sorted(_STAGED))
+    def test_program_opens_its_stages(self, staged_paths, program):
+        seen = {t for p in staged_paths[program]
+                for t in _tokens(p) if STAGE_SEP in t}
+        want = {f"{ph}{STAGE_SEP}{st}"
+                for ph, sts in _STAGED[program].items() for st in sts}
+        assert seen == want, (sorted(want - seen), sorted(seen - want))
+
+    def test_every_stage_is_opened_somewhere(self):
+        opened = {(ph, st) for prog in _STAGED.values()
+                  for ph, sts in prog.items() for st in sts}
+        assert opened == {(ph, st) for ph, sts in STAGES.items()
+                          for st in sts}
+
+    @pytest.mark.parametrize("program", sorted(_STAGED))
+    def test_first_phase_same_with_stages_stripped(self, staged_paths,
+                                                   program):
+        """The guard that no metric the benchmark had can move: the first
+        ``sphexa/<phase>`` of every op's path is what it is with the stage
+        scopes taken out of the path."""
+        import re
+
+        phase_re = _phase_re()
+        first = lambda p: getattr(phase_re.search(p), "group",
+                                  lambda _: None)(1)
+        staged = 0
+        for path in staged_paths[program]:
+            stripped = re.sub(
+                r"sphexa/[A-Za-z0-9_.:+-]+~[A-Za-z0-9_.:+-]+/?", "", path)
+            staged += stripped != path
+            assert first(path) == first(stripped), path
+            assert first(path) in PHASES
+        assert staged > 20
+
+    @pytest.mark.parametrize("program", ["mesh-sparse", "mesh-windowed"])
+    def test_exchange_collectives_carry_wire_or_psum(self, staged_paths,
+                                                     program):
+        """Every collective of the two exchanges reads under ``~wire`` or
+        ``~psum``, and those two stages hold nothing else (``add`` is a
+        psum's own reduction)."""
+        phase_re = _phase_re()
+        collectives = 0
+        for path in staged_paths[program]:
+            prim = path.rsplit("/", 1)[-1]
+            stage = _tokens(path)[-1].partition(STAGE_SEP)[2]
+            if phase_re.search(path).group(1) in ("halo-exchange",
+                                                  "gravity-exchange") \
+                    and prim in _COLLECTIVES:
+                collectives += 1
+                assert stage in ("wire", "psum"), path
+            if stage in ("wire", "psum"):
+                assert prim in _COLLECTIVES + ("add",), path
+        assert collectives >= 5
 
 
 # ---------------------------------------------------------------------------
