@@ -57,6 +57,7 @@ def _static_estimate(sim, n):
             cfg, mesh=sim._mesh, shard_axis="p",
             halo_window=hi.get("wmax", 0),
             halo_cells=tuple(hi.get("caps", ())),
+            halo_runs=hi.get("run_slots", 0),
         )
     closed = jax.make_jaxpr(
         lambda s, b: prop.step_hydro_ve(s, b, cfg, None)

@@ -86,8 +86,8 @@ def measure(side, P, settle=True):
     # buffers — compare against the true sparse need above
     from sphexa_tpu.parallel.sizing import device_sparse_halo
 
-    hcells = device_sparse_halo(state.x, state.y, state.z, state.h, keys,
-                                box, cfg.nbr, P=P)
+    hcells, _ = device_sparse_halo(state.x, state.y, state.z, state.h, keys,
+                                   box, cfg.nbr, P=P)
     win = (P - 1) * wmax
     rep = (P - 1) * S
     # gravity near field (the MAC-sized sparse serve, r13): per-dest

@@ -491,6 +491,7 @@ def step_std_sharded():
         sim._cfg, mesh=sim._mesh, shard_axis="p",
         halo_window=(hi["wmax"] if hi["mode"] == "windowed" else 0),
         halo_cells=tuple(hi.get("caps", ())),
+        halo_runs=hi.get("run_slots", 0),
     )
     return EntryCase(
         fn=lambda s, b: prop.step_hydro_std(s, b, cfg_sh, None),
@@ -547,6 +548,7 @@ def step_std_blockdt_sharded():
         sim._cfg, mesh=sim._mesh, shard_axis="p",
         halo_window=(hi["wmax"] if hi["mode"] == "windowed" else 0),
         halo_cells=tuple(hi.get("caps", ())),
+        halo_runs=hi.get("run_slots", 0),
     )
     return EntryCase(
         fn=lambda s, b, bd: prop.step_hydro_std_blockdt(

@@ -273,6 +273,7 @@ def compute_gravity_ewald(
         # exist exactly when compute_gravity emits them
         diag0["halo_rows"] = jnp.int32(0)
         diag0["halo_occ"] = jnp.float32(0)
+        diag0["halo_runs"] = jnp.int32(0)
     (ax, ay, az, phi, diag), _ = jax.lax.scan(
         body, (zeros, zeros, zeros, zeros, diag0), (shifts, is_base)
     )

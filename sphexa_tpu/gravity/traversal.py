@@ -80,6 +80,14 @@ class GravityConfig:
     # estimate_gravity_caps(let_shards=P). The slab bbox is recomputed
     # every solve from the live positions, so the set is never stale.
     let_cap: int = 0
+    # slots of the near field's run axis on a mesh: there a block's leaf
+    # ranges are merged into runs BEFORE the exchange and the runs cut to
+    # this many (exchange.cut_run_slots), so the exchange's per-slot
+    # index work follows the live runs and not p2p_cap. Sized with the
+    # caps by estimate_gravity_caps(let_shards=P) from the same exact
+    # sweep; 0 = p2p_cap, the full width. A block with more runs trips
+    # the near field's escape sentinel (p2p_max == p2p_cap + 1).
+    p2p_run_cap: int = 0
     # near-field engine: stream the P2P leaf ranges through the pallas
     # pair engine (sph/pallas_pairs.py) instead of XLA gathers — the
     # dominant cost of the XLA formulation at 1e5+ particles. Set by the
@@ -169,12 +177,24 @@ def _block_bboxes(x, y, z, blk: int):
     return bmin, bmax
 
 
+def _p2p_run_cap(leaf_cap: int, slab_rows: int = 0) -> int:
+    """Row cap of a merged near-field run (the pair engine's DMA window is
+    sized from it). On a mesh (``slab_rows`` > 0) a run is merged from
+    GLOBAL rows and must come from at most two slabs, so that the
+    exchange's boundary split leaves one remainder a run."""
+    cap = max(leaf_cap, 1024)
+    return min(cap, slab_rows) if slab_rows else cap
+
+
 @functools.partial(jax.jit,
-                   static_argnames=("meta", "theta", "blk", "shards"))
+                   static_argnames=("meta", "theta", "blk", "shards",
+                                    "run_cap"))
 def _slab_list_highwater(x, y, z, node_mass, node_com, box: Box,
                          tree: GravityTree, meta: GravityTreeMeta,
-                         theta: float, blk: int, shards: int):
-    """(m2p_max, p2p_max, cand_max) over EVERY target block of ``blk`` rows
+                         theta: float, blk: int, shards: int,
+                         edges=None, run_cap: int = 0):
+    """(m2p_max, p2p_max, cand_max[, runs_max]) over EVERY target block of
+    ``blk`` rows
     a sharded solve forms: slab k of ``reshape(shards, S)`` is shard k's,
     and its blocks start at its first row. On the device, one dense
     blocks x nodes MAC sweep per (re)configuration: the list lengths of
@@ -184,7 +204,18 @@ def _slab_list_highwater(x, y, z, node_mass, node_com, box: Box,
     margin growth reached in the driver's four attempts (PERF.md, PR 29).
     ``cand_max`` is the superblock pre-pass's count (open set + accepted
     cut) when ``blk`` is a superblock's rows. The ancestor test is the
-    bitmask path's: accept re-evaluated on the PARENT's geometry."""
+    bitmask path's: accept re-evaluated on the PARENT's geometry.
+
+    ``runs_max`` (with ``edges``, the leaves' row boundaries, and
+    ``run_cap`` > 0): an upper bound of the runs a block's near-field
+    leaves merge into at gap 0 (``_merge_runs`` as the mesh's near field
+    calls it). Counted exactly: the stretches of row-adjacent opened
+    leaves, by re-evaluating the MAC on the non-empty leaves' geometry
+    in ROW order (gathered once, so adjacency is a shift and no block
+    pays a gather). Bounded: the pieces ``run_cap`` cuts a stretch into —
+    a piece is closed only when the next leaf would pass the cap, so it
+    holds more than ``run_cap`` less the fullest leaf's rows, and a
+    block's stretches give at most rows // that more pieces."""
     n = x.shape[0]
     S = n // shards
     nbs = -(-S // blk)
@@ -212,12 +243,29 @@ def _slab_list_highwater(x, y, z, node_mass, node_com, box: Box,
         d = jnp.maximum(jnp.abs(bc[None, :] - gc) - bs[None, :] - gs, 0.0)
         return jnp.sum(d * d, axis=1) >= m2
 
+    if run_cap:
+        # the non-empty leaves in row order (an empty leaf is never opened,
+        # and between two non-empty neighbours it holds no row)
+        lrows = edges[1:] - edges[:-1]
+        in_rows = jnp.argsort(lrows == 0, stable=True)
+        lnode = tree.node_of_leaf[in_rows]
+        l_rows = lrows[in_rows]
+        l_ok, lcc, lch, lmac2 = (leaf_ok[lnode] & (l_rows > 0), cc[lnode],
+                                 ch[lnode], mac2[lnode])
+        piece = jnp.maximum(run_cap - jnp.max(lrows) + 1, 1)
+
     def one(args):
         bc, bs = args
         acc = valid & accept(bc, bs, cc, ch, mac2)
         anc = anc_ok & accept(bc, bs, pcc, pch, pmac2)
-        return (jnp.sum(acc & ~anc), jnp.sum(leaf_ok & ~acc),
-                jnp.sum(~anc))
+        out = (jnp.sum(acc & ~anc), jnp.sum(leaf_ok & ~acc), jnp.sum(~anc))
+        if run_cap:
+            opened = l_ok & ~accept(bc, bs, lcc, lch, lmac2)
+            heads = opened & ~jnp.concatenate([jnp.zeros_like(opened[:1]),
+                                               opened[:-1]])
+            out += (jnp.sum(heads)
+                    + jnp.sum(jnp.where(opened, l_rows, 0)) // piece,)
+        return out
 
     counts = jax.lax.map(one, (0.5 * (hi + lo), 0.5 * (hi - lo)),
                          batch_size=16)
@@ -228,7 +276,7 @@ def estimate_gravity_caps(
     x, y, z, m, sorted_keys, box: Box,
     tree: GravityTree, meta: GravityTreeMeta, cfg: GravityConfig,
     sample_blocks: int = 256, margin: float = 1.5, quantum: int = 32,
-    let_shards: int = 0,
+    let_shards: int = 0, run_margin: float = 1.4,
 ) -> GravityConfig:
     """Size the interaction-list caps from the current distribution.
 
@@ -237,15 +285,19 @@ def estimate_gravity_caps(
     target blocks in numpy and pad the observed maxima (on a mesh,
     ``let_shards`` > 1: every block, on the device). The sampled caps are
     upper bounds by sampling only — the overflow diagnostics returned by
-    compute_gravity remain the correctness guard.
+    compute_gravity remain the correctness guard. ``run_margin``: the
+    margin of ``p2p_run_cap`` (mesh only), the near field's halo margin
+    and not ``margin``: a block with more runs trips the near field's
+    sentinel, and that margin is what the driver grows for it.
     """
     node_mass, node_com, node_q, edges = compute_multipoles(
         x, y, z, m, sorted_keys, tree, meta
     )
+    edges_d = edges  # the device's; the name is the host's copy below
     # everything fetched is O(tree) or O(N/target_block) — never the
     # particle arrays themselves (the O(N/P) reconfiguration contract,
     # VERDICT r3 #3); per-block bboxes come from one jitted reduction
-    from sphexa_tpu.parallel.sizing import fetch
+    from sphexa_tpu.parallel.sizing import fetch, pad_run_slots
 
     n = x.shape[0]
     blk = cfg.target_block
@@ -308,7 +360,12 @@ def estimate_gravity_caps(
         anc = np.where(self_parent, False, accept[parent])
         return accept, anc
 
+    def pad(v, mg=margin):
+        return int(np.ceil(v * mg / quantum) * quantum)
+
+    leaf_cap = pad(int(counts.max()) if len(counts) else 1)
     c_cap_max = 1
+    runs_max = 0
     if let_shards > 1:
         # a sharded solve: every block and superblock each slab forms,
         # classified on the device. The host sample below is a KNOWN
@@ -320,10 +377,12 @@ def estimate_gravity_caps(
         # while another cell is brought. One exact sweep for both paths,
         # measured in pairs on that cell, and caps that one outlier
         # block does not set for every block: PERF.md section 7.
-        sweep = lambda rows: fetch(_slab_list_highwater(
+        sweep = lambda rows, **runs: fetch(_slab_list_highwater(
             x, y, z, node_mass, node_com, box, tree, meta, cfg.theta,
-            rows, let_shards))
-        m2p_max, p2p_max = (max(int(v), 1) for v in sweep(blk)[:2])
+            rows, let_shards, **runs))
+        m2p_max, p2p_max, _, runs_max = (max(int(v), 1) for v in sweep(
+            blk, edges=edges_d,
+            run_cap=_p2p_run_cap(leaf_cap, n // let_shards)))
         if cfg.super_factor > 0:
             c_cap_max = max(int(sweep(cfg.super_factor * blk)[2]), 1)
     else:
@@ -360,20 +419,21 @@ def estimate_gravity_caps(
             _, anc = classify(b0, min(b1, nb))
             let_max = max(let_max, int((~anc).sum()))
 
-    def pad(v, mg=margin):
-        return int(np.ceil(v * mg / quantum) * quantum)
-
-    leaf_cap = pad(int(counts.max()) if len(counts) else 1)
     # the m2p cap gets its own (tighter) margin — M2P eval cost is linear
     # in the cap, and the sampled maximum is exact whenever all blocks are
     # sampled. Scaled by margin/1.5 so the driver's overflow-retry margin
     # growth still reaches any true high water.
     m2p_margin = cfg.m2p_cap_margin * margin / 1.5
+    p2p_cap = min(pad(p2p_max), meta.num_leaves)
     return dataclasses.replace(
         cfg,
         m2p_cap=min(pad(m2p_max, m2p_margin), meta.num_nodes),
-        p2p_cap=min(pad(p2p_max), meta.num_leaves),
+        p2p_cap=p2p_cap,
         leaf_cap=leaf_cap,
+        p2p_run_cap=(
+            min(pad_run_slots(runs_max, run_margin), p2p_cap)
+            if let_shards > 1 else cfg.p2p_run_cap
+        ),
         # only re-size when the hierarchical path is on: clobbering the
         # configured value for sf=0 would sabotage a later enable
         super_cap=(
@@ -557,9 +617,36 @@ def compute_multipoles_sharded(
     return node_mass, node_com, node_q, edges
 
 
+def _p2p_ranges(starts, lens, sh3, nruns):
+    """GroupRanges of a near field: no image shifts, no fold periods."""
+    from sphexa_tpu.sph.pallas_pairs import GroupRanges
+
+    return GroupRanges(
+        starts=starts, lens=lens, shift_x=sh3[0], shift_y=sh3[1],
+        shift_z=sh3[2], ncells=nruns, occupancy=jnp.int32(0),
+        boxl=jnp.full((3,), 1e30, jnp.float32),
+    )
+
+
+@named_stage("gravity-p2p", "merge-runs")
+def _merge_p2p_runs(starts, lens, run_cap: int, leaf=None):
+    """A block's near-field leaf ranges (NB, p2p_cap) merged into runs of
+    at most ``run_cap`` rows at gap 0, compacted to the front: (ranges,
+    cells). ``leaf``: each range's leaf index; ``cells`` is then the
+    runs' first and last leaf — a gap-0 run of leaves covers exactly the
+    leaves between them (any other in that span holds no row), which is
+    what the sparse exchange marks coverage from — else ``()``."""
+    from sphexa_tpu.sph.pallas_pairs import _merge_runs
+
+    zero3 = jnp.zeros(starts.shape + (3,), jnp.float32)
+    rs, rl, sh3, nruns, cells = _merge_runs(
+        starts, lens, lens > 0, zero3, run_cap, 0, cell=leaf)
+    return _p2p_ranges(rs, rl, sh3, nruns), cells
+
+
 @named_phase("gravity-p2p")
 def _pallas_p2p(x, y, z, m, h, shift, allow_self, cfg: GravityConfig,
-                starts, lens, jdata=None, i_offset=0):
+                starts, lens, jdata=None, i_offset=0, nruns=None):
     """Near-field P2P through the streamed pair engine.
 
     ``starts``/``lens`` are the per-block near-leaf ranges from the MAC
@@ -568,6 +655,11 @@ def _pallas_p2p(x, y, z, m, h, shift, allow_self, cfg: GravityConfig,
     with gap=0 ONLY: a bridged gap would stream particles of leaves whose
     mass already arrives via M2P (no distance cutoff masks them away),
     double-counting. Returns (ax, ay, az, phi), each (NB*block,).
+
+    ``nruns`` (NB,): ``starts``/``lens`` are RUNS already, compacted to
+    the front and ``nruns`` of them live a block (``_merge_p2p_runs``,
+    what the mesh's sparse near field hands the exchange): taken as they
+    are.
 
     Under shard_map, ``jdata = (x, y, z, m, h)`` supplies the j-side
     candidate arrays (slab + halo annex) the (pre-localized) ranges
@@ -581,18 +673,13 @@ def _pallas_p2p(x, y, z, m, h, shift, allow_self, cfg: GravityConfig,
     blk = cfg.target_block
     nbr = NeighborConfig(
         level=1, cap=cfg.leaf_cap, group=blk,
-        run_cap=max(cfg.leaf_cap, 1024), gap=0,
+        run_cap=_p2p_run_cap(cfg.leaf_cap), gap=0,
     )
-    zero3 = jnp.zeros(starts.shape + (3,), jnp.float32)
-    with stage_scope("gravity-p2p", "merge-runs"):
-        rs, rl, sh3, nruns, _ = pp._merge_runs(
-            starts, lens, lens > 0, zero3, nbr.run_cap, 0
-        )
-    ranges = pp.GroupRanges(
-        starts=rs, lens=rl, shift_x=sh3[0], shift_y=sh3[1], shift_z=sh3[2],
-        ncells=nruns, occupancy=jnp.int32(0),
-        boxl=jnp.full((3,), 1e30, jnp.float32),
-    )
+    if nruns is None:
+        ranges, _ = _merge_p2p_runs(starts, lens, nbr.run_cap)
+    else:
+        zf = jnp.zeros(starts.shape, jnp.float32)
+        ranges = _p2p_ranges(starts, lens, (zf, zf, zf), nruns)
 
     def pair_body(geom, i_fields, j_fields, accs):
         ax, ay, az, phi = accs
@@ -1339,6 +1426,7 @@ def compute_gravity(
                 (idx, bnum, _target_blocks(num_chunks, chunk, blk)))
     escaped = jnp.asarray(False)
     grav_halo_metrics = None
+    merged_n = None  # the mesh's sparse near field merges before it serves
     if cfg.use_pallas:
         ax, ay, az, phi, m2p_n, p2p_n, p2p_starts, p2p_lens, *leaf = out
         starts2 = p2p_starts.reshape(-1, cfg.p2p_cap)
@@ -1351,18 +1439,9 @@ def compute_gravity(
             # flip the p2p sentinel so the driver re-sizes). The caller
             # clamps the window/caps <= slab rows (_gravity_sharded_stage).
             from sphexa_tpu.parallel import exchange as ex
-            from sphexa_tpu.sph.pallas_pairs import GroupRanges
 
             axis, P_, win = shard
             kk = jax.lax.axis_index(axis)
-            zf = jnp.zeros_like(starts2, dtype=jnp.float32)
-            pr = GroupRanges(
-                starts=starts2, lens=lens2, shift_x=zf, shift_y=zf,
-                shift_z=zf,
-                ncells=jnp.zeros(starts2.shape[0], jnp.int32),  # recomputed
-                occupancy=jnp.int32(0),
-                boxl=jnp.full((3,), 1e30, jnp.float32),
-            )
             # ``gravity-exchange`` is opened OUTSIDE the exchange layer's
             # own ``halo-exchange`` scopes: a capture's reader takes the
             # first scope of an op's path, so the near field's serve is
@@ -1373,13 +1452,23 @@ def compute_gravity(
                 # in the exchange.py sense, so the cell-granular serve
                 # ships only the rows of leaves this slab's essential
                 # set opens — sized by sizing.device_gravity_halo, with
-                # full slabs (caps == S) as the retry ceiling
+                # full slabs (caps == S) as the retry ceiling. The
+                # exchange's index work goes with its run SLOTS, so the
+                # leaves are merged into runs first, as the kernel wants
+                # them anyway, and the runs cut to their sized high-water
+                # (cfg.p2p_run_cap); a phantom tail block's list (a point
+                # bbox: discarded forces, but other runs than any block
+                # the sizing saw) holds none
                 leaf2 = leaf[0].reshape(-1, cfg.p2p_cap)
+                real = jnp.arange(lens2.shape[0]) < num_blocks
                 with phase_scope("gravity-exchange"):
+                    pr, cells = _merge_p2p_runs(
+                        starts2, jnp.where(real[:, None], lens2, 0),
+                        _p2p_run_cap(cfg.leaf_cap, n), leaf=leaf2)
                     lranges, covered_all, escaped, covered = (
                         ex.localize_ranges_sparse(
-                            pr, edges, n, P_, win, kk, axis,
-                            cells=(leaf2, leaf2))
+                            pr, edges, n, P_, win, kk, axis, cells=cells,
+                            run_slots=cfg.p2p_run_cap)
                     )
                     halo, _ = ex.serve_sparse(
                         (x, y, z, m, h), covered_all, edges, n, win, P_,
@@ -1388,7 +1477,13 @@ def compute_gravity(
                 grav_halo_metrics = ex.exchange_metrics_sparse(
                     covered, edges, n, win, P_, kk
                 )
+                grav_halo_metrics["halo_runs"] = ex.live_runs_max(pr)
+                merged_n = lranges.ncells
             else:
+                zf = jnp.zeros_like(starts2, dtype=jnp.float32)
+                pr = _p2p_ranges(
+                    starts2, lens2, (zf, zf, zf),
+                    jnp.zeros(starts2.shape[0], jnp.int32))  # recomputed
                 with phase_scope("gravity-exchange"):
                     lranges, bounds, escaped = ex.localize_ranges(
                         pr, n, P_, win, kk, axis
@@ -1404,7 +1499,7 @@ def compute_gravity(
             starts2, lens2 = lranges.starts, lranges.lens
         pax, pay, paz, pphi = _pallas_p2p(
             x, y, z, m, h, shift, allow_self, cfg,
-            starts2, lens2, jdata=jd,
+            starts2, lens2, jdata=jd, nruns=merged_n,
         )
         blkpad = ax.reshape(-1).shape[0]
         ax = ax.reshape(-1) + pax[:blkpad]
@@ -1503,6 +1598,7 @@ def compute_gravity(
         # gravity-stage exchange telemetry by _gravity_sharded_stage
         diagnostics["halo_rows"] = grav_halo_metrics["halo_rows"]
         diagnostics["halo_occ"] = grav_halo_metrics["halo_occ"]
+        diagnostics["halo_runs"] = grav_halo_metrics["halo_runs"]
     if with_phi:
         return ax, ay, az, phi, diagnostics
     egrav = 0.5 * jnp.sum(m * phi)
@@ -1515,7 +1611,7 @@ def compute_gravity(
 #: analog of propagator.SHARD_DIAG_KEYS. Present only when row caps size
 #: the sparse serve: the windowed / full-slab gravity path emits neither,
 #: keeping its lowering byte-identical.
-GRAV_SHARD_DIAG_KEYS = ("gshard_rows", "gshard_occ")
+GRAV_SHARD_DIAG_KEYS = ("gshard_rows", "gshard_occ", "gshard_runs")
 
 
 def chain_stage_reductions(egrav, diag, axis):
@@ -1552,16 +1648,19 @@ def finish_sharded_stage(gx, gy, gz, egrav, diag, axis):
     # JXA201 total order instead of forking it
     grows = diag.pop("halo_rows", None)
     gocc = diag.pop("halo_occ", None)
+    gruns = diag.pop("halo_runs", None)
     egrav, diag = chain_stage_reductions(egrav, diag, axis)
     if grows is not None:
         from sphexa_tpu.parallel.exchange import chain_after
 
-        packed = jnp.stack([grows.astype(jnp.float32), gocc])
+        packed = jnp.stack([grows.astype(jnp.float32), gocc,
+                            gruns.astype(jnp.float32)])
         g = jax.lax.all_gather(
             chain_after(packed, diag["p2p_max"]), axis
         )
         diag["gshard_rows"] = g[:, 0].astype(jnp.int32)
         diag["gshard_occ"] = g[:, 1]
+        diag["gshard_runs"] = g[:, 2].astype(jnp.int32)
     return gx, gy, gz, egrav, diag
 
 

@@ -31,6 +31,17 @@ Simulation sizes, ``halo_mode="sparse"``, ``PropagatorConfig.halo_cells``):
    (sizing.device_sparse_halo), so the volume tracks the halo surface;
 4. ``localize_ranges_sparse`` rewrites the runs into j-buffer rows.
 
+Steps 1 and 4 are index work per run SLOT (the split's sorts, the
+coverage scatter-adds, the rewrite's lookups: a TPU scatter or gather
+pays per index, live or dead), and the slots are mostly dead: the
+prologue compacts a group's runs to the front of its W3 window slots
+and ``_merge_runs`` leaves about seven of 125-729 live. So the run axis
+is cut on entry (``cut_run_slots``) to ``run_slots``, the fullest
+group's live runs as the same sizing pass observes them, padded
+(``PropagatorConfig.halo_runs``; the near field: its leaves merged into
+runs first, ``GravityConfig.p2p_run_cap``). A caller that sizes none
+keeps the full width.
+
 **Windowed** (``shard_halo_stage``; ``halo_mode="windowed"``, and the
 full-slab fallback of the retry loop): per source shard ONE row window
 [lo, hi) covering every run needed from it, an all_gather of the
@@ -40,7 +51,8 @@ ONE all_to_all of fixed (P, Wmax, nf) buffers per serve. Comm volume
 why the sparse stage exists (docs/NEXT.md round 4).
 
 Either way a run that escapes what was sized (particle drift since the
-last sizing) zeroes itself and trips the step's occupancy sentinel
+last sizing: a row cap, a window, or more live runs in a group than
+``run_slots``) zeroes itself and trips the step's occupancy sentinel
 (``fold_escape_sentinel``); the CALLER owns recovery — discard the step
 and rebuild the sharded stepper with larger caps (tests/test_parallel.py
 exercises both the sentinel and the resize), mirroring the neighbor-cap
@@ -71,6 +83,30 @@ def _stage(stage: str):
     first scope tells the two apart, the stage is the same word in both.
     ``wire`` goes round every collective and nothing else."""
     return stage_scope("halo-exchange", stage)
+
+
+def cut_run_slots(ranges: GroupRanges, run_slots: int, cells=None):
+    """The run-slot axis of ``ranges`` (and of their carried ``cells``) cut
+    to its first ``run_slots`` slots: the prologue compacts a group's live
+    runs to the FRONT of its W3 window slots and ``ncells`` counts them, so
+    the cut is a slice, and every per-slot index op downstream (the split's
+    sorts, the coverage scatters, the rewrite's lookups, the pair kernels'
+    SMEM blocks) works on ``run_slots`` columns instead of W3. Returns
+    (ranges, cells, over); ``over``: some group holds more live runs than
+    the cut keeps — the caller folds it into ``escaped`` so that the step is
+    discarded and re-sized, as for a row cap: no run is dropped silently.
+    ``run_slots`` 0 (a caller that sized none) or >= the width: unchanged."""
+    if not 0 < run_slots < ranges.starts.shape[1]:
+        return ranges, cells, jnp.asarray(False)
+    cut = lambda a: a[:, :run_slots]
+    over = jnp.any(ranges.ncells > run_slots)
+    ranges = ranges._replace(
+        starts=cut(ranges.starts), lens=cut(ranges.lens),
+        shift_x=cut(ranges.shift_x), shift_y=cut(ranges.shift_y),
+        shift_z=cut(ranges.shift_z))
+    if cells is not None:
+        cells = tuple(cut(c) for c in cells)
+    return ranges, cells, over
 
 
 def estimate_halo_window(
@@ -209,11 +245,24 @@ def _split_runs_cells(ranges: GroupRanges, table, S: int, P: int, c0=None):
                 _cells_of_runs(starts, lens, table)[0])
     bcell = _cells_of_rows(
         jnp.arange(1, max(P, 2), dtype=jnp.int32) * S, table)
-    rem_c0 = bcell[jnp.clip(ranges.starts // S, 0, bcell.shape[0] - 1)]
+    rem_c0 = _select(jnp.clip(ranges.starts // S, 0, bcell.shape[0] - 1),
+                     bcell)
     starts, lens, (*sh3, c0), nruns, ovf = _split_runs(
         ranges.starts, ranges.lens, sh3 + (c0,), S, extra=extra,
         rem_payloads=sh3 + (rem_c0,))
     return starts, lens, tuple(sh3), nruns, ovf, c0
+
+
+def _select(idx, values):
+    """``values[idx]`` for a table of a few entries (one per shard or per
+    distance), as a chain of selects: a TPU gather pays per INDEX whatever
+    the table holds (PERF.md PR 30), an elementwise select does not.
+    ``values``: a sequence of static ints or a small 1-D array; an index
+    outside it reads 0."""
+    out = jnp.zeros_like(idx)
+    for i in range(len(values)):
+        out = jnp.where(idx == i, values[i], out)
+    return out
 
 
 def window_bounds(starts, lens, S: int, P: int, k, axis: str):
@@ -269,7 +318,7 @@ def serve_windows(fields: Sequence, bounds_all, S: int, Wmax: int,
 
 
 def shard_halo_stage(x, y, z, h, keys, box, nbr, P: int, Wmax: int,
-                     axis: str):
+                     axis: str, run_slots: int = 0):
     """Shared prologue of a sharded pair-op stage: global table ->
     group windows on the local slab -> localized runs + serve/jbuf
     closures. One implementation for every sharded force stage so the
@@ -286,7 +335,8 @@ def shard_halo_stage(x, y, z, h, keys, box, nbr, P: int, Wmax: int,
     k = jax.lax.axis_index(axis)
     table = global_cell_table(keys, nbr.level, axis)
     granges = group_cell_ranges(x, y, z, h, None, box, nbr, table=table)
-    ranges, bounds, escaped = localize_ranges(granges, S, P, Wmax, k, axis)
+    ranges, bounds, escaped = localize_ranges(granges, S, P, Wmax, k, axis,
+                                              run_slots=run_slots)
 
     def serve(fields):
         return serve_windows(fields, bounds, S, Wmax, P, k, axis)
@@ -295,7 +345,17 @@ def shard_halo_stage(x, y, z, h, keys, box, nbr, P: int, Wmax: int,
         return tuple(jnp.concatenate([o, a]) for o, a in zip(own, halo))
 
     metrics = exchange_metrics_windowed(bounds, Wmax, P, k)
+    metrics["halo_runs"] = live_runs_max(granges)
     return ranges, serve, jbuf, escaped, metrics
+
+
+@named_phase("shard-metrics")
+def live_runs_max(ranges: GroupRanges):
+    """This shard's high-water of live runs a group (or near-field block),
+    of the runs as the exchange RECEIVES them, before ``cut_run_slots``:
+    the ``live_runs_max`` of the ``exchange`` event, what the sized
+    ``run_slots`` is held against."""
+    return jnp.max(ranges.ncells).astype(jnp.int32)
 
 
 @named_phase("shard-metrics")
@@ -482,7 +542,7 @@ def _sparse_layout_dest(covered_all, dest, table, S: int, k):
 @named_phase("halo-exchange")
 def localize_ranges_sparse(
     ranges: GroupRanges, table, S: int, P: int, hmax: Tuple[int, ...],
-    k, axis: str, cells=None,
+    k, axis: str, cells=None, run_slots: int = 0,
 ) -> Tuple[GroupRanges, jax.Array, jax.Array, jax.Array]:
     """Sparse analog of ``localize_ranges``: rewrite global-row runs into
     j-buffer rows [own slab (S) | packed annex (sum(hmax))] using the
@@ -499,11 +559,18 @@ def localize_ranges_sparse(
     remainder piece starts at a slab boundary, whose cell is one of
     P - 1. ``None`` searches the table for the split pieces' cells
     (_cells_of_runs) and returns the same ranges, escapes and layouts.
+
+    ``run_slots``: the sized high-water of live runs a group
+    (sizing.device_sparse_halo; ``cut_run_slots``). The split, the
+    coverage and the rewrite below, and the ranges handed to the pair
+    kernels, are ``run_slots + max(8, P - 1)`` slots wide instead of the
+    window's W3 + that; a group with more live runs trips ``escaped``.
     """
     if len(hmax) != P - 1:
         raise ValueError(f"hmax needs P-1={P-1} per-distance caps, got "
                          f"{len(hmax)}")
     with _stage("localize"):
+        ranges, cells, slots_ovf = cut_run_slots(ranges, run_slots, cells)
         starts, lens, sh3, nruns, split_ovf, c0 = _split_runs_cells(
             ranges, table, S, P, c0=None if cells is None else cells[0])
     with _stage("cover"):
@@ -516,8 +583,8 @@ def localize_ranges_sparse(
         covered_all = jax.lax.all_gather(covered, axis)  # (P, ncells)
     with _stage("localize"):
         out, escaped = _localize_sparse(
-            ranges, starts, lens, sh3, nruns, split_ovf, c0, covered, table,
-            S, P, hmax, k)
+            ranges, starts, lens, sh3, nruns, split_ovf | slots_ovf, c0,
+            covered, table, S, P, hmax, k)
     return out, covered_all, escaped, covered
 
 
@@ -528,30 +595,34 @@ def _localize_sparse(ranges, starts, lens, sh3, nruns, split_ovf, c0,
     escape flag. Returns (localized ranges, escaped)."""
     clen, poff, need = _sparse_layout(covered, table, S, P)  # per src j
     # static per-distance caps: need from src j rides round (k - j) % P
-    hmax_arr = jnp.asarray((0,) + tuple(hmax), jnp.int32)  # index by r
+    caps = (0,) + tuple(hmax)  # index by r
     src_j = jnp.arange(P, dtype=jnp.int32)
     r_of_j = (k - src_j) % P
-    over = (need > hmax_arr[r_of_j]) & (src_j != k)
+    over = (need > jnp.asarray(caps, jnp.int32)[r_of_j]) & (src_j != k)
     escaped = jnp.any(over) | split_ovf
 
     # annex offset of distance r: S + sum of previous rounds' caps
-    prefix = np.concatenate([[0], np.cumsum(hmax)]).astype(np.int32)
-    prefix_arr = jnp.asarray(prefix)  # (P,), prefix[r-1] = offset of r
+    prefix = (0,) + tuple(int(v) for v in np.cumsum(hmax))  # [r-1]: of r
 
     active = lens > 0
     src = jnp.clip(starts // S, 0, P - 1)
     own = src == k
-    clip_lo = jnp.maximum(table[c0], src * S)
-    packed = poff[src, c0] + (starts - clip_lo)
+    # packed row of a run = poff[src, c0] + starts - max(table[c0], src*S):
+    # everything but ``starts`` is a function of (src, c0), so ONE lookup
+    # per slot into the (P * ncells,) table of that difference (a TPU
+    # gather pays per index: two tables by the same index pay twice)
+    ncells = table.shape[0] - 1
+    run_off = (poff - jnp.maximum(table[None, :-1], src_j[:, None] * S))
+    packed = run_off.reshape(-1)[src * ncells + c0] + starts
     r_run = (k - src) % P
-    cap_run = hmax_arr[r_run]
+    cap_run = _select(r_run, caps)
     # a run past its round's cap would index outside the annex: zero it
     # (escaped already tripped above via need > cap, so the step is
     # discarded and re-sized — same contract as the windowed path)
     in_cap = own | (packed + lens <= cap_run)
     local = jnp.where(
         own, starts - k * S,
-        S + prefix_arr[jnp.clip(r_run - 1, 0, P - 1)] + packed,
+        S + _select(jnp.clip(r_run - 1, 0, P - 1), prefix) + packed,
     )
     lens = jnp.where(active & in_cap, lens, 0)
     local = jnp.where(lens > 0, local, 0)
@@ -565,14 +636,15 @@ def _localize_sparse(ranges, starts, lens, sh3, nruns, split_ovf, c0,
 
 
 def shard_halo_stage_sparse(x, y, z, h, keys, box, nbr, P: int,
-                            hmax: Tuple[int, ...], axis: str):
+                            hmax: Tuple[int, ...], axis: str,
+                            run_slots: int = 0):
     """Sparse-exchange variant of ``shard_halo_stage`` — same contract
     (ranges, serve, jbuf, escaped, metrics), comm volume sum(hmax) rows
     per serve instead of (P-1) * Wmax. The reference analog is
     exchangeHalos' per-peer leaf-range p2p (exchange_halos.hpp:43-119);
     here the range lists are implicit in the all_gathered coverage
     bitmaps + the replicated cell table, so the negotiation is
-    O(P * ncells) bits."""
+    O(P * ncells) bits. ``run_slots``: ``localize_ranges_sparse``."""
     from sphexa_tpu.sph.pallas_pairs import group_cell_ranges
 
     S = x.shape[0]
@@ -581,7 +653,8 @@ def shard_halo_stage_sparse(x, y, z, h, keys, box, nbr, P: int,
     granges, cells = group_cell_ranges(x, y, z, h, None, box, nbr,
                                        table=table, with_cells=True)
     ranges, covered_all, escaped, covered = localize_ranges_sparse(
-        granges, table, S, P, hmax, k, axis, cells=cells
+        granges, table, S, P, hmax, k, axis, cells=cells,
+        run_slots=run_slots,
     )
 
     # one total order over EVERY collective this stage issues, carried
@@ -600,6 +673,7 @@ def shard_halo_stage_sparse(x, y, z, h, keys, box, nbr, P: int,
         return tuple(jnp.concatenate([o, a]) for o, a in zip(own, halo))
 
     metrics = exchange_metrics_sparse(covered, table, S, hmax, P, k)
+    metrics["halo_runs"] = live_runs_max(granges)
     return ranges, serve, jbuf, escaped, metrics
 
 
@@ -627,6 +701,7 @@ def exchange_metrics_sparse(covered, table, S: int,
 @named_phase("halo-exchange")
 def localize_ranges(
     ranges: GroupRanges, S: int, P: int, Wmax: int, k, axis: str,
+    run_slots: int = 0,
 ) -> Tuple[GroupRanges, jax.Array, jax.Array]:
     """Rewrite a GLOBAL-row GroupRanges into j-buffer rows
     [own slab (S) | annex (P * Wmax)]. Returns (localized ranges,
@@ -634,9 +709,11 @@ def localize_ranges(
 
     Runs outside their source's served window (drift since the last
     Wmax sizing) zero out and flip ``escaped``, which the caller folds
-    into the occupancy sentinel.
+    into the occupancy sentinel. ``run_slots``: as
+    ``localize_ranges_sparse``'s.
     """
     with _stage("localize"):
+        ranges, _, slots_ovf = cut_run_slots(ranges, run_slots)
         starts, lens, sh3, nruns, split_ovf = _split_runs(
             ranges.starts, ranges.lens,
             (ranges.shift_x, ranges.shift_y, ranges.shift_z), S,
@@ -646,8 +723,8 @@ def localize_ranges(
         mine, bounds_all = window_bounds(starts, lens, S, P, k, axis)
     with _stage("localize"):
         out, escaped = _localize_windows(
-            ranges, starts, lens, sh3, nruns, split_ovf, bounds_all, S, P,
-            Wmax, k)
+            ranges, starts, lens, sh3, nruns, split_ovf | slots_ovf,
+            bounds_all, S, P, Wmax, k)
     return out, bounds_all, escaped
 
 

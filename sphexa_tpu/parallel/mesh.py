@@ -66,7 +66,7 @@ def _place_aux_leaf(leaf, n: int, place, pspec, rspec):
 
 def make_sharded_step(mesh: Mesh, cfg: PropagatorConfig, step_fn=step_hydro_std,
                       halo_window: int = 0, halo_cells=(), grav_cells=(),
-                      aux_cfg=None):
+                      aux_cfg=None, halo_runs: int = 0):
     """Jit the full step with particle arrays sharded over the mesh.
 
     GSPMD partitions the entire program: the SFC sort's key exchange is the
@@ -81,7 +81,9 @@ def make_sharded_step(mesh: Mesh, cfg: PropagatorConfig, step_fn=step_hydro_std,
     replicated global octree (assignment.hpp:51-53). ``grav_cells``
     (P-1 per-distance row caps from sizing.device_gravity_halo) switches
     the gravity near field to the MAC-sized sparse serve; empty ships
-    full peer slabs.
+    full peer slabs. ``halo_runs`` (sizing.device_sparse_halo's second
+    value) cuts the SPH halo's run axis to the sized high-water of live
+    runs; 0 keeps the window's full width.
 
     turb-ve / std-cooling carry extra per-step state through the stepper
     (the reference runs every propagator under the full MPI domain,
@@ -121,6 +123,7 @@ def make_sharded_step(mesh: Mesh, cfg: PropagatorConfig, step_fn=step_hydro_std,
             cfg = dataclasses.replace(cfg, mesh=mesh, shard_axis="p",
                                       halo_window=halo_window,
                                       halo_cells=tuple(halo_cells),
+                                      halo_runs=int(halo_runs),
                                       grav_cells=tuple(grav_cells))
         else:
             cfg = dataclasses.replace(cfg, backend="xla")

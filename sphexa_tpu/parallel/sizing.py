@@ -210,9 +210,25 @@ def _own_slab_read(need, S: int, what: str):
             f"own {S} rows; its needs cannot be trusted on this backend")
 
 
+def pad_run_slots(runs: int, margin: float, quantum: int = 8) -> int:
+    """Slots of an exchange's run axis from the observed high-water of
+    live runs a group (``exchange.cut_run_slots``): the halo's margin, a
+    floor of four more because the count is a small integer (a group
+    that gains three runs must not trip at any margin), rounded up to
+    ``quantum``. The caller clamps it to the full width."""
+    return int(-(-(int(max(int(runs), 1) * margin) + 4) // quantum) * quantum)
+
+
 @functools.partial(jax.jit, static_argnames=("nbr", "P", "mesh"))
 def sparse_need_matrix(x, y, z, h, keys, box, nbr, P: int, mesh=None):
-    """(P_dest, P_src) row-need matrix of the sparse cell-granular halo
+    """``sparse_needs_and_runs``' need matrix alone."""
+    return sparse_needs_and_runs(x, y, z, h, keys, box, nbr, P, mesh)[0]
+
+
+@functools.partial(jax.jit, static_argnames=("nbr", "P", "mesh"))
+def sparse_needs_and_runs(x, y, z, h, keys, box, nbr, P: int, mesh=None):
+    """(need, runs). ``need``: the (P_dest, P_src) row-need matrix of the
+    sparse cell-granular halo
     exchange: entry [k, j] = rows shard k's covered cells clip to shard
     j's slab (diagonal = own slab, served locally). Computed from the
     SAME monotone group windows the in-step stage marks coverage with
@@ -220,7 +236,9 @@ def sparse_need_matrix(x, y, z, h, keys, box, nbr, P: int, mesh=None):
     escape can only fire after genuine drift — and the in-step
     telemetry ``shard_rows`` (exchange.exchange_metrics_sparse) must
     equal this matrix's off-diagonal row sums on an undrifted state
-    (pinned by tests/test_parallel.py).
+    (pinned by tests/test_parallel.py). ``runs``: (P,) each slab's
+    high-water of live runs a group (``max(ranges.ncells)`` of the same
+    prologue), what the exchange's run-slot axis is sized from.
 
     The needs are taken the way the step takes them, under ``shard_map``
     over ``mesh`` (the run's; without one, the first P local devices):
@@ -264,12 +282,13 @@ def sparse_need_matrix(x, y, z, h, keys, box, nbr, P: int, mesh=None):
         ranges, cells = group_cell_ranges(xk, yk, zk, hk, None, box, nbr,
                                           table=table, with_cells=True)
         covered = coverage_from_runs(ranges.starts, ranges.lens, table, cells)
-        return _sparse_layout(covered, table, S, P)[2][None, :]
+        return (_sparse_layout(covered, table, S, P)[2][None, :],
+                jnp.max(ranges.ncells)[None])
 
     rows = PartitionSpec(axis)
     return shard_map(slab_need, mesh=mesh, in_specs=(rows,) * 5,
-                     out_specs=rows, check_vma=False)(
-        skeys, xs, ys, zs, hs)  # (P_dest, P_src)
+                     out_specs=(rows, rows), check_vma=False)(
+        skeys, xs, ys, zs, hs)  # (P_dest, P_src), (P,)
 
 
 def _per_distance_needs(need, P: int):
@@ -287,30 +306,37 @@ def _per_distance_needs(need, P: int):
 
 @functools.partial(jax.jit, static_argnames=("nbr", "P", "mesh"))
 def _sparse_halo_needs(x, y, z, h, keys, box, nbr, P: int, mesh=None):
-    """``_per_distance_needs`` of ``sparse_need_matrix``."""
-    need = sparse_need_matrix(x, y, z, h, keys, box, nbr, P, mesh)
-    return _per_distance_needs(need, P)
+    """``_per_distance_needs`` of ``sparse_needs_and_runs``' matrix, then
+    the fullest group's live runs over all slabs: one array, one fetch."""
+    need, runs = sparse_needs_and_runs(x, y, z, h, keys, box, nbr, P, mesh)
+    return jnp.concatenate([_per_distance_needs(need, P),
+                            jnp.max(runs)[None]])
 
 
 def device_sparse_halo(x, y, z, h, keys, box, nbr, P: int,
                        margin: float = 1.4, quantum: int = 256,
-                       mesh=None) -> Tuple[int, ...]:
-    """Size the sparse exchange's static per-distance row caps (the
-    Hmax tuple of shard_halo_stage_sparse). P scalars to the host.
-    ``mesh``: the run's mesh (``sparse_need_matrix``)."""
+                       mesh=None) -> Tuple[Tuple[int, ...], int]:
+    """Size the sparse exchange from the current state: (caps, run_slots).
+    ``caps``: the static per-distance row caps (the Hmax tuple of
+    shard_halo_stage_sparse). ``run_slots``: the slots of its run axis
+    (``pad_run_slots`` of the fullest group's live runs, at most the
+    window's W3: ``PropagatorConfig.halo_runs``), under the same
+    ``margin``: a trip of either grows both. P + 1 scalars to the host.
+    ``mesh``: the run's mesh (``sparse_needs_and_runs``)."""
     import dataclasses
 
     n = x.shape[0]
     S = n // P
     if nbr.run_cap > S:
         nbr = dataclasses.replace(nbr, run_cap=S)
-    *per_r, own = np.asarray(fetch(_sparse_halo_needs(x, y, z, h, keys, box,
-                                                      nbr, P, mesh)))
+    *per_r, own, runs = np.asarray(fetch(_sparse_halo_needs(
+        x, y, z, h, keys, box, nbr, P, mesh)))
     _own_slab_read(own, S, "sparse halo")
     pad = lambda v: min(
         int(-(-int(max(int(v), 1) * margin) // quantum) * quantum), S
     )
-    return tuple(pad(v) for v in per_r)
+    return (tuple(pad(v) for v in per_r),
+            min(pad_run_slots(runs, margin), nbr.window ** 3))
 
 
 # ---------------------------------------------------------------------------

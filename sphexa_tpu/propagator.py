@@ -73,7 +73,8 @@ STEP_DIAG_KEYS = ("dt", "nc_mean", "nc_max", "occupancy", "rho_max",
 #: flush boundary, so they add ZERO host syncs to the deferred happy path
 #: (pinned by tests/test_telemetry.py). Present only on mesh runs through
 #: the pallas fast path; consumers must .get() them.
-SHARD_DIAG_KEYS = ("shard_rows", "shard_occ", "shard_work", "shard_trips")
+SHARD_DIAG_KEYS = ("shard_rows", "shard_occ", "shard_work", "shard_trips",
+                   "shard_runs")
 
 #: GRAV_SHARD_DIAG_KEYS (imported above, gravity/traversal.py) is the
 #: gravity stage's analog of SHARD_DIAG_KEYS.
@@ -154,6 +155,13 @@ class PropagatorConfig:
     # sum(halo_cells) rows per serve and tracks the halo surface instead
     # of degenerating to whole slabs (docs/NEXT.md round-4 measurement)
     halo_cells: Tuple[int, ...] = ()
+    # slots of the SPH halo's run axis: the sized high-water of live runs
+    # a group (parallel/sizing.device_sparse_halo, beside halo_cells; a
+    # sized value like them, never an option). The exchange's per-slot
+    # index work and the pair kernels' range blocks are this wide, not
+    # the window's W3; a group with more runs trips the halo sentinel.
+    # 0 (a caller that sizes none) = W3, the full width
+    halo_runs: int = 0
     # MAC-sized sparse gravity near-field exchange: P-1 per-DISTANCE row
     # caps (parallel/sizing.device_gravity_halo) for the leaf-granular
     # serve inside compute_gravity's shard path. () = full peer slabs
@@ -449,9 +457,11 @@ def _halo_stage_fn(cfg: PropagatorConfig, nbr, P: int, S_shard: int):
     axis = cfg.shard_axis
     if cfg.halo_cells:
         hmax = tuple(min(c, S_shard) for c in cfg.halo_cells)
-        return lambda *a: ex.shard_halo_stage_sparse(*a, nbr, P, hmax, axis)
+        return lambda *a: ex.shard_halo_stage_sparse(
+            *a, nbr, P, hmax, axis, run_slots=cfg.halo_runs)
     Wmax = min(cfg.halo_window, S_shard) or S_shard
-    return lambda *a: ex.shard_halo_stage(*a, nbr, P, Wmax, axis)
+    return lambda *a: ex.shard_halo_stage(*a, nbr, P, Wmax, axis,
+                                          run_slots=cfg.halo_runs)
 
 
 def exchange_fields_per_step(prop: str, av_clean: bool = False) -> int:
@@ -471,8 +481,8 @@ def exchange_fields_per_step(prop: str, av_clean: bool = False) -> int:
 
 def _shard_metrics(ranges, escaped, metrics, axis: str, token=None):
     """(P,) replicated per-shard telemetry arrays (SHARD_DIAG_KEYS) from
-    one force stage's halo-exchange products: the four per-shard scalars
-    are stacked and shipped in ONE all_gather — O(4P) floats over ICI,
+    one force stage's halo-exchange products: the five per-shard scalars
+    are stacked and shipped in ONE all_gather — O(5P) floats over ICI,
     the Warren-Salmon per-processor work accounting riding the step's
     diagnostics. ``shard_work`` is the candidate rows this shard streams
     per pair op (the pair-stage work proxy); everything travels as f32
@@ -489,15 +499,17 @@ def _shard_metrics(ranges, escaped, metrics, axis: str, token=None):
             metrics["halo_occ"].astype(jnp.float32),
             work,
             jnp.asarray(escaped, jnp.float32),
+            metrics["halo_runs"].astype(jnp.float32),
         ])
         if token is not None:
             packed = chain_after(packed, token)
-        g = jax.lax.all_gather(packed, axis)  # (P, 4) replicated
+        g = jax.lax.all_gather(packed, axis)  # (P, 5) replicated
         return {
             "shard_rows": g[:, 0].astype(jnp.int32),
             "shard_occ": g[:, 1],
             "shard_work": g[:, 2],
             "shard_trips": g[:, 3].astype(jnp.int32),
+            "shard_runs": g[:, 4].astype(jnp.int32),
         }
 
 

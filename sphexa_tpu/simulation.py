@@ -988,7 +988,7 @@ class Simulation:
         from sphexa_tpu.sfc.box import make_global_box
 
         wmax = 0
-        hcells = ()
+        hcells, hruns = (), 0
         if self._cfg.backend == "pallas" and self.prop_name != "nbody":
             # device-side discovery: the needs scan runs as jitted
             # reductions over the sharded arrays and only P-1 scalars
@@ -1007,7 +1007,7 @@ class Simulation:
                 keys = compute_sfc_keys(s.x, s.y, s.z, gbox,
                                         curve=self.curve)
             if self._halo_mode == "sparse":
-                hcells = device_sparse_halo(
+                hcells, hruns = device_sparse_halo(
                     s.x, s.y, s.z, s.h, keys, gbox, self._cfg.nbr,
                     P=self._mesh.size, margin=self._halo_margin,
                     mesh=self._mesh,
@@ -1033,7 +1033,7 @@ class Simulation:
         if hcells:
             shipped = int(sum(min(c, S) for c in hcells))
             self._halo_info = {"mode": "sparse", "caps": tuple(hcells),
-                               "shipped_rows": shipped}
+                               "shipped_rows": shipped, "run_slots": hruns}
         elif self._cfg.backend == "pallas" and self.prop_name != "nbody":
             w = min(wmax, S) or S
             self._halo_info = {"mode": "windowed", "wmax": w,
@@ -1058,8 +1058,10 @@ class Simulation:
             if self._grav_cells:
                 caps = tuple(min(int(c), S) for c in self._grav_cells)
                 shipped = int(sum(caps))
-                self._grav_halo_info = {"mode": "sparse", "caps": caps,
-                                        "shipped_rows": shipped}
+                g = self._cfg.gravity
+                self._grav_halo_info = {
+                    "mode": "sparse", "caps": caps, "shipped_rows": shipped,
+                    "run_slots": g.p2p_run_cap or g.p2p_cap}
             else:
                 self._grav_halo_info = {"mode": "windowed", "wmax": S,
                                         "shipped_rows": (P - 1) * S}
@@ -1068,7 +1070,7 @@ class Simulation:
                 self._grav_halo_info["shipped_rows"] * 5 * 4 * nshell)
         self._stepper = make_sharded_step(
             self._mesh, self._cfg, self._step_fn(),
-            halo_window=wmax, halo_cells=hcells,
+            halo_window=wmax, halo_cells=hcells, halo_runs=hruns,
             grav_cells=self._grav_cells, aux_cfg=aux_cfg,
         )
 
@@ -1128,6 +1130,7 @@ class Simulation:
             # sharded solves classify against the per-shard essential
             # node set (LET analog) instead of the full replicated tree
             let_shards=self._mesh.size if self._mesh is not None else 0,
+            run_margin=self._grav_halo_margin,
         )
         self._gtree = gtree
         ewald = None
@@ -1494,6 +1497,7 @@ class Simulation:
             ginfo = self._grav_halo_info or {}
             return ("sharded", self.prop_name, self._cfg,
                     info.get("caps"), info.get("wmax"),
+                    info.get("run_slots"),
                     ginfo.get("caps"), ginfo.get("wmax"))
         return (self.prop_name, self._cfg, self.turb_cfg,
                 self.cooling_cfg, donate_now,
@@ -1636,6 +1640,15 @@ class Simulation:
 
         work, rows, occ = arr("shard_work"), arr("shard_rows"), \
             arr("shard_occ")
+
+        def run_fields(info, runs):
+            # schema-v14: the run axis of a sparse exchange, the sized
+            # slots beside the fullest group's live runs (fullest shard)
+            if runs is None or not info.get("run_slots"):
+                return {}
+            return {"run_slots": int(info["run_slots"]),
+                    "live_runs_max": int(runs.max())}
+
         # per-shard trips reaching this point are always zero — a tripped
         # sentinel folds into occupancy==cap+1 and the step/window is
         # discarded before any emit; halo_trips is counted at the ONE
@@ -1656,7 +1669,7 @@ class Simulation:
                                               for o in occ],
                 bytes_per_step=int(info.get("bytes_per_step", 0)),
                 trips=int(tel.counters.get("halo_trips", 0)),
-                stage="sph",
+                stage="sph", **run_fields(info, arr("shard_runs")),
             )
         # schema-v7: the gravity near field gets its own exchange event
         # when the MAC-sized sparse serve is active (gshard_* diagnostics
@@ -1673,7 +1686,7 @@ class Simulation:
                                                for o in gocc],
                 bytes_per_step=int(ginfo.get("bytes_per_step", 0)),
                 trips=int(tel.counters.get("grav_halo_trips", 0)),
-                stage="gravity",
+                stage="gravity", **run_fields(ginfo, arr("gshard_runs")),
             )
         # the watchdog: max/mean per metric against the configured ratio
         for metric, a in (("work", work), ("halo_rows", rows),

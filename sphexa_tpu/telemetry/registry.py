@@ -57,12 +57,19 @@ import numpy as np
 #: steps solved gravity (live slots over lists x cap of the superblock
 #: candidate lists and of the blocks' M2P and P2P lists, averaged over
 #: the window's steps: how far the block loop's width-following stages
-#: engage). No kind, no REQUIRED field: v13 readers accept v1-v12 files.
-SCHEMA_VERSION = 13
+#: engage). No kind, no REQUIRED field: v13 readers accept v1-v12 files;
+#: v14 the run axis of a sparse exchange: optional ``run_slots`` /
+#: ``live_runs_max`` on ``exchange`` (the sized slots of the exchange's
+#: run axis, ``PropagatorConfig.halo_runs`` / ``GravityConfig
+#: .p2p_run_cap``, and the fullest group's or block's live runs on the
+#: fullest shard: the exchange's per-slot index work goes with the
+#: first, a trip is the second passing it). No kind, no REQUIRED field:
+#: v14 readers accept v1-v13 files.
+SCHEMA_VERSION = 14
 
 #: event schema versions this reader understands (older versions only
 #: ever ADD kinds, so the per-kind field table below covers them all)
-SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13)
+SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14)
 
 #: every event kind the schema admits, with its required payload fields
 #: (beyond the envelope ``v``/``seq``/``t``/``kind``). The CLI's --strict
@@ -88,7 +95,8 @@ EVENT_KINDS: Dict[str, tuple] = {
     # -- v2: distributed kinds (one run, P shards) ------------------------
     # per-window halo-exchange record: ``rows`` = per-shard TRUE candidate
     # need (device-measured), ``shipped_rows`` = the static sized volume
-    # actually moved per serve (sum(hmax) sparse / (P-1)*Wmax windowed)
+    # actually moved per serve (sum(hmax) sparse / (P-1)*Wmax windowed);
+    # since v14 with the optional ``run_slots`` / ``live_runs_max``
     "exchange": ("it", "shipped_rows", "rows"),
     # per-window load record: per-shard particle counts + work proxies
     "shard_load": ("it", "particles"),

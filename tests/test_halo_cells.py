@@ -11,6 +11,7 @@ neither the per-run search nor the payloads can come back unnoticed.
 
 import dataclasses
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
@@ -151,16 +152,32 @@ def test_unsplit_coverage_differs_only_on_empty_boundary_cells():
 # ---------------------------------------------------------------------------
 
 
-@functools.lru_cache(maxsize=None)
+SIDES = {"sedov": 16, "evrard": 20}
+
+
 def _sedov_slabs(P: int):
-    """Sedov 16^3 globally SFC-sorted (what the sharded step hands the
-    stage), the clamped NeighborConfig and sized per-distance caps."""
-    from sphexa_tpu.init import init_sedov
+    """``_slabs`` of the Sedov box, without the run slots."""
+    return _slabs(P)[:4]
+
+
+@functools.lru_cache(maxsize=None)
+def _slabs(P: int, case: str = "sedov"):
+    """Sedov 16^3 (or the Evrard sphere of the 20^3 lattice, 4,201, the
+    smallest whose grid takes a window of 4; trimmed to the mesh as main()
+    trims it) globally SFC-sorted (what the sharded
+    step hands the stage), the clamped NeighborConfig, the sized
+    per-distance caps and the sized run slots."""
+    from sphexa_tpu.init import make_initializer
     from sphexa_tpu.parallel.sizing import device_sparse_halo
     from sphexa_tpu.sfc.box import make_global_box
     from sphexa_tpu.simulation import make_propagator_config
 
-    state, box, const = init_sedov(16)
+    state, box, const = make_initializer(case)(SIDES[case])
+    if state.n % P:
+        n_full, keep = state.n, state.n // P * P
+        state = jax.tree.map(
+            lambda a: a[:keep] if getattr(a, "ndim", 0) >= 1
+            and a.shape[0] == n_full else a, state)
     cfg = make_propagator_config(state, box, const, block=512,
                                  backend="pallas")
     gbox = make_global_box(state.x, state.y, state.z, box)
@@ -171,14 +188,14 @@ def _sedov_slabs(P: int):
     nbr = cfg.nbr
     if nbr.run_cap > S:  # same clamp as the sharded force stages
         nbr = dataclasses.replace(nbr, run_cap=S)
-    sized = device_sparse_halo(state.x, state.y, state.z, state.h, keys,
-                               gbox, nbr, P=P)
-    return (gbox, keys[order], x, y, z, h), nbr, S, sized
+    sized, run_slots = device_sparse_halo(
+        state.x, state.y, state.z, state.h, keys, gbox, nbr, P=P)
+    return (gbox, keys[order], x, y, z, h), nbr, S, sized, run_slots
 
 
-def _stage_fn(P: int, nbr, S: int, hmax, carried: bool):
+def _stage_fn(P: int, nbr, S: int, hmax, carried: bool, run_slots: int = 0):
     """shard_map'd sparse halo prologue returning everything the serves
-    and the engines read."""
+    and the engines read (+ the live-run high-water, last)."""
     from jax.sharding import PartitionSpec
 
     from sphexa_tpu.parallel import make_mesh
@@ -191,16 +208,17 @@ def _stage_fn(P: int, nbr, S: int, hmax, carried: bool):
             x, y, z, h, None, box, nbr, table=table, with_cells=True)
         ranges, covered_all, escaped, covered = ex.localize_ranges_sparse(
             granges, table, S, P, hmax, k, "p",
-            cells=cells if carried else None)
+            cells=cells if carried else None, run_slots=run_slots)
         rows = ex.exchange_metrics_sparse(covered, table, S, hmax, P, k)
         lift = lambda a: jnp.asarray(a)[None]
         return (tuple(lift(a) for a in ranges), lift(covered_all),
-                lift(escaped), lift(covered), lift(rows["halo_rows"]), table)
+                lift(escaped), lift(covered), lift(rows["halo_rows"]), table,
+                lift(ex.live_runs_max(granges)))
 
     Pp, Pr = PartitionSpec("p"), PartitionSpec()
     return shard_map(
         stage, mesh=make_mesh(P), in_specs=(Pr, Pp, Pp, Pp, Pp, Pp),
-        out_specs=((Pp,) * 8, Pp, Pp, Pp, Pp, Pr), check_vma=False,
+        out_specs=((Pp,) * 8, Pp, Pp, Pp, Pp, Pr, Pp), check_vma=False,
     )
 
 
@@ -212,8 +230,8 @@ def test_stage_same_with_carried_and_searched_cells(P, caps):
     # two programs: one collective order each (exchange.chain_after)
     a = jax.jit(_stage_fn(P, nbr, S, hmax, carried=True))(*args)
     b = jax.jit(_stage_fn(P, nbr, S, hmax, carried=False))(*args)
-    ra, cov_all_a, esc_a, cov_a, rows_a, table = a
-    rb, cov_all_b, esc_b, cov_b, rows_b, _ = b
+    ra, cov_all_a, esc_a, cov_a, rows_a, table, _ = a
+    rb, cov_all_b, esc_b, cov_b, rows_b, _, _ = b
     for fa, fb in zip(ra, rb):  # starts, lens, shifts, ncells, occ, boxl
         _eq(fa, fb)
     _eq(esc_a, esc_b)
@@ -228,6 +246,100 @@ def test_stage_same_with_carried_and_searched_cells(P, caps):
     _eq(np.asarray(cov_a) & filled, np.asarray(cov_b) & filled)
     _eq(np.asarray(cov_all_a) & filled, np.asarray(cov_all_b) & filled)
     assert not (np.asarray(cov_a) ^ np.asarray(cov_b))[:, filled].any()
+
+
+# ---------------------------------------------------------------------------
+# the run-slot axis cut to the sized high-water of live runs
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("case", ["sedov", "evrard"])
+@pytest.mark.parametrize("P", [4, 8])
+def test_narrowed_stage_equals_full_width(P, case):
+    """The stage over ``run_slots`` slots a group is the stage over the
+    window's W3: the same coverage bitmaps, the same escapes, the same
+    rows needed, and the same localized runs in the same slots (the live
+    runs sit at the front; the slots the cut drops are dead ones)."""
+    args, nbr, S, hmax, run_slots = _slabs(P, case)
+    w3 = nbr.window ** 3
+    assert 0 < run_slots < w3
+    full = jax.jit(_stage_fn(P, nbr, S, hmax, carried=True))(*args)
+    cut = jax.jit(_stage_fn(P, nbr, S, hmax, True, run_slots))(*args)
+    rf, cov_all_f, esc_f, cov_f, rows_f, _, live_f = full
+    rc, cov_all_c, esc_c, cov_c, rows_c, _, live_c = cut
+    extra = max(8, P - 1)
+    assert rf[0].shape[-1] == w3 + extra
+    assert rc[0].shape[-1] == run_slots + extra
+    # the sized slots hold the fullest group's runs of an undrifted state
+    _eq(live_f, live_c)
+    assert 0 < int(np.asarray(live_f).max()) <= run_slots
+    for a, b in ((cov_all_f, cov_all_c), (cov_f, cov_c), (esc_f, esc_c),
+                 (rows_f, rows_c)):
+        _eq(a, b)
+    assert not np.asarray(esc_c).any()
+    width = rc[0].shape[-1]
+    for a, b in zip(rf[:5], rc[:5]):  # starts, lens, the three shifts
+        _eq(np.asarray(a)[..., :width], b)
+        assert not np.asarray(a)[..., width:].any()  # dead beyond the cut
+    for a, b in zip(rf[5:], rc[5:]):  # ncells, occupancy, boxl
+        _eq(a, b)
+    assert (np.asarray(rc[1]) > 0).any()
+
+
+@pytest.mark.parametrize("case", ["sedov", "evrard"])
+def test_run_slots_under_the_high_water_trip_the_sentinel(case):
+    """One slot under the fullest group's live runs: ``escaped`` on the
+    shards that hold such a group, and on no other; at the high-water
+    itself, on none. No run is dropped without the flag."""
+    P = 4
+    args, nbr, S, hmax, run_slots = _slabs(P, case)
+    live = np.asarray(jax.jit(_stage_fn(P, nbr, S, hmax, True))(*args)[6])
+    hw = int(live.max())
+    at = jax.jit(_stage_fn(P, nbr, S, hmax, True, hw))(*args)
+    under = jax.jit(_stage_fn(P, nbr, S, hmax, True, hw - 1))(*args)
+    assert not np.asarray(at[2]).any()
+    _eq(np.asarray(under[2]).ravel(), live.ravel() > hw - 1)
+    # a cut that drops live runs drops their cells from the bitmap too:
+    # the flag is what keeps such a step from being used
+    assert np.asarray(under[3]).sum() <= np.asarray(at[3]).sum()
+
+
+@pytest.mark.parametrize("P", [2, 4])
+def test_windowed_stage_narrowed_equals_full_width(P):
+    """``localize_ranges`` (the windowed fallback) shares the split with
+    the sparse stage and takes the same cut."""
+    from jax.sharding import PartitionSpec
+
+    from sphexa_tpu.parallel import make_mesh
+    from sphexa_tpu.propagator import shard_map
+
+    args, nbr, S, _, run_slots = _slabs(P)
+
+    def stage(slots):
+        def fn(box, keys, x, y, z, h):
+            ranges, serve, jbuf, escaped, metrics = ex.shard_halo_stage(
+                x, y, z, h, keys, box, nbr, P, S, "p", run_slots=slots)
+            lift = lambda a: jnp.asarray(a)[None]
+            return (ranges.starts, ranges.lens, ranges.ncells,
+                    lift(escaped), lift(metrics["halo_runs"]),
+                    jbuf((x,), serve((x,)))[0])
+
+        Pp, Pr = PartitionSpec("p"), PartitionSpec()
+        return jax.jit(shard_map(
+            fn, mesh=make_mesh(P), in_specs=(Pr, Pp, Pp, Pp, Pp, Pp),
+            out_specs=(Pp,) * 6, check_vma=False))
+
+    full, cut = stage(0)(*args), stage(run_slots)(*args)
+    width = cut[0].shape[1]
+    assert width == run_slots + max(8, P - 1) < full[0].shape[1]
+    for a, b in zip(full[:2], cut[:2]):
+        _eq(np.asarray(a)[:, :width], b)
+        assert not np.asarray(a)[:, width:].any()
+    for a, b in zip(full[2:], cut[2:]):
+        _eq(a, b)
+    assert not np.asarray(cut[3]).any()
+    assert np.asarray(stage(int(np.asarray(full[4]).max()) - 1)(
+        *args)[3]).any()
 
 
 # ---------------------------------------------------------------------------
@@ -293,3 +405,84 @@ def test_one_chip_prologue_sorts_no_cell_payload(run_cap, plain, cells):
     )(*fields).jaxpr
     assert _sort_operands(trace()) == plain
     assert _sort_operands(trace(with_cells=True)) == cells
+
+
+# ---------------------------------------------------------------------------
+# the driver: a cut under the live runs is a sentinel trip, re-sized
+# ---------------------------------------------------------------------------
+
+TRIP_RUNNER = """
+    import dataclasses
+    import json
+    import numpy as np
+    import sphexa_tpu.parallel.sizing as sz
+    from sphexa_tpu.init import make_initializer
+    from sphexa_tpu.simulation import Simulation
+    from sphexa_tpu.telemetry import Telemetry
+    from sphexa_tpu.telemetry.sinks import MemorySink
+
+    # the first sizing hands out one slot less than the fullest group's
+    # live runs (no margin can: the floor is additive); later ones are
+    # the program's own
+    real, seen = sz.pad_run_slots, []
+
+    def under(runs, margin, quantum=8):
+        seen.append(int(runs))
+        if len(seen) == 1:
+            return int(runs) - 1
+        return real(runs, margin, quantum)
+
+    sz.pad_run_slots = under
+    state, box, const = make_initializer({case!r})({side})
+    const = dataclasses.replace(const, g=0.0)  # the SPH halo alone
+    n4 = state.n // 4 * 4
+    state = jax.tree.map(
+        lambda a: a[:n4] if getattr(a, "ndim", 0) >= 1 else a, state)
+    sink = MemorySink()
+    sim = Simulation(state, box, const, prop="std", backend="pallas",
+                     num_devices=4, check_every=1,
+                     telemetry=Telemetry(sinks=[sink]))
+    started = sim._halo_info["run_slots"]
+    margin0 = sim._halo_margin
+    for _ in range(2):
+        sim.step()
+    ex = [e for e in sink.events if e["kind"] == "exchange"]
+    print("RUN-SLOTS-RESULT " + json.dumps(dict(
+        seen=seen, started=started, ended=sim._halo_info["run_slots"],
+        stepper=sim._stepper.cfg.halo_runs,
+        trips=int(sim.telemetry.counters.get("halo_trips", 0)),
+        margin=[margin0, sim._halo_margin],
+        reasons=[e["reason"] for e in sink.events
+                 if e["kind"] == "reconfigure"],
+        events=[[e["run_slots"], e["live_runs_max"], e["trips"]]
+                for e in ex],
+        finite=bool(np.isfinite(np.asarray(sim.state.x)).all()),
+        iteration=int(sim.iteration))))
+"""
+
+
+@pytest.mark.parametrize("case", ["sedov", "evrard"])
+def test_driver_resizes_run_slots_after_a_trip(case):
+    """``Simulation`` on a mesh whose run slots were sized one under the
+    fullest group's live runs: the first step trips the halo sentinel
+    (occupancy == cap + 1), is discarded, counts a ``halo_trips`` and
+    grows the halo margin; the re-sized run holds every run and passes."""
+    from conftest import run_mesh_subprocess
+
+    out = run_mesh_subprocess(TRIP_RUNNER.format(case=case,
+                                                 side=SIDES[case]))
+    lines = [ln for ln in out.stdout.splitlines()
+             if ln.startswith("RUN-SLOTS-RESULT ")]
+    assert lines, out.stderr[-3000:]
+    r = json.loads(lines[-1].split(" ", 1)[1])
+    hw = r["seen"][0]
+    assert hw >= 2 and r["started"] == hw - 1
+    assert r["trips"] == 1 and r["reasons"][:2] == ["initial", "overflow"]
+    assert r["margin"][1] == pytest.approx(1.5 * r["margin"][0])
+    assert r["ended"] == r["stepper"] >= hw + 4
+    assert r["iteration"] == 2 and r["finite"]
+    # one event a verified step: the slots the step ran with, the fullest
+    # group's live runs under them, the trip counted once
+    assert len(r["events"]) == 2
+    for slots, live, trips in r["events"]:
+        assert slots == r["ended"] and 0 < live <= slots and trips == 1

@@ -118,7 +118,7 @@ RUNNER = """
         run=list(sim._halo_info["caps"]),
         fresh=list(device_sparse_halo(
             *(np.asarray(a) for a in (s0.x, s0.y, s0.z, s0.h, keys0)), gbox,
-            sim._cfg.nbr, P=4, margin=sim._halo_margin)))
+            sim._cfg.nbr, P=4, margin=sim._halo_margin)[0]))
     if case == "tripped":
         sim._grav_halo_margin = {trip_margin}
         sim._configure(reason="test-undersize")
@@ -170,6 +170,27 @@ RUNNER = """
             *one[:4], one[5], gb, sim._gtree, meta, g, shards=4)],
         windows=[[e.get(k) for k in fill_keys]
                  for e in sink.events[mark:] if e["kind"] == "window"])
+    # the near field's run axis: the same stage with the runs' slots at
+    # the full p2p_cap (what a caller that sizes none runs), and with the
+    # near field in the order it had before (the full-slab windowed serve:
+    # every leaf localized on its own, the runs merged afterwards)
+    solve = lambda cfg: [np.asarray(a) for a in tv.compute_gravity(
+        *srt, gb, sim._gtree, meta, dataclasses.replace(cfg, G=const.g))[:3]]
+    sized_acc = np.stack(solve(g), axis=1).astype(np.float64)
+    full_acc = np.stack(solve(dataclasses.replace(g, p2p_run_cap=0)), axis=1)
+    leaf_first = np.stack(solve(dataclasses.replace(
+        g, on_mesh=g.on_mesh[:2] + ((),))), axis=1).astype(np.float64)
+    rel = (np.linalg.norm(sized_acc - leaf_first, axis=1)
+           / np.linalg.norm(leaf_first, axis=1))
+    out["run_axis"] = dict(
+        p2p_cap=g.p2p_cap, p2p_run_cap=g.p2p_run_cap,
+        full_width_equal=bool((sized_acc == full_acc).all()),
+        leaf_first_rel=[float(np.sqrt(np.mean(rel ** 2))), float(rel.max())],
+        live=int(diag["gshard_runs"].max()),
+        sph=[[e.get("run_slots"), e.get("live_runs_max")]
+             for e in sink.events[mark:]
+             if e["kind"] == "exchange" and e.get("stage") == "sph"],
+        sph_slots=[sim._halo_info["run_slots"], sim._cfg.nbr.window ** 3])
     # the scopes of the step this run launched, from its lowered IR
     import io, re
     ss = sim.sim_state
@@ -201,7 +222,8 @@ RUNNER = """
                for k in ("reconfigure", "rollback", "replay", "retrace")}},
         gravity_exchange_events=[
             {{k: e[k] for k in ("mode", "shipped_rows", "rows", "occ",
-                               "steps", "trips")}}
+                               "steps", "trips", "run_slots",
+                               "live_runs_max")}}
             for e in events
             if e["kind"] == "exchange" and e.get("stage") == "gravity"],
         slab=out["particles"] // 4, iteration=int(sim.iteration))
@@ -393,6 +415,38 @@ def test_sentinel_bookkeeping_and_exchange_events(mesh_run):
         assert [e["steps"] for e in ev] == [4, 4] and last["trips"] == 0
 
 
+def test_merged_first_near_field_is_the_leaf_first_one(mesh_run):
+    """The mesh's sparse near field merges a block's leaves into runs
+    BEFORE the exchange and cuts the runs to their sized high-water. Same
+    work: bitwise the solve over the full ``p2p_cap`` slots; and, against
+    the order the near field had before (leaves localized one by one,
+    merged afterwards: what the full-slab windowed serve still runs), the
+    same pairs summed in other chunks, f32 rounding apart. The direct-sum
+    readings of the tests above are the merged-first solve's."""
+    case, r = mesh_run
+    a = r["run_axis"]
+    assert a["full_width_equal"]
+    rms, worst = a["leaf_first_rel"]
+    assert rms < 1e-6 and worst < 1e-5, a["leaf_first_rel"]
+
+
+def test_run_axis_is_sized_and_reported(mesh_run):
+    """``p2p_run_cap`` and ``halo_runs``: sized under the full width, over
+    the live runs of every verified step (no trip of theirs in any case:
+    the ``tripped`` case undersizes the row caps), and on the ``exchange``
+    events of both stages (schema v14)."""
+    case, r = mesh_run
+    a = r["run_axis"]
+    assert 0 < a["live"] <= a["p2p_run_cap"] < a["p2p_cap"]
+    for e in r["gravity_exchange_events"]:
+        assert e["run_slots"] == a["p2p_run_cap"]
+        assert 0 < e["live_runs_max"] <= e["run_slots"]
+    slots, w3 = a["sph_slots"]
+    assert 0 < slots < w3 and a["sph"]
+    for got_slots, live in a["sph"]:
+        assert got_slots == slots and 0 < live <= slots
+
+
 def test_gravity_exchange_scope_is_the_first_of_its_ops(mesh_run):
     """benchmarks/trace_reduce.py takes the FIRST ``sphexa/<phase>`` of an
     op's path: the near field's serve must carry ``gravity-exchange``
@@ -408,13 +462,22 @@ def test_gravity_exchange_scope_is_the_first_of_its_ops(mesh_run):
         sc["collective_first_scopes"])
 
 
-@pytest.mark.parametrize("rows", [64, 8 * 64])
-def test_sized_from_every_block_each_slab_forms(rows):
+@pytest.mark.parametrize("rows,run_cap", [(64, 1024), (8 * 64, 1024),
+                                          (64, 128)])
+def test_sized_from_every_block_each_slab_forms(rows, run_cap):
     """``estimate_gravity_caps`` on a mesh takes its list high-water marks
     from ``_slab_list_highwater``: every slab-local block (or superblock),
     swept on the device. Against plain numpy over the same blocks (the
     cell's first chip run died of a 256-of-16k sample that missed the one
-    block with twice the list of any sampled, PR 29)."""
+    block with twice the list of any sampled, PR 29).
+
+    The fourth count sizes the near field's run axis (``p2p_run_cap``):
+    an upper bound of the runs a block's opened leaves merge into at gap 0
+    under ``run_cap`` rows. Held here to its definition (stretches of
+    row-adjacent opened leaves + rows // the least closed piece, fullest
+    block) and, block by block, over the runs ``_merge_runs`` itself makes
+    of the same leaves (``run_cap`` 128, two leaves a run, is there for
+    the clipping)."""
     import jax.numpy as jnp
 
     from sphexa_tpu.gravity.traversal import (
@@ -434,10 +497,19 @@ def test_sized_from_every_block_each_slab_forms(rows):
     order = jnp.argsort(keys)[:n4]
     xs, ys, zs, ms = (a[order] for a in (state.x, state.y, state.z, state.m))
     tree, meta = sim._gtree, sim._cfg.grav_meta
-    nm, com, _, _ = compute_multipoles(xs, ys, zs, ms, keys[order], tree,
-                                       meta)
+    nm, com, _, edges = compute_multipoles(xs, ys, zs, ms, keys[order], tree,
+                                           meta)
     got = np.asarray(_slab_list_highwater(
+        xs, ys, zs, nm, com, sim.box, tree, meta, 0.5, rows, 4,
+        edges=edges, run_cap=run_cap))
+    # without the leaves' rows: the three counts alone, the same
+    assert (np.asarray(_slab_list_highwater(
         xs, ys, zs, nm, com, sim.box, tree, meta, 0.5, rows, 4))
+        == got[:3]).all()
+    edges = np.asarray(edges)
+    lrows = np.diff(edges)
+    leaf_of_node = np.asarray(tree.leaf_of_node)
+    piece = max(run_cap - int(lrows.max()) + 1, 1)
 
     valid = np.asarray(nm) > 0
     cc, ch, mac2 = (np.asarray(a) for a in _monotone_mac_geometry(
@@ -445,8 +517,8 @@ def test_sized_from_every_block_each_slab_forms(rows):
     parent, is_leaf = np.asarray(tree.parent), np.asarray(tree.is_leaf)
     root = parent == np.arange(meta.num_nodes)
     pos = np.stack([np.asarray(a) for a in (xs, ys, zs)], axis=1)
-    want, S = np.zeros(3, np.int64), n4 // 4
-    per_block = []
+    S = n4 // 4
+    per_block, lists = [], []
     for k in range(4):
         slab = pos[k * S:(k + 1) * S]
         for b0 in range(0, S, rows):
@@ -456,7 +528,42 @@ def test_sized_from_every_block_each_slab_forms(rows):
             d = np.maximum(np.abs(bc - cc) - bs - ch, 0.0)
             acc = valid & ((d * d).sum(1) >= mac2)
             anc = np.where(root, False, acc[parent])
-            per_block.append([(acc & ~anc).sum(),
-                              (is_leaf & valid & ~acc).sum(), (~anc).sum()])
+            opened = np.sort(leaf_of_node[is_leaf & valid & ~acc])
+            adjacent = edges[opened[1:]] == edges[opened[:-1] + 1]
+            bound = ((1 + (~adjacent).sum() if len(opened) else 0)
+                     + lrows[opened].sum() // piece)
+            per_block.append([(acc & ~anc).sum(), len(opened), (~anc).sum(),
+                              bound])
+            lists.append(opened)
     per_block = np.asarray(per_block)
     assert (got == per_block.max(axis=0)).all(), (got, per_block.max(0))
+
+    # the runs the near field makes of the same leaves, block by block
+    from sphexa_tpu.gravity.traversal import _merge_p2p_runs
+
+    cap = max(len(l) for l in lists)
+    leaf = np.zeros((len(lists), cap), np.int32)
+    live = np.zeros((len(lists), cap), bool)
+    for b, l in enumerate(lists):
+        leaf[b, :len(l)], live[b, :len(l)] = l, True
+    ranges, (c0, c1) = _merge_p2p_runs(
+        jnp.asarray(np.where(live, edges[leaf], 0)),
+        jnp.asarray(np.where(live, lrows[leaf], 0)), run_cap,
+        leaf=jnp.asarray(leaf))
+    nruns = np.asarray(ranges.ncells)
+    assert (nruns <= per_block[:, 3]).all() and nruns.max() > 1
+    if run_cap < 1024:  # the cap cuts stretches: more runs than stretches
+        assert (np.asarray(ranges.lens) <= run_cap).all()
+        assert nruns.max() > (per_block[:, 3]
+                              - [lrows[l].sum() // piece for l in lists]).max()
+    # the runs cover the leaves' rows and no other, and carry the first
+    # and last leaf of each run
+    for b in (0, len(lists) // 2, len(lists) - 1):
+        k = int(nruns[b])
+        st, ln = (np.asarray(a)[b, :k] for a in (ranges.starts, ranges.lens))
+        rows_of = lambda s_, l_: np.concatenate(
+            [np.arange(a, a + n) for a, n in zip(s_, l_)] + [[]])
+        l = lists[b]
+        assert (rows_of(st, ln) == rows_of(edges[l], lrows[l])).all()
+        assert (edges[np.asarray(c0)[b, :k]] == st).all()
+        assert (edges[np.asarray(c1)[b, :k] + 1] == st + ln).all()

@@ -311,7 +311,7 @@ class TestSpans:
             sp["bytes"] = 3
         after = time.perf_counter_ns()
         (e,) = sink.events
-        assert e["kind"] == "span" and e["v"] == SCHEMA_VERSION == 13
+        assert e["kind"] == "span" and e["v"] == SCHEMA_VERSION == 14
         assert validate_event(e) == []
         assert (e["name"], e["parent"], e["it"]) == ("sphexa:x", None, 12)
         assert (e["reason"], e["bytes"]) == ("r", 3)
@@ -627,10 +627,13 @@ class TestDistributedTelemetry:
         # shipped rows == sum of the sized per-distance caps
         gbox = make_global_box(state.x, state.y, state.z, box)
         keys = compute_sfc_keys(state.x, state.y, state.z, gbox)
-        hc = device_sparse_halo(state.x, state.y, state.z, state.h, keys,
-                                gbox, sim._cfg.nbr, P=2,
-                                margin=sim._halo_margin)
+        hc, runs = device_sparse_halo(
+            state.x, state.y, state.z, state.h, keys, gbox, sim._cfg.nbr,
+            P=2, margin=sim._halo_margin)
         assert exchanges[-1]["shipped_rows"] == sum(min(c, S) for c in hc)
+        # schema v14: the sized run slots beside the fullest group's runs
+        assert all(e["run_slots"] == runs >= e["live_runs_max"] > 0
+                   for e in exchanges)
         mems = by_kind("memory")
         assert {e["point"] for e in mems} >= {"post-compile", "flush"}
         # the science ledger rode the same sharded fetch: its sums
