@@ -51,10 +51,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="momentum/energy pair-cutoff convention: on = min-h "
                         "symmetric (default), off = reference-parity "
                         "one-sided; overrides the snapshot's symPairs attr")
-    p.add_argument("--evolve-chem", action="store_true", dest="evolve_chem",
-                   help="std-cooling: evolve the 6-species primordial "
-                        "network (H/H+/He/He+/He++/e) instead of the CIE "
-                        "table with static fractions")
     p.add_argument("--glass", default=None,
                    help="glass template HDF5 file, tiled into every "
                         "lattice-based IC (init/utils.hpp glass blocks); "
@@ -345,12 +341,6 @@ def main(argv=None) -> int:
         # row-aligned with the trimmed particle arrays
         if chem_restored is not None:
             chem_restored = trim(chem_restored)
-    cooling_cfg = None
-    if args.prop == "std-cooling" and args.evolve_chem:
-        from sphexa_tpu.physics.cooling import CoolingConfig
-
-        cooling_cfg = CoolingConfig(gamma=const.gamma, evolve_species=True)
-
     # telemetry registry shared by the driver, the loop and the dump's
     # spans; --telemetry-dir adds the persisted JSONL sink (the sink-less
     # registry costs counters only)
@@ -408,7 +398,7 @@ def main(argv=None) -> int:
         sim = Simulation(state, box, const, prop=args.prop,
                          av_clean=args.avclean and args.prop in ("ve", "turb-ve"),
                          turb_state=turb_state, turb_cfg=turb_cfg,
-                         chem=chem_restored, cooling_cfg=cooling_cfg,
+                         chem=chem_restored,
                          theta=args.theta,
                          m2p_cap_margin=args.m2p_cap_margin,
                          num_devices=args.devices, halo_mode=args.halo_mode,

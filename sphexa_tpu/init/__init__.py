@@ -56,7 +56,13 @@ CASES: Dict[str, Callable] = {
 #: forms; without it Evrard at -n 200 on four v5e chips sizes its halo at
 #: the 256-row floor, and its near-field list from a sample that misses
 #: the fullest block, and dies after four re-sizes, six minutes in.
-CAPABILITIES = frozenset({"list-lifecycle", "mesh-gravity"})
+#: ``cooling-network`` (PR 36): std-cooling evolves the six-species
+#: network and integrates its source without cancellation; without it
+#: ``--prop std-cooling`` is the CIE table with pass-through fractions (a
+#: cheaper program under the same name) and its source is (u' - u) / dt,
+#: zero or one ulp of u over dt at a step's dt.
+CAPABILITIES = frozenset({"list-lifecycle", "mesh-gravity",
+                          "cooling-network"})
 
 
 def split_case_spec(name: str):

@@ -70,6 +70,7 @@ class CoolingConfig:
     # place of the CIE table: species ODEs + composition-resolved cooling
     # per step, the cooler.cpp solve_chemistry role. False keeps the
     # metal-inclusive CIE curve with diagnostic-only fractions.
+    # Simulation(prop="std-cooling") builds its default with True.
     evolve_species: bool = False
 
     @property
@@ -207,26 +208,41 @@ def cooling_timestep(rho_code, u_code, chem: ChemistryData, cfg: CoolingConfig):
     return cfg.ct_crit * jnp.min(tc)
 
 
+def implicit_u_subcycle(u, dt_sub, cool, heat):
+    """One subcycle of the energy update both integrators share:
+    ``(u_new, rate)``.
+
+    Cooling is applied as u' = u / (1 + dt_sub * L/u), which is
+    unconditionally stable and positivity-preserving; heating is added
+    explicitly. ``rate`` is the same subcycle's (u' - u) / dt_sub formed
+    WITHOUT the difference: heat - L / (1 + dt_sub * L/u). At a step's dt
+    u' - u is a few ulp of u (wind-shock's ramp starts at dt 1e-10 against
+    cooling times of 3 to 300), so the differenced form reads zero or
+    one ulp over dt; the rate is a quotient of two well-conditioned
+    numbers at every dt."""
+    damp = 1.0 + dt_sub * cool / jnp.maximum(u, 1e-30)
+    return u / damp + dt_sub * heat, heat - cool / damp
+
+
 def cool_particles(dt, rho_code, u_code, chem: ChemistryData, cfg: CoolingConfig):
     """Integrate the cooling source over dt; returns du/dt averaged over the
     step (the quantity the propagator adds to du,
-    std_hydro_grackle.hpp:214-226).
-
-    Sub-cycled semi-implicit update: cooling is applied as
-    u' = u / (1 + dt_sub * L/u), which is unconditionally stable and
-    positivity-preserving for net cooling; heating is added explicitly.
+    std_hydro_grackle.hpp:214-226): the mean of the subcycles' rates
+    (``implicit_u_subcycle``), accumulated beside ``u``.
     """
     dt_sub = dt / cfg.substeps
 
-    def body(u, _):
+    def body(carry, _):
+        u, acc = carry
         dudt = cooling_rate(rho_code, u, chem, cfg)
         cool = jnp.where(dudt < 0, -dudt, 0.0)
         heat = jnp.where(dudt > 0, dudt, 0.0)
-        u_new = u / (1.0 + dt_sub * cool / jnp.maximum(u, 1e-30)) + dt_sub * heat
-        return u_new, None
+        u_new, rate = implicit_u_subcycle(u, dt_sub, cool, heat)
+        return (u_new, acc + rate), None
 
-    u_final, _ = jax.lax.scan(body, u_code, None, length=cfg.substeps)
-    return (u_final - u_code) / dt
+    (_, acc), _ = jax.lax.scan(
+        body, (u_code, jnp.zeros_like(u_code)), None, length=cfg.substeps)
+    return acc / cfg.substeps
 
 
 def cool_step(dt, rho_code, u_code, chem: ChemistryData, cfg: CoolingConfig):

@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from sphexa_tpu.physics.cooling import (
-    KB, MH, ChemistryData, CoolingConfig, u_to_temp,
+    KB, MH, ChemistryData, CoolingConfig, implicit_u_subcycle, u_to_temp,
 )
 
 
@@ -274,8 +274,10 @@ def evolve_primordial(dt, rho_code, u_code, chem: ChemistryData,
     (metal_cooling24: the CIE-table residual over the network's own
     equilibrium, scaled by the particle's metal fraction — the GRACKLE
     network+metal-table decomposition) -> positivity-preserving
-    implicit u update. Returns (du_avg, new ChemistryData); the metal
-    FRACTION itself passes through unevolved.
+    implicit u update (cooling.implicit_u_subcycle, whose rates are
+    accumulated beside u: du_avg is their mean, never (u_fin - u) / dt).
+    Returns (du_avg, new ChemistryData); the metal FRACTION itself passes
+    through unevolved.
     """
     r0, c0 = _prefactors(cfg)
     sub = cfg.substeps
@@ -285,7 +287,7 @@ def evolve_primordial(dt, rho_code, u_code, chem: ChemistryData,
     metal = chem.metal
 
     def body(carry, _):
-        u, y = carry
+        u, acc, y = carry
         mu = _mu_of_y(y, metal)
         T = jnp.maximum(u_to_temp(u, mu, cfg), 10.0)
         dens = rho_code * r0  # k * dens * y_e = dy/dt per code time
@@ -296,20 +298,18 @@ def evolve_primordial(dt, rho_code, u_code, chem: ChemistryData,
         cool = rho_code * c0 * (
             species_cooling24(T, y_new) + metal_cooling24(T, metal, cfg)
         )
-        heat = cfg.heating_code
-        u_new = (u / (1.0 + dt_sub * cool / jnp.maximum(u, 1e-30))
-                 + dt_sub * heat)
-        return (u_new, y_new), None
+        u_new, rate = implicit_u_subcycle(u, dt_sub, cool, cfg.heating_code)
+        return (u_new, acc + rate, y_new), None
 
-    y0 = _y_of(chem)
-    (u_fin, y_fin), _ = jax.lax.scan(body, (u_code, y0), None, length=sub)
+    carry0 = (u_code, jnp.zeros_like(u_code), _y_of(chem))
+    (_, acc, y_fin), _ = jax.lax.scan(body, carry0, None, length=sub)
     new_chem = ChemistryData(
         hi=y_fin["hi"], hii=y_fin["hii"],
         hei=y_fin["hei"] * 4.0, heii=y_fin["heii"] * 4.0,
         heiii=y_fin["heiii"] * 4.0,
         e=y_fin["e"], metal=metal,
     )
-    return (u_fin - u_code) / dt, new_chem
+    return acc / sub, new_chem
 
 
 def primordial_cooling_timestep(rho_code, u_code, chem: ChemistryData,

@@ -55,7 +55,7 @@ from sphexa_tpu.sph.timestep import (
     compute_timestep,
     rho_timestep,
 )
-from sphexa_tpu.util.phases import phase_scope
+from sphexa_tpu.util.phases import phase_scope, stage_scope
 
 #: Canonical scalar diagnostics every propagator's step emits — the
 #: naming contract between the step functions, the Simulation driver's
@@ -852,7 +852,7 @@ def _step_hydro_std_cooling(
      gdiag, chem) = _std_forces(state, box, cfg, gtree, aux=chem,
                                 lists=lists)
 
-    with phase_scope("cooling"):
+    with phase_scope("cooling"), stage_scope("cooling", "limiter"):
         u = const.cv * state.temp
         dt_cool = cool_timestep(rho, u, chem, cool_cfg)
     with phase_scope("timestep"):
@@ -861,7 +861,7 @@ def _step_hydro_std_cooling(
         )
     # evolved-network mode advances the species alongside u
     # (solve_chemistry, cooler.cpp:313); CIE mode passes chem through
-    with phase_scope("cooling"):
+    with phase_scope("cooling"), stage_scope("cooling", "network"):
         du_cool, chem = cool_step(dt, rho, u, chem, cool_cfg)
         du = du + du_cool
 

@@ -771,14 +771,18 @@ class Simulation:
             # fresh OU phases but keeps the derived static config
             if self.turb_state is None:
                 self.turb_state = fresh_state
-        # radiative cooling (std-cooling propagator): reduced CIE model
+        # radiative cooling (std-cooling propagator): the evolved
+        # six-species network with the metal residual, upstream's
+        # HydroGrackleProp role; the CIE table with pass-through
+        # fractions is CoolingConfig(evolve_species=False), handed in
         self.cooling_cfg = cooling_cfg
         self.chem = chem
         if prop == "std-cooling":
             from sphexa_tpu.physics.cooling import ChemistryData, CoolingConfig
 
             if self.cooling_cfg is None:
-                self.cooling_cfg = CoolingConfig(gamma=const.gamma)
+                self.cooling_cfg = CoolingConfig(gamma=const.gamma,
+                                                 evolve_species=True)
             if self.chem is None:
                 self.chem = ChemistryData.ionized(state.n)
             if self._mesh is not None:
@@ -1808,6 +1812,12 @@ class Simulation:
             "h_max": ext("h_max", np.max),
             "du_max": ext("du_max", np.max),
         }
+        # schema v15: the cooling step's limiter and source, where the
+        # step carries them (std-cooling)
+        for name, key in (("dt_cool_min", "dt_cool"),
+                          ("du_cool_min", "du_cool_min")):
+            if any(key in d for d in ds):
+                agg[name] = ext(key, np.min)
         tel.event("numerics", it=rows[-1]["it"], steps=len(rows),
                   limiter=lim, nonfinite=bad, **agg)
 

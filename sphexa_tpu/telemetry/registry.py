@@ -64,12 +64,19 @@ import numpy as np
 #: .p2p_run_cap``, and the fullest group's or block's live runs on the
 #: fullest shard: the exchange's per-slot index work goes with the
 #: first, a trip is the second passing it). No kind, no REQUIRED field:
-#: v14 readers accept v1-v13 files.
-SCHEMA_VERSION = 14
+#: v14 readers accept v1-v13 files;
+#: v15 the cooling step's limiter and source: optional ``dt_cool_min`` /
+#: ``du_cool_min`` on ``numerics`` where the step carries the
+#: diagnostics ``dt_cool`` / ``du_cool_min`` (std-cooling): the window's
+#: smallest cooling-time limit ct_crit * min|u / du_dt| and its most
+#: negative step-averaged cooling source, over finite samples like the
+#: other extrema. No kind, no REQUIRED field: v15 readers accept v1-v14
+#: files.
+SCHEMA_VERSION = 15
 
 #: event schema versions this reader understands (older versions only
 #: ever ADD kinds, so the per-kind field table below covers them all)
-SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14)
+SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15)
 
 #: every event kind the schema admits, with its required payload fields
 #: (beyond the envelope ``v``/``seq``/``t``/``kind``). The CLI's --strict
@@ -113,7 +120,8 @@ EVENT_KINDS: Dict[str, tuple] = {
     # under deferred checking
     "physics": ("it", "etot"),
     # per-window numerics health: dt-limiter histogram, neighbor-cap
-    # clip / h-saturation counts, nonfinite counts, field extrema
+    # clip / h-saturation counts, nonfinite counts, field extrema; since
+    # v15 with the optional ``dt_cool_min`` / ``du_cool_min``
     "numerics": ("it",),
     # conservation-drift watchdog: |etot - etot0|/|etot0| crossed the
     # configured budget (Simulation(drift_budget=...) / --drift-budget)

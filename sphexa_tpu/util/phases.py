@@ -101,6 +101,10 @@ STAGES = {
         "psum",  # the sharded upsweep's all-reduces
         "jbuf",  # own + annex concatenates of the near field
     ),
+    "cooling": (
+        "limiter",  # cool_timestep: the rates once more, a min over N
+        "network",  # cool_step: the subcycled species + energy update
+    ),
 }
 assert set(STAGES) <= set(PHASES)
 assert all(len(set(v)) == len(v) for v in STAGES.values())

@@ -150,8 +150,10 @@ class TestChemAlignment:
         chem = ChemistryData.ionized(n)
         chem = dc.replace(chem, metal=jnp.asarray(tag.astype(np.float32)))
 
+        # the table mode passes the fractions through: the tag survives
         sim = Simulation(state, box, const, prop="std-cooling", block=256,
-                         chem=chem)
+                         chem=chem, cooling_cfg=CoolingConfig(
+                             gamma=const.gamma, evolve_species=False))
         sim.step()
         sim.step()
         x_now = np.asarray(sim.state.x)
@@ -168,7 +170,9 @@ class TestCoolingPropagator:
         from sphexa_tpu.simulation import Simulation
 
         state, box, const = make_initializer("evrard-cooling")(10)
-        sim = Simulation(state, box, const, prop="std-cooling", block=256)
+        sim = Simulation(state, box, const, prop="std-cooling", block=256,
+                         cooling_cfg=CoolingConfig(gamma=const.gamma,
+                                                   evolve_species=False))
         e0 = conserved_quantities(sim.state, const)
         for _ in range(3):
             d = sim.step()

@@ -161,6 +161,9 @@ _STAGED = {
     "mesh-windowed": dict(_GRAVITY_LOOP, **{
         "gravity-mac": ("geometry", "let", "classify", "compact"),
         "gravity-exchange": ("psum", "jbuf"), "halo-exchange": _EXCHANGE}),
+    # the std-cooling step: the limiter's pass and the subcycled network
+    "chip-cooling": {"neighbors": ("windows", "cell-ranges"),
+                     "cooling": ("limiter", "network")},
 }
 
 
@@ -192,13 +195,14 @@ def _ir_text(lowered):
 @pytest.fixture(scope="module")
 def staged_paths():
     """{program: scope paths of every op} of four lowered (NOT compiled)
-    Evrard VE steps at audit scale, built through the real Simulation."""
+    Evrard VE steps and one Sedov std-cooling step at audit scale, built
+    through the real Simulation."""
     import dataclasses as dc
     import re
 
     import jax
 
-    from sphexa_tpu.init import init_evrard
+    from sphexa_tpu.init import init_evrard, init_sedov
     from sphexa_tpu.observables import ObservableSpec
     from sphexa_tpu.parallel.mesh import make_sharded_step
     from sphexa_tpu.propagator import step_hydro_ve
@@ -238,6 +242,10 @@ def staged_paths():
     for name, step in (("mesh-sparse", sparse), ("mesh-windowed", windowed)):
         lowered[name] = step._jitted.lower(ss.particles, ss.box,
                                            mesh._gtree, None)
+    cool = Simulation(*init_sedov(6), **dict(kw, prop="std-cooling"))
+    lowered["chip-cooling"] = _PROPAGATORS["std-cooling"].lower(
+        cool.state, cool.box, cool._cfg, cool._gtree, cool.chem,
+        cool.cooling_cfg)
     return {name: sorted(p for p in set(re.findall(
         r'loc\("([^"]*)"', _ir_text(low))) if "sphexa/" in p)
         for name, low in lowered.items()}
