@@ -183,36 +183,44 @@ def group_cell_ranges(
 
         offsets = jnp.asarray(_window_offsets(cfg.window))  # (W3, 3)
         cells = base[:, None, :] + offsets[None, :, :]  # (NG, W3, 3) unwrapped
-        wrapped = jnp.mod(cells, ncell)
         in_range = (cells >= 0) & (cells < ncell)
         unique = offsets[None, :, :] < ncell
         cell_ok = jnp.all(
             jnp.where(periodic[None, None, :], unique, in_range), axis=-1
         )  # (NG, W3)
-        lookup = jnp.where(
-            periodic[None, None, :], wrapped, jnp.clip(cells, 0, ncell - 1)
-        )
 
-        ckey = encode(
-            lookup[..., 0].astype(KEY_DTYPE),
-            lookup[..., 1].astype(KEY_DTYPE),
-            lookup[..., 2].astype(KEY_DTYPE),
-            bits=level,
-        )
+        use_table = table is not None or ncell**3 <= 4 * max(n, 1024)
+        if not use_table:
+            lookup = jnp.where(
+                periodic[None, None, :], jnp.mod(cells, ncell),
+                jnp.clip(cells, 0, ncell - 1),
+            )
+            ckey = encode(
+                lookup[..., 0].astype(KEY_DTYPE),
+                lookup[..., 1].astype(KEY_DTYPE),
+                lookup[..., 2].astype(KEY_DTYPE),
+                bits=level,
+            )
     with stage_scope("neighbors", "cell-ranges"):
-        if table is not None or ncell**3 <= 4 * max(n, 1024):
-            # ONE cell-starts table for the whole grid, then per-(group, cell)
-            # range lookups are gathers from it — a binary search per window
-            # cell into the N-element u64 key array costs ~20 emulated-u64
-            # gathers each and dominated the prologue
+        if use_table:
+            # ONE cell-starts table for the whole grid (a binary search per
+            # window cell into the N-element key array dominated the
+            # prologue), read per group as blocks of its grid-ordered copy
+            # (_window_cell_ranges)
             if table is None:
                 cid = (sorted_keys >> shift).astype(jnp.int32)  # ascending
                 table = jnp.searchsorted(
                     cid, jnp.arange(ncell**3 + 1, dtype=jnp.int32)
                 ).astype(jnp.int32)
-            ck32 = ckey.astype(jnp.int32)
-            start = table[ck32]
-            end = table[ck32 + 1]
+            # slots that cell_ok masks read whatever the block holds there
+            # (a wrapped copy, or the empty pad past an open edge); nothing
+            # downstream reads them: both compactions and ``occupancy``
+            # select on ``keep``
+            start, raw_len, cell = _window_cell_ranges(
+                table, base, level, cfg.window, encode,
+                tuple(b == BoundaryType.periodic for b in box.boundaries),
+                with_cells,
+            )
         else:
             # deep grids (possible when a caller bypasses the occupancy-driven
             # level heuristic): the table would be O(8^level) — search instead
@@ -220,7 +228,8 @@ def group_cell_ranges(
             end = jnp.searchsorted(
                 sorted_keys, (ckey + KEY_DTYPE(1)) << shift
             ).astype(jnp.int32)
-        raw_len = end - start
+            raw_len = end - start
+            cell = ckey.astype(jnp.int32) if with_cells else None
         lens = jnp.where(cell_ok, jnp.minimum(raw_len, cfg.cap), 0)
 
         if engine_fold(box, cfg):
@@ -248,7 +257,6 @@ def group_cell_ranges(
             img = jnp.floor_divide(cells, ncell).astype(jnp.float32)  # (NG, W3, 3)
             shifts = img * box.lengths[None, None, :]
 
-        cell = ckey.astype(jnp.int32) if with_cells else None
         if cfg.run_cap > 0:
             # merge SFC-adjacent survivors into long streamed runs (fewer,
             # fuller chunks; see _merge_runs)
@@ -290,6 +298,74 @@ def group_cell_ranges(
         ncells=ncells, occupancy=occupancy, boxl=boxl.astype(jnp.float32),
     )
     return (ranges, run_cells) if with_cells else ranges
+
+
+def _window_cell_ranges(table, base, level: int, window: int, encode, wraps,
+                        with_cells: bool):
+    """``(start, raw_len, key)`` of every slot of every group's window,
+    each (NG, W3) int32 in _window_offsets' slot order (x slowest, z
+    fastest), read from the cell-starts ``table`` (SFC-key order) as
+    BLOCKS of a grid-ordered copy of it; ``key`` (the slot's index in
+    ``table``) is None without ``with_cells``.
+
+    A group's window is a contiguous W x W x W block of the cell grid;
+    only the curve key in between made it W^3 scattered reads, and a TPU
+    gather pays per INDEX, not per value. So, once per call:
+
+    1. ``grid[cx, cy, cz] = table[encode(cx, cy, cz)]`` (and the cell's
+       length, and the key itself): ncell^3 indices, the keys from an iota
+       on the device;
+    2. every axis padded so that a block never leaves the array: a
+       periodic axis (``wraps[d]``, static) with its wrapped copies, to
+       ``ncell + window - 1`` (``base mod ncell`` is the block's corner
+       there); an open one, whose ``base`` the caller has already clipped
+       into ``[0, max(0, ncell - window)]``, with empty cells to
+       ``max(ncell, window)``;
+    3. the (y, z) planes of every possible block as ROWS: shifted static
+       slices, ``rows[x, y, z] = grid[x, y:y+W, z:z+W]``, W^2 values wide
+       and already in slot order;
+    4. per group, its W planes are W rows: W indices a group instead of
+       W^3 (or twice that), and (NG, W, W^2) IS (NG, W3).
+
+    Everything is derived from ``table`` and ``base``, so under shard_map
+    the tables vary as the (global, replicated) table does and the result
+    as the local slab's groups do; no collective."""
+    ncell = 1 << level
+    cx, cy, cz = (
+        jax.lax.broadcasted_iota(KEY_DTYPE, (ncell,) * 3, d) for d in range(3)
+    )
+    key = encode(cx, cy, cz, bits=level).astype(jnp.int32)
+    start = table[key]
+    chans = [start, table[key + 1] - start] + ([key] if with_cells else [])
+
+    # distinct block corners per axis, and the padded extent they need
+    corners = [ncell if w else max(1, ncell - window + 1) for w in wraps]
+    _, ny, nz = corners
+    corner = jnp.stack(
+        [jnp.mod(base[:, d], ncell) if wraps[d] else base[:, d]
+         for d in range(3)], axis=1)
+    plane = jnp.arange(window, dtype=jnp.int32)
+    row = ((corner[:, 0, None] + plane[None, :]) * ny
+           + corner[:, 1, None]) * nz + corner[:, 2, None]  # (NG, W)
+
+    def pad(g, d):
+        extent = corners[d] + window - 1
+        if wraps[d]:
+            reps, rest = divmod(extent, ncell)
+            return jnp.concatenate(
+                [g] * reps + [jax.lax.slice_in_dim(g, 0, rest, axis=d)], axis=d)
+        return jnp.pad(g, [(0, extent - ncell if a == d else 0)
+                           for a in range(3)])
+
+    out = []
+    for g in chans:
+        for d in range(3):
+            g = pad(g, d)
+        g = jnp.stack([g[:, :, k:k + nz] for k in range(window)], axis=-1)
+        g = jnp.stack([g[:, j:j + ny] for j in range(window)], axis=-2)
+        rows = g.reshape(-1, window * window)  # (Px * ny * nz, W^2)
+        out.append(rows[row].reshape(base.shape[0], window**3))
+    return out[0], out[1], (out[2] if with_cells else None)
 
 
 def _merge_runs(start, lens, keep, shifts, run_cap: int, gap: int,

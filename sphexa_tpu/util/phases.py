@@ -73,9 +73,10 @@ PHASES = (
 #: at 10 ms a step or more in some cell, and every collective
 STAGES = {
     "neighbors": (
-        "windows",      # group bboxes, window cells and their curve keys
-        "cell-ranges",  # table[cell] / table[cell + 1] lookups, cull,
-                        # compaction sorts and run merge
+        "windows",      # group bboxes and window cells (their curve keys
+                        # on the deep-grid searchsorted fallback only)
+        "cell-ranges",  # cell table into grid order, each window read as
+                        # rows of it; cull, compaction sorts and run merge
     ),
     "halo-exchange": (
         "table",     # global cell table: slab histogram + cumsum
