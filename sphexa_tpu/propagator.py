@@ -220,10 +220,13 @@ def _sort_by_keys(state: ParticleState, box: Box, curve: str, aux=None,
     # sphexa/sort: the whole keygen + argsort + permute program is one
     # attribution phase (profiler traces; util/phases.py taxonomy)
     with phase_scope("sort"):
-        keys = compute_sfc_keys(state.x, state.y, state.z, box, curve=curve)
+        with stage_scope("sort", "keys"):
+            keys = compute_sfc_keys(state.x, state.y, state.z, box,
+                                    curve=curve)
         if bins is None:
-            order = jnp.argsort(keys)
-            sorted_keys = keys[order]
+            with stage_scope("sort", "order"):
+                order = jnp.argsort(keys)
+                sorted_keys = keys[order]
     n = state.n
 
     def permute_tree(tree, order):
@@ -250,8 +253,13 @@ def _sort_by_keys(state: ParticleState, box: Box, curve: str, aux=None,
 
     if bins is None:
         with phase_scope("sort"):
-            return (permute_tree(state, order), sorted_keys,
-                    permute_tree(aux, order))
+            with stage_scope("sort", "permute"):
+                state = permute_tree(state, order)
+            # the aux pytree's gather (the chemistry of a std-cooling
+            # step) apart from the state's
+            with stage_scope("sort", "aux"):
+                aux = permute_tree(aux, order)
+            return state, sorted_keys, aux
 
     with phase_scope("dt-bins"):
         skey = bdt.fold_bin_key(keys, bins)
@@ -261,9 +269,17 @@ def _sort_by_keys(state: ParticleState, box: Box, curve: str, aux=None,
 
     def do_resort(state, keys, aux):
         with phase_scope("sort"):
-            order = jnp.argsort(skey)
-            return permute_tree(state, order), keys[order], \
-                permute_tree(aux, order)
+            with stage_scope("sort", "order"):
+                order = jnp.argsort(skey)
+            with stage_scope("sort", "permute"):
+                state = permute_tree(state, order)
+            # after the state's gather, where it was: the ops keep their
+            # order and the lowering its digest
+            with stage_scope("sort", "order"):
+                keys = keys[order]
+            with stage_scope("sort", "aux"):
+                aux = permute_tree(aux, order)
+            return state, keys, aux
 
     def keep(state, keys, aux):
         return state, keys, aux
@@ -865,8 +881,15 @@ def _step_hydro_std_cooling(
         du_cool, chem = cool_step(dt, rho, u, chem, cool_cfg)
         du = du + du_cool
 
+    # e_cool_rate: the power the source gives the gas (negative where it
+    # radiates), sum(m du_cool) in the ledger's units (eint is sum(m u)).
+    # The driver turns it into the energy of the step, with the
+    # Adams-Bashforth weights the integrator gives du (Simulation.
+    # _cooling_energy): the plain product with dt is half a step's
+    # cooling off
     gdiag = {**(gdiag or {}), "dt_cool": dt_cool,
-             "du_cool_min": jnp.min(du_cool)}
+             "du_cool_min": jnp.min(du_cool),
+             "e_cool_rate": jnp.sum(state.m * du_cool)}
     with phase_scope("timestep"):
         limiter = _dt_limiter(state.min_dt, const, courant=dt_courant,
                               cool=dt_cool,

@@ -618,6 +618,14 @@ class Simulation:
         # drivers that never drain don't grow an unbounded list
         self._collect_science = bool(science_rows)
         self._science: list = []
+        #: energy the cooling source has given the gas over every
+        #: VERIFIED step so far (negative: radiated), in etot's units;
+        #: etot - e_cool is what a std-cooling run conserves. None where
+        #: no step carries the source (schema v16, _cooling_energy)
+        self.e_cool: Optional[float] = None
+        # the last verified step's (e_cool_rate, dt): the lagged half of
+        # the integrator's Adams-Bashforth energy update
+        self._e_cool_last = (0.0, float(state.min_dt))
         # live science surface (schema v8, observables/snapshot.py): the
         # in-graph field-grid deposit rides the diagnostics dict and is
         # fetched at the SAME check/flush boundaries — zero added host
@@ -1734,6 +1742,28 @@ class Simulation:
         rows, self._science = self._science, []
         return rows
 
+    def _cooling_energy(self, d) -> Optional[float]:
+        """The energy the cooling source gave the gas in one verified
+        step, from the step's fetched ``e_cool_rate`` = sum(m du_cool)
+        and ``dt``: host arithmetic. The integrator advances u by
+        ``du (dt + a) - du_m1 a`` with ``a = dt^2 / (2 dt_m1)``
+        (positions.energy_update), and du holds du_cool, so the source's
+        share of the step is the same form of its own rates (the frozen
+        rows of a fixed boundary and the exponential fallback of a
+        negative u aside). Called once per verified step, in order: a
+        rolled-back step never reaches it, so its share is dropped with
+        the step. After a restart the lagged rate starts at zero (half a
+        step's cooling, once)."""
+        if "e_cool_rate" not in d:
+            return None
+        rate, dt = float(d["e_cool_rate"]), float(d["dt"])
+        rate_m1, dt_m1 = self._e_cool_last
+        a = 0.5 * dt * dt / dt_m1
+        step = rate * (dt + a) - rate_m1 * a
+        self._e_cool_last = (rate, dt)
+        self.e_cool = (self.e_cool or 0.0) + step
+        return step
+
     def _emit_science(self, fetched, its) -> None:
         """Schema-v3 physics observability at the fetch boundary: one
         ``physics`` + one ``numerics`` event per checked step / clean
@@ -1760,6 +1790,9 @@ class Simulation:
                    "angmom": float(d["obs_angmom"])}
             if "obs_extra" in d:
                 row["extra"] = float(d["obs_extra"])
+            e_step = self._cooling_energy(d)
+            if e_step is not None:
+                row["e_cool_step"], row["e_cool"] = e_step, self.e_cool
             rows.append(row)
         if self._collect_science:
             self._science.extend(rows)
@@ -1818,6 +1851,11 @@ class Simulation:
                           ("du_cool_min", "du_cool_min")):
             if any(key in d for d in ds):
                 agg[name] = ext(key, np.min)
+        # schema v16: what the cooling source gave the gas (negative:
+        # radiated), per verified step of the window and in all so far
+        if all("e_cool" in r for r in rows):
+            agg["e_cool"] = rows[-1]["e_cool"]
+            agg["e_cool_step"] = [r["e_cool_step"] for r in rows]
         tel.event("numerics", it=rows[-1]["it"], steps=len(rows),
                   limiter=lim, nonfinite=bad, **agg)
 

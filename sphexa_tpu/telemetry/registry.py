@@ -71,12 +71,21 @@ import numpy as np
 #: smallest cooling-time limit ct_crit * min|u / du_dt| and its most
 #: negative step-averaged cooling source, over finite samples like the
 #: other extrema. No kind, no REQUIRED field: v15 readers accept v1-v14
+#: files;
+#: v16 the radiated-energy counter: optional ``e_cool`` / ``e_cool_step``
+#: on ``numerics`` where the step carries ``e_cool_rate`` = sum(m du_cool)
+#: (std-cooling): the energy the cooling source gave the gas (negative:
+#: radiated) over every verified step so far, and per verified step of
+#: the window (a list, parallel to ``physics.its``), with the
+#: integrator's Adams-Bashforth weights; ``etot - e_cool`` is what such a
+#: run conserves. No kind, no REQUIRED field: v16 readers accept v1-v15
 #: files.
-SCHEMA_VERSION = 15
+SCHEMA_VERSION = 16
 
 #: event schema versions this reader understands (older versions only
 #: ever ADD kinds, so the per-kind field table below covers them all)
-SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15)
+SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15,
+                      16)
 
 #: every event kind the schema admits, with its required payload fields
 #: (beyond the envelope ``v``/``seq``/``t``/``kind``). The CLI's --strict
@@ -121,7 +130,8 @@ EVENT_KINDS: Dict[str, tuple] = {
     "physics": ("it", "etot"),
     # per-window numerics health: dt-limiter histogram, neighbor-cap
     # clip / h-saturation counts, nonfinite counts, field extrema; since
-    # v15 with the optional ``dt_cool_min`` / ``du_cool_min``
+    # v15 with the optional ``dt_cool_min`` / ``du_cool_min``; since v16
+    # with the optional ``e_cool`` / ``e_cool_step``
     "numerics": ("it",),
     # conservation-drift watchdog: |etot - etot0|/|etot0| crossed the
     # configured budget (Simulation(drift_budget=...) / --drift-budget)

@@ -72,6 +72,12 @@ PHASES = (
 #: the stages of a phase, ``{phase: (stage, ...)}``: what the records put
 #: at 10 ms a step or more in some cell, and every collective
 STAGES = {
+    "sort": (
+        "keys",     # SFC keys of every particle
+        "order",    # the argsort and the sorted keys
+        "permute",  # the state's fields stacked and gathered by row
+        "aux",      # the same gather of the aux pytree (chemistry), alone
+    ),
     "neighbors": (
         "windows",      # group bboxes and window cells (their curve keys
                         # on the deep-grid searchsorted fallback only)

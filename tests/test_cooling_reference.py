@@ -109,17 +109,20 @@ class TestCellFiles:
                      if c["name"] == cell["config"])
         assert entry["reduced"] == config["reduced"] == ["ranks"]
         assert entry["source"] == config["source"]
+        # the metrics this cell is the first to report (PR 38 appended the
+        # Evrard cooling cell to their lists and brought the fourth)
         ours = {m["name"]: m for m in bench["per_layer"]
-                if m.get("workloads") == [CELL]}
+                if m.get("workloads", [None])[0] == CELL}
         assert sorted(ours) == ["cooling_dt_ratio", "cooling_ms_step",
-                                "cooling_network_ms_step"]
+                                "cooling_network_ms_step",
+                                "cooling_radiated_share"]
         assert {m["layer"] for m in ours.values()} == {"cooling"}
         for name in ours:
             assert os.path.exists(os.path.join(BENCH, "layers",
                                                name + ".py"))
         listed = [m["name"] for g in ("end_to_end", "per_layer")
                   for m in bench[g] if CELL in m.get("workloads", ())]
-        assert len(listed) == 13 + 3 and "updates_per_s_chip" in listed
+        assert len(listed) == 13 + 4 and "updates_per_s_chip" in listed
 
     def test_configuration_states_the_programs_model(self, config):
         """The ``cooling`` block the reference is given IS what
@@ -256,7 +259,7 @@ class TestSimulationDefault:
         """Schema v15. At dt 1e-10 the source is the rate itself: the
         cloud's -0.055, not the -162.6 of one ulp of u over dt."""
         _, sink = stepped
-        assert SCHEMA_VERSION == 15
+        assert SCHEMA_VERSION == 16
         events = sink.of_kind("numerics")
         assert events and all(validate_event(e) == [] for e in events)
         for e in events:

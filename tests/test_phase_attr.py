@@ -140,7 +140,11 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks")
 _COLLECTIVES = ("psum", "pmax", "pmin", "all_gather", "all_to_all",
                 "ppermute")
+#: the per-step sort of a streamed step; ``aux`` only where an aux pytree
+#: rides it (the chemistry of a std-cooling step)
+_SORT = ("keys", "order", "permute")
 _GRAVITY_LOOP = {
+    "sort": _SORT,
     "neighbors": ("windows", "cell-ranges"),
     "gravity-mac": ("geometry", "prepass", "classify", "compact"),
     "gravity-p2p": ("leaf-ranges", "merge-runs", "kernel"),
@@ -162,7 +166,8 @@ _STAGED = {
         "gravity-mac": ("geometry", "let", "classify", "compact"),
         "gravity-exchange": ("psum", "jbuf"), "halo-exchange": _EXCHANGE}),
     # the std-cooling step: the limiter's pass and the subcycled network
-    "chip-cooling": {"neighbors": ("windows", "cell-ranges"),
+    "chip-cooling": {"sort": _SORT + ("aux",),
+                     "neighbors": ("windows", "cell-ranges"),
                      "cooling": ("limiter", "network")},
 }
 
