@@ -24,10 +24,14 @@ Kernel shape, patterned on sph/pallas_pairs.py's streaming engine:
 - compacted lanes land in a 256-lane staging window; every time it fills
   past 128 lanes one ALIGNED sublane row is emitted to the output list
   (the same fill/emit scheme as the list-walk engine's staging buffer);
-- chunks with zero set bits for a class skip all of the above behind one
-  scalar test — the level-major node order clusters the accepted cut
-  into a few contiguous level bands, so most chunks cost only the
-  popcount.
+- the per-chunk class counts are taken outside the kernel, by one
+  elementwise + lane-reduce fusion over the packed classes the caller
+  just wrote (``_chunk_class_counts``), and ride in per row through SMEM:
+  a chunk with no lane of either class costs two scalar reads and one
+  branch, no vector load — the level-major node order clusters the
+  accepted cut into a few contiguous level bands, so most chunks of a
+  superblock's pre-pass are such (13 % hold a live lane at Evrard 1.1M,
+  7 % on the four-slab mesh: scripts/count_compact_chunks.py).
 
 Counts are accumulated UNCLIPPED, so a list overflowing its cap keeps
 reporting the true high water and the driver's diagnostic/regrow contract
@@ -59,8 +63,10 @@ IDX_MASK = (1 << IDX_BITS) - 1
 DEAD = 2 << IDX_BITS
 
 
-def _kernel(pk_ref, out0_ref, out1_ref, cnt_ref, stage_ref, chunks=None):
-    """``chunks``: how many of the row's T chunks to walk (None = all)."""
+def _kernel(n0_ref, n1_ref, pk_ref, out0_ref, out1_ref, cnt_ref, stage_ref,
+            chunks=None):
+    """``n0_ref`` / ``n1_ref``: the row's per-chunk class counts in SMEM.
+    ``chunks``: how many of the row's T chunks to walk (None = all)."""
     T = pk_ref.shape[1] if chunks is None else chunks
     out_rows = (out0_ref.shape[1], out1_ref.shape[1])
 
@@ -77,52 +83,57 @@ def _kernel(pk_ref, out0_ref, out1_ref, cnt_ref, stage_ref, chunks=None):
     lane1 = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
     out_refs = (out0_ref, out1_ref)
 
+    def compact(k, cls, val, cnt, done):
+        """A chunk's ``cnt`` > 0 lanes of class ``k`` behind the ``done``
+        already staged."""
+        maskf = (cls == k).astype(jnp.float32)
+        fill = done % 128
+        row = done // 128
+        # mask to sublane-major via diag-embed + MXU column product
+        dcol = jnp.dot(jnp.broadcast_to(maskf, (128, 128)) * eye,
+                       ones_col, preferred_element_type=jnp.float32)
+        rcol = jnp.dot(lt, dcol,
+                       preferred_element_type=jnp.float32)  # (128,1)
+        # one-hot gather with the staging fill folded in: column j
+        # takes the candidate of rank (j - fill) mod 128
+        tgt = ((lan2 - fill + 128) & 127).astype(jnp.float32)
+        onehot = jnp.where(rcol == tgt, dcol, 0.0)  # (128, 128)
+        comp = jnp.dot(val, onehot,
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)  # (1,128)
+        m0 = (lane1 >= fill) & (lane1 < fill + cnt)
+        m1 = lane1 < (fill + cnt - 128)
+        stage_ref[k, 0:1, :128] = jnp.where(
+            m0, comp, stage_ref[k, 0:1, :128])
+        stage_ref[k, 0:1, 128:] = jnp.where(
+            m1, comp, stage_ref[k, 0:1, 128:])
+
+        emit = fill + cnt >= 128
+
+        @pl.when(emit & (row < out_rows[k]))
+        def _():
+            out_refs[k][0, pl.ds(row, 1), :] = (
+                stage_ref[k, 0:1, :128].astype(jnp.int32))
+
+        @pl.when(emit)
+        def _():
+            stage_ref[k, 0:1, :128] = stage_ref[k, 0:1, 128:]
+            stage_ref[k, 0:1, 128:] = jnp.zeros((1, 128), jnp.float32)
+
     def body(t, done):
-        pk = pk_ref[0, pl.ds(t, 1), :]  # (1, 128)
-        cls = pk >> IDX_BITS  # packed values are nonnegative
-        val = (pk & IDX_MASK).astype(jnp.float32)
-        new_done = []
-        for k in (0, 1):
-            maskf = (cls == k).astype(jnp.float32)
-            cnt = jnp.sum(maskf).astype(jnp.int32)
-            fill = done[k] % 128
-            row = done[k] // 128
+        cnts = (n0_ref[0, 0, t], n1_ref[0, 0, t])
 
-            @pl.when(cnt > 0)
-            def _(k=k, maskf=maskf, fill=fill, cnt=cnt):
-                # mask to sublane-major via diag-embed + MXU column product
-                dcol = jnp.dot(jnp.broadcast_to(maskf, (128, 128)) * eye,
-                               ones_col, preferred_element_type=jnp.float32)
-                rcol = jnp.dot(lt, dcol,
-                               preferred_element_type=jnp.float32)  # (128,1)
-                # one-hot gather with the staging fill folded in: column j
-                # takes the candidate of rank (j - fill) mod 128
-                tgt = ((lan2 - fill + 128) & 127).astype(jnp.float32)
-                onehot = jnp.where(rcol == tgt, dcol, 0.0)  # (128, 128)
-                comp = jnp.dot(val, onehot,
-                               precision=jax.lax.Precision.HIGHEST,
-                               preferred_element_type=jnp.float32)  # (1,128)
-                m0 = (lane1 >= fill) & (lane1 < fill + cnt)
-                m1 = lane1 < (fill + cnt - 128)
-                stage_ref[k, 0:1, :128] = jnp.where(
-                    m0, comp, stage_ref[k, 0:1, :128])
-                stage_ref[k, 0:1, 128:] = jnp.where(
-                    m1, comp, stage_ref[k, 0:1, 128:])
+        # a chunk neither class has a lane in: two SMEM reads, one branch
+        @pl.when(cnts[0] + cnts[1] > 0)
+        def _():
+            pk = pk_ref[0, pl.ds(t, 1), :]  # (1, 128)
+            cls = pk >> IDX_BITS  # packed values are nonnegative
+            val = (pk & IDX_MASK).astype(jnp.float32)
+            for k in (0, 1):
+                pl.when(cnts[k] > 0)(functools.partial(
+                    compact, k, cls, val, cnts[k], done[k]))
 
-            emit = fill + cnt >= 128
-
-            @pl.when(emit & (row < out_rows[k]))
-            def _(k=k, row=row):
-                out_refs[k][0, pl.ds(row, 1), :] = (
-                    stage_ref[k, 0:1, :128].astype(jnp.int32))
-
-            @pl.when(emit)
-            def _(k=k):
-                stage_ref[k, 0:1, :128] = stage_ref[k, 0:1, 128:]
-                stage_ref[k, 0:1, 128:] = jnp.zeros((1, 128), jnp.float32)
-
-            new_done.append(done[k] + cnt)
-        return tuple(new_done)
+        return done[0] + cnts[0], done[1] + cnts[1]
 
     done = jax.lax.fori_loop(0, T, body, (jnp.int32(0), jnp.int32(0)))
 
@@ -138,11 +149,41 @@ def _kernel(pk_ref, out0_ref, out1_ref, cnt_ref, stage_ref, chunks=None):
         lane1 == 0, done[0], jnp.where(lane1 == 1, done[1], 0))
 
 
-def _kernel_live(live_ref, pk_ref, out0_ref, out1_ref, cnt_ref, stage_ref):
+def _kernel_live(live_ref, n0_ref, n1_ref, pk_ref, out0_ref, out1_ref,
+                 cnt_ref, stage_ref):
     """The same walk over the row's first ``ceil(live / 128)`` chunks."""
     live = live_ref[pl.program_id(0)]
     chunks = jnp.clip((live + 127) // 128, 0, pk_ref.shape[1])
-    _kernel(pk_ref, out0_ref, out1_ref, cnt_ref, stage_ref, chunks=chunks)
+    _kernel(n0_ref, n1_ref, pk_ref, out0_ref, out1_ref, cnt_ref, stage_ref,
+            chunks=chunks)
+
+
+def _chunked(packed):
+    """``packed`` (B, C) as (B, T, 128) chunks, the tail padded DEAD."""
+    B, C = packed.shape
+    T = max(1, -(-C // 128))
+    if T * 128 > C:
+        packed = jnp.concatenate(
+            [packed, jnp.full((B, T * 128 - C), DEAD, jnp.int32)], axis=1
+        )
+    return packed.reshape(B, T, 128)
+
+
+def _chunk_class_counts(pk):
+    """(cnt0, cnt1), each (B, T) int32: the lanes of class 0 and of class
+    1 in every 128-lane chunk of ``pk`` (B, T, 128)."""
+    cls = pk >> IDX_BITS
+    return tuple(jnp.sum(cls == k, axis=2, dtype=jnp.int32) for k in (0, 1))
+
+
+def live_chunks(packed):
+    """(B,) int32: the 128-lane chunks of each row of ``packed`` (as
+    ``compact_class_lists`` takes it) that hold a lane of class 0 or 1,
+    i.e. the chunks its walk does not skip. The counts are the kernel's
+    own fusion over the same array: called beside it in one program, XLA
+    makes them once."""
+    cnt0, cnt1 = _chunk_class_counts(_chunked(packed))
+    return jnp.sum(cnt0 + cnt1 > 0, axis=1, dtype=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("cap0", "cap1", "interpret"))
@@ -164,20 +205,24 @@ def compact_class_lists(packed, cap0: int, cap1: int,
     so they need not even be written; lists and counts are those of the
     full walk, bit for bit. Without it every chunk is walked.
     """
-    B, C = packed.shape
-    T = max(1, -(-C // 128))
-    if T * 128 > C:
-        packed = jnp.concatenate(
-            [packed, jnp.full((B, T * 128 - C), DEAD, jnp.int32)], axis=1
-        )
-    pk = packed.reshape(B, T, 128)
+    pk = _chunked(packed)
+    B, T, _ = pk.shape
+    # counted here, inside the jit, so that no caller can hand the kernel
+    # counts that are not its chunks'; under ``live`` the walk never reads
+    # the counts past its last chunk either
+    cnts = tuple(c[:, None, :] for c in _chunk_class_counts(pk))
     r0 = max(1, -(-cap0 // 128))
     r1 = max(1, -(-cap1 // 128))
     # index maps take the prefetched scalars after the grid indices
     row = lambda b, *_: (b, 0, 0)
     spec = dict(
         grid=(B,),
-        in_specs=[pl.BlockSpec((1, T, 128), row)],
+        in_specs=[
+            # a few KB a row: T int32 per class
+            pl.BlockSpec((1, 1, T), row, memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, T), row, memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, T, 128), row),
+        ],
         out_specs=[
             pl.BlockSpec((1, r0, 128), row),
             pl.BlockSpec((1, r1, 128), row),
@@ -192,13 +237,13 @@ def compact_class_lists(packed, cap0: int, cap1: int,
     ]
     if live is None:
         outs = pl.pallas_call(_kernel, out_shape=out_shape,
-                              interpret=interpret, **spec)(pk)
+                              interpret=interpret, **spec)(*cnts, pk)
     else:
         outs = pl.pallas_call(
             _kernel_live, out_shape=out_shape, interpret=interpret,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, **spec),
-        )(live.astype(jnp.int32), pk)
+        )(live.astype(jnp.int32), *cnts, pk)
     list0 = outs[0].reshape(B, r0 * 128)[:, :cap0]
     list1 = outs[1].reshape(B, r1 * 128)[:, :cap1]
     return list0, outs[2][:, 0, 0], list1, outs[2][:, 0, 1]

@@ -1208,14 +1208,17 @@ def compute_gravity(
                 pk = jax.vmap(one_super_pre)(*tgt)
                 sc, sn, _, _ = pcmp.compact_class_lists(
                     pk, scap, 128, interpret=interp)
-                return sc, sn
+                return sc, sn, pcmp.live_chunks(pk)
 
             with phase_scope("gravity-mac"), \
                     stage_scope("gravity-mac", "prepass"):
-                scand, scand_n = jax.lax.map(
+                scand, scand_n, pre_live = jax.lax.map(
                     pre_chunk, tuple(pre_chunks(a) for a in tgtb[:3]))
             scand = scand.reshape(-1, scap)[:num_super]
             scand_n = scand_n.reshape(-1)[:num_super]
+            # the pre-pass walks every chunk of every super's row
+            pre_visits = num_super * -(-(ecap if use_let else num_n) // 128)
+            pre_live = jnp.sum(pre_live.reshape(-1)[:num_super])
             c_max = jnp.max(scand_n)
 
             idxb = jnp.arange(num_super * sf * blk, dtype=jnp.int32)
@@ -1254,14 +1257,18 @@ def compute_gravity(
                     om, mn, op, pn = pcmp.compact_class_lists(
                         pk, cfg.m2p_cap, cfg.p2p_cap, interpret=interp,
                         live=jnp.broadcast_to(live, (sf,)))
-                return _eval_blocks(bidx, tgt, om, mn, op, pn)
+                # the walk ends at the super's own count, and the slots
+                # past it are DEAD: every live chunk is one it visits
+                return (_eval_blocks(bidx, tgt, om, mn, op, pn),
+                        pcmp.live_chunks(pk),
+                        jnp.broadcast_to((live + 127) // 128, (sf,)))
 
             # the block loops carry the MAC's scope and no stage: what
             # reads as (gravity-mac, gravity-mac) is the loop's carry and
             # slicing; the body by stage: benchmarks/stage_times.py
             with phase_scope("gravity-mac"):
-                out = jax.lax.map(one_super_main,
-                                  (scand, scand_n, idxb, tgtb))
+                out, main_live, main_visits = jax.lax.map(
+                    one_super_main, (scand, scand_n, idxb, tgtb))
         else:
             geo0 = let_geo if use_let else dense_geo
 
@@ -1276,10 +1283,13 @@ def compute_gravity(
                         stage_scope("gravity-mac", "compact"):
                     om, mn, op, pn = pcmp.compact_class_lists(
                         pk, cfg.m2p_cap, cfg.p2p_cap, interpret=interp)
-                return _eval_blocks(bidx, tgt, om, mn, op, pn)
+                return (_eval_blocks(bidx, tgt, om, mn, op, pn),
+                        pcmp.live_chunks(pk),
+                        jnp.full((chunk,), -(-pk.shape[1] // 128),
+                                 jnp.int32))
 
             with phase_scope("gravity-mac"):
-                out = jax.lax.map(
+                out, main_live, main_visits = jax.lax.map(
                     one_chunk_bm,
                     (idx, _target_blocks(num_chunks, chunk, blk)))
 
@@ -1558,6 +1568,15 @@ def compute_gravity(
             p2p_hw = chain_after(p2p_hw, jd[0])
         p2p_hw = fold_escape_sentinel(p2p_hw, escaped, cfg.p2p_cap, shard[0])
 
+    def chunk_live(live, visits):
+        """Chunks holding a live lane over the chunks the compaction
+        kernel's walk visits, real rows only: what share of its visits
+        pays for more than a scalar test."""
+        live = jnp.sum(jnp.where(real_blk, live.reshape(real_blk.shape), 0))
+        visits = jnp.sum(
+            jnp.where(real_blk, visits.reshape(real_blk.shape), 0))
+        return live.astype(jnp.float32) / jnp.maximum(visits, 1)
+
     def fill(counts, cap, lists):
         """Live slots of ``lists`` real lists over their ``cap`` slots
         each: how far the block loop's width-following stages engage
@@ -1590,6 +1609,14 @@ def compute_gravity(
                       else jnp.float32(0)),
         "m2p_fill": fill(m2p_n, cfg.m2p_cap, num_blocks),
         "p2p_fill": fill(p2p_n, cfg.p2p_cap, num_blocks),
+        # the bitmask compaction's live chunks over the chunks it walks
+        # (0 = no such pass): the superblocks' pre-pass over the full
+        # tree or the LET list, and the blocks' main pass
+        "prepass_chunk_live": (
+            pre_live.astype(jnp.float32) / jnp.float32(pre_visits)
+            if use_bitmask and sf > 0 else jnp.float32(0)),
+        "compact_chunk_live": (chunk_live(main_live, main_visits)
+                               if use_bitmask else jnp.float32(0)),
     }
     if grav_halo_metrics is not None:
         # sparse MAC-window mode only (the windowed / grav_window=0
@@ -1643,9 +1670,9 @@ def finish_sharded_stage(gx, gy, gz, egrav, diag, axis):
     diagnostics maxed, the sparse serve's per-shard telemetry gathered."""
     # per-shard exchange telemetry rides OUTSIDE the pmax fold (the
     # schema-v7 gravity-stage events need the (P,) vectors, not the
-    # max); the all_gather chains on diag["p2p_max"] — the LAST link
-    # of chain_stage_reductions' sorted chain — extending the
-    # JXA201 total order instead of forking it
+    # max); the all_gather chains on the LAST link of
+    # chain_stage_reductions' sorted chain, whichever key that is,
+    # extending the JXA201 total order instead of forking it
     grows = diag.pop("halo_rows", None)
     gocc = diag.pop("halo_occ", None)
     gruns = diag.pop("halo_runs", None)
@@ -1656,7 +1683,7 @@ def finish_sharded_stage(gx, gy, gz, egrav, diag, axis):
         packed = jnp.stack([grows.astype(jnp.float32), gocc,
                             gruns.astype(jnp.float32)])
         g = jax.lax.all_gather(
-            chain_after(packed, diag["p2p_max"]), axis
+            chain_after(packed, diag[max(diag)]), axis
         )
         diag["gshard_rows"] = g[:, 0].astype(jnp.int32)
         diag["gshard_occ"] = g[:, 1]
@@ -1717,7 +1744,7 @@ def compute_gravity_on_mesh(x, y, z, m, h, sorted_keys, box: Box,
     dspec = sharded_diag_specs(win, (
         "m2p_max", "p2p_max", "leaf_occ", "c_max", "let_max",
         "compact_width", "mac_work_ratio", "cand_fill", "m2p_fill",
-        "p2p_fill"))
+        "p2p_fill", "prepass_chunk_live", "compact_chunk_live"))
     Pp, Pr = PartitionSpec(axis), PartitionSpec()
     return shard_map(
         stage,

@@ -1622,14 +1622,18 @@ class Simulation:
 
     @staticmethod
     def _list_fills(fetched) -> Dict[str, float]:
-        """Schema-v13 payload of a verified window's (or checked step's)
-        event where the steps solved gravity: ``cand_fill`` / ``m2p_fill``
-        / ``p2p_fill``, the tree solve's list occupancies (live slots ÷
-        lists x cap, traversal.compute_gravity) averaged over the steps.
-        ``fetched`` is already on the host: no transfer is added."""
+        """Payload of a verified window's (or checked step's) event where
+        the steps solved gravity, averaged over the steps: ``cand_fill`` /
+        ``m2p_fill`` / ``p2p_fill`` (schema v13), the tree solve's list
+        occupancies (live slots ÷ lists x cap), and ``prepass_chunk_live``
+        / ``compact_chunk_live`` (v17), the compaction kernel's live
+        chunks ÷ the chunks its two walks visit
+        (traversal.compute_gravity). ``fetched`` is already on the host:
+        no transfer is added."""
         return {
             k: round(float(np.mean([float(d[k]) for d in fetched])), 6)
-            for k in ("cand_fill", "m2p_fill", "p2p_fill")
+            for k in ("cand_fill", "m2p_fill", "p2p_fill",
+                      "prepass_chunk_live", "compact_chunk_live")
             if all(k in d for d in fetched)
         }
 

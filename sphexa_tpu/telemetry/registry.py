@@ -79,13 +79,22 @@ import numpy as np
 #: the window (a list, parallel to ``physics.its``), with the
 #: integrator's Adams-Bashforth weights; ``etot - e_cool`` is what such a
 #: run conserves. No kind, no REQUIRED field: v16 readers accept v1-v15
-#: files.
-SCHEMA_VERSION = 16
+#: files;
+#: v17 the compaction kernel's live chunks: optional
+#: ``prepass_chunk_live`` / ``compact_chunk_live`` on ``window`` and
+#: ``step`` beside the v13 fills: chunks of 128 slots that hold a live
+#: lane ÷ chunks the kernel's walk visits, of the superblocks' pre-pass
+#: (rows x every chunk of the full tree or the LET list) and of the
+#: blocks' main pass (rows x the chunks up to their superblock's count);
+#: a dead chunk costs the kernel a scalar test, a live one three MXU
+#: products a class. 0 where the solve runs no such pass. No kind, no
+#: REQUIRED field: v17 readers accept v1-v16 files.
+SCHEMA_VERSION = 17
 
 #: event schema versions this reader understands (older versions only
 #: ever ADD kinds, so the per-kind field table below covers them all)
 SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15,
-                      16)
+                      16, 17)
 
 #: every event kind the schema admits, with its required payload fields
 #: (beyond the envelope ``v``/``seq``/``t``/``kind``). The CLI's --strict
@@ -94,7 +103,8 @@ EVENT_KINDS: Dict[str, tuple] = {
     "launch": ("it",),            # one deferred-window step dispatched
     "step": ("it", "wall_s"),     # one synchronously checked step done
     # deferred flush; since v11 with the optional ``planned_steps``;
-    # since v13 (as "step") with the optional list fills of a gravity run
+    # since v13 (as "step") with the optional list fills of a gravity
+    # run, since v17 with its compaction kernel's live-chunk shares
     "window": ("it", "steps", "wall_s", "per_step_s"),
     "reconfigure": ("it", "reason"),
     "rollback": ("it", "steps", "reason"),

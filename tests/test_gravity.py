@@ -315,14 +315,13 @@ class TestWidthFollowsTheLists:
         if length == 0:
             assert all(not np.any(np.asarray(a)) for a in got)
 
-    @pytest.mark.parametrize("shape", ["sort", "bitmask-supers"])
-    def test_fill_diagnostics_are_the_counts(self, shape):
-        """cand_fill / m2p_fill / p2p_fill against every block and
-        superblock of a small Evrard sphere classified in numpy."""
+    @pytest.fixture(scope="class", params=["sort", "bitmask-supers"])
+    def small_evrard(self, request):
+        """(shape, the solve's diagnostics, the arguments of
+        gravity_counts' ``counted_*``) of a small Evrard sphere."""
         import dataclasses
 
         import jax
-        from gravity_counts import counted_fills
 
         from sphexa_tpu.init import init_evrard
         from sphexa_tpu.sfc.box import make_global_box
@@ -335,19 +334,46 @@ class TestWidthFollowsTheLists:
                                         state.h))
         keys = keys[o]
         cfg = GravityConfig(theta=0.5, bucket_size=64)
-        if shape != "sort":
-            cfg = dataclasses.replace(cfg, target_block=32, super_factor=4,
-                                      compaction="bitmask")
+        if request.param != "sort":
+            # buckets of 8: a tree of 2,337 nodes, 19 chunks of 128, so
+            # that some of the kernel's chunks are dead
+            cfg = dataclasses.replace(cfg, bucket_size=8, target_block=32,
+                                      super_factor=4, compaction="bitmask")
         tree, meta = build_gravity_tree(np.asarray(keys), cfg.bucket_size)
         cfg = estimate_gravity_caps(x, y, z, m, keys, gbox, tree, meta, cfg)
         diag = jax.device_get(compute_gravity(
             x, y, z, m, h, keys, gbox, tree, meta, cfg)[-1])
         assert int(diag["m2p_max"]) <= cfg.m2p_cap
-        want = counted_fills(x, y, z, m, keys, gbox, tree, meta, cfg)
+        return request.param, diag, (x, y, z, m, keys, gbox, tree, meta, cfg)
+
+    def test_fill_diagnostics_are_the_counts(self, small_evrard):
+        """cand_fill / m2p_fill / p2p_fill against every block and
+        superblock of a small Evrard sphere classified in numpy."""
+        from gravity_counts import counted_fills
+
+        shape, diag, system = small_evrard
+        want = counted_fills(*system)
         got = [float(diag[k]) for k in ("cand_fill", "m2p_fill", "p2p_fill")]
         np.testing.assert_allclose(got, want, rtol=1e-6)
         assert (got[0] > 0) == (shape != "sort")
         assert 0 < got[1] < 1 and 0 < got[2] < 1
+
+    def test_chunk_live_diagnostics_are_the_counts(self, small_evrard):
+        """prepass_chunk_live / compact_chunk_live, the compaction
+        kernel's live chunks over the chunks its walks visit, against the
+        same classification with an ``any`` per 128 slots; 0 where the
+        solve has no such kernel."""
+        from gravity_counts import counted_chunk_live
+
+        shape, diag, system = small_evrard
+        got = [float(diag[k])
+               for k in ("prepass_chunk_live", "compact_chunk_live")]
+        if shape == "sort":
+            assert got == [0.0, 0.0]
+            return
+        np.testing.assert_allclose(got, counted_chunk_live(*system),
+                                   rtol=1e-6)
+        assert 0 < got[0] < 1 and 0 < got[1] < 1
 
 
 @pytest.mark.slow

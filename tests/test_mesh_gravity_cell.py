@@ -151,7 +151,7 @@ RUNNER = """
     # windows' events, which carry the steps' fills
     import dataclasses
     sys.path[:0] = [{tests!r}]
-    from gravity_counts import counted_fills
+    from gravity_counts import counted_chunk_live, counted_fills
     from sphexa_tpu.gravity import traversal as tv
     s1 = sim.state
     gb = make_global_box(s1.x, s1.y, s1.z, sim.box)
@@ -169,6 +169,16 @@ RUNNER = """
         counted=[float(v) for v in counted_fills(
             *one[:4], one[5], gb, sim._gtree, meta, g, shards=4)],
         windows=[[e.get(k) for k in fill_keys]
+                 for e in sink.events[mark:] if e["kind"] == "window"])
+    # likewise the compaction kernel's live chunks over the chunks its two
+    # walks visit (the bitmask case alone runs the kernel)
+    live_keys = ("prepass_chunk_live", "compact_chunk_live")
+    out["chunk_live"] = dict(
+        stage=[float(diag[k]) for k in live_keys],
+        counted=[float(v) for v in counted_chunk_live(
+            *one[:4], one[5], gb, sim._gtree, meta, g, shards=4)]
+        if g.compaction == "bitmask" else [0.0, 0.0],
+        windows=[[e.get(k) for k in live_keys]
                  for e in sink.events[mark:] if e["kind"] == "window"])
     # the near field's run axis: the same stage with the runs' slots at
     # the full p2p_cap (what a caller that sizes none runs), and with the
@@ -343,6 +353,28 @@ def test_list_fills_are_the_fullest_slabs_counts(mesh_run):
                                atol=1e-7)
     assert (f["stage"][0] > 0) == (case == "bitmask")
     assert 0 < f["stage"][1] < 1 and 0 < f["stage"][2] < 1
+    assert len(f["windows"]) >= 1
+    for got in f["windows"]:
+        np.testing.assert_allclose(got, f["stage"], rtol=0.1, atol=1e-7)
+
+
+def test_chunk_live_shares_are_the_fullest_slabs_counts(mesh_run):
+    """prepass_chunk_live / compact_chunk_live of the sharded stage (pmax
+    over shards) against numpy counts over the slabs' own LET lists,
+    superblocks and blocks; 0 where the solve runs no compaction kernel;
+    and every verified window's event carries the steps' shares (schema
+    v17)."""
+    case, r = mesh_run
+    f = r["chunk_live"]
+    if case != "bitmask":
+        assert f["stage"] == f["counted"] == [0.0, 0.0]
+    else:
+        # a node at the MAC's edge that flips (see above) moves one chunk
+        # of a few hundred
+        np.testing.assert_allclose(f["stage"], f["counted"], rtol=2e-2)
+        # (a LET list of a few chunks has no dead one: the shares with
+        # dead chunks in them are test_gravity.py's, on one device)
+        assert 0 < f["stage"][0] <= 1 and 0 < f["stage"][1] <= 1
     assert len(f["windows"]) >= 1
     for got in f["windows"]:
         np.testing.assert_allclose(got, f["stage"], rtol=0.1, atol=1e-7)

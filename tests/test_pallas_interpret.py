@@ -279,3 +279,86 @@ def test_gravity_compact_kernel_live_bound_is_the_full_scan(C, live):
     for b in range(B):
         assert int(full[1][b]) == int((cls[b] == 0).sum())
         assert int(full[3][b]) == int((cls[b] == 1).sum())
+
+
+def _compact_reference(cls, vals, cap0, cap1):
+    """What ``compact_class_lists`` returns, in numpy: each class's values
+    in candidate order cut at its cap (zeros past the kept), the counts
+    unclipped."""
+    out = []
+    for k, cap in ((0, cap0), (1, cap1)):
+        lst = np.zeros((len(cls), cap), np.int32)
+        for b in range(len(cls)):
+            kept = vals[b][cls[b] == k][:cap]
+            lst[b, :len(kept)] = kept
+        out += [lst, (cls == k).sum(axis=1).astype(np.int32)]
+    return out
+
+
+def _compact_case(name, rng):
+    """(cls, cap0, cap1, live) of one case of the test below: three rows
+    of classes 0 / 1 / 2 (2 = dropped)."""
+    C, cap0, cap1, live = 1024, 192, 64, None
+    if name == "all-dead":
+        cls = np.full((3, C), 2)
+    elif name == "all-live":  # every chunk, both classes: overflows both
+        cls = rng.integers(0, 2, size=(3, C))
+    elif name == "one-lane-in-the-last-chunk":
+        cls = np.full((3, C), 2)
+        cls[0, C - 1], cls[1, C - 128], cls[2, C - 77] = 0, 1, 0
+    elif name == "no-class-1":  # the pre-pass: 0 or 2, bands of live chunks
+        cls = np.full((3, C), 2)
+        cls[:, 128:300] = 0
+        cls[:, 640:700] = np.where(rng.random((3, 60)) < 0.3, 0, 2)
+    elif name == "overflow":
+        cls = rng.integers(0, 3, size=(3, C))
+    elif name == "ragged-width":  # C no multiple of 128, sparse classes
+        C = 1000
+        cls = np.where(rng.random((3, C)) < 0.02,
+                       rng.integers(0, 2, size=(3, C)), 2)
+    else:  # "live-<n>": the walk bounded by the rows' live count
+        live = C if name == "live-C" else int(name.split("-")[1])
+        cls = rng.integers(0, 3, size=(3, C))
+        cls[:, live:] = 2
+    return cls, cap0, cap1, live
+
+
+@pytest.mark.parametrize("case", [
+    "all-dead", "all-live", "one-lane-in-the-last-chunk", "no-class-1",
+    "overflow", "ragged-width", "live-0", "live-1", "live-127", "live-128",
+    "live-129", "live-C"])
+def test_gravity_compact_kernel_smem_counts_equal_the_reference(case):
+    """The kernel reads each chunk's class counts from SMEM and skips a
+    chunk whose two counts are zero: lists, UNCLIPPED counts and the
+    truncation past a cap are the numpy reference's bit for bit, whatever
+    the share of dead chunks; under ``live=`` with garbage past the last
+    live chunk, whose counts (taken over the whole row) are then never
+    read. ``live_chunks`` is the number of chunks the walk does not
+    skip."""
+    from sphexa_tpu.gravity import pallas_compact as pc
+
+    rng = np.random.default_rng(sum(case.encode()))
+    cls, cap0, cap1, live = _compact_case(case, rng)
+    B, C = cls.shape
+    vals = rng.integers(0, 1 << 20, size=(B, C))
+    packed = (cls << pc.IDX_BITS) | vals
+    chunk_any = np.pad(cls < 2, ((0, 0), (0, -C % 128))).reshape(
+        B, -1, 128).any(axis=2)
+    np.testing.assert_array_equal(
+        np.asarray(pc.live_chunks(jnp.asarray(packed, jnp.int32))),
+        chunk_any.sum(axis=1))
+    kw = {}
+    if live is not None:
+        packed[:, -(-live // 128) * 128:] = 7  # class 0, value 7: unread
+        kw = dict(live=jnp.full((B,), live, jnp.int32))
+    got = pc.compact_class_lists(jnp.asarray(packed, jnp.int32), cap0, cap1,
+                                 interpret=True, **kw)
+    want = _compact_reference(cls, vals, cap0, cap1)
+    for name, a, b in zip(("list0", "n0", "list1", "n1"), got, want):
+        np.testing.assert_array_equal(np.asarray(a), b, err_msg=name)
+    if case in ("all-live", "overflow", "live-C"):
+        assert want[1].min() > cap0 and want[3].min() > cap1
+    if case == "all-dead":
+        assert not chunk_any.any() and not want[1].any()
+    if case == "no-class-1":
+        assert not want[3].any() and 0 < chunk_any.mean() < 0.5

@@ -311,7 +311,7 @@ class TestSpans:
             sp["bytes"] = 3
         after = time.perf_counter_ns()
         (e,) = sink.events
-        assert e["kind"] == "span" and e["v"] == SCHEMA_VERSION == 16
+        assert e["kind"] == "span" and e["v"] == SCHEMA_VERSION == 17
         assert validate_event(e) == []
         assert (e["name"], e["parent"], e["it"]) == ("sphexa:x", None, 12)
         assert (e["reason"], e["bytes"]) == ("r", 3)

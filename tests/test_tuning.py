@@ -376,9 +376,11 @@ class TestSchemaV7:
         # for the tree solve's list fills on ``window`` / ``step``, v14
         # for the run axis of ``exchange`` (``run_slots``, ``live_runs_max``),
         # v15 for the cooling extrema on ``numerics``, v16 for the
-        # radiated-energy counter on it
-        assert SCHEMA_VERSION == 16
-        assert not {7, 10, 11, 12, 13, 14, 15, 16} & set(KIND_SINCE.values())
+        # radiated-energy counter on it, v17 for the compaction kernel's
+        # live-chunk shares beside v13's fills
+        assert SCHEMA_VERSION == 17
+        assert not ({7, 10, 11, 12, 13, 14, 15, 16, 17}
+                    & set(KIND_SINCE.values()))
 
     def test_v7_staged_exchange_validates(self):
         for stage in ("sph", "gravity"):
