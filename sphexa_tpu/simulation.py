@@ -1317,12 +1317,15 @@ class Simulation:
         it, None where none did), ``slot_need``/``slot_cap`` (chunk slots
         of the fullest group against the per-group budget),
         ``slots_live``/``slots_cap`` (rows of the flat lane table in use
-        against its budget: the occupancy) and ``attempts``. All of it
-        is host state or rides the overflow fetch the rebuild always
-        made."""
+        against its budget: the occupancy), ``chunks_live``/``runs_live``/
+        ``run_rows`` (kept chunks, runs and the rows a run's copy
+        fetches: the share of a pass's fetched rows that a lane is taken
+        from) and ``attempts``. All of it is host state or rides the
+        overflow fetch the rebuild always made."""
         import jax as _jax
 
         from sphexa_tpu.propagator import rebuild_pair_lists
+        from sphexa_tpu.sph.pallas_pairs import list_run_rows
 
         self._list_reason = reason  # a build that raises retries as itself
         if served_to is None:
@@ -1347,8 +1350,10 @@ class Simulation:
                 state, box, lists, aux = rebuild_pair_lists(
                     self.state, self.box, self._cfg, aux
                 )
-                overflow, need, live = (int(v) for v in _jax.device_get(
-                    (lists.overflow, lists.slot_need, lists.slots_live)))
+                overflow, need, live, chunks, runs = (
+                    int(v) for v in _jax.device_get(
+                        (lists.overflow, lists.slot_need, lists.slots_live,
+                         lists.chunks_live, lists.runs_live)))
             if not overflow:
                 self.state, self.box, self._lists = state, box, lists
                 if aux is not None:
@@ -1364,6 +1369,8 @@ class Simulation:
                     slack=None if slack is None else round(slack, 6),
                     slot_need=need, slot_cap=self._cfg.list_slot_cap,
                     slots_live=live, slots_cap=self._cfg.list_slots_cap,
+                    chunks_live=chunks, runs_live=runs,
+                    run_rows=list_run_rows(self._cfg.nbr),
                     attempts=attempt,
                     rate=None if rate is None else round(rate, 6),
                     cover_steps=cover,

@@ -51,6 +51,7 @@ from sphexa_tpu.sfc.box import Box
 from sphexa_tpu.sph.pallas_pairs import (
     LIST_ROW_TILE,
     GroupRanges,
+    list_run_rows,
     _dma_rows,
     _prep_i,
     _round_up,
@@ -86,6 +87,11 @@ class PairLists(NamedTuple):
     #                       (the rebuild's event reports it beside the cap)
     slots_live: jax.Array  # () int32 — table rows the kept chunks needed,
     #                       each group's rounded up to the LIST_ROW_TILE
+    chunks_live: jax.Array  # () int32 — kept chunks = chunk visits a pass
+    runs_live: jax.Array  # () int32 — runs = tiles of ``list_run_rows``
+    #                       rows a pass fetches; chunks_live over
+    #                       (runs_live x rows) is the share of the fetched
+    #                       rows a lane is taken from
     lanes_total: jax.Array  # () int64-ish f32 — sum of cnt (diagnostics)
     xb: jax.Array         # build positions + smoothing lengths: the
     yb: jax.Array         # validity reduction compares current state
@@ -128,14 +134,15 @@ def lists_valid(x, y, z, h, lists: PairLists):
     return list_slack(x, y, z, h, lists) >= 0.0
 
 
-def _stream_marks(cfg: NeighborConfig, common, jref, buf, sems, on_chunk):
+def _stream_marks(common, jref, buf, sems, on_chunk):
     """Shared body of the two mark kernels: stream one group's candidate
     runs with a minimal body (inflated-bbox lane test) and hand every
     chunk's lane mask to ``on_chunk(slot, mask)``; returns the number of
-    chunks streamed. ``common``: the refs of ``_mark_specs``."""
+    chunks streamed. ``common``: the refs of ``_mark_specs``; a run's
+    copy is the rows of a ``buf`` slot (the widest run the pass streams)."""
     (starts, lens, shx_r, shy_r, shz_r, ncells, skin_s,
      xi_r, yi_r, zi_r, hi_r) = common
-    R = _dma_rows(cfg.dma_cap)
+    R = buf.shape[1]
     nc_g = ncells[0, 0, 0]
 
     def dma(w, slot):
@@ -205,9 +212,10 @@ def _smem_spec(shape):
 
 
 def _mark_specs(cfg: NeighborConfig, ranges: GroupRanges, i_fields,
-                skin):
+                skin, run_rows: int):
     """The two mark kernels' common inputs, the first ``len(args)`` of
-    each: (in_specs, args), and the scratch both stream through."""
+    each: (in_specs, args), and the scratch both stream through, a copy
+    of ``run_rows`` rows a run."""
     num_groups = ranges.num_groups
     w3 = ranges.starts.shape[1]
     G = cfg.group
@@ -233,7 +241,7 @@ def _mark_specs(cfg: NeighborConfig, ranges: GroupRanges, i_fields,
         *[a.reshape(num_groups, 1, G) for a in i_fields],
     )
     scratch = [
-        pltpu.VMEM((2, _dma_rows(cfg.dma_cap), 8, 128), jnp.float32),
+        pltpu.VMEM((2, run_rows, 8, 128), jnp.float32),
         pltpu.SemaphoreType.DMA((2,)),
     ]
     return in_specs, args, scratch
@@ -250,7 +258,8 @@ def _count_marks(cfg: NeighborConfig, slot_cap: int, interpret: bool,
     scalar unit per chunk."""
     spad = _round_up(slot_cap, 128)
     num_groups = ranges.num_groups
-    in_specs, args, scratch = _mark_specs(cfg, ranges, i_fields, skin)
+    in_specs, args, scratch = _mark_specs(cfg, ranges, i_fields, skin,
+                                          _dma_rows(cfg.dma_cap))
     ncommon = len(args)
 
     def kernel(*refs):
@@ -264,7 +273,7 @@ def _count_marks(cfg: NeighborConfig, slot_cap: int, interpret: bool,
         # dead slots must read as empty
         marks[...] = jnp.zeros((spad, 128), jnp.float32)
         total_out[0, 0, 0] = _stream_marks(
-            cfg, refs[:ncommon], jref, buf, sems, on_chunk)
+            refs[:ncommon], jref, buf, sems, on_chunk)
         sums = jax.lax.dot_general(
             jnp.ones((8, 128), jnp.float32), marks[...],
             (((1,), (1,)), ((), ())),
@@ -296,7 +305,8 @@ def _mark_rows(cfg: NeighborConfig, slot_cap: int, rows: int,
                interpret: bool, ranges: GroupRanges, i_fields, j_packed,
                skin, fill, seg, ntile):
     """Mosaic mark pass over the PRUNED runs (every chunk of them keeps a
-    lane): write each kept chunk's lane BITS, with its staging fill in
+    lane; a run is a tile of ``list_run_rows`` rows, and so is its copy
+    here): write each kept chunk's lane BITS, with its staging fill in
     the bits above, to row ``seg * LIST_ROW_TILE + k`` of the flat
     ``(rows, 128)`` table. A group's rows are staged in VMEM and leave
     in ``ntile`` sublane-tile DMAs (an output BLOCK per group would
@@ -304,7 +314,8 @@ def _mark_rows(cfg: NeighborConfig, slot_cap: int, rows: int,
     Compaction indices and pre-rotation are batched XLA post-passes."""
     swin = _round_up(slot_cap, LIST_ROW_TILE)
     num_groups = ranges.num_groups
-    in_specs, args, scratch = _mark_specs(cfg, ranges, i_fields, skin)
+    in_specs, args, scratch = _mark_specs(cfg, ranges, i_fields, skin,
+                                          list_run_rows(cfg))
     ncommon = len(args)
 
     def kernel(*refs):
@@ -320,7 +331,7 @@ def _mark_rows(cfg: NeighborConfig, slot_cap: int, rows: int,
 
         # rows past the kept count inside the last tile must read empty
         stage[...] = jnp.zeros((swin, 128), jnp.int32)
-        _stream_marks(cfg, refs[:ncommon], jref, buf, sems, on_chunk)
+        _stream_marks(refs[:ncommon], jref, buf, sems, on_chunk)
         seg_g = seg_r[0, 0, 0]
         nt = ntile_r[0, 0, 0]
 
@@ -373,18 +384,25 @@ def _run_chunks(starts, lens):
     return jnp.where(lens > 0, (starts % 128 + lens + 127) // 128, 0)
 
 
-def _prune_empty_chunks(ranges: GroupRanges, cnt, slot_cap: int):
+def _prune_empty_chunks(ranges: GroupRanges, cnt, slot_cap: int,
+                        run_rows: int):
     """Rebuild the candidate runs to exclude chunks with NO marked lane:
     every engine pass then neither DMAs nor iterates them (the measured
     per-chunk base cost is ~115 ns even when the math is skipped).
 
-    New runs are maximal consecutive kept-chunk intervals WITHIN one
-    original run, with exact particle bounds (the intersection of the
-    original [s, s+len) with the kept rows) — never merged across
+    New runs are consecutive kept-chunk intervals WITHIN one original
+    run, cut again at every ``run_rows``-th chunk (``list_run_rows``:
+    the list kernels fetch a run as one tile of that many rows, so a run
+    never streams more), with exact particle bounds (the intersection of
+    the original [s, s+len) with the piece's rows) — never merged across
     original runs, so the in-run candidate mask admits exactly the
     original run's particles and no cross-run double counting can occur.
     Dropped chunks had no lane inside any group's inflated bbox, so no
-    pair is lost. Returns (new_ranges, cnt) with the kept slots' counts
+    pair is lost; the cut moves no chunk, so the chunk sequence and
+    everything indexed by it (cnt, fill, emit, tail, the lane table) are
+    what the un-cut prune gives. A piece holds a kept chunk, so a group
+    has at most as many runs as kept chunks (<= slot_cap, the run axis
+    of the new ranges). Returns (new_ranges, cnt) with the kept slots' counts
     compacted to the front and zeros behind them (the compacted chunk
     sequence preserves original order, so staging fills computed on the
     zero-preserving cumsum are unchanged).
@@ -420,6 +438,10 @@ def _prune_empty_chunks(ranges: GroupRanges, cnt, slot_cap: int):
         [jnp.zeros((ng, 1), bool), kept[:, :-1]], axis=1
     )
     head = kept & ((c_of_s == 0) | ~kept_prev)
+    # ... and a tile's worth of chunks further on inside an interval
+    since = s_idx[None, :] - jax.lax.cummax(
+        jnp.where(head, s_idx[None, :], -1), axis=1)
+    head = kept & (head | (since % run_rows == 0))
 
     # run end = hi of the last consecutive kept slot (reverse scan, the
     # _merge_runs pattern)
@@ -549,7 +571,8 @@ def build_pair_lists(
 
     # drop empty chunks from the runs (the engines then neither DMA nor
     # iterate them) and compact the per-slot counts to the new order
-    ranges, cnt = _prune_empty_chunks(ranges, cnt, slot_cap)
+    ranges, cnt = _prune_empty_chunks(ranges, cnt, slot_cap,
+                                      list_run_rows(cfg))
 
     # staging bookkeeping, precomputed so the walk kernel carries no
     # sequential fill state: fill before chunk s = (exclusive cumsum of
@@ -575,6 +598,8 @@ def build_pair_lists(
         cnt=cnt, fill=fill, emit=emit,
         tail=tail, overflow=overflow.astype(jnp.int32),
         slot_need=slot_need, slots_live=slots_live,
+        chunks_live=jnp.sum((cnt > 0).astype(jnp.int32)),
+        runs_live=jnp.sum(ranges.ncells),
         lanes_total=jnp.sum(csum[:, -1].astype(jnp.float32)),
         xb=x, yb=y, zb=z, hb=h,
         skin=jnp.asarray(skin, jnp.float32),

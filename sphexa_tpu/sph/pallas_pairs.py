@@ -488,6 +488,33 @@ def _round_up(v: int, q: int) -> int:
 LIST_ROW_TILE = 8
 
 
+#: a RUN of the persistent lists is a tile of at most this many 128-lane
+#: chunks: the build cuts the pruned runs to it (pair_lists.
+#: _prune_empty_chunks) and the two list kernels below fetch exactly that
+#: many rows a run, whatever the un-pruned runs' width (``_dma_rows``: 13
+#: at run_cap 1536, for the 3.2 chunks a pruned run kept). Chosen on the
+#: chip (PERF.md, PR 40), one value for every list op and configuration
+LIST_RUN_ROWS = 4
+
+#: tiles in the list kernels' ring: LIST_RING - 1 runs' copies in flight
+#: while one is walked
+LIST_RING = 4
+
+
+def list_run_rows(cfg: NeighborConfig) -> int:
+    """Rows a run of the persistent lists streams at most = rows the list
+    kernels fetch a run: never more than ``pack_j_fields``' tail pad."""
+    return min(LIST_RUN_ROWS, _dma_rows(cfg.dma_cap))
+
+
+def _ring_ahead(w, slot, ring: int):
+    """Slot of the run ``ring - 1`` ahead of run ``w`` (whose slot is
+    ``w % ring``) in a ring of ``ring`` buffers: the one run ``w - 1``
+    left (``1 - slot`` in a ring of two: the streamed engine's own form,
+    kept to the equation: LOWERING_LOCK.json holds its lowering)."""
+    return 1 - slot if ring == 2 else (w + (ring - 1)) % ring
+
+
 def _dma_rows(cap: int) -> int:
     """Rows of 128 covering any cell range [s, s+len<=cap): the range
     starts at lane offset s%128 inside row s//128 and extends at most
@@ -600,13 +627,15 @@ def group_pair_engine(
       counts (sph/pair_lists.py mark bits) gate each chunk's math — the
       AABB chunk-cull for free (no AABB table, no in-kernel bbox math),
       available to every op while lists are valid. Requires CW == 1 and
-      excludes ``chunk_skip``.
+      excludes ``chunk_skip``. The lists' runs are tiles: a copy is
+      ``list_run_rows`` rows into a ring of ``LIST_RING`` buffers.
     - returns fn(ranges, i_fields(NG,G) x num_i, j_packed, i_offset,
       allow_self) -> (outs (NG, G) x num_out, nc (NG, G)); ``allow_self``
       (traced bool) admits the self-index pair — replica-image passes of
       periodic gravity need it.
     """
     R = _dma_rows(cfg.dma_cap)
+    RING = 2                 # run buffers: one walked, the rest in flight
     nf_pad = _round_up(num_j, 8)
     CW = max(1, cfg.chunk_pair)  # chunks per inner-loop trip
     LW = 128 * CW            # lane width of the pair-math tiles
@@ -615,6 +644,7 @@ def group_pair_engine(
         if CW != 1:
             raise ValueError("skip_slots requires chunk_pair == 1")
         chunk_skip = False
+        R, RING = list_run_rows(cfg), LIST_RING
     if chunk_skip is None:
         # bitmask bits live in one int32, so the DMA window must fit 31
         # chunks; beyond that (huge run_cap) the cull is simply skipped
@@ -658,11 +688,13 @@ def group_pair_engine(
                 aabb_ref.at[pl.ds(row_s, R), :], abuf.at[slot], asems.at[slot]
             )
 
-        @pl.when(nc_g > 0)
-        def _():
-            dma(0, 0).start()
-            if chunk_skip:
-                dma_aabb(0, 0).start()
+        # the group's first RING - 1 copies start before anything else
+        for w0 in range(RING - 1):
+            @pl.when(w0 < nc_g)
+            def _():
+                dma(w0, w0).start()
+                if chunk_skip:
+                    dma_aabb(w0, w0).start()
 
         i_fields = [r[0, 0][:, None] for r in i_refs]  # (G, 1) each
         xi, yi, zi, hi = i_fields[:4]
@@ -684,15 +716,17 @@ def group_pair_engine(
         h4 = 4.0 * hi * hi
         lx, ly, lz = boxl[0, 0, 0], boxl[0, 0, 1], boxl[0, 0, 2]
 
-        def cell_body(w, carry):
-            slot = w % 2
-
-            @pl.when(w + 1 < nc_g)
+        def _prefetch(w, slot):
+            @pl.when(w + (RING - 1) < nc_g)
             def _():
-                dma(w + 1, 1 - slot).start()
+                dma(w + (RING - 1), _ring_ahead(w, slot, RING)).start()
                 if chunk_skip:
-                    dma_aabb(w + 1, 1 - slot).start()
+                    dma_aabb(w + (RING - 1),
+                             _ring_ahead(w, slot, RING)).start()
 
+        def cell_body(w, carry):
+            slot = w % RING
+            _prefetch(w, slot)
             dma(w, slot).wait()
 
             s = starts[0, 0, w]
@@ -778,16 +812,20 @@ def group_pair_engine(
                 if want_nc:
                     ncacc_ref[...] = ncacc_ref[...] + mask.astype(jnp.int32)
 
-            def chunk_body(t, carry2):
-                if SKIP:
-                    # persistent-list mark bits: a chunk with no lane in
-                    # the group's inflated bbox skips its math for one
-                    # SMEM test (the AABB cull with zero DMA cost)
-                    @pl.when(cnt_r[0, 0, carry2 + t] > 0)
+            if SKIP:
+                # a run of the lists is a tile of at most R chunks: a
+                # static unroll. Persistent-list mark bits: a chunk with
+                # no lane in the group's inflated bbox skips its math
+                # for one SMEM test (the AABB cull with zero DMA cost)
+                for t in range(R):
+                    @pl.when((t < nch) & (cnt_r[0, 0, jnp.minimum(
+                        carry + t, skip_slots - 1)] > 0))
                     def _():
                         chunk_math(t)
 
-                    return carry2
+                return carry + nch
+
+            def chunk_body(t, carry2):
                 if not chunk_skip:
                     chunk_math(t)
                     return carry2
@@ -805,14 +843,13 @@ def group_pair_engine(
                 return carry2
 
             ntrip = (nch + CW - 1) // CW
-            slot_base = jax.lax.fori_loop(0, ntrip, chunk_body, carry)
-            return slot_base + nch if SKIP else slot_base
+            return jax.lax.fori_loop(0, ntrip, chunk_body, carry)
 
         if CW > 1:
             # zero the pad rows the odd-tail paired read may touch:
             # uninitialized VMEM can hold inf/NaN bit patterns, and bodies
             # may multiply a mask-zeroed factor by raw geometry (0*inf=NaN)
-            for s_ in range(2):
+            for s_ in range(RING):
                 for k_ in range(CW - 1):
                     buf[s_, R + k_] = jnp.zeros((nf_pad, 128), jnp.float32)
         for r in acc_refs:
@@ -903,8 +940,8 @@ def group_pair_engine(
             + [pl.BlockSpec((1, 1, G), lambda g: (g, 0, 0))],
             scratch_shapes=[
                 # CW-1 pad rows absorb the paired read's odd-run tail
-                pltpu.VMEM((2, R + CW - 1, nf_pad, 128), jnp.float32),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((RING, R + CW - 1, nf_pad, 128), jnp.float32),
+                pltpu.SemaphoreType.DMA((RING,)),
             ]
             + [pltpu.VMEM((G, LW), jnp.float32) for _ in range(num_acc)]
             + [pltpu.VMEM((G, LW), jnp.int32)]
@@ -948,7 +985,7 @@ def group_pair_engine_lists(
     want_nc: bool = True,
     sym_jf: Optional[int] = None,
 ):
-    """List-walk variant of ``group_pair_engine``: identical DMA-run
+    """List-walk variant of ``group_pair_engine``: the same run-by-run
     streaming, but every chunk's candidate lanes are COMPACTED with the
     persistent lists' per-chunk gather indices (sph/pair_lists.py) and
     merged into a dense 256-lane staging window; the pair math fires only
@@ -958,7 +995,10 @@ def group_pair_engine_lists(
 
     Contract differences from the streaming engine:
     - call(lists, i_fields, j_packed, i_offset, allow_self) — runs come
-      from lists.ranges (build-time, skin-inflated);
+      from lists.ranges (build-time, skin-inflated), and a run is a TILE:
+      at most ``list_run_rows`` chunks, fetched as exactly that many rows
+      into a ring of ``LIST_RING`` tiles, so a run's chunk loop is a
+      static unroll whose lane gathers are issued together;
     - the gather indices come from the lists' FLAT table (one row per
       kept chunk, sized by the sum over groups): a group's rows are a
       window of slot_cap rows from its segment's first row, so chunk k
@@ -969,7 +1009,8 @@ def group_pair_engine_lists(
       (exact for n < 2^24; the HBM-headroom bound is 8M rows/chip), so
       the self-pair and shard-offset tests read it from staging.
     """
-    R = _dma_rows(cfg.dma_cap)
+    RR = list_run_rows(cfg)
+    RING = LIST_RING
     nf_pad = _round_up(num_j + 1, 8)  # +1: staged global-index row
     IDXR = num_j                       # sublane row of the staged index
 
@@ -992,13 +1033,16 @@ def group_pair_engine_lists(
         def dma(w, slot):
             row_s = starts[0, 0, w] // 128
             return pltpu.make_async_copy(
-                jref.at[pl.ds(row_s, R), :, :],
+                jref.at[pl.ds(row_s, RR), :, :],
                 buf.at[slot], sems.at[slot],
             )
 
-        @pl.when(nc_g > 0)
-        def _():
-            dma(0, 0).start()
+        # the group's first RING - 1 tiles are on their way before
+        # anything else
+        for w0 in range(RING - 1):
+            @pl.when(w0 < nc_g)
+            def _():
+                dma(w0, w0).start()
 
         i_fields = [r[0, 0][:, None] for r in i_refs]  # (G, 1) each
         xi, yi, zi, hi = i_fields[:4]
@@ -1034,65 +1078,69 @@ def group_pair_engine_lists(
             if want_nc:
                 ncacc_ref[...] = ncacc_ref[...] + mask.astype(jnp.int32)
 
-        def cell_body(w, slot_base):
-            slot = w % 2
-
-            @pl.when(w + 1 < nc_g)
+        def _prefetch(w, slot):
+            @pl.when(w + (RING - 1) < nc_g)
             def _():
-                dma(w + 1, 1 - slot).start()
+                dma(w + (RING - 1), _ring_ahead(w, slot, RING)).start()
 
-            dma(w, slot).wait()
-            s = starts[0, 0, w]
-            ln = lens[0, 0, w]
-            shx = shx_r[0, 0, w]
-            shy = shy_r[0, 0, w]
-            shz = shz_r[0, 0, w]
-            row0 = s // 128
-            off = s - row0 * 128
-            nch = (off + ln + 127) // 128
+        s_last = cnt_r.shape[-1] - 1
 
-            def chunk_body(t, _c):
-                si = slot_base + t
-                cnt = cnt_r[0, 0, si]
+        def _walk_tile(slot, slot_base, nch, row0, shx, shy, shz):
+            # image-resolve the coordinate rows: one (nf_pad, 1) shift
+            # column a run
+            shift_col = jnp.where(
+                subl[:, :1] == 0, shx,
+                jnp.where(subl[:, :1] == 1, shy,
+                          jnp.where(subl[:, :1] == 2, shz, 0.0)),
+            )
+            # first every chunk's compaction, independent of each other
+            # and of the staging window: one scheduling region, so the
+            # tile's gathers overlap. A chunk past the run's own (the
+            # tile's rows behind it) takes a zero count: it merges
+            # nothing and emits nothing
+            chunks = []
+            for t in range(RR):
+                si = jnp.minimum(slot_base + t, s_last)
+                live = t < nch
+                cnt = jnp.where(live, cnt_r[0, 0, si], 0)
                 fill = fill_r[0, 0, si]
+                # gidx arrives PRE-ROTATED by the staging fill, so the
+                # compaction + rotation is ONE lane gather
+                gi_row = gidx_ref[si][None, :]  # (1, 128) int32
+                rolled = jnp.take_along_axis(
+                    buf[slot, t],
+                    jnp.broadcast_to(gi_row, (nf_pad, 128)), axis=1,
+                ) + shift_col
+                # the global-index row: one sublane select
+                idx_f = ((row0 + t) * 128 + gi_row).astype(jnp.float32)
+                rolled = jnp.where(
+                    subl == IDXR, jnp.broadcast_to(idx_f, rolled.shape),
+                    rolled,
+                )
+                chunks.append((si, live, cnt, fill, rolled))
+            # then the merges into the staging window, in order
+            for si, live, cnt, fill, rolled in chunks:
+                m0 = (lane_f >= fill) & (lane_f < fill + cnt)
+                m1 = lane_f < (fill + cnt - 128)
+                stage[:, :128] = jnp.where(m0, rolled, stage[:, :128])
+                stage[:, 128:] = jnp.where(m1, rolled, stage[:, 128:])
 
-                @pl.when(cnt > 0)
-                def _():
-                    # gidx arrives PRE-ROTATED by the staging fill, so
-                    # the compaction + rotation is ONE lane gather
-                    gi_row = gidx_ref[si][None, :]  # (1, 128) int32
-                    rolled = jnp.take_along_axis(
-                        buf[slot, t],
-                        jnp.broadcast_to(gi_row, (nf_pad, 128)), axis=1,
-                    )
-                    # image-resolve the coordinate rows and insert the
-                    # global-index row — one (nf_pad, 1) shift column +
-                    # one sublane select
-                    shift_col = jnp.where(
-                        subl[:, :1] == 0, shx,
-                        jnp.where(subl[:, :1] == 1, shy,
-                                  jnp.where(subl[:, :1] == 2, shz, 0.0)),
-                    )
-                    rolled = rolled + shift_col
-                    idx_f = ((row0 + t) * 128 + gi_row).astype(jnp.float32)
-                    rolled = jnp.where(
-                        subl == IDXR, jnp.broadcast_to(idx_f, rolled.shape),
-                        rolled,
-                    )
-                    m0 = (lane_f >= fill) & (lane_f < fill + cnt)
-                    m1 = lane_f < (fill + cnt - 128)
-                    stage[:, :128] = jnp.where(m0, rolled, stage[:, :128])
-                    stage[:, 128:] = jnp.where(m1, rolled, stage[:, 128:])
-
-                @pl.when(emit_r[0, 0, si] > 0)
+                @pl.when(live & (emit_r[0, 0, si] > 0))
                 def _():
                     stage_math(jnp.int32(128))
                     stage[:, :128] = stage[:, 128:]
                     stage[:, 128:] = jnp.zeros((nf_pad, 128), jnp.float32)
 
-                return _c
-
-            jax.lax.fori_loop(0, nch, chunk_body, 0)
+        def cell_body(w, slot_base):
+            slot = w % RING
+            _prefetch(w, slot)
+            dma(w, slot).wait()
+            s = starts[0, 0, w]
+            ln = lens[0, 0, w]
+            row0 = s // 128
+            nch = (s - row0 * 128 + ln + 127) // 128
+            _walk_tile(slot, slot_base, nch, row0,
+                       shx_r[0, 0, w], shy_r[0, 0, w], shz_r[0, 0, w])
             return slot_base + nch
 
         stage[...] = jnp.zeros((nf_pad, 256), jnp.float32)
@@ -1176,8 +1224,8 @@ def group_pair_engine_lists(
             ],
             out_specs=[vmem_spec() for _ in range(num_out_arrays + 1)],
             scratch_shapes=[
-                pltpu.VMEM((2, R, nf_pad, 128), jnp.float32),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((RING, RR, nf_pad, 128), jnp.float32),
+                pltpu.SemaphoreType.DMA((RING,)),
             ]
             + [pltpu.VMEM((G, 128), jnp.float32) for _ in range(num_acc)]
             + [pltpu.VMEM((G, 128), jnp.int32)]

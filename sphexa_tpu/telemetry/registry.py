@@ -88,13 +88,21 @@ import numpy as np
 #: blocks' main pass (rows x the chunks up to their superblock's count);
 #: a dead chunk costs the kernel a scalar test, a live one three MXU
 #: products a class. 0 where the solve runs no such pass. No kind, no
-#: REQUIRED field: v17 readers accept v1-v16 files.
-SCHEMA_VERSION = 17
+#: REQUIRED field: v17 readers accept v1-v16 files;
+#: v18 the pair lists' run tiles: optional ``chunks_live`` / ``runs_live``
+#: / ``run_rows`` on ``rebuild_lists`` beside the v12 rows: the chunks
+#: that keep a lane (a pass visits each once; ``slots_live`` rounds them
+#: up to the row tile a group), the runs they lie in, and the rows a
+#: run's copy fetches (``pallas_pairs.list_run_rows``: a run is a tile of
+#: at most that many chunks). ``chunks_live / (runs_live x run_rows)`` is
+#: the share of the rows a pass fetches that a lane is taken from. No
+#: kind, no REQUIRED field: v18 readers accept v1-v17 files.
+SCHEMA_VERSION = 18
 
 #: event schema versions this reader understands (older versions only
 #: ever ADD kinds, so the per-kind field table below covers them all)
 SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15,
-                      16, 17)
+                      16, 17, 18)
 
 #: every event kind the schema admits, with its required payload fields
 #: (beyond the envelope ``v``/``seq``/``t``/``kind``). The CLI's --strict
@@ -112,7 +120,8 @@ EVENT_KINDS: Dict[str, tuple] = {
     "retrace": ("it", "delta"),   # jit cache grew on a launch (recompile)
     # persistent pair lists (re)built; since v10 with the optional WHY
     # payload: reason, age_steps, slack, slot_need, slot_cap, attempts;
-    # since v11 also rate, cover_steps; since v12 slots_live, slots_cap
+    # since v11 also rate, cover_steps; since v12 slots_live, slots_cap;
+    # since v18 chunks_live, runs_live, run_rows
     "rebuild_lists": ("it",),
     "phases": ("it",),            # per-iteration host phase laps
     "trace": ("dir",),            # jax.profiler trace started

@@ -171,16 +171,22 @@ def test_planned_windows_do_not_roll_back(driven):
 
 
 def test_rebuild_event_says_why(driven):
-    _, _, sink, _, _ = driven
+    from sphexa_tpu.sph.pallas_pairs import list_run_rows
+
+    sim, _, sink, _, _ = driven
     events = sink.of_kind("rebuild_lists")
     for e in events:
-        assert e["v"] == SCHEMA_VERSION == 17 and validate_event(e) == []
+        assert e["v"] == SCHEMA_VERSION == 18 and validate_event(e) == []
         assert e["reason"] in ("first", "proactive", "expiry", "rollback",
                                "reconfigure")
         assert 0 < e["slot_need"] <= e["slot_cap"] and e["attempts"] >= 1
         # v12: the flat lane table's occupancy, whole 8-row tiles
         assert 0 < e["slots_live"] <= e["slots_cap"]
         assert e["slots_live"] % 8 == 0
+        # v18: kept chunks, the runs they lie in, the rows a run fetches
+        assert e["run_rows"] == list_run_rows(sim._cfg.nbr)
+        assert 0 < e["runs_live"] <= e["chunks_live"] <= e["slots_live"]
+        assert e["chunks_live"] <= e["runs_live"] * e["run_rows"]
         triggered = e["reason"] in ("proactive", "expiry", "rollback")
         assert (e["slack"] is not None) == triggered
         if e["reason"] == "proactive":
@@ -296,7 +302,7 @@ def test_kicked_trajectory_matches_streaming(kicked, streamed_12, key):
 class TestSchemaV10:
     def test_v10_and_v11_add_no_kind_and_no_required_field(self):
         # (nor does v12: ``rebuild_lists.slots_live`` / ``slots_cap``)
-        assert SCHEMA_VERSION == 17
+        assert SCHEMA_VERSION == 18
         assert not {10, 11, 12, 13, 14} & set(KIND_SINCE.values())
         assert EVENT_KINDS["rebuild_lists"] == ("it",)
         assert EVENT_KINDS["window"] == ("it", "steps", "wall_s",

@@ -546,9 +546,10 @@ def test_slots_cap_overflow_sentinel(case):
     assert int(lists.slot_need) <= scap
 
 
-def _prune_reference(starts, lens, shifts, ncells, cnt):
-    """``_prune_empty_chunks`` in plain loops: maximal stretches of kept
-    chunks within one candidate run, with exact particle bounds."""
+def _prune_reference(starts, lens, shifts, ncells, cnt, run_rows):
+    """``_prune_empty_chunks`` in plain loops: stretches of kept chunks
+    within one candidate run, cut at every ``run_rows``-th chunk, with
+    exact particle bounds."""
     ng, scap = cnt.shape
     out = {k: np.zeros((ng, scap), starts.dtype if k in "sl" else np.float32)
            for k in ("s", "l", "x", "y", "z")}
@@ -566,16 +567,16 @@ def _prune_reference(starts, lens, shifts, ncells, cnt):
                 if slot < scap and cnt[g, slot] > 0:
                     kept_slots.append(slot)
                     hi = min(s + ln, (row + 1) * 128)
-                    if cur is None:
-                        cur = [max(s, row * 128), hi, w]
+                    if cur is None or cur[3] == run_rows:
+                        cur = [max(s, row * 128), hi, w, 1]
                         runs.append(cur)
                     else:
-                        cur[1] = hi
+                        cur[1], cur[3] = hi, cur[3] + 1
                 else:
                     cur = None
                 slot += 1
         heads[g] = len(runs)
-        for k, (lo, hi, w) in enumerate(runs):
+        for k, (lo, hi, w, _) in enumerate(runs):
             out["s"][g, k], out["l"][g, k] = lo, hi - lo
             for key, sh in zip("xyz", shifts):
                 out[key][g, k] = sh[g, w]
@@ -584,12 +585,20 @@ def _prune_reference(starts, lens, shifts, ncells, cnt):
     return out, heads, perm
 
 
+#: run tiles the prune is held to: 13 = the un-cut runs' own width at these
+#: sizes (no stretch of kept chunks is longer), the shipped tile, and two
+#: that cut most stretches
+RUN_ROWS = [13, pp.LIST_RUN_ROWS, 2, 1]
+
+
+@pytest.mark.parametrize("run_rows", RUN_ROWS)
 @pytest.mark.parametrize("thin", [False, True], ids=["marked", "thinned"])
-def test_prune_matches_a_plain_loop(case, built, thin):
+def test_prune_matches_a_plain_loop(case, built, thin, run_rows):
     """The prune's slot -> run lookups (masked sums over the runs since
-    PR 28, gathers before) against loops over runs and chunks; ``thinned``
+    PR 28, gathers before) and its cut of a stretch into tiles of
+    ``run_rows`` chunks against loops over runs and chunks; ``thinned``
     also drops every third kept chunk, so stretches break inside runs."""
-    from sphexa_tpu.sph.pair_lists import _prune_empty_chunks
+    from sphexa_tpu.sph.pair_lists import _prune_empty_chunks, _run_chunks
 
     ss, keys, box, const, nbr = case
     _, skin, scap = built
@@ -602,11 +611,13 @@ def test_prune_matches_a_plain_loop(case, built, thin):
     cnt *= (np.arange(scap)[None, :] < nch[:, None])
     if thin:
         cnt[:, ::3] = 0
-    new, packed = _prune_empty_chunks(ranges, jnp.asarray(cnt), scap)
+    new, packed = _prune_empty_chunks(ranges, jnp.asarray(cnt), scap,
+                                      run_rows)
     shifts = [np.asarray(a) for a in
               (ranges.shift_x, ranges.shift_y, ranges.shift_z)]
     ref, heads, ref_perm = _prune_reference(
-        starts, lens, shifts, np.asarray(ranges.ncells), cnt)
+        starts, lens, shifts, np.asarray(ranges.ncells), cnt, run_rows)
+    assert _run_chunks(new.starts, new.lens).max() <= run_rows
     np.testing.assert_array_equal(np.asarray(new.ncells), heads)
     np.testing.assert_array_equal(np.asarray(new.starts), ref["s"])
     np.testing.assert_array_equal(np.asarray(new.lens), ref["l"])
@@ -618,3 +629,4 @@ def test_prune_matches_a_plain_loop(case, built, thin):
     assert (np.diff((kept_first > 0).astype(int), axis=1) <= 0).all()
     assert live.max() > 0
     np.testing.assert_array_equal(np.asarray(packed), kept_first)
+

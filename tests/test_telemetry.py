@@ -90,6 +90,32 @@ class TestRegistry:
         del bad["wall_s"]
         assert any("wall_s" in p for p in validate_event(bad))
 
+    @pytest.mark.parametrize("v", [12, 17, SCHEMA_VERSION])
+    def test_rebuild_lists_run_tile_fields(self, v):
+        """Schema v18: ``chunks_live`` / ``runs_live`` / ``run_rows`` on
+        ``rebuild_lists`` are optional payload: an event with them is
+        clean, and so are a v12 and a v17 writer's without them."""
+        from sphexa_tpu.telemetry.registry import (
+            EVENT_KINDS, KIND_SINCE, SUPPORTED_VERSIONS)
+
+        assert SCHEMA_VERSION == 18 == SUPPORTED_VERSIONS[-1]
+        assert EVENT_KINDS["rebuild_lists"] == ("it",)
+        assert 18 not in KIND_SINCE.values()
+        e = {"v": v, "seq": 0, "t": 1.0, "kind": "rebuild_lists", "it": 10,
+             "reason": "proactive", "age_steps": 7, "slack": 0.1,
+             "slot_need": 231, "slot_cap": 296, "slots_live": 600216,
+             "slots_cap": 2375680, "attempts": 1, "rate": 0.15,
+             "cover_steps": 7}
+        if v >= 18:
+            e.update(chunks_live=545000, runs_live=210000, run_rows=4)
+        sink = MemorySink()
+        t = Telemetry(sinks=[sink])
+        t.event("rebuild_lists", **{k: val for k, val in e.items()
+                                    if k not in ("v", "seq", "t", "kind")})
+        (sent,) = sink.events
+        assert validate_event(e) == [] == validate_event(sent)
+        assert sent["v"] == 18
+
     def test_console_sink_and_printer_routing(self):
         lines = []
         sink = ConsoleSink(printer=lines.append)
@@ -311,7 +337,7 @@ class TestSpans:
             sp["bytes"] = 3
         after = time.perf_counter_ns()
         (e,) = sink.events
-        assert e["kind"] == "span" and e["v"] == SCHEMA_VERSION == 17
+        assert e["kind"] == "span" and e["v"] == SCHEMA_VERSION == 18
         assert validate_event(e) == []
         assert (e["name"], e["parent"], e["it"]) == ("sphexa:x", None, 12)
         assert (e["reason"], e["bytes"]) == ("r", 3)

@@ -377,9 +377,11 @@ class TestSchemaV7:
         # for the run axis of ``exchange`` (``run_slots``, ``live_runs_max``),
         # v15 for the cooling extrema on ``numerics``, v16 for the
         # radiated-energy counter on it, v17 for the compaction kernel's
-        # live-chunk shares beside v13's fills
-        assert SCHEMA_VERSION == 17
-        assert not ({7, 10, 11, 12, 13, 14, 15, 16, 17}
+        # live-chunk shares beside v13's fills, v18 for the pair lists'
+        # run tiles (``rebuild_lists.chunks_live`` / ``runs_live`` /
+        # ``run_rows``)
+        assert SCHEMA_VERSION == 18
+        assert not ({7, 10, 11, 12, 13, 14, 15, 16, 17, 18}
                     & set(KIND_SINCE.values()))
 
     def test_v7_staged_exchange_validates(self):
