@@ -49,7 +49,7 @@ def main():
     ap.add_argument("--skin-rel", type=float, default=0.2,
                     help="skin as a fraction of 2*h_max")
     ap.add_argument("--ve", action="store_true",
-                    help="also measure the VE ops walk-vs-skip")
+                    help="also measure the VE ops, lists vs streamed")
     args = ap.parse_args()
 
     state, box, const = init_sedov(args.n)
@@ -121,7 +121,8 @@ def main():
     if not args.ve:
         return
 
-    # ---- VE ops: walk vs chunk-skip list modes
+    # ---- VE ops on the kernels pallas_pairs.PAIR_OP_ENGINE names (a bench
+    # of an op on the other list kernel patches its row there)
     from sphexa_tpu.sph.hydro_ve import compute_eos_ve
 
     t_xm, (xm, _, _) = timed(
@@ -131,44 +132,38 @@ def main():
     (kx, gradh), _ = pp.pallas_ve_def_gradh(x, y, z, h, m, xm, None, box,
                                             const, nbr, lists=lists)
     prho, cve, rhove, pve = compute_eos_ve(ss.temp, m, kx, xm, gradh, const)
-    # the fused IAD + divv/curlv op (one pass where there were two): skip
-    # vs walk on lists, and the streamed engine the Evrard cells run; under
-    # a velocity field with a gradient, so the outputs compare on something
+    # the fused IAD + divv/curlv op (one pass where there were two): on
+    # lists, and the streamed engine the Evrard cells run; under a
+    # velocity field with a gradient, so the outputs compare on something
     two_pi = 2.0 * np.pi / box.lengths
     v = (jnp.sin(two_pi[0] * x), jnp.sin(two_pi[1] * y + two_pi[0] * x),
          jnp.cos(two_pi[2] * z))
     dv_args = (x, y, z, *v, h, kx, xm)
     for gv in (False, True):
-        f_w = jax.jit(lambda ls, *a: pp.pallas_iad_divv_curlv(
-            *a, None, box, const, nbr, lists=ls, list_walk=True,
-            with_gradv=gv))
-        f_k = jax.jit(lambda ls, *a: pp.pallas_iad_divv_curlv(
-            *a, None, box, const, nbr, lists=ls, list_walk=False,
-            with_gradv=gv))
+        f_l = jax.jit(lambda ls, *a: pp.pallas_iad_divv_curlv(
+            *a, None, box, const, nbr, lists=ls, with_gradv=gv))
         f_s = jax.jit(lambda rng, *a: pp.pallas_iad_divv_curlv(
             *a, keys, box, const, nbr, ranges=rng, with_gradv=gv))
-        tw, ow = timed(f_w, lists, *dv_args)
-        tk, ok_ = timed(f_k, lists, *dv_args)
+        tl, ol = timed(f_l, lists, *dv_args)
         ts, os_ = timed(f_s, ranges, *dv_args)
         dd = max(float(jnp.max(jnp.abs(a - b)))
-                 for a, b in zip(ow[1] + os_[1], ok_[1] + ok_[1]))
-        print(f"iad+divv{'+gradv' if gv else '      '}: skip {tk*1e3:7.1f} ms"
-              f"  walk {tw*1e3:7.1f} ms  x{tk/tw:.2f}  stream {ts*1e3:7.1f}"
-              f" ms  d={dd:.2e} of {float(jnp.max(jnp.abs(ok_[1][0]))):.2f}")
-    cs0 = ok_[0]
+                 for a, b in zip(ol[1], os_[1]))
+        print(f"iad+divv{'+gradv' if gv else '      '}: stream {ts*1e3:7.1f}"
+              f" ms  lists {tl*1e3:7.1f} ms  x{ts/tl:.2f}  d={dd:.2e} of "
+              f"{float(jnp.max(jnp.abs(os_[1][0]))):.2f}")
+    cs0 = os_[0]
 
-    divv = ok_[1][0]
+    divv = os_[1][0]
     av_args = (x, y, z, *v, h, cve, kx, xm, divv, ss.alpha, *cs0)
-    f_w = jax.jit(lambda ls, *a: pp.pallas_av_switches(
-        *a, None, box, 1e-5, const, nbr, lists=ls, list_walk=True))
-    f_k = jax.jit(lambda ls, *a: pp.pallas_av_switches(
-        *a, None, box, 1e-5, const, nbr, lists=ls, list_walk=False))
-    tw, aw = timed(f_w, lists, *av_args)
-    tk, ak = timed(f_k, lists, *av_args)
-    dd = float(jnp.max(jnp.abs(aw[0] - ak[0])))
-    print(f"av_switch : skip   {tk*1e3:7.1f} ms  walk  {tw*1e3:7.1f} ms  "
-          f"x{tk/tw:.2f}  d={dd:.2e}")
-
+    f_l = jax.jit(lambda ls, *a: pp.pallas_av_switches(
+        *a, None, box, 1e-5, const, nbr, lists=ls))
+    f_s = jax.jit(lambda rng, *a: pp.pallas_av_switches(
+        *a, keys, box, 1e-5, const, nbr, ranges=rng))
+    tl, al = timed(f_l, lists, *av_args)
+    ts, as_ = timed(f_s, ranges, *av_args)
+    dd = float(jnp.max(jnp.abs(al[0] - as_[0])))
+    print(f"av_switch : stream {ts*1e3:7.1f} ms  lists {tl*1e3:7.1f} ms  "
+          f"x{ts/tl:.2f}  d={dd:.2e}")
 
 if __name__ == "__main__":
     main()

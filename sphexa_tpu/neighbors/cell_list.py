@@ -63,10 +63,6 @@ class NeighborConfig:
     # the distance test, or genuine neighbors counted once). 0 disables.
     run_cap: int = 0
     gap: int = 0
-    # chunks per engine inner-loop trip (pair math on (G, 128*chunk_pair)
-    # tiles). 0 = default 1. Measured SLOWER at 2 on v5e (docs/NEXT.md);
-    # kept for future hardware.
-    chunk_pair: int = 0
 
     @property
     def num_candidates(self) -> int:

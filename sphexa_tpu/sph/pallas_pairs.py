@@ -59,14 +59,6 @@ from sphexa_tpu.sph.kernels import (
 
 GROUP = 128  # default targets per group (NeighborConfig.group overrides)
 
-# chunks processed per inner-loop trip: 2 = the pair math runs on (G, 256)
-# tiles (two 128-lane chunks). MEASURED SLOWER on v5e (467 vs 410 ms for
-# the std Sedov 100^3 pipeline): the per-field lane concats cost more than
-# the halved loop overhead saves — the per-chunk overhead is accumulator
-# read-modify-write + field loads, which pairing cannot reduce. Kept for
-# future hardware; configured via NeighborConfig.chunk_pair (0 = 1).
-# (docs/NEXT.md round-4 notes.)
-
 
 class PairGeom(NamedTuple):
     """Per-(target, candidate) geometry handed to the pair body."""
@@ -626,8 +618,8 @@ def group_pair_engine(
     - ``skip_slots``: when > 0, the call takes a PairLists whose per-chunk
       counts (sph/pair_lists.py mark bits) gate each chunk's math — the
       AABB chunk-cull for free (no AABB table, no in-kernel bbox math),
-      available to every op while lists are valid. Requires CW == 1 and
-      excludes ``chunk_skip``. The lists' runs are tiles: a copy is
+      available to every op while lists are valid. Excludes
+      ``chunk_skip``. The lists' runs are tiles: a copy is
       ``list_run_rows`` rows into a ring of ``LIST_RING`` buffers.
     - returns fn(ranges, i_fields(NG,G) x num_i, j_packed, i_offset,
       allow_self) -> (outs (NG, G) x num_out, nc (NG, G)); ``allow_self``
@@ -637,12 +629,8 @@ def group_pair_engine(
     R = _dma_rows(cfg.dma_cap)
     RING = 2                 # run buffers: one walked, the rest in flight
     nf_pad = _round_up(num_j, 8)
-    CW = max(1, cfg.chunk_pair)  # chunks per inner-loop trip
-    LW = 128 * CW            # lane width of the pair-math tiles
     SKIP = skip_slots > 0
     if SKIP:
-        if CW != 1:
-            raise ValueError("skip_slots requires chunk_pair == 1")
         chunk_skip = False
         R, RING = list_run_rows(cfg), LIST_RING
     if chunk_skip is None:
@@ -674,12 +662,8 @@ def group_pair_engine(
 
         def dma(w, slot):
             row_s = starts[0, 0, w] // 128
-            # dst slices off the CW-1 tail pad rows (uninitialized garbage
-            # the odd-tail paired read may touch — every accumulation is
-            # mask-selected, so garbage never reaches an output)
             return pltpu.make_async_copy(
-                jref.at[pl.ds(row_s, R), :, :],
-                buf.at[slot, pl.ds(0, R)], sems.at[slot]
+                jref.at[pl.ds(row_s, R), :, :], buf.at[slot], sems.at[slot]
             )
 
         def dma_aabb(w, slot):
@@ -712,7 +696,7 @@ def group_pair_engine(
             ioff[0, 0, 0] + gi * G
             + jax.lax.broadcasted_iota(jnp.int32, (G, 1), 0)
         )
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, LW), 1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
         h4 = 4.0 * hi * hi
         lx, ly, lz = boxl[0, 0, 0], boxl[0, 0, 1], boxl[0, 0, 2]
 
@@ -755,28 +739,13 @@ def group_pair_engine(
                     jnp.int32(1),
                     jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0),
                 )
-                # AABB rows beyond the run's nch describe the NEXT run's
-                # rows — mask them so a paired trip (CW > 1) whose tail
-                # chunk is past the run never fires on a stale verdict
-                in_run = jax.lax.broadcasted_iota(
-                    jnp.int32, (R, 1), 0) < nch
-                bits = jnp.sum(jnp.where(hit_rows & in_run, pow2, 0))
+                # (AABB rows beyond the run's nch describe the NEXT run's
+                # rows: the chunk loop stops at nch and never tests them)
+                bits = jnp.sum(jnp.where(hit_rows, pow2, 0))
 
             def chunk_math(t):
-                # one trip covers CW consecutive 128-lane chunks: the pair
-                # math runs on (G, 128*CW) tiles, amortizing the per-trip
-                # scalar/loop overhead over CW chunks
-                c = t * CW
-                parts = [buf[slot, c + k] for k in range(CW)]  # (nf_pad, 128)
-                if CW == 1:
-                    j_fields = [parts[0][f][None, :] for f in range(num_j)]
-                else:
-                    j_fields = [
-                        jnp.concatenate(
-                            [p[f][None, :] for p in parts], axis=1
-                        )
-                        for f in range(num_j)
-                    ]
+                chunk = buf[slot, t]  # (nf_pad, 128)
+                j_fields = [chunk[f][None, :] for f in range(num_j)]
                 if fold:
                     # tiny-grid path: shifts are all zero, fold per pair
                     jx, jy, jz = j_fields[0], j_fields[1], j_fields[2]
@@ -794,7 +763,7 @@ def group_pair_engine(
                     ry = yi - jy
                     rz = zi - jz
                 d2 = rx * rx + ry * ry + rz * rz
-                cand = (row0 + c) * 128 + lane
+                cand = (row0 + t) * 128 + lane
                 mask = (cand >= s) & (cand < s + ln)
                 if pair_cutoff:
                     mask = mask & (d2 < h4)
@@ -830,31 +799,20 @@ def group_pair_engine(
                     chunk_math(t)
                     return carry2
 
-                # the trip's AABB verdict is CW bits of the run's bitmask —
-                # skipping the whole (G, 128*CW) tile's pair math for
-                # gap-bridged / overshoot chunks costs one scalar test
-                @pl.when(
-                    (jax.lax.shift_right_logical(bits, t * CW)
-                     & ((1 << CW) - 1)) != 0
-                )
+                # the chunk's AABB verdict is one bit of the run's bitmask —
+                # skipping the (G, 128) tile's pair math for gap-bridged /
+                # overshoot chunks costs one scalar test
+                @pl.when((jax.lax.shift_right_logical(bits, t) & 1) != 0)
                 def _():
                     chunk_math(t)
 
                 return carry2
 
-            ntrip = (nch + CW - 1) // CW
-            return jax.lax.fori_loop(0, ntrip, chunk_body, carry)
+            return jax.lax.fori_loop(0, nch, chunk_body, carry)
 
-        if CW > 1:
-            # zero the pad rows the odd-tail paired read may touch:
-            # uninitialized VMEM can hold inf/NaN bit patterns, and bodies
-            # may multiply a mask-zeroed factor by raw geometry (0*inf=NaN)
-            for s_ in range(RING):
-                for k_ in range(CW - 1):
-                    buf[s_, R + k_] = jnp.zeros((nf_pad, 128), jnp.float32)
         for r in acc_refs:
-            r[...] = jnp.zeros((G, LW), jnp.float32)
-        ncacc_ref[...] = jnp.zeros((G, LW), jnp.int32)
+            r[...] = jnp.zeros((G, 128), jnp.float32)
+        ncacc_ref[...] = jnp.zeros((G, 128), jnp.int32)
         jax.lax.fori_loop(0, nc_g, cell_body, 0)
         accs = tuple(r[...] for r in acc_refs)
 
@@ -939,12 +897,11 @@ def group_pair_engine(
             ]
             + [pl.BlockSpec((1, 1, G), lambda g: (g, 0, 0))],
             scratch_shapes=[
-                # CW-1 pad rows absorb the paired read's odd-run tail
-                pltpu.VMEM((RING, R + CW - 1, nf_pad, 128), jnp.float32),
+                pltpu.VMEM((RING, R, nf_pad, 128), jnp.float32),
                 pltpu.SemaphoreType.DMA((RING,)),
             ]
-            + [pltpu.VMEM((G, LW), jnp.float32) for _ in range(num_acc)]
-            + [pltpu.VMEM((G, LW), jnp.int32)]
+            + [pltpu.VMEM((G, 128), jnp.float32) for _ in range(num_acc)]
+            + [pltpu.VMEM((G, 128), jnp.int32)]
             + (
                 [pltpu.VMEM((2, R, 128), jnp.float32),
                  pltpu.SemaphoreType.DMA((2,))]
@@ -1285,6 +1242,71 @@ def _op_aabb(jfields: Sequence, box: Box, cfg: NeighborConfig):
     return chunk_aabb_table(jfields[0], jfields[1], jfields[2], cfg.dma_cap)
 
 
+#: THE place where a pair op's kernel is chosen: op -> (kernel on the
+#: persistent lists, AABB chunk cull when streamed). "skip" is
+#: ``group_pair_engine(skip_slots=)``: whole kept chunks, the build's mark
+#: bits gating each; "walk" is ``group_pair_engine_lists``: the kept lanes
+#: compacted into full staging chunks. No argument, flag or environment
+#: variable overrides a row; a by-hand bench that wants an op on the other
+#: kernel patches its row. Moving the skip ops onto the walk (ROADMAP S1 a)
+#: changes rows, not functions.
+PAIR_OP_ENGINE = {
+    # cheap body: the mark-bit chunk skip beats in-kernel compaction
+    # (compaction's src-side take_along exceeds the ~10-op body); streamed,
+    # the chunk cull lost on the cheap ops (ROADMAP, dead ends on record)
+    "density": ("skip", False),  # pallas_xmass rides it
+    "iad": ("skip", False),
+    "gradh": ("skip", False),
+    "momentum-energy-std": ("walk", True),
+    # 15 read-modify-write accumulators a visit weigh more than the ninth
+    # staged row's second sublane tile: 358.2 ms walk against 530.3 skip
+    # at Sedov 160^3 on a v5e (scripts/bench_lists.py --ve -n 160, PR 40's
+    # tree; the ledger's sedov-ve-4m level 1.157 is PR 32's, on the walk)
+    "divv-curlv": ("walk", True),
+    # rsqrt + signal-velocity max make this body heavy enough for lane
+    # compaction: 364.4 against 510.5 ms (same run)
+    "av-switches": ("walk", True),
+    "momentum-energy-ve": ("walk", True),
+}
+
+
+def _run_pair_op(op: str, pair_body: Callable, finalize: Callable,
+                 i_fields: Sequence, jfields: Sequence, *, num_acc: int,
+                 box: Box, cfg: NeighborConfig, ranges, lists, i_offset,
+                 interpret: bool, want_nc: bool = False,
+                 sym_jf: Optional[int] = None):
+    """Everything after an op's fields are ready: build the kernel its
+    ``PAIR_OP_ENGINE`` row names, pack the j-fields for it (the walk
+    stages the candidate's index as one more row), call it. Returns the
+    kernel's raw (NG, 1, G) outputs, the neighbour counts last, and the
+    occupancy of the ranges it ran over."""
+    on_lists, cull = PAIR_OP_ENGINE[op]
+    num_j = len(jfields)
+    dims = dict(num_i=len(i_fields), num_j=num_j, num_acc=num_acc, cfg=cfg,
+                interpret=interpret, want_nc=want_nc, sym_jf=sym_jf)
+    if lists is None:
+        engine = group_pair_engine(
+            pair_body, finalize, fold=engine_fold(box, cfg),
+            chunk_skip=None if cull else False, **dims)
+        outs = engine(ranges, i_fields, pack_j_fields(jfields, cfg.dma_cap),
+                      i_offset,
+                      aabb=_op_aabb(jfields, box, cfg) if cull else None)
+        return outs, ranges.occupancy
+    if on_lists == "walk":
+        engine = group_pair_engine_lists(pair_body, finalize, **dims)
+        outs = engine(
+            lists, i_fields,
+            pack_j_fields(jfields, cfg.dma_cap, nf_min=num_j + 1), i_offset)
+    else:
+        engine = group_pair_engine(
+            pair_body, finalize, chunk_skip=False,
+            skip_slots=lists.slot_cap, **dims)
+        outs = engine(lists.ranges, i_fields,
+                      pack_j_fields(jfields, cfg.dma_cap), i_offset,
+                      skip=lists)
+    return outs, lists.ranges.occupancy
+
+
 @named_phase("density")
 def pallas_density(
     x, y, z, h, m, sorted_keys, box: Box, const, cfg: NeighborConfig,
@@ -1327,26 +1349,11 @@ def pallas_density(
         return (rho,)
 
     i_fields = _prep_i(x, y, z, h, (1.0 / (h * h), m), cfg.group)
-    jf = jdata or (x, y, z, m)
-    if lists is not None:
-        # cheap body: the mark-bit chunk skip beats in-kernel compaction
-        # (compaction's src-side take_along exceeds the ~10-op body)
-        engine = group_pair_engine(
-            pair_body, finalize, num_i=6, num_j=4, num_acc=1, cfg=cfg,
-            fold=False, interpret=interpret, chunk_skip=False,
-            skip_slots=lists.slot_cap,
-        )
-        jp = pack_j_fields(jf, cfg.dma_cap)
-        rho, nc = engine(lists.ranges, i_fields, jp, i_offset, skip=lists)
-        return rho.reshape(-1)[:n], nc.reshape(-1)[:n], \
-            lists.ranges.occupancy
-    engine = group_pair_engine(
-        pair_body, finalize, num_i=6, num_j=4, num_acc=1, cfg=cfg,
-        fold=engine_fold(box, cfg), interpret=interpret, chunk_skip=False,
-    )
-    jp = pack_j_fields(jf, cfg.dma_cap)
-    rho, nc = engine(ranges, i_fields, jp, i_offset)
-    return rho.reshape(-1)[:n], nc.reshape(-1)[:n], ranges.occupancy
+    (rho, nc), occ = _run_pair_op(
+        "density", pair_body, finalize, i_fields, jdata or (x, y, z, m),
+        num_acc=1, box=box, cfg=cfg, ranges=ranges, lists=lists,
+        i_offset=i_offset, interpret=interpret, want_nc=True)
+    return rho.reshape(-1)[:n], nc.reshape(-1)[:n], occ
 
 
 def _iad_tau_terms(geom):
@@ -1405,7 +1412,6 @@ def pallas_iad(
 
     if ranges is None and lists is None:
         ranges = group_cell_ranges(x, y, z, h, sorted_keys, box, cfg)
-    fold = engine_fold(box, cfg)
 
     def pair_body_lanes(geom, i_fields, j_fields, accs):
         inv_h2 = i_fields[4]
@@ -1428,25 +1434,11 @@ def pallas_iad(
     # Sedov 100^3): the per-chunk NT-dot relayout exceeds the ~20 VPU ops
     # it saves. Revisit if Mosaic grows a cheap lane-contraction.
     i_fields = _prep_i(x, y, z, h, (1.0 / (h * h),), cfg.group)
-    jf = jdata or (x, y, z, vol)
-    if lists is not None:
-        engine = group_pair_engine(
-            pair_body_lanes, finalize, num_i=5, num_j=4, num_acc=6,
-            cfg=cfg, fold=False, interpret=interpret, chunk_skip=False,
-            want_nc=False, skip_slots=lists.slot_cap,
-        )
-        jp = pack_j_fields(jf, cfg.dma_cap)
-        *cs, _nc = engine(lists.ranges, i_fields, jp, i_offset,
-                          skip=lists)
-        return tuple(c.reshape(-1)[:n] for c in cs), \
-            lists.ranges.occupancy
-    engine = group_pair_engine(
-        pair_body_lanes, finalize, num_i=5, num_j=4, num_acc=6, cfg=cfg,
-        fold=fold, interpret=interpret, chunk_skip=False, want_nc=False,
-    )
-    jp = pack_j_fields(jf, cfg.dma_cap)
-    *cs, _nc = engine(ranges, i_fields, jp, i_offset)
-    return tuple(c.reshape(-1)[:n] for c in cs), ranges.occupancy
+    (*cs, _nc), occ = _run_pair_op(
+        "iad", pair_body_lanes, finalize, i_fields,
+        jdata or (x, y, z, vol), num_acc=6, box=box, cfg=cfg, ranges=ranges,
+        lists=lists, i_offset=i_offset, interpret=interpret)
+    return tuple(c.reshape(-1)[:n] for c in cs), occ
 
 
 @named_phase("momentum-energy")
@@ -1562,24 +1554,11 @@ def pallas_momentum_energy_std(
                    j11, j12, j13, j22, j23, j33)
     sym = 3 if getattr(const, "sym_pairs", True) else None
     f = lambda a: a.reshape(-1)[:n]
-    if lists is not None:
-        engine = group_pair_engine_lists(
-            pair_body, finalize, num_i=18, num_j=17, num_acc=5, cfg=cfg,
-            interpret=interpret, want_nc=False, sym_jf=sym,
-        )
-        jp = pack_j_fields(jfields, cfg.dma_cap, nf_min=18)
-        ax, ay, az, du, dt_i, _nc = engine(lists, i_fields, jp, i_offset)
-        return (f(ax), f(ay), f(az), f(du), jnp.min(f(dt_i)),
-                lists.ranges.occupancy)
-    engine = group_pair_engine(
-        pair_body, finalize, num_i=18, num_j=17, num_acc=5, cfg=cfg,
-        fold=engine_fold(box, cfg), interpret=interpret, want_nc=False,
-        sym_jf=sym,
-    )
-    jp = pack_j_fields(jfields, cfg.dma_cap)
-    ax, ay, az, du, dt_i, _nc = engine(ranges, i_fields, jp, i_offset,
-                                       aabb=_op_aabb(jfields, box, cfg))
-    return f(ax), f(ay), f(az), f(du), jnp.min(f(dt_i)), ranges.occupancy
+    (ax, ay, az, du, dt_i, _nc), occ = _run_pair_op(
+        "momentum-energy-std", pair_body, finalize, i_fields, jfields,
+        num_acc=5, box=box, cfg=cfg, ranges=ranges, lists=lists,
+        i_offset=i_offset, interpret=interpret, sym_jf=sym)
+    return f(ax), f(ay), f(az), f(du), jnp.min(f(dt_i)), occ
 
 
 # ---------------------------------------------------------------------------
@@ -1659,38 +1638,12 @@ def pallas_ve_def_gradh(
         return (kx, gradh)
 
     i_fields = _prep_i(x, y, z, h, (1.0 / (h * h), m, xm), cfg.group)
-    jf = jdata or (x, y, z, m, xm)
     f = lambda a: a.reshape(-1)[:n]
-    if lists is not None:
-        engine = group_pair_engine(
-            pair_body, finalize, num_i=7, num_j=5, num_acc=3, cfg=cfg,
-            fold=False, interpret=interpret, chunk_skip=False,
-            want_nc=False, skip_slots=lists.slot_cap,
-        )
-        jp = pack_j_fields(jf, cfg.dma_cap)
-        kx, gradh, _nc = engine(lists.ranges, i_fields, jp, i_offset,
-                                skip=lists)
-        return (f(kx), f(gradh)), lists.ranges.occupancy
-    engine = group_pair_engine(
-        pair_body, finalize, num_i=7, num_j=5, num_acc=3, cfg=cfg,
-        fold=engine_fold(box, cfg), interpret=interpret, chunk_skip=False,
-        want_nc=False,
-    )
-    jp = pack_j_fields(jf, cfg.dma_cap)
-    kx, gradh, _nc = engine(ranges, i_fields, jp, i_offset)
-    return (f(kx), f(gradh)), ranges.occupancy
-
-
-#: engine of the fused IAD + divv/curlv op on persistent lists: True =
-#: list-walk (lane compaction), False = mark-bit chunk skip. Measured on a
-#: v5e at Sedov 160^3 = 4.1M (scripts/bench_lists.py --ve -n 160, PR 32):
-#: walk 505.3 ms vs skip 542.3 (gradv outputs: 513.4 vs 548.1) — the
-#: 15 read-modify-write accumulators a visit weigh more than the ninth
-#: staged row's second sublane tile; the two ops this one replaces were
-#: 420.6 (iad, skip) + 471.0 (divv/curlv, skip). Streamed (no lists,
-#: AABB chunk cull): 541.2 on the same data. In sedov-ve-4m.steady's
-#: traced step: 499.6 (walk) vs 536.1 (skip).
-IAD_DIVV_LIST_WALK = True
+    (kx, gradh, _nc), occ = _run_pair_op(
+        "gradh", pair_body, finalize, i_fields, jdata or (x, y, z, m, xm),
+        num_acc=3, box=box, cfg=cfg, ranges=ranges, lists=lists,
+        i_offset=i_offset, interpret=interpret)
+    return (f(kx), f(gradh)), occ
 
 
 @named_phase("divv-curlv")
@@ -1698,7 +1651,7 @@ def pallas_iad_divv_curlv(
     x, y, z, vx, vy, vz, h, kx, xm,
     sorted_keys, box: Box, const, cfg: NeighborConfig,
     ranges=None, with_gradv: bool = False, interpret: bool = False,
-    jdata=None, i_offset=0, lists=None, list_walk=None,
+    jdata=None, i_offset=0, lists=None,
 ):
     """IAD tensor AND velocity divergence/curl through the IAD gradient in
     ONE neighbour pass (iad_kern.hpp + divv_curlv_kern.hpp:43-120; the
@@ -1716,9 +1669,7 @@ def pallas_iad_divv_curlv(
     dv11..dv33]); ``with_gradv`` changes the outputs only.
 
     Under shard_map, ``jdata = (x, y, z, xm/kx, xm, vx, vy, vz)`` supplies
-    the j-side candidate arrays — same contract as pallas_density.
-    ``list_walk``: the by-hand bench's and the tests' engine override on
-    lists (None = IAD_DIVV_LIST_WALK)."""
+    the j-side candidate arrays — same contract as pallas_density."""
     n = x.shape[0]
     wc = kernel_poly_coeffs(float(const.sinc_index), const.kernel_choice)
     K = float(const.K)
@@ -1773,32 +1724,11 @@ def pallas_iad_divv_curlv(
     i_fields = _prep_i(
         x, y, z, h, (1.0 / (h * h), knorm, vx, vy, vz), cfg.group
     )
-    jf = jdata or (x, y, z, xm / kx, xm, vx, vy, vz)
-    dims = dict(num_i=9, num_j=8, num_acc=15, cfg=cfg, interpret=interpret,
-                want_nc=False)
-    walk = IAD_DIVV_LIST_WALK if list_walk is None else list_walk
-    if lists is None:
-        engine = group_pair_engine(
-            pair_body, finalize, fold=engine_fold(box, cfg), **dims
-        )
-        *outs, _nc = engine(ranges, i_fields, pack_j_fields(jf, cfg.dma_cap),
-                            i_offset, aabb=_op_aabb(jf, box, cfg))
-        occ = ranges.occupancy
-    elif walk:
-        engine = group_pair_engine_lists(pair_body, finalize, **dims)
-        *outs, _nc = engine(
-            lists, i_fields, pack_j_fields(jf, cfg.dma_cap, nf_min=9),
-            i_offset)
-        occ = lists.ranges.occupancy
-    else:
-        engine = group_pair_engine(
-            pair_body, finalize, fold=False, chunk_skip=False,
-            skip_slots=lists.slot_cap, **dims
-        )
-        *outs, _nc = engine(lists.ranges, i_fields,
-                            pack_j_fields(jf, cfg.dma_cap), i_offset,
-                            skip=lists)
-        occ = lists.ranges.occupancy
+    (*outs, _nc), occ = _run_pair_op(
+        "divv-curlv", pair_body, finalize, i_fields,
+        jdata or (x, y, z, xm / kx, xm, vx, vy, vz), num_acc=15, box=box,
+        cfg=cfg, ranges=ranges, lists=lists, i_offset=i_offset,
+        interpret=interpret)
     outs = tuple(a.reshape(-1)[:n] for a in outs)
     return outs[:6], outs[6:], occ
 
@@ -1809,7 +1739,7 @@ def pallas_av_switches(
     c11, c12, c13, c22, c23, c33,
     sorted_keys, box: Box, dt, const, cfg: NeighborConfig,
     ranges=None, interpret: bool = False, jdata=None, i_offset=0,
-    lists=None, list_walk: bool = True,
+    lists=None,
 ):
     """Per-particle viscosity switch evolution (av_switches_kern.hpp:43-137)
     with the search fused in. Returns (alpha_new (n,), occupancy).
@@ -1886,36 +1816,12 @@ def pallas_av_switches(
          c11, c12, c13, c22, c23, c33, vx, vy, vz, alpha, dt_b),
         cfg.group,
     )
-    jf = jdata or (x, y, z, c, vx, vy, vz, xm / kx, divv)
-    if lists is not None:
-        if list_walk:
-            # rsqrt + signal-velocity max make this body heavy enough
-            # for lane compaction: 62.0 vs 67.4 ms at 80^3
-            # (scripts/bench_lists.py --ve)
-            engine = group_pair_engine_lists(
-                pair_body, finalize, num_i=19, num_j=9, num_acc=4,
-                cfg=cfg, interpret=interpret, want_nc=False,
-            )
-            jp = pack_j_fields(jf, cfg.dma_cap, nf_min=10)
-            alpha_new, _nc = engine(lists, i_fields, jp, i_offset)
-            return alpha_new.reshape(-1)[:n], lists.ranges.occupancy
-        engine = group_pair_engine(
-            pair_body, finalize, num_i=19, num_j=9, num_acc=4, cfg=cfg,
-            fold=False, interpret=interpret, chunk_skip=False,
-            want_nc=False, skip_slots=lists.slot_cap,
-        )
-        jp = pack_j_fields(jf, cfg.dma_cap)
-        alpha_new, _nc = engine(lists.ranges, i_fields, jp, i_offset,
-                                skip=lists)
-        return alpha_new.reshape(-1)[:n], lists.ranges.occupancy
-    engine = group_pair_engine(
-        pair_body, finalize, num_i=19, num_j=9, num_acc=4, cfg=cfg,
-        fold=engine_fold(box, cfg), interpret=interpret, want_nc=False,
-    )
-    jp = pack_j_fields(jf, cfg.dma_cap)
-    alpha_new, _nc = engine(ranges, i_fields, jp, i_offset,
-                            aabb=_op_aabb(jf, box, cfg))
-    return alpha_new.reshape(-1)[:n], ranges.occupancy
+    (alpha_new, _nc), occ = _run_pair_op(
+        "av-switches", pair_body, finalize, i_fields,
+        jdata or (x, y, z, c, vx, vy, vz, xm / kx, divv), num_acc=4, box=box,
+        cfg=cfg, ranges=ranges, lists=lists, i_offset=i_offset,
+        interpret=interpret)
+    return alpha_new.reshape(-1)[:n], occ
 
 
 @named_phase("momentum-energy")
@@ -1950,9 +1856,6 @@ def pallas_momentum_energy_ve(
 
     if ranges is None and lists is None:
         ranges = group_cell_ranges(x, y, z, h, sorted_keys, box, cfg)
-
-    NI = 23 + (7 if av_clean else 0)
-    NJ = 23 + (6 if av_clean else 0)
 
     def pair_body(geom, i_fields, j_fields, accs):
         momx, momy, momz, energy, avisc_e, maxvs = accs
@@ -2095,21 +1998,8 @@ def pallas_momentum_energy_ve(
     i_fields = _prep_i(x, y, z, h, tuple(extra_i), cfg.group)
     sym = 3 if getattr(const, "sym_pairs", True) else None
     f = lambda a: a.reshape(-1)[:n]
-    if lists is not None:
-        engine = group_pair_engine_lists(
-            pair_body, finalize, num_i=NI, num_j=NJ, num_acc=6, cfg=cfg,
-            interpret=interpret, want_nc=False, sym_jf=sym,
-        )
-        jp = pack_j_fields(tuple(jfields), cfg.dma_cap, nf_min=NJ + 1)
-        ax, ay, az, du, dt_i, _nc = engine(lists, i_fields, jp, i_offset)
-        return (f(ax), f(ay), f(az), f(du), jnp.min(f(dt_i)),
-                lists.ranges.occupancy)
-    engine = group_pair_engine(
-        pair_body, finalize, num_i=NI, num_j=NJ, num_acc=6, cfg=cfg,
-        fold=engine_fold(box, cfg), interpret=interpret, want_nc=False,
-        sym_jf=sym,
-    )
-    jp = pack_j_fields(tuple(jfields), cfg.dma_cap)
-    ax, ay, az, du, dt_i, _nc = engine(ranges, i_fields, jp, i_offset,
-                                       aabb=_op_aabb(jfields, box, cfg))
-    return f(ax), f(ay), f(az), f(du), jnp.min(f(dt_i)), ranges.occupancy
+    (ax, ay, az, du, dt_i, _nc), occ = _run_pair_op(
+        "momentum-energy-ve", pair_body, finalize, i_fields, jfields,
+        num_acc=6, box=box, cfg=cfg, ranges=ranges, lists=lists,
+        i_offset=i_offset, interpret=interpret, sym_jf=sym)
+    return f(ax), f(ay), f(az), f(du), jnp.min(f(dt_i)), occ

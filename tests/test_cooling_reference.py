@@ -259,7 +259,7 @@ class TestSimulationDefault:
         """Schema v15. At dt 1e-10 the source is the rate itself: the
         cloud's -0.055, not the -162.6 of one ulp of u over dt."""
         _, sink = stepped
-        assert SCHEMA_VERSION == 18
+        assert SCHEMA_VERSION >= 15
         events = sink.of_kind("numerics")
         assert events and all(validate_event(e) == [] for e in events)
         for e in events:

@@ -72,8 +72,11 @@ class TestCompileCache:
         import jax
 
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        # (the tier's own per-run cache, conftest.pytest_configure, is what
+        # the config names before the call; the helper must leave it be)
+        before = jax.config.jax_compilation_cache_dir
         assert device.enable_compile_cache() is None
-        assert jax.config.jax_compilation_cache_dir is None
+        assert jax.config.jax_compilation_cache_dir == before
 
     def test_scope_names_are_part_of_the_cache_key(self):
         """Two programs that differ in a scope name alone get two keys

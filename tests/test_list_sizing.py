@@ -29,9 +29,7 @@ from sphexa_tpu.sph.kernels import h_fixed_point, update_h
 from sphexa_tpu.telemetry import Telemetry
 from sphexa_tpu.telemetry.sinks import MemorySink
 
-# noh 16^3: open box, the shift path. sedov 30^3: periodic with a real
-# grid (fold mode would reject lists). As tests/test_pair_lists.py.
-CASES = [(init_noh, 16), (init_sedov, 30)]
+from pair_list_cases import CASES  # noh 16^3, sedov 30^3: one size a case
 
 
 @pytest.mark.parametrize("nc", [40, 50, 80, 100, 123])
@@ -47,7 +45,7 @@ def test_h_fixed_point_of_a_resting_h_is_itself():
     assert h_fixed_point(0.0125, 0.0125) == pytest.approx(0.0125, rel=1e-12)
 
 
-@pytest.mark.parametrize("init,side", CASES, ids=["noh", "sedov"])
+@pytest.mark.parametrize("init,side", list(CASES.values()), ids=list(CASES))
 @pytest.mark.parametrize("grow", [1.0, 1.08], ids=["as-sized", "h+8pc"])
 def test_list_window_covers_what_the_build_inflates(init, side, grow):
     """The build searches bbox -/+ (2 h_max + skin): inside the sizing's

@@ -176,7 +176,7 @@ def test_rebuild_event_says_why(driven):
     sim, _, sink, _, _ = driven
     events = sink.of_kind("rebuild_lists")
     for e in events:
-        assert e["v"] == SCHEMA_VERSION == 18 and validate_event(e) == []
+        assert e["v"] == SCHEMA_VERSION and validate_event(e) == []
         assert e["reason"] in ("first", "proactive", "expiry", "rollback",
                                "reconfigure")
         assert 0 < e["slot_need"] <= e["slot_cap"] and e["attempts"] >= 1
@@ -302,7 +302,7 @@ def test_kicked_trajectory_matches_streaming(kicked, streamed_12, key):
 class TestSchemaV10:
     def test_v10_and_v11_add_no_kind_and_no_required_field(self):
         # (nor does v12: ``rebuild_lists.slots_live`` / ``slots_cap``)
-        assert SCHEMA_VERSION == 18
+        assert SCHEMA_VERSION >= 14
         assert not {10, 11, 12, 13, 14} & set(KIND_SINCE.values())
         assert EVENT_KINDS["rebuild_lists"] == ("it",)
         assert EVENT_KINDS["window"] == ("it", "steps", "wall_s",

@@ -49,7 +49,7 @@ class TestSnapshotSchema:
         snaps = sink.of_kind("snapshot")
         assert [e["it"] for e in snaps] == [1, 2]
         for e in snaps:
-            assert e["v"] == SCHEMA_VERSION == 18
+            assert e["v"] == SCHEMA_VERSION
             assert validate_event(e) == []
             assert e["fields"] == ["rho", "temp"] and e["grid"] == 8
             z = np.load(e["path"], allow_pickle=False)

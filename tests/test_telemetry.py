@@ -114,7 +114,7 @@ class TestRegistry:
                                     if k not in ("v", "seq", "t", "kind")})
         (sent,) = sink.events
         assert validate_event(e) == [] == validate_event(sent)
-        assert sent["v"] == 18
+        assert sent["v"] == SCHEMA_VERSION
 
     def test_console_sink_and_printer_routing(self):
         lines = []
@@ -337,7 +337,7 @@ class TestSpans:
             sp["bytes"] = 3
         after = time.perf_counter_ns()
         (e,) = sink.events
-        assert e["kind"] == "span" and e["v"] == SCHEMA_VERSION == 18
+        assert e["kind"] == "span" and e["v"] == SCHEMA_VERSION
         assert validate_event(e) == []
         assert (e["name"], e["parent"], e["it"]) == ("sphexa:x", None, 12)
         assert (e["reason"], e["bytes"]) == ("r", 3)

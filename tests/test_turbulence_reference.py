@@ -219,6 +219,10 @@ def test_a_mode_table_that_is_no_multiple_of_the_turn(turb):
 
 
 # -- the driven step family: a rolled-back window draws the same noise ------
+# (ONE driven run of 24,389 particles, interpret mode: 90-130 s, the file's
+# wall. Here, in a file of 21 tests, and not in a file of its own: xdist's
+# ``--dist loadfile`` starts the files with the most tests first, and a file
+# of one test starts last: it was the run's tail, 90 s of 663.)
 
 SIDE = 29  # the smallest periodic box whose grid takes persistent lists
 
@@ -243,17 +247,21 @@ def test_a_rolled_back_window_replays_the_same_noise_bitwise():
     """The OU phases and the PRNG key advance inside the jitted step; the
     window's pin carries the turb slot, so a rollback restores both and the
     replay must draw the noise the window drew. One window of four steps
-    from the IC, once undisturbed and once with its fetched diagnostics
-    doctored to read ``list expired``: same bits, particles and turb."""
+    from the IC whose fetched diagnostics are doctored to read ``list
+    expired``: what the four launched steps left (read as the rollback
+    starts, before it restores the pin) against what the four replayed
+    steps leave: same bits, particles and turb. (ONE driven Simulation:
+    an undisturbed twin would run the window's own program on the
+    window's own inputs, which the first pass already is.)"""
     from sphexa_tpu.telemetry.sinks import MemorySink
 
-    sink_a, sink_b = MemorySink(), MemorySink()
-    plain, rolled = _turb_sim(sink_a), _turb_sim(sink_b)
-    assert plain._use_lists and plain._aux_slot == "turb"
-    key0 = np.asarray(plain.turb_state.key)
+    sink = MemorySink()
+    sim = _turb_sim(sink)
+    assert sim._use_lists and sim._aux_slot == "turb"
+    key0 = np.asarray(sim.turb_state.key)
 
     # flush() asks once (the first bad step) and _rollback() once more
-    fresh, left = rolled._lists_fresh, [2]
+    fresh, left = sim._lists_fresh, [2]
 
     def expired_twice(diagnostics):
         if left[0]:
@@ -261,29 +269,34 @@ def test_a_rolled_back_window_replays_the_same_noise_bitwise():
             return False
         return fresh(diagnostics)
 
-    rolled._lists_fresh = expired_twice
-    for sim in (plain, rolled):
-        for _ in range(4):
-            sim.step()
-        sim.flush()
-    assert not sink_a.of_kind("rollback")
-    (rb,) = sink_b.of_kind("rollback")
+    rollback, drew = sim._rollback, []
+
+    def rollback_after_reading(*args):
+        assert sim.iteration == 4
+        drew.append((_bits(sim.turb_state), _bits(sim.state)))
+        return rollback(*args)
+
+    sim._lists_fresh = expired_twice
+    sim._rollback = rollback_after_reading
+    for _ in range(4):
+        sim.step()
+    sim.flush()
+    (rb,) = sink.of_kind("rollback")
     assert rb["reason"] == "list-expiry" and rb["steps"] == 4
     assert rb["to_it"] == 0
-    (rp,) = sink_b.of_kind("replay")
-    assert rp["steps"] == 4 and plain.iteration == rolled.iteration == 4
+    (rp,) = sink.of_kind("replay")
+    assert rp["steps"] == 4 and sim.iteration == 4
 
-    for a, b in zip(_bits(plain.turb_state), _bits(rolled.turb_state)):
+    ((turb_drawn, state_drawn),) = drew
+    for a, b in zip(turb_drawn, _bits(sim.turb_state)):
         np.testing.assert_array_equal(a, b)
-    for a, b in zip(_bits(plain.state), _bits(rolled.state)):
+    for a, b in zip(state_drawn, _bits(sim.state)):
         np.testing.assert_array_equal(a, b)
     # four steps advanced the stream, and the stirring moved the gas
-    assert not np.array_equal(np.asarray(plain.turb_state.key), key0)
-    rows = plain.drain_science()
+    assert not np.array_equal(np.asarray(sim.turb_state.key), key0)
+    rows = sim.drain_science()
     assert [r["it"] for r in rows] == [1, 2, 3, 4]
     assert rows[-1]["ecin"] > rows[0]["ecin"] > 0.0
-    assert [r["etot"] for r in rows] == [
-        r["etot"] for r in rolled.drain_science()]
 
 
 # -- the cell's files ---------------------------------------------------------
