@@ -201,7 +201,7 @@ class PropagatorConfig:
 
 
 def _sort_by_keys(state: ParticleState, box: Box, curve: str, aux=None,
-                  bins=None, resort_drift: float = 0.0):
+                  bins=None, resort_drift: float = 0.0, shards: int = 0):
     """Global SFC sort: the analog of domain.sync()'s keygen + radix sort
     (cstone/domain/assignment.hpp:84-122). Every field array is gathered
     into key order; scalars pass through untouched. ``aux``: an optional
@@ -216,6 +216,13 @@ def _sort_by_keys(state: ParticleState, box: Box, curve: str, aux=None,
     fixed resort cadence measured net-negative, the check is the new
     idea). Returns ``(state, keys, aux, resorted, inversions)``; the
     plain path keeps its 3-tuple and its lowering byte-identical.
+
+    ``shards`` > 1 (plain path, an ``aux`` carried over that many equal
+    slabs of a mesh): a fourth value, the rows whose sorted position lies
+    on another slab than the one they came from. GSPMD makes the aux
+    gather an all-gather of every slab's rows to every device; this says
+    how many of them the sort really moved (telemetry ``exchange`` stage
+    ``sort``).
     """
     # sphexa/sort: the whole keygen + argsort + permute program is one
     # attribution phase (profiler traces; util/phases.py taxonomy)
@@ -259,6 +266,12 @@ def _sort_by_keys(state: ParticleState, box: Box, curve: str, aux=None,
             # step) apart from the state's
             with stage_scope("sort", "aux"):
                 aux = permute_tree(aux, order)
+                if shards > 1:
+                    slab = n // shards
+                    home = jnp.arange(n, dtype=order.dtype) // slab
+                    migrants = jnp.sum(
+                        (order // slab != home).astype(jnp.int32))
+                    return state, sorted_keys, aux, migrants
             return state, sorted_keys, aux
 
     with phase_scope("dt-bins"):
@@ -713,7 +726,10 @@ def _force_stage_prologue(state, box, cfg: PropagatorConfig, lists, aux=None,
                           keys=None):
     """Shared head of the force stages: list mode (frozen order, validity
     diagnostics) vs per-step box regrow + global sort. Returns
-    (state, box, keys, ldiag, aux); keys is None in list mode.
+    (state, box, keys, ldiag, aux); keys is None in list mode. ``ldiag``
+    is the prologue's own diagnostics: the list's validity in list mode,
+    ``sort_migrant_rows`` where an aux state rides a mesh's sort, else
+    None.
 
     ``keys`` non-None: the caller already regrew the box and sorted (the
     blockdt builders run the bin-folded drift-aware sort themselves) —
@@ -736,6 +752,12 @@ def _force_stage_prologue(state, box, cfg: PropagatorConfig, lists, aux=None,
     # role); box limits are traced values, so this never recompiles
     with phase_scope("sort"):
         box = make_global_box(state.x, state.y, state.z, box)
+    if aux is not None and cfg.shard_axis is not None:
+        # an aux state through the mesh's global sort (std-cooling's
+        # chemistry): count what the gather redistributes
+        state, keys, aux, migrants = _sort_by_keys(
+            state, box, cfg.curve, aux=aux, shards=cfg.mesh.size)
+        return state, box, keys, {"sort_migrant_rows": migrants}, aux
     state, keys, aux = _sort_by_keys(state, box, cfg.curve, aux=aux)
     return state, box, keys, None, aux
 

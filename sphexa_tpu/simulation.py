@@ -1711,6 +1711,18 @@ class Simulation:
                 trips=int(tel.counters.get("grav_halo_trips", 0)),
                 stage="gravity", **run_fields(ginfo, arr("gshard_runs")),
             )
+        # schema-v19: the per-step global sort of a step that carries an
+        # aux state over the mesh (std-cooling's chemistry). GSPMD ships
+        # every slab's rows to every device for that gather
+        # (``shipped_rows``, per device); ``migrant_rows`` of the sorted
+        # ``rows`` really changed slab in the window's last step
+        migrants = diagnostics.get("sort_migrant_rows")
+        if migrants is not None:
+            tel.event(
+                "exchange", it=self.iteration, steps=steps, mode="gspmd",
+                shipped_rows=(P - 1) * particles[0], rows=self.state.n,
+                migrant_rows=int(migrants), stage="sort",
+            )
         # the watchdog: max/mean per metric against the configured ratio
         for metric, a in (("work", work), ("halo_rows", rows),
                           ("halo_occ", occ)):

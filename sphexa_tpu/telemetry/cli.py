@@ -273,7 +273,7 @@ def summarize_shards(run_dir: str) -> Dict:
     events, problems = load_events(run_dir)
     loads = _of_kind(events, "shard_load")
     all_ex = _of_kind(events, "exchange")
-    exchanges = [e for e in all_ex if e.get("stage", "sph") != "gravity"]
+    exchanges = [e for e in all_ex if e.get("stage", "sph") == "sph"]
     gexchanges = [e for e in all_ex if e.get("stage") == "gravity"]
     memories = _of_kind(events, "memory")
     imbalances = _of_kind(events, "imbalance")

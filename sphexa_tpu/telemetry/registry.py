@@ -96,13 +96,24 @@ import numpy as np
 #: run's copy fetches (``pallas_pairs.list_run_rows``: a run is a tile of
 #: at most that many chunks). ``chunks_live / (runs_live x run_rows)`` is
 #: the share of the rows a pass fetches that a lane is taken from. No
-#: kind, no REQUIRED field: v18 readers accept v1-v17 files.
-SCHEMA_VERSION = 18
+#: kind, no REQUIRED field: v18 readers accept v1-v17 files;
+#: v19 the mesh's sort of an aux state: ``exchange`` gains the stage
+#: ``"sort"`` beside ``"sph"`` and ``"gravity"``, emitted where a step
+#: carries a per-particle aux pytree (std-cooling's chemistry) through
+#: the global SFC sort on a mesh. There ``rows`` is the number of rows
+#: sorted (an int, not a per-shard list), ``shipped_rows`` what each
+#: device receives for the gather GSPMD makes of it ((P - 1) slabs), and
+#: the optional ``migrant_rows`` the rows whose sorted position lies on
+#: another slab than they came from, in the window's last step:
+#: ``migrant_rows / rows`` is the share of that exchange that is real
+#: redistribution. No kind, no REQUIRED field: v19 readers accept v1-v18
+#: files.
+SCHEMA_VERSION = 19
 
 #: event schema versions this reader understands (older versions only
 #: ever ADD kinds, so the per-kind field table below covers them all)
 SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15,
-                      16, 17, 18)
+                      16, 17, 18, 19)
 
 #: every event kind the schema admits, with its required payload fields
 #: (beyond the envelope ``v``/``seq``/``t``/``kind``). The CLI's --strict
@@ -131,7 +142,9 @@ EVENT_KINDS: Dict[str, tuple] = {
     # per-window halo-exchange record: ``rows`` = per-shard TRUE candidate
     # need (device-measured), ``shipped_rows`` = the static sized volume
     # actually moved per serve (sum(hmax) sparse / (P-1)*Wmax windowed);
-    # since v14 with the optional ``run_slots`` / ``live_runs_max``
+    # since v14 with the optional ``run_slots`` / ``live_runs_max``;
+    # since v19 also stage "sort": ``rows`` the rows sorted (an int),
+    # with the optional ``migrant_rows``
     "exchange": ("it", "shipped_rows", "rows"),
     # per-window load record: per-shard particle counts + work proxies
     "shard_load": ("it", "particles"),

@@ -111,7 +111,9 @@ class TestCellFiles:
         rates = next(m for m in bench["end_to_end"]
                      if m["name"] == "updates_per_s_chip")
         assert CELL in rates["workloads"]
-        assert sum(w["chips"] == 4 for w in bench["workloads"]) == 2
+        # at most half of a benchmark's cells may ask for four chips
+        assert (2 * sum(w["chips"] == 4 for w in bench["workloads"])
+                <= len(bench["workloads"]))
 
     def test_guarantees_state_every_limit_with_its_reason(self, config):
         g = config["guarantees"]

@@ -98,7 +98,7 @@ class TestRegistry:
         from sphexa_tpu.telemetry.registry import (
             EVENT_KINDS, KIND_SINCE, SUPPORTED_VERSIONS)
 
-        assert SCHEMA_VERSION == 18 == SUPPORTED_VERSIONS[-1]
+        assert SCHEMA_VERSION == 19 == SUPPORTED_VERSIONS[-1]
         assert EVENT_KINDS["rebuild_lists"] == ("it",)
         assert 18 not in KIND_SINCE.values()
         e = {"v": v, "seq": 0, "t": 1.0, "kind": "rebuild_lists", "it": 10,
@@ -112,6 +112,36 @@ class TestRegistry:
         t = Telemetry(sinks=[sink])
         t.event("rebuild_lists", **{k: val for k, val in e.items()
                                     if k not in ("v", "seq", "t", "kind")})
+        (sent,) = sink.events
+        assert validate_event(e) == [] == validate_event(sent)
+        assert sent["v"] == SCHEMA_VERSION
+
+    @pytest.mark.parametrize("v", [14, 18, SCHEMA_VERSION])
+    def test_exchange_sort_stage_fields(self, v):
+        """Schema v19: ``exchange`` of stage ``sort`` (the mesh's global
+        sort of a step's aux state) carries ``rows`` as the number of rows
+        sorted and the optional ``migrant_rows``; no kind and no required
+        field came, so a v14 and a v18 writer's exchange events stay
+        clean."""
+        from sphexa_tpu.telemetry.registry import (
+            EVENT_KINDS, KIND_SINCE, SUPPORTED_VERSIONS)
+
+        assert SUPPORTED_VERSIONS == tuple(range(1, SCHEMA_VERSION + 1))
+        assert EVENT_KINDS["exchange"] == ("it", "shipped_rows", "rows")
+        assert 19 not in KIND_SINCE.values()
+        e = {"v": v, "seq": 0, "t": 1.0, "kind": "exchange", "it": 8,
+             "steps": 4, "mode": "sparse", "shipped_rows": 417280,
+             "rows": [90210, 117004, 117311, 90077], "stage": "sph",
+             "run_slots": 48, "live_runs_max": 34}
+        if v >= 19:
+            e.update(mode="gspmd", stage="sort", shipped_rows=3141807,
+                     rows=4189076, migrant_rows=212)
+            for k in ("run_slots", "live_runs_max"):
+                del e[k]
+        sink = MemorySink()
+        t = Telemetry(sinks=[sink])
+        t.event("exchange", **{k: val for k, val in e.items()
+                               if k not in ("v", "seq", "t", "kind")})
         (sent,) = sink.events
         assert validate_event(e) == [] == validate_event(sent)
         assert sent["v"] == SCHEMA_VERSION
@@ -1115,6 +1145,11 @@ class TestCli:
                     shipped_rows=2864, rows=[1000 + it, 900],
                     occ=[0.95, 0.7], bytes_per_step=2864 * 5 * 4,
                     trips=1, stage="gravity")
+            # schema v19: the sort's record of a step with an aux state
+            # (``rows`` an int) belongs to neither stage's aggregate
+            t.event("exchange", it=it, steps=3, mode="gspmd",
+                    shipped_rows=256, rows=512, migrant_rows=3,
+                    stage="sort")
         t.close()
         write_manifest(str(d), particles=512, mesh_shape=(2,))
         assert cli_main(["shards", str(d)]) == 0
