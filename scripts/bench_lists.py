@@ -1,5 +1,7 @@
 """Measure the list-walk engine vs the streaming engine per op on real
-TPU hardware (Sedov 100^3 by default) plus the list-build cost.
+TPU hardware (Sedov 100^3 by default; ``--init noh -n 128`` and
+``--init wind-shock -n 100`` build the other list cells' geometries)
+plus the list-build cost.
 
 Timing follows the rules in docs/NEXT.md: chain a data dependency across
 repeats and discard the first post-compile batch.
@@ -16,7 +18,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from sphexa_tpu.init import init_sedov
+from sphexa_tpu.init import make_initializer
 from sphexa_tpu.propagator import _sort_by_keys
 from sphexa_tpu.simulation import make_propagator_config
 from sphexa_tpu.sph import pallas_pairs as pp
@@ -45,6 +47,8 @@ def timed(fn, *args, reps=10, **kw):
 
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--init", default="sedov",
+                    choices=["sedov", "noh", "wind-shock"])
     ap.add_argument("-n", type=int, default=100)
     ap.add_argument("--skin-rel", type=float, default=0.2,
                     help="skin as a fraction of 2*h_max")
@@ -52,7 +56,7 @@ def main():
                     help="also measure the VE ops, lists vs streamed")
     args = ap.parse_args()
 
-    state, box, const = init_sedov(args.n)
+    state, box, const = make_initializer(args.init)(args.n)
     cfg = make_propagator_config(state, box, const, backend="pallas")
     nbr = cfg.nbr
     print(f"N={state.n}  level={nbr.level} cap={nbr.cap} "
@@ -72,6 +76,12 @@ def main():
     assert int(lists.overflow) == 0
     lanes = float(lists.lanes_total) / state.n
     print(f"list build: {t_build*1e3:7.1f} ms   lanes/target={lanes:.0f}")
+    # what the walk's compaction is worth: of the lanes of the chunks a
+    # pass visits, the share it stages (and does the pair math on)
+    kept = int(lists.chunks_live)
+    print(f"groups={lists.cnt.shape[0]}  kept chunks/group="
+          f"{kept / lists.cnt.shape[0]:.1f}  lanes kept per chunk lane="
+          f"{float(lists.lanes_total) / (128.0 * kept):.3f}")
 
     t_rng, ranges = timed(
         jax.jit(lambda *a: pp.group_cell_ranges(*a, box, nbr)),
@@ -121,16 +131,29 @@ def main():
     if not args.ve:
         return
 
-    # ---- VE ops on the kernels pallas_pairs.PAIR_OP_ENGINE names (a bench
-    # of an op on the other list kernel patches its row there)
+    # ---- VE ops: on lists the walk, streamed with the cull their
+    # pallas_pairs.PAIR_OP_ENGINE row names
     from sphexa_tpu.sph.hydro_ve import compute_eos_ve
 
-    t_xm, (xm, _, _) = timed(
-        jax.jit(lambda ls, *a: pp.pallas_xmass(*a, None, box, const, nbr,
-                                               lists=ls)),
-        lists, x, y, z, h, m)
-    (kx, gradh), _ = pp.pallas_ve_def_gradh(x, y, z, h, m, xm, None, box,
-                                            const, nbr, lists=lists)
+    f_s = jax.jit(lambda rng, *a: pp.pallas_xmass(*a, box, const, nbr,
+                                                  ranges=rng))
+    f_l = jax.jit(lambda ls, *a: pp.pallas_xmass(*a, box, const, nbr,
+                                                 lists=ls))
+    t0, (xm0, _, _) = timed(f_s, ranges, x, y, z, h, m, keys)
+    t1, (xm, _, _) = timed(f_l, lists, x, y, z, h, m, None)
+    dd = float(jnp.max(jnp.abs(xm0 - xm) / xm0))
+    print(f"xmass     : stream {t0*1e3:7.1f} ms  lists {t1*1e3:7.1f} ms  "
+          f"x{t0/t1:.2f}  dxm={dd:.2e}")
+    f_s = jax.jit(lambda rng, *a: pp.pallas_ve_def_gradh(
+        *a, box, const, nbr, ranges=rng))
+    f_l = jax.jit(lambda ls, *a: pp.pallas_ve_def_gradh(
+        *a, box, const, nbr, lists=ls))
+    t0, ((kx0, gh0), _) = timed(f_s, ranges, x, y, z, h, m, xm, keys)
+    t1, ((kx, gradh), _) = timed(f_l, lists, x, y, z, h, m, xm, None)
+    dd = float(jnp.max(jnp.abs(kx0 - kx) / kx0))
+    dg = float(jnp.max(jnp.abs(gh0 - gradh)))
+    print(f"gradh     : stream {t0*1e3:7.1f} ms  lists {t1*1e3:7.1f} ms  "
+          f"x{t0/t1:.2f}  dkx={dd:.2e} dgradh={dg:.2e}")
     prho, cve, rhove, pve = compute_eos_ve(ss.temp, m, kx, xm, gradh, const)
     # the fused IAD + divv/curlv op (one pass where there were two): on
     # lists, and the streamed engine the Evrard cells run; under a

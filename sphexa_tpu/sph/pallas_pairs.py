@@ -482,20 +482,20 @@ LIST_ROW_TILE = 8
 
 #: a RUN of the persistent lists is a tile of at most this many 128-lane
 #: chunks: the build cuts the pruned runs to it (pair_lists.
-#: _prune_empty_chunks) and the two list kernels below fetch exactly that
+#: _prune_empty_chunks) and the list kernel below fetches exactly that
 #: many rows a run, whatever the un-pruned runs' width (``_dma_rows``: 13
 #: at run_cap 1536, for the 3.2 chunks a pruned run kept). Chosen on the
 #: chip (PERF.md, PR 40), one value for every list op and configuration
 LIST_RUN_ROWS = 4
 
-#: tiles in the list kernels' ring: LIST_RING - 1 runs' copies in flight
+#: tiles in the list kernel's ring: LIST_RING - 1 runs' copies in flight
 #: while one is walked
 LIST_RING = 4
 
 
 def list_run_rows(cfg: NeighborConfig) -> int:
     """Rows a run of the persistent lists streams at most = rows the list
-    kernels fetch a run: never more than ``pack_j_fields``' tail pad."""
+    kernel fetches a run: never more than ``pack_j_fields``' tail pad."""
     return min(LIST_RUN_ROWS, _dma_rows(cfg.dma_cap))
 
 
@@ -586,7 +586,6 @@ def group_pair_engine(
     chunk_skip: Optional[bool] = None,
     want_nc: bool = True,
     sym_jf: Optional[int] = None,
-    skip_slots: int = 0,
 ):
     """Build a pallas_call for one SPH pair op.
 
@@ -615,12 +614,6 @@ def group_pair_engine(
     - ``want_nc``: accumulate per-target neighbor counts (the trailing
       output). Ops that ignore the counts pass False and save the
       count's read-modify-write in every chunk.
-    - ``skip_slots``: when > 0, the call takes a PairLists whose per-chunk
-      counts (sph/pair_lists.py mark bits) gate each chunk's math — the
-      AABB chunk-cull for free (no AABB table, no in-kernel bbox math),
-      available to every op while lists are valid. Excludes
-      ``chunk_skip``. The lists' runs are tiles: a copy is
-      ``list_run_rows`` rows into a ring of ``LIST_RING`` buffers.
     - returns fn(ranges, i_fields(NG,G) x num_i, j_packed, i_offset,
       allow_self) -> (outs (NG, G) x num_out, nc (NG, G)); ``allow_self``
       (traced bool) admits the self-index pair — replica-image passes of
@@ -629,10 +622,6 @@ def group_pair_engine(
     R = _dma_rows(cfg.dma_cap)
     RING = 2                 # run buffers: one walked, the rest in flight
     nf_pad = _round_up(num_j, 8)
-    SKIP = skip_slots > 0
-    if SKIP:
-        chunk_skip = False
-        R, RING = list_run_rows(cfg), LIST_RING
     if chunk_skip is None:
         # bitmask bits live in one int32, so the DMA window must fit 31
         # chunks; beyond that (huge run_cap) the cull is simply skipped
@@ -645,12 +634,10 @@ def group_pair_engine(
 
     def kernel(*refs):
         starts, lens, shx_r, shy_r, shz_r, ncells, boxl, ioff, aself = refs[:9]
-        base = 10 if SKIP else 9
-        cnt_r = refs[9] if SKIP else None
-        i_refs = refs[base : base + num_i]
-        jref = refs[base + num_i]
-        nj_in = base + 2 + num_i if chunk_skip else base + 1 + num_i
-        aabb_ref = refs[base + 1 + num_i] if chunk_skip else None
+        i_refs = refs[9 : 9 + num_i]
+        jref = refs[9 + num_i]
+        nj_in = 11 + num_i if chunk_skip else 10 + num_i
+        aabb_ref = refs[10 + num_i] if chunk_skip else None
         out_refs = refs[nj_in : -2]
         nc_ref = refs[-2]
         (buf, sems, acc_refs, ncacc_ref, abuf, asems) = refs[-1]
@@ -781,19 +768,6 @@ def group_pair_engine(
                 if want_nc:
                     ncacc_ref[...] = ncacc_ref[...] + mask.astype(jnp.int32)
 
-            if SKIP:
-                # a run of the lists is a tile of at most R chunks: a
-                # static unroll. Persistent-list mark bits: a chunk with
-                # no lane in the group's inflated bbox skips its math
-                # for one SMEM test (the AABB cull with zero DMA cost)
-                for t in range(R):
-                    @pl.when((t < nch) & (cnt_r[0, 0, jnp.minimum(
-                        carry + t, skip_slots - 1)] > 0))
-                    def _():
-                        chunk_math(t)
-
-                return carry + nch
-
             def chunk_body(t, carry2):
                 if not chunk_skip:
                     chunk_math(t)
@@ -836,11 +810,9 @@ def group_pair_engine(
             kernel(*refs[:-ns], (buf, sems, acc_refs, refs[-1], None, None))
 
     def call(ranges: GroupRanges, i_fields: Sequence, j_packed,
-             i_offset=0, allow_self=False, aabb=None, skip=None):
+             i_offset=0, allow_self=False, aabb=None):
         if chunk_skip and aabb is None:
             raise ValueError("chunk_skip engine needs the chunk AABB table")
-        if SKIP and skip is None:
-            raise ValueError("skip_slots engine needs the PairLists")
         num_groups = ranges.num_groups
         # run-slot width comes from the ranges themselves: the sharded
         # path appends boundary-split slots beyond the window block
@@ -884,7 +856,6 @@ def group_pair_engine(
                 pl.BlockSpec((1, 1, 1), lambda g: (0, 0, 0),
                              memory_space=pltpu.SMEM),  # allow_self
             ]
-            + ([smem_spec((1, 1, skip_slots))] if SKIP else [])  # cnt
             + [
                 pl.BlockSpec((1, 1, G), lambda g: (g, 0, 0))
                 for _ in range(num_i)
@@ -914,8 +885,6 @@ def group_pair_engine(
         ] + [jax.ShapeDtypeStruct((num_groups, 1, G), jnp.int32)]
         args = (
             (starts, lens, shx, shy, shz, ncells, boxl, ioff, aself)
-            + ((skip.cnt.reshape(num_groups, 1, skip_slots),)
-               if SKIP else ())
             + (*i_fields, j_packed)
             + ((aabb,) if chunk_skip else ())
         )
@@ -1243,28 +1212,24 @@ def _op_aabb(jfields: Sequence, box: Box, cfg: NeighborConfig):
 
 
 #: THE place where a pair op's kernel is chosen: op -> (kernel on the
-#: persistent lists, AABB chunk cull when streamed). "skip" is
-#: ``group_pair_engine(skip_slots=)``: whole kept chunks, the build's mark
-#: bits gating each; "walk" is ``group_pair_engine_lists``: the kept lanes
-#: compacted into full staging chunks. No argument, flag or environment
-#: variable overrides a row; a by-hand bench that wants an op on the other
-#: kernel patches its row. Moving the skip ops onto the walk (ROADMAP S1 a)
-#: changes rows, not functions.
+#: persistent lists, AABB chunk cull when streamed). "walk" is
+#: ``group_pair_engine_lists``, the one list kernel there is: the kept
+#: lanes compacted into full staging chunks. No argument, flag or
+#: environment variable overrides a row; a by-hand bench patches it.
 PAIR_OP_ENGINE = {
-    # cheap body: the mark-bit chunk skip beats in-kernel compaction
-    # (compaction's src-side take_along exceeds the ~10-op body); streamed,
-    # the chunk cull lost on the cheap ops (ROADMAP, dead ends on record)
-    "density": ("skip", False),  # pallas_xmass rides it
-    "iad": ("skip", False),
-    "gradh": ("skip", False),
+    # one staged sublane tile (4-5 j-fields + the index row): math on the
+    # 10 staged chunks a group beats math on its 34 whole kept ones. Walk
+    # against the mark-bit chunk skip PR 43 deleted, ms a pass on a v5e at
+    # Sedov 160^3 / wind-shock -n 100 / Noh 1.1M: density 261.6 / 307.2 /
+    # 59.1 against 311.0 / 360.7 / 66.8, iad 296.8 / 350.8 / 68.5 against
+    # 396.2 / 458.7 / 86.6, gradh 298.3 / 350.4 / 68.3 against 400.5 /
+    # 464.4 / 86.7 (PERF.md section 5). Streamed, the chunk cull lost on
+    # the cheap ops (ROADMAP, dead ends on record)
+    "density": ("walk", False),  # pallas_xmass rides it
+    "iad": ("walk", False),
+    "gradh": ("walk", False),
     "momentum-energy-std": ("walk", True),
-    # 15 read-modify-write accumulators a visit weigh more than the ninth
-    # staged row's second sublane tile: 358.2 ms walk against 530.3 skip
-    # at Sedov 160^3 on a v5e (scripts/bench_lists.py --ve -n 160, PR 40's
-    # tree; the ledger's sedov-ve-4m level 1.157 is PR 32's, on the walk)
     "divv-curlv": ("walk", True),
-    # rsqrt + signal-velocity max make this body heavy enough for lane
-    # compaction: 364.4 against 510.5 ms (same run)
     "av-switches": ("walk", True),
     "momentum-energy-ve": ("walk", True),
 }
@@ -1292,18 +1257,12 @@ def _run_pair_op(op: str, pair_body: Callable, finalize: Callable,
                       i_offset,
                       aabb=_op_aabb(jfields, box, cfg) if cull else None)
         return outs, ranges.occupancy
-    if on_lists == "walk":
-        engine = group_pair_engine_lists(pair_body, finalize, **dims)
-        outs = engine(
-            lists, i_fields,
-            pack_j_fields(jfields, cfg.dma_cap, nf_min=num_j + 1), i_offset)
-    else:
-        engine = group_pair_engine(
-            pair_body, finalize, chunk_skip=False,
-            skip_slots=lists.slot_cap, **dims)
-        outs = engine(lists.ranges, i_fields,
-                      pack_j_fields(jfields, cfg.dma_cap), i_offset,
-                      skip=lists)
+    if on_lists != "walk":
+        raise ValueError(f"{op}: no list kernel {on_lists!r}")
+    engine = group_pair_engine_lists(pair_body, finalize, **dims)
+    outs = engine(
+        lists, i_fields,
+        pack_j_fields(jfields, cfg.dma_cap, nf_min=num_j + 1), i_offset)
     return outs, lists.ranges.occupancy
 
 

@@ -83,12 +83,23 @@ def test_build_structure(case, built):
     np.testing.assert_array_equal(np.asarray(lists.tail), csum[:, -1] % 128)
 
 
-def test_density_lists_match_streaming(case, built):
+@pytest.fixture(scope="module")
+def streamed_std(case):
+    """(rho, nc, c11..c33) of the streamed engine: what the walk's cheap
+    ops are held to."""
+    ss, keys, box, const, nbr = case
+    x, y, z, h, m = ss.x, ss.y, ss.z, ss.h, ss.m
+    rho, nc, _ = pp.pallas_density(x, y, z, h, m, keys, box, const, nbr,
+                                   interpret=True)
+    cs, _ = pp.pallas_iad(x, y, z, h, m / rho, keys, box, const, nbr,
+                          interpret=True)
+    return rho, nc, cs
+
+
+def test_density_lists_match_streaming(case, built, streamed_std):
     ss, keys, box, const, nbr = case
     lists, _, _ = built
-    rho0, nc0, _ = pp.pallas_density(
-        ss.x, ss.y, ss.z, ss.h, ss.m, keys, box, const, nbr, interpret=True
-    )
+    rho0, nc0, _ = streamed_std
     rho1, nc1, _ = pp.pallas_density(
         ss.x, ss.y, ss.z, ss.h, ss.m, None, box, const, nbr,
         interpret=True, lists=lists,
@@ -98,29 +109,167 @@ def test_density_lists_match_streaming(case, built):
                                rtol=2e-6)
 
 
-def test_momentum_std_lists_match_streaming(case, built):
+def _tensor_scale(cs):
+    """Off-diagonal components of C are ~0 on near-uniform lattices (pure
+    cancellation noise), so an atol scales with the TENSOR magnitude."""
+    return max(float(np.abs(np.asarray(b)).max()) for b in cs)
+
+
+def _iad_on(case, lists, rho):
+    ss, keys, box, const, nbr = case
+    return pp.pallas_iad(ss.x, ss.y, ss.z, ss.h, ss.m / rho, None, box,
+                         const, nbr, interpret=True, lists=lists)[0]
+
+
+@pytest.fixture(scope="module")
+def iad_lists(case, built, streamed_std):
+    return _iad_on(case, built[0], streamed_std[0])
+
+
+IAD_C = ["c11", "c12", "c13", "c22", "c23", "c33"]
+
+
+@pytest.mark.parametrize("comp", range(6), ids=IAD_C)
+def test_iad_lists_match_streaming(streamed_std, iad_lists, comp):
+    """Each component of ``pallas_iad``'s C on the walk against the
+    streamed engine."""
+    cs = streamed_std[2]
+    np.testing.assert_allclose(np.asarray(iad_lists[comp]),
+                               np.asarray(cs[comp]), rtol=2e-5,
+                               atol=1e-6 * _tensor_scale(cs))
+
+
+# -- the walk's flush, on groups the cases' own lists do not hold: a group
+# that keeps no chunk, one whose kept lanes end exactly on a staged chunk
+# (``tail`` 0 after an emit) and one a lane past it. The marks of three
+# groups are edited on the host and the staging bookkeeping redone from them
+
+EDGES = {"no-chunk": 1, "ends-on-a-chunk": 2, "one-lane-past": 3}
+
+
+def _spare_lanes(case, lists, bits, cands, runs, g):
+    """Marked lanes of group ``g`` that no target of the group reaches
+    (inside the skin-inflated bbox, outside every 2 h_i sphere): taking
+    one off the list drops no pair. ``(slot_cap, 128)`` bool."""
+    ss, _, _, _, nbr = case
+    G, n = nbr.group, ss.x.shape[0]
+    ii = np.minimum(np.arange(g * G, (g + 1) * G), n - 1)
+    rg = lists.ranges
+    d2 = np.zeros((G,) + bits[g].shape, np.float64)
+    for a, sh in zip((ss.x, ss.y, ss.z),
+                     (rg.shift_x, rg.shift_y, rg.shift_z)):
+        a = np.asarray(a, np.float64)
+        j = a[np.minimum(cands[g], n - 1)] + np.asarray(sh)[g][runs[g]][
+            :, None]
+        d2 += (a[ii][:, None, None] - j[None]) ** 2
+    h2 = 4.0 * np.asarray(ss.h, np.float64)[ii] ** 2
+    reached = (d2 < 1.0001 * h2[:, None, None]).any(0)
+    return (bits[g] > 0) & ~reached
+
+
+@pytest.fixture(scope="module")
+def edge_lists(case, built):
+    """The case's lists with group ``EDGES["no-chunk"]`` keeping
+    nothing, the next group's spare lanes thinned until its kept lanes are
+    a whole number of staged chunks, the third's until one lane more. Every
+    kept chunk keeps a lane, so runs and segments stand."""
+    ss, keys, box, const, nbr = case
+    lists, skin, _ = built
+    bits, cands, runs = _mark_bits(ss, nbr, lists, skin)
+    np.testing.assert_array_equal(bits.sum(-1), np.asarray(lists.cnt))
+    for g, past in ((EDGES["ends-on-a-chunk"], 0),
+                    (EDGES["one-lane-past"], 1)):
+        spare = _spare_lanes(case, lists, bits, cands, runs, g)
+        drop = (bits[g].sum() - past) % 128
+        for k, l in zip(*np.nonzero(spare)):
+            if drop and bits[g, k].sum() > 1:
+                bits[g, k, l] = 0
+                drop -= 1
+        assert drop == 0, "too few spare lanes"
+    none = EDGES["no-chunk"]
+    bits[none] = 0
+    rot, cnt, fill = _dense_table(bits)
+    gidx, seg = np.array(lists.gidx), np.asarray(lists.seg)
+    for g in EDGES.values():
+        kept = int((cnt[g] > 0).sum())
+        gidx[8 * seg[g]:8 * seg[g] + kept] = rot[g, :kept]
+    ranges = lists.ranges._replace(
+        ncells=lists.ranges.ncells.at[none].set(0))
+    return lists._replace(
+        ranges=ranges, gidx=jnp.asarray(gidx), cnt=jnp.asarray(cnt),
+        fill=jnp.asarray(fill),
+        emit=jnp.asarray((fill + cnt >= 128).astype(np.int32)),
+        tail=jnp.asarray(cnt.sum(1) % 128))
+
+
+@pytest.fixture(scope="module")
+def edge_outputs(case, built, edge_lists, streamed_std):
+    """density and iad on the walk over the edited and the case's own
+    lists."""
+    ss, keys, box, const, nbr = case
+    out = []
+    for ls in (edge_lists, built[0]):
+        rho, nc, _ = pp.pallas_density(
+            ss.x, ss.y, ss.z, ss.h, ss.m, None, box, const, nbr,
+            interpret=True, lists=ls)
+        out.append((rho, nc) + tuple(_iad_on(case, ls, streamed_std[0])))
+    return [[np.asarray(a) for a in o] for o in out]
+
+
+@pytest.mark.parametrize("edge", list(EDGES))
+def test_walk_flush_on_edge_groups(case, edge_lists, edge_outputs,
+                                   streamed_std, edge):
+    ss, keys, box, const, nbr = case
+    g, G = EDGES[edge], nbr.group
+    rows = slice(g * G, (g + 1) * G)
+    cnt, emit = np.asarray(edge_lists.cnt)[g], np.asarray(edge_lists.emit)[g]
+    kept = int((cnt > 0).sum())
+    tail = int(edge_lists.tail[g])
+    got, own = edge_outputs
+    rho0, nc0, cs0 = streamed_std
+    if edge == "no-chunk":
+        assert kept == 0 and tail == 0
+        assert int(edge_lists.ranges.ncells[g]) == 0
+        # nothing walked, nothing flushed: the self term alone
+        np.testing.assert_array_equal(got[1][rows], 0)
+        np.testing.assert_allclose(
+            got[0][rows], np.asarray(const.K * ss.m / ss.h**3)[rows],
+            rtol=1e-6)
+    else:
+        assert kept > 0 and cnt.sum() > 128
+        if edge == "ends-on-a-chunk":
+            # the last kept chunk completes a staged chunk: no flush
+            assert tail == 0 and emit[kept - 1] == 1
+        else:
+            assert tail == 1
+        np.testing.assert_array_equal(got[1][rows], np.asarray(nc0)[rows])
+        np.testing.assert_allclose(got[0][rows], np.asarray(rho0)[rows],
+                                   rtol=2e-6)
+        for a, b in zip(got[2:], cs0):
+            np.testing.assert_allclose(a[rows], np.asarray(b)[rows],
+                                       rtol=2e-5,
+                                       atol=1e-6 * _tensor_scale(cs0))
+    # the groups whose marks were not edited: the same rows in the same
+    # order, bit for bit
+    rest = np.ones(got[0].shape[0], bool)
+    for e in EDGES.values():
+        rest[e * G:(e + 1) * G] = False
+    for a, b in zip(got, own):
+        np.testing.assert_array_equal(a[rest], b[rest])
+
+
+def test_momentum_std_lists_match_streaming(case, built, streamed_std):
     ss, keys, box, const, nbr = case
     lists, _, _ = built
     x, y, z, h, m = ss.x, ss.y, ss.z, ss.h, ss.m
-    rho, _, _ = pp.pallas_density(x, y, z, h, m, keys, box, const, nbr,
-                                  interpret=True)
+    rho, _, cs = streamed_std
     from sphexa_tpu.sph.hydro_std import compute_eos_std
 
     p, c = compute_eos_std(ss.temp, rho, const)
-    cs, _ = pp.pallas_iad(x, y, z, h, m / rho, keys, box, const, nbr,
-                          interpret=True)
     args = (x, y, z, ss.vx, ss.vy, ss.vz, h, m, rho, p, c, *cs)
     ax0, ay0, az0, du0, dt0, _ = pp.pallas_momentum_energy_std(
         *args, keys, box, const, nbr, interpret=True
     )
-    cs1, _ = pp.pallas_iad(x, y, z, h, m / rho, None, box, const, nbr,
-                           interpret=True, lists=lists)
-    # off-diagonal components are ~0 on near-uniform lattices (pure
-    # cancellation noise), so the atol scales with the TENSOR magnitude
-    csc = max(float(np.abs(np.asarray(b)).max()) for b in cs)
-    for a, b in zip(cs1, cs):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-5, atol=1e-6 * csc)
     ax1, ay1, az1, du1, dt1, _ = pp.pallas_momentum_energy_std(
         *args, None, box, const, nbr, interpret=True, lists=lists
     )
@@ -177,7 +326,7 @@ def test_momentum_ve_lists_match_streaming(case, built):
                                rtol=1e-4, atol=1e-5 * sc)
     # the fused op's C is pallas_iad's: the same moments in the same
     # order, the same inverse (to the bit on one engine)
-    csc = max(float(np.abs(np.asarray(b)).max()) for b in cs)
+    csc = _tensor_scale(cs)
     for a0, a1, b in zip(cs0, cs1, cs):
         np.testing.assert_array_equal(np.asarray(a0), np.asarray(b))
         np.testing.assert_allclose(np.asarray(a1), np.asarray(b),
@@ -324,10 +473,10 @@ def test_slot_cap_overflow_sentinel(case):
 # contiguous from an 8-row tile boundary, sized by the sum over groups ------
 
 
-def _dense_table(ss, nbr, lists, skin):
-    """The dense ``(groups, slot_cap, 128)`` table the flat one replaced,
-    in numpy: the mark test redone over the list's (pruned) runs, then the
-    old post-passes verbatim (cnt, fill, cumsum, dst, 128-wide sort)."""
+def _mark_bits(ss, nbr, lists, skin):
+    """``(bits, cand, run)``: ``(groups, slot_cap, 128)`` 0/1, the build's
+    mark test redone in numpy over the list's (pruned) runs; each lane's
+    sorted-array index; ``(groups, slot_cap)`` the run a slot came from."""
     f32 = np.float32
     x, y, z, h = (np.asarray(a, f32) for a in (ss.x, ss.y, ss.z, ss.h))
     n, G = x.shape[0], nbr.group
@@ -338,6 +487,8 @@ def _dense_table(ss, nbr, lists, skin):
               (rg.shift_x, rg.shift_y, rg.shift_z)]
     ng, scap = np.asarray(lists.cnt).shape
     bits = np.zeros((ng, scap, 128), np.int32)
+    cands = np.zeros((ng, scap, 128), np.int32)
+    runs = np.zeros((ng, scap), np.int32)
     pad = lambda a: np.concatenate([a, np.zeros(128 + nbr.dma_cap, f32)])
     xp, yp, zp = pad(x), pad(y), pad(z)
     lane = np.arange(128)
@@ -357,8 +508,16 @@ def _dense_table(ss, nbr, lists, skin):
                     j = jp[cand] + sh[g, w]
                     m &= (j >= a) & (j <= b)
                 if slot < scap:
-                    bits[g, slot] = m
+                    bits[g, slot], cands[g, slot], runs[g, slot] = m, cand, w
                 slot += 1
+    return bits, cands, runs
+
+
+def _dense_table(bits):
+    """The dense ``(groups, slot_cap, 128)`` table the flat one replaced,
+    from the mark ``bits``: the old post-passes verbatim (cnt, fill,
+    cumsum, dst, 128-wide sort)."""
+    lane = np.arange(128)
     cnt = bits.sum(-1)
     csum = np.cumsum(cnt, axis=1)
     fill = (csum - cnt) % 128
@@ -370,7 +529,7 @@ def _dense_table(ss, nbr, lists, skin):
 
 
 def _assert_flat_is_dense(ss, nbr, lists, skin):
-    rot, cnt, fill = _dense_table(ss, nbr, lists, skin)
+    rot, cnt, fill = _dense_table(_mark_bits(ss, nbr, lists, skin)[0])
     np.testing.assert_array_equal(np.asarray(lists.cnt), cnt)
     np.testing.assert_array_equal(np.asarray(lists.fill), fill)
     gidx, seg = np.asarray(lists.gidx), np.asarray(lists.seg)

@@ -4,9 +4,9 @@ tests/test_pair_list_tiles_sedov.py (``sedov``) each run under their own
 ``CASE`` (not collected itself; one file held both until PR 41).
 
 The build cuts the pruned runs to ``LIST_RUN_ROWS`` chunks
-(pair_lists._prune_empty_chunks) and the two list kernels (the walk, and
-the skip form of the streamed engine) fetch exactly that many rows a run
-through a ring of ``LIST_RING`` tiles. The parent's shape is the same code
+(pair_lists._prune_empty_chunks) and the list kernel (the walk; every
+list op since PR 43) fetches exactly that many rows a run through a ring
+of ``LIST_RING`` tiles. The parent's shape is the same code
 at 13 rows (the un-cut runs' width at these sizes) and a ring of two:
 everything here is held BITWISE to it, INTERPRET mode. (The prune against
 plain loops, at several tiles:

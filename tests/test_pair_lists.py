@@ -86,8 +86,8 @@ def test_simulation_list_rebuild_on_expiry():
 
 #: today's rows of pallas_pairs.PAIR_OP_ENGINE: (kernel on lists, AABB cull
 #: when streamed). A PR that moves an op edits its row there and here.
-ENGINE_ROWS = [("density", "skip", False), ("iad", "skip", False),
-               ("gradh", "skip", False),
+ENGINE_ROWS = [("density", "walk", False), ("iad", "walk", False),
+               ("gradh", "walk", False),
                ("momentum-energy-std", "walk", True),
                ("divv-curlv", "walk", True), ("av-switches", "walk", True),
                ("momentum-energy-ve", "walk", True)]
@@ -126,10 +126,12 @@ def tiny():
 def test_the_table_names_the_kernel_each_op_builds(tiny, op, on_lists, cull,
                                                    monkeypatch):
     """pallas_pairs.PAIR_OP_ENGINE is the one place the choice lives: with
-    ``lists`` an op builds the kernel its row names (the walk packs
-    ``num_j + 1`` rows: the staged index; skip ``num_j``), without them the
-    streamed engine with the cull its row names; and a patched row moves
-    the op. No kernel runs: both builders are spied on."""
+    ``lists`` an op builds the kernel its row names (the walk, the one
+    list kernel there is, packs ``num_j + 1`` rows: the staged index),
+    without them the streamed engine with the cull its row names; no
+    ``skip_slots`` / ``skip`` keyword (the mark-bit form PR 43 deleted)
+    reaches either builder; and a patched row moves the op. No kernel
+    runs: both builders are spied on."""
     from types import SimpleNamespace
 
     ss, keys, box, const, nbr = tiny
@@ -153,7 +155,7 @@ def test_the_table_names_the_kernel_each_op_builds(tiny, op, on_lists, cull,
         return builder
 
     pack = pp.pack_j_fields
-    monkeypatch.setattr(pp, "group_pair_engine", spy("streamed-or-skip"))
+    monkeypatch.setattr(pp, "group_pair_engine", spy("streamed"))
     monkeypatch.setattr(pp, "group_pair_engine_lists", spy("walk"))
     monkeypatch.setattr(pp, "pack_j_fields", lambda fields, cap, nf_min=0: (
         packed.append((len(fields), nf_min)), pack(fields, cap, nf_min))[1])
@@ -168,25 +170,21 @@ def test_the_table_names_the_kernel_each_op_builds(tiny, op, on_lists, cull,
         return name, bkw, ckw, num_j, nf_min
 
     name, bkw, ckw, num_j, nf_min = run(lists=lists)
-    if on_lists == "walk":
-        assert name == "walk" and nf_min == num_j + 1
-        assert "skip_slots" not in bkw
-    else:
-        assert name == "streamed-or-skip" and nf_min == 0
-        assert bkw["skip_slots"] == 24 and bkw["chunk_skip"] is False
-        assert ckw["skip"] is lists
+    assert on_lists == name == "walk" and nf_min == num_j + 1
+    assert "skip_slots" not in bkw and "skip" not in ckw
     name, bkw, ckw, num_j, nf_min = run(ranges=ranges)
-    assert name == "streamed-or-skip" and nf_min == 0
+    assert name == "streamed" and nf_min == 0
     assert "skip_slots" not in bkw and "skip" not in ckw
     # fold mode and the cull's default are the engine's own, from the box
     assert bkw["fold"] is False
     assert bkw["chunk_skip"] is (None if cull else False)
     assert (ckw["aabb"] is not None) == cull
-    # the row is what decides: the other row, the other kernel
-    other = "skip" if on_lists == "walk" else "walk"
-    monkeypatch.setitem(pp.PAIR_OP_ENGINE, op, (other, not cull))
-    assert (run(lists=lists)[0] == "walk") == (other == "walk")
+    # the row is what decides: the other cull streamed, and on lists a
+    # kernel that is not there is refused, not replaced by the walk
+    monkeypatch.setitem(pp.PAIR_OP_ENGINE, op, ("skip", not cull))
     assert (run(ranges=ranges)[2]["aabb"] is not None) == (not cull)
+    with pytest.raises(ValueError, match="no list kernel 'skip'"):
+        run(lists=lists)
 
 
 def test_table_segments_zero_kept_and_the_tables_end():
