@@ -2,7 +2,7 @@
 and visits: runs, kept chunks, the runs' length histogram and the rows a
 group's copies bring in, for the shipped run tile and any others.
 
-    python3 scripts/count_list_runs.py [--init sedov|noh|wind-shock]
+    python3 scripts/count_list_runs.py [--init sedov|noh|wind-shock|evrard]
         [--side 160] [--run-rows 13,8,6,4,3]
 
 The configuration is the program's own for a run with lists
@@ -151,7 +151,7 @@ def count(init: str, side: int, run_rows, skin_rel: float = 0.2):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--init", default="sedov",
-                    choices=["sedov", "noh", "wind-shock"])
+                    choices=["sedov", "noh", "wind-shock", "evrard"])
     ap.add_argument("--side", type=int, default=160)
     ap.add_argument("--run-rows", default=None,
                     help="comma-separated run tiles to count (default: the "

@@ -366,14 +366,59 @@ def _gravity_sharded_stage(state, box, cfg, gtree, keys):
     )(box, keys, state.x, state.y, state.z, state.m, state.h)
 
 
+def _key_sorted_sources(state, box, curve: str):
+    """The tree solve's five inputs in key order, of a state that is not:
+    ``(gbox, sorted_keys, order, x, y, z, m, h)``. A list step keeps the
+    order its lists froze (and the hydro grid's box), so the solve sorts
+    its own copy: the box regrown over the live positions as the streamed
+    prologue regrows it, the keys, and ONE sort that carries the five
+    fields and an iota as payloads. On a v5e at 1.1M rows that sort reads
+    5 ms where an argsort, the sorted keys' gather and a row gather of
+    the stacked fields read 2 + 8 + 5.6: a gather pays per index, a
+    sort's payloads ride along (PERF.md, PR 44)."""
+    with phase_scope("sort"):
+        with stage_scope("sort", "keys"):
+            gbox = make_global_box(state.x, state.y, state.z, box)
+            keys = compute_sfc_keys(state.x, state.y, state.z, gbox,
+                                    curve=curve)
+        with stage_scope("sort", "order"):
+            iota = jnp.arange(keys.shape[0], dtype=jnp.int32)
+            sorted_keys, x, y, z, m, h, order = jax.lax.sort(
+                (keys, state.x, state.y, state.z, state.m, state.h, iota),
+                num_keys=1)
+    return gbox, sorted_keys, order, x, y, z, m, h
+
+
+def _to_frozen_order(order, gx, gy, gz):
+    """The solve's accelerations back in the order the state is in: one
+    sort keyed on ``order`` (a permutation) with the three as payloads
+    (3.8 ms on a v5e at 1.1M rows; the inverse permutation and a row
+    gather of the (N, 3) stack read 12.5)."""
+    with phase_scope("sort"), stage_scope("sort", "permute"):
+        _, gx, gy, gz = jax.lax.sort((order, gx, gy, gz), num_keys=1)
+    return gx, gy, gz
+
+
 def _add_gravity(state, box, keys, cfg, gtree, ax, ay, az):
     """Self-gravity coupling: Barnes-Hut accel added to the hydro accel.
 
     The analog of mHolder_.upsweep + traverse inside computeForces
-    (main/src/propagator/gravity_wrapper.hpp:97-123): runs on the
-    SFC-sorted arrays the step just produced. Returns updated accels,
-    egrav, the acceleration dt candidate, and solver diagnostics.
+    (main/src/propagator/gravity_wrapper.hpp:97-123). The solve runs on
+    key-sorted arrays: the state the step just sorted (``keys`` its
+    sorted keys), or, in a list step (``keys`` None: the state is in the
+    lists' frozen order), a key-sorted copy of ``x, y, z, m, h`` it makes
+    itself, with the accelerations brought back to the frozen order. The
+    sort work reads under phase ``sort``, the solve under its own phases.
+    Returns updated accels, egrav, the acceleration dt candidate, and
+    solver diagnostics (all order-free).
     """
+    x, y, z, m, h = state.x, state.y, state.z, state.m, state.h
+    order = None
+    if keys is None:
+        # (the mesh's stage takes the state itself, in key order)
+        assert cfg.shard_axis is None, "lists do not reach the mesh's solve"
+        box, keys, order, x, y, z, m, h = _key_sorted_sources(
+            state, box, cfg.curve)
     if cfg.shard_axis is not None:
         gx, gy, gz, egrav, gdiag = _gravity_sharded_stage(
             state, box, cfg, gtree, keys
@@ -381,15 +426,17 @@ def _add_gravity(state, box, keys, cfg, gtree, ax, ay, az):
     elif cfg.ewald is not None:
         gcfg = dataclasses.replace(cfg.gravity, G=cfg.const.g)
         gx, gy, gz, egrav, gdiag = compute_gravity_ewald(
-            state.x, state.y, state.z, state.m, state.h, keys, box,
+            x, y, z, m, h, keys, box,
             gtree, cfg.grav_meta, gcfg, cfg.ewald,
         )
     else:
         gcfg = dataclasses.replace(cfg.gravity, G=cfg.const.g)
         gx, gy, gz, egrav, gdiag = compute_gravity(
-            state.x, state.y, state.z, state.m, state.h, keys, box,
+            x, y, z, m, h, keys, box,
             gtree, cfg.grav_meta, gcfg,
         )
+    if order is not None:
+        gx, gy, gz = _to_frozen_order(order, gx, gy, gz)
     ax, ay, az = ax + gx, ay + gy, az + gz
     with phase_scope("timestep"):
         dt_acc = acceleration_timestep(ax, ay, az, cfg.const)
@@ -725,7 +772,9 @@ def _ve_forces_sharded(state, box, cfg: PropagatorConfig, keys):
 def _force_stage_prologue(state, box, cfg: PropagatorConfig, lists, aux=None,
                           keys=None):
     """Shared head of the force stages: list mode (frozen order, validity
-    diagnostics) vs per-step box regrow + global sort. Returns
+    diagnostics; with or without self-gravity on one device, whose solve
+    sorts a copy of its own inputs, ``_add_gravity``) vs per-step box
+    regrow + global sort. Returns
     (state, box, keys, ldiag, aux); keys is None in list mode. ``ldiag``
     is the prologue's own diagnostics: the list's validity in list mode,
     ``sort_migrant_rows`` where an aux state rides a mesh's sort, else
@@ -739,10 +788,11 @@ def _force_stage_prologue(state, box, cfg: PropagatorConfig, lists, aux=None,
     if lists is not None:
         from sphexa_tpu.sph.pair_lists import list_slack
 
-        if cfg.gravity is not None or cfg.shard_axis is not None:
+        if cfg.shard_axis is not None:
             raise NotImplementedError(
-                "persistent lists compose with single-device gravity-off "
-                "steps; gravity/sharded runs rebuild per step")
+                "persistent lists compose with single-device steps (with "
+                "or without self-gravity); a mesh step sorts and streams "
+                "every step")
         with phase_scope("neighbors"):
             slack = list_slack(state.x, state.y, state.z, state.h, lists)
             ldiag = {"list_slack": slack,
@@ -776,7 +826,9 @@ def _std_forces(
     regrow, NO sort (the order is frozen at the last rebuild), NO
     prologue; a ``list_ok`` diagnostic reports the Verlet-skin validity
     of THIS step's input positions (an invalid step is discarded and
-    replayed by the driver, like a cap overflow)."""
+    replayed by the driver, like a cap overflow). Under self-gravity
+    (one device) the hydro stays in the frozen order and the tree solve
+    takes a key-sorted copy of ``x, y, z, m, h`` (``_add_gravity``)."""
     const = cfg.const
     state, box, keys, ldiag, aux = _force_stage_prologue(
         state, box, cfg, lists, aux, keys=keys
@@ -950,7 +1002,8 @@ def _ve_forces(
     box regrow -> sort -> neighbors -> xmass -> ve_def_gradh -> EOS ->
     IAD -> divv/curlv -> AV switches -> momentum/energy [-> gravity].
     Returns the sorted state plus everything the step tail needs.
-    ``lists``: persistent-list steady-step fast path (see _std_forces).
+    ``lists``: persistent-list steady-step fast path, under self-gravity
+    too (see _std_forces).
     """
     const = cfg.const
     state, box, keys, ldiag, _ = _force_stage_prologue(

@@ -383,6 +383,42 @@ def make_propagator_config(
     )
 
 
+#: how many of the particles of largest ``h`` hull_h_relax counts around
+HULL_TOP = 32
+
+
+def hull_h_relax(state: ParticleState, box: Box, ng0: int) -> float:
+    """Where the largest smoothing lengths of an open box are heading,
+    over ``h`` as it stands (>= 1): counted on the host before any step.
+
+    A cloud in an open box has a hull whose particles find half their
+    neighbours (fewer at an edge), and ``update_h`` stops moving at
+    ``h * cbrt(ng0 / nc)`` (kernels.h_fixed_point). Lists sized for the
+    IC's ``h`` are re-sized after the first verified step shows the
+    growth (``Simulation._lists_cover_h``): a second set of step and
+    rebuild programs in every start-up. So the ``HULL_TOP`` particles of
+    largest ``h`` (ties: farthest from the centroid, the hull of a cloud
+    of one ``h``) have their neighbours inside ``2 h`` counted here,
+    O(HULL_TOP x N) on the host arrays, and the FIRST sizing starts from
+    the largest fixed point among them. Called once, by the constructor:
+    from the first verified step on the step's own ``nc`` says it better
+    (``_lists_cover_h``, which also remains the net under this estimate
+    of which particles matter). A periodic box has no hull: 1."""
+    if any(b == BoundaryType.periodic for b in box.boundaries):
+        return 1.0
+    xyz = np.stack([np.asarray(a) for a in (state.x, state.y, state.z)],
+                   axis=1)
+    h = np.asarray(state.h)
+    away = np.sum((xyz - xyz.mean(axis=0)) ** 2, axis=1)
+    h_to = 0.0
+    for i in np.lexsort((away, h))[-HULL_TOP:]:
+        near = np.abs(xyz[:, 0] - xyz[i, 0]) < 2.0 * h[i]
+        d2 = np.sum((xyz[near] - xyz[i]) ** 2, axis=1)
+        nc = np.count_nonzero(d2 < 4.0 * h[i] ** 2)  # itself included
+        h_to = max(h_to, float(h[i]) * np.cbrt(ng0 / nc))
+    return max(1.0, h_to / float(h.max()))
+
+
 def _dealias_leaves(tree):
     """Copy pytree leaves that are the SAME array object as an earlier
     leaf, so the whole tree is donatable (XLA: `f(donate(a), donate(a))`
@@ -801,10 +837,10 @@ class Simulation:
                 self.chem = shard_state(self.chem, self._mesh)
         # persistent neighbor lists (sph/pair_lists.py): steady steps skip
         # the global sort + prologue and lane-compact the momentum ops;
-        # enabled on the single-device pallas path without gravity (the
-        # gravity tree rebuild needs fresh keys per step today). The
-        # eligibility re-derives at every _configure (fold mode depends
-        # on the sized grid).
+        # enabled on the single-device pallas path, with or without
+        # self-gravity (the tree solve sorts a copy of its five inputs,
+        # propagator._add_gravity). The eligibility re-derives at every
+        # _configure (fold mode depends on the sized grid).
         self._want_lists = use_lists
         self._list_skin_rel = list_skin_rel
         self._lists = None
@@ -845,6 +881,11 @@ class Simulation:
         self._last_diag: Dict[str, float] = {"reconfigured": 0.0}
         self._cfg: Optional[PropagatorConfig] = None
         self._gtree = None
+        if self._lists_eligible and resolve_backend(backend) == "pallas":
+            # lists outlive many steps: the first sizing is for where the
+            # hull's h is heading, before any step has shown it
+            self._h_relax = hull_h_relax(self.state, self.box,
+                                         self.const.ng0)
         self._configure(reason="initial")
 
     # -- static config management ------------------------------------------
@@ -855,7 +896,6 @@ class Simulation:
         return (
             self._want_lists
             and self._mesh is None
-            and not self.gravity_on
             and self.prop_name != "nbody"
             and not self._blockdt
         )
@@ -1104,8 +1144,12 @@ class Simulation:
         from sphexa_tpu.parallel.sizing import leaf_array_from_device_keys
         from sphexa_tpu.sfc.keys import compute_sfc_keys
 
+        # the box the keys were made in: in list mode self.box is the
+        # lists' (regrown at a rebuild, not by the steps), and the caps
+        # are sized in the box the step's solve will regrow
+        gbox = self.box
         if keys_cache is not None:
-            keys_d, order = keys_cache[0], keys_cache[1]
+            keys_d, order, gbox = keys_cache
         else:
             keys_d = compute_sfc_keys(s.x, s.y, s.z, self.box,
                                       curve=self.curve)
@@ -1133,7 +1177,7 @@ class Simulation:
                     "bitmask" if shape["super_factor"] > 0
                     and shape["use_pallas"] else "sort")
         gcfg = estimate_gravity_caps(
-            xs, ys, zs, ms, skeys, self.box, gtree, meta,
+            xs, ys, zs, ms, skeys, gbox, gtree, meta,
             GravityConfig(theta=self.theta, bucket_size=self.grav_bucket,
                           G=self.const.g,
                           m2p_cap_margin=self.m2p_cap_margin,

@@ -157,9 +157,13 @@ def stepped(request):
 
 
 class TestCoupledStep:
-    def test_streamed_engine_under_gravity(self, stepped):
+    def test_the_engine_under_gravity(self, stepped):
+        """Since PR 44 the Mosaic engine walks its pair lists under
+        self-gravity on one device; the XLA engine has none."""
         sim = stepped[0]
-        assert sim.gravity_on and sim.pair_lists is None
+        on_lists = sim._cfg.backend == "pallas"
+        assert sim.gravity_on and sim._use_lists is on_lists
+        assert (sim.pair_lists is not None) is on_lists
         assert sim.cooling_cfg.evolve_species
 
     def test_step_is_the_references(self, stepped, config):
@@ -209,7 +213,7 @@ class TestCoupledStep:
 
 
 class TestChemThroughSortsReconfigureAndRollback:
-    """Twelve steps, a sort each, under gravity on the streamed path: a
+    """Twelve steps, a sort each, under gravity on the XLA engine: a
     forced gravity reconfigure at iteration 4 and, at iteration 8, a near
     field cap cut under the lists' need, so the next window's first step
     overflows and the driver rolls back, re-sizes and replays."""
@@ -243,7 +247,10 @@ class TestChemThroughSortsReconfigureAndRollback:
 
     def test_went_through_a_reconfigure_and_a_rollback(self, driven):
         sim, sink, _, _ = driven
-        assert sim.iteration == 12 and sim.pair_lists is None
+        # (on the CPU ``auto`` is the XLA engine: sorted and streamed every
+        # step; the same drive on lists is tests/test_gravity_lists.py's)
+        assert sim.iteration == 12
+        assert (sim.pair_lists is not None) is (sim._cfg.backend == "pallas")
         reasons = [e["reason"] for e in sink.of_kind("reconfigure")]
         assert reasons.count("overflow") >= 2
         rollbacks = sink.of_kind("rollback")

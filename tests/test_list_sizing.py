@@ -104,15 +104,32 @@ def noh_run():
     return _drive(init_noh, 16, 16)
 
 
-def test_first_verified_step_resizes_for_the_relaxed_h(noh_run):
+def test_first_sizing_covers_the_relaxed_h(noh_run):
     sim, sink, _ = noh_run
     reasons = [(e["it"], e["reason"]) for e in sink.of_kind("reconfigure")]
-    # the rim's nc is about half of ng0: h is heading 26-30 % up, past the
-    # 10 % slack, and the driver knows after ONE verified step
-    assert reasons == [(0, "initial"), (1, "h-relax")], reasons
+    # the rim's nc is about half of ng0: h is heading 26-35 % up, past the
+    # 10 % slack, and since PR 44 the first configure knows it from a host
+    # count of the hull's neighbours (simulation.hull_h_relax): no re-size
+    # (a second set of step and rebuild programs) in the start-up
+    assert reasons == [(0, "initial")], reasons
     assert 1.1 < sim._h_sized / float(np.asarray(init_noh(16)[0].h).max())
     # and the sizing held: no list-slot, overflow or stale-grid re-size
     # while the rim relaxed
+    builds = sink.of_kind("rebuild_lists")
+    assert builds[0]["reason"] == "first"
+    assert all(e["attempts"] == 1 for e in builds)
+
+
+def test_first_verified_step_resizes_where_the_count_missed(monkeypatch):
+    """The net under the count: with the hull unseen (an estimate of 1),
+    the driver knows after ONE verified step and re-sizes once."""
+    import sphexa_tpu.simulation as simulation
+
+    monkeypatch.setattr(simulation, "hull_h_relax", lambda *a, **k: 1.0)
+    sim, sink, _ = _drive(init_noh, 16, 4)
+    reasons = [(e["it"], e["reason"]) for e in sink.of_kind("reconfigure")]
+    assert reasons == [(0, "initial"), (1, "h-relax")], reasons
+    assert 1.1 < sim._h_sized / float(np.asarray(init_noh(16)[0].h).max())
     builds = sink.of_kind("rebuild_lists")
     assert [e["reason"] for e in builds[:2]] == ["first", "reconfigure"]
     assert all(e["attempts"] == 1 for e in builds)
@@ -120,10 +137,10 @@ def test_first_verified_step_resizes_for_the_relaxed_h(noh_run):
 
 def test_relaxation_estimate_tracks_the_run(noh_run):
     sim, _, rows = noh_run
-    # after the re-size the estimate is refreshed once more (the first
-    # verified step after THAT configure) and is what a later configure
-    # would size for: h still has 5-20 % to go at iteration 2
-    assert 1.0 <= sim._h_relax < 1.3
+    # the constructor's count is replaced by what the first verified
+    # step shows, and that is what a later configure would size for: h
+    # still has 5-30 % to go at iteration 1
+    assert 1.0 <= sim._h_relax < 1.3, sim._h_relax
     assert sim._h_configured is None  # checked once per configure
     assert [r["it"] for r in rows] == list(range(1, 17))
 

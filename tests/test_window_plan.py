@@ -81,8 +81,9 @@ CASES = {
     "check-every-1": (1, 0.15, _flat, True, 15,
                       [1] * 15, [7, 7],
                       (0.15, 5, 1)),
-    # lists off (gravity, mesh, nbody, block-dt): steps carry no
-    # list_slack, the planner is inert, windows are whole
+    # lists off (mesh, nbody, block-dt, ``use_lists=False``; one-chip
+    # gravity walks lists since PR 44): steps carry no list_slack, the
+    # planner is inert, windows are whole
     "lists-off": (4, 0.15, _flat, False, 16, [4, 4, 4, 4], [], None),
     # dt grows by the limiter's 1.1 per step, so each step uses 10 % more
     # skin than the one before: the estimate must not under-read it
