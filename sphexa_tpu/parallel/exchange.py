@@ -59,7 +59,7 @@ exercises both the sentinel and the resize), mirroring the neighbor-cap
 overflow contract. What the stage costs on four chips is in PERF.md §5.
 """
 
-from typing import Sequence, Tuple
+from typing import NamedTuple, Sequence, Tuple
 
 import numpy as np
 import jax
@@ -341,12 +341,9 @@ def shard_halo_stage(x, y, z, h, keys, box, nbr, P: int, Wmax: int,
     def serve(fields):
         return serve_windows(fields, bounds, S, Wmax, P, k, axis)
 
-    def jbuf(own, halo):
-        return tuple(jnp.concatenate([o, a]) for o, a in zip(own, halo))
-
     metrics = exchange_metrics_windowed(bounds, Wmax, P, k)
     metrics["halo_runs"] = live_runs_max(granges)
-    return ranges, serve, jbuf, escaped, metrics
+    return ranges, serve, _jbuf, escaped, metrics
 
 
 @named_phase("shard-metrics")
@@ -539,6 +536,88 @@ def _sparse_layout_dest(covered_all, dest, table, S: int, k):
     return clen, csum - clen
 
 
+class FrozenHalo(NamedTuple):
+    """The send layout a slab froze with its persistent pair lists
+    (sph/pair_lists.PairLists.halo): what ``serve_frozen`` ships every
+    steady step without coverage, cell table or packing arithmetic. Built
+    by ``freeze_send_layout`` under the rebuild's ``shard_map``; each
+    leaf is a slab's own (global arrays: the slabs' concatenation)."""
+
+    send: Tuple[jax.Array, ...]  # P-1 x (hmax[r-1],) int32: the slab's
+    #                              rows round r ships to its distance-r
+    #                              successor (``_pack_rows``' ``ridx``)
+    rows: jax.Array   # (1,) int32 - remote rows this slab needed at the
+    #                   build (``exchange_metrics_sparse``' halo_rows)
+    occ: jax.Array    # (1,) f32 - fullest round's need / cap at the build
+    runs: jax.Array   # (1,) int32 - fullest group's live runs at the build
+
+
+def freeze_send_layout(covered_all, table, S: int, hmax: Tuple[int, ...],
+                       P: int, k) -> Tuple[jax.Array, ...]:
+    """The packed row indices of every serve round of THIS negotiation
+    (``serve_sparse`` computes the same ``ridx`` per round and serve): a
+    pure function of the all_gathered coverage and the replicated table,
+    so what the receivers' localized runs index stays where it is for as
+    long as the slabs keep their rows."""
+    with _stage("pack"):
+        out = []
+        for r in range(1, P):
+            clen, poff = _sparse_layout_dest(covered_all, (k + r) % P, table,
+                                             S, k)
+            out.append(_pack_rows(clen, poff, table, S, k, hmax[r - 1]))
+        return tuple(out)
+
+
+@named_phase("halo-exchange")
+def serve_frozen(fields: Sequence, send: Tuple[jax.Array, ...], P: int,
+                 axis: str, token=None):
+    """``serve_sparse`` over a frozen layout: the same P-1 chained ppermute
+    rounds in the same buffers, each round's rows one gather of the
+    stacked fields by the frozen index. No coverage, no table, no packing
+    arithmetic. Returns (annex fields, token) like ``serve_sparse``."""
+    with _stage("pack"):
+        local = jnp.stack(fields, axis=1)  # (S, nf)
+    nf = local.shape[1]
+    parts = []
+    for r in range(1, P):
+        with _stage("pack"):
+            out = local[send[r - 1]]  # (Hmax_r, nf)
+            if token is not None:
+                out = chain_after(out, token)
+        perm = [(i, (i + r) % P) for i in range(P)]
+        with _stage("wire"):
+            parts.append(jax.lax.ppermute(out, axis, perm))
+        token = parts[-1]
+    with _stage("jbuf"):
+        annex = jnp.concatenate(parts, axis=0) if parts else local[:0]
+        return [annex[:, f] for f in range(nf)], token
+
+
+def frozen_halo_stage(halo: FrozenHalo, P: int, axis: str):
+    """A steady list step's stand-in for ``shard_halo_stage_sparse``:
+    ``(serve, jbuf, metrics)`` over the frozen layout. Nothing is
+    negotiated: the rows go where the list's runs index them, the rounds
+    of every serve chained into one total order across calls
+    (``chain_after``), and the exchange metrics are the build's."""
+    chain = {"token": None}
+
+    def serve(fields):
+        out, tok = serve_frozen(fields, halo.send, P, axis,
+                                token=chain["token"])
+        chain["token"] = tok
+        return out
+
+    metrics = {"halo_rows": halo.rows[0], "halo_occ": halo.occ[0],
+               "halo_runs": halo.runs[0]}
+    return serve, _jbuf, metrics
+
+
+def _jbuf(own, halo):
+    """[own slab | annex] of each field: the j-buffer a slab's localized
+    runs index."""
+    return tuple(jnp.concatenate([o, a]) for o, a in zip(own, halo))
+
+
 @named_phase("halo-exchange")
 def localize_ranges_sparse(
     ranges: GroupRanges, table, S: int, P: int, hmax: Tuple[int, ...],
@@ -637,21 +716,29 @@ def _localize_sparse(ranges, starts, lens, sh3, nruns, split_ovf, c0,
 
 def shard_halo_stage_sparse(x, y, z, h, keys, box, nbr, P: int,
                             hmax: Tuple[int, ...], axis: str,
-                            run_slots: int = 0):
+                            run_slots: int = 0, radius_pad=0.0,
+                            freeze: bool = False):
     """Sparse-exchange variant of ``shard_halo_stage`` — same contract
     (ranges, serve, jbuf, escaped, metrics), comm volume sum(hmax) rows
     per serve instead of (P-1) * Wmax. The reference analog is
     exchangeHalos' per-peer leaf-range p2p (exchange_halos.hpp:43-119);
     here the range lists are implicit in the all_gathered coverage
     bitmaps + the replicated cell table, so the negotiation is
-    O(P * ncells) bits. ``run_slots``: ``localize_ranges_sparse``."""
+    O(P * ncells) bits. ``run_slots``: ``localize_ranges_sparse``.
+
+    ``radius_pad``: group_cell_ranges' coverage slack, the pair lists'
+    skin: windows, coverage and localized runs are then the inflated
+    ones. ``freeze`` (static; the pair-list rebuild): a sixth value, the
+    ``FrozenHalo`` of this negotiation, which ``frozen_halo_stage``
+    serves the steady steps from."""
     from sphexa_tpu.sph.pallas_pairs import group_cell_ranges
 
     S = x.shape[0]
     k = jax.lax.axis_index(axis)
     table = global_cell_table(keys, nbr.level, axis)
     granges, cells = group_cell_ranges(x, y, z, h, None, box, nbr,
-                                       table=table, with_cells=True)
+                                       table=table, radius_pad=radius_pad,
+                                       with_cells=True)
     ranges, covered_all, escaped, covered = localize_ranges_sparse(
         granges, table, S, P, hmax, k, axis, cells=cells,
         run_slots=run_slots,
@@ -669,12 +756,15 @@ def shard_halo_stage_sparse(x, y, z, h, keys, box, nbr, P: int,
         chain["token"] = tok
         return out
 
-    def jbuf(own, halo):
-        return tuple(jnp.concatenate([o, a]) for o, a in zip(own, halo))
-
     metrics = exchange_metrics_sparse(covered, table, S, hmax, P, k)
     metrics["halo_runs"] = live_runs_max(granges)
-    return ranges, serve, jbuf, escaped, metrics
+    if freeze:
+        halo = FrozenHalo(
+            send=freeze_send_layout(covered_all, table, S, hmax, P, k),
+            rows=metrics["halo_rows"][None], occ=metrics["halo_occ"][None],
+            runs=metrics["halo_runs"][None])
+        return ranges, serve, _jbuf, escaped, metrics, halo
+    return ranges, serve, _jbuf, escaped, metrics
 
 
 @named_phase("shard-metrics")

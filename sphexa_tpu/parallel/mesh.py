@@ -93,6 +93,13 @@ def make_sharded_step(mesh: Mesh, cfg: PropagatorConfig, step_fn=step_hydro_std,
     ``(state, box, diag, new_aux)``. Turbulence phases are replicated
     (they are global mode tables); chemistry arrays are per-particle and
     ride the slab sharding + the in-step SFC sort.
+
+    Persistent pair lists (``cfg.list_slot_cap`` > 0, sized by the
+    Simulation for the hydro step families): ``stepper.rebuild(state, box,
+    aux)`` is the jitted ``propagator.rebuild_pair_lists_sharded`` (global
+    sort + every slab's list build and frozen send layout), and
+    ``stepper(..., lists=)`` / ``step_sim(..., lists=)`` the steady list
+    step; without ``lists`` the same stepper streams.
     """
     from sphexa_tpu.propagator import (
         STEP_AUX_SLOT,
@@ -143,16 +150,23 @@ def make_sharded_step(mesh: Mesh, cfg: PropagatorConfig, step_fn=step_hydro_std,
 
     rspec = NamedSharding(mesh, P())
 
-    def inner(s, b, gtree=None, aux=None):
+    def inner(s, b, gtree=None, aux=None, lists=None):
+        kw = {} if lists is None else {"lists": lists}
         if step_fn in aux_props:
             new_state, new_box, diag, new_aux = step_fn(
-                s, b, cfg, gtree, aux, aux_cfg
+                s, b, cfg, gtree, aux, aux_cfg, **kw
             )
         elif step_fn in blockdt_props:
             new_state, new_box, diag, new_aux = step_fn(s, b, cfg, gtree, aux)
         else:
-            new_state, new_box, diag = step_fn(s, b, cfg, gtree)
+            new_state, new_box, diag = step_fn(s, b, cfg, gtree, **kw)
             new_aux = None
+        new_state, new_box, new_aux = place(s.n, new_state, new_box, new_aux)
+        return new_state, new_box, diag, new_aux
+
+    def place(n, new_state, new_box, new_aux):
+        """The placement every program of this stepper leaves its carry
+        in, so that the next one starts from what it compiled for."""
         # keep the particle arrays sharded on the way out so the next step
         # starts from slab-owned arrays (no silent replication creep)...
         constrain = lambda l: (
@@ -168,17 +182,17 @@ def make_sharded_step(mesh: Mesh, cfg: PropagatorConfig, step_fn=step_hydro_std,
         # aux leaves: per-particle arrays (chemistry) stay slab-sharded,
         # global tables (turbulence modes/phases) stay replicated
         aux_place = lambda l: _place_aux_leaf(
-            l, s.n, jax.lax.with_sharding_constraint, pspec, rspec
+            l, n, jax.lax.with_sharding_constraint, pspec, rspec
         )
         return (jax.tree.map(constrain, new_state),
-                jax.tree.map(rep, new_box), diag,
+                jax.tree.map(rep, new_box),
                 jax.tree.map(aux_place, new_aux))
 
     # inputs are placed by shard_state; GSPMD propagates those shardings
     # through the whole program, one compiled executable reused every step
     jitted = jax.jit(inner)
 
-    def stepper(s, b, gtree=None, aux=None):
+    def commit(s, b, aux):
         # commit the box (and aux, same placement rule as aux_place)
         # replicated/sharded BEFORE the first call: an uncommitted input
         # on step 0 compiles a second executable variant vs the committed
@@ -192,12 +206,16 @@ def make_sharded_step(mesh: Mesh, cfg: PropagatorConfig, step_fn=step_hydro_std,
                 ),
                 aux,
             )
-        out = jitted(s, b, gtree, aux)
+        return b, aux
+
+    def stepper(s, b, gtree=None, aux=None, lists=None):
+        b, aux = commit(s, b, aux)
+        out = jitted(s, b, gtree, aux, lists)
         return out if step_fn in carry_props else out[:3]
 
     aux_slot = STEP_AUX_SLOT.get(step_fn)
 
-    def step_sim(sim, gtree=None):
+    def step_sim(sim, gtree=None, lists=None):
         """Advance one step on the unified ``state.SimState`` carry:
         the sharded face of ``propagator.step_sim_state``. Routes through
         ``stepper`` (same placement commits, same jitted executable —
@@ -205,12 +223,34 @@ def make_sharded_step(mesh: Mesh, cfg: PropagatorConfig, step_fn=step_hydro_std,
         this step function owns, so the carry treedef is closed under
         stepping (the JXA503 invariant)."""
         aux = getattr(sim, aux_slot) if aux_slot else None
-        out = stepper(sim.particles, sim.box, gtree, aux)
+        out = stepper(sim.particles, sim.box, gtree, aux, lists)
         new_sim = sim.with_slot(aux_slot, out[3] if aux_slot else None,
                                 particles=out[0], box=out[1])
         return new_sim, out[2]
 
     stepper.step_sim = step_sim
+
+    if cfg.list_slot_cap > 0 and cfg.shard_axis is not None:
+        from sphexa_tpu.propagator import rebuild_pair_lists_sharded
+
+        def rebuild_inner(s, b, aux=None):
+            new_state, new_box, lists, new_aux = rebuild_pair_lists_sharded(
+                s, b, cfg, aux)
+            new_state, new_box, new_aux = place(s.n, new_state, new_box,
+                                                new_aux)
+            return new_state, new_box, lists, new_aux
+
+        rebuild_jit = jax.jit(rebuild_inner)
+
+        def rebuild(s, b, aux=None):
+            """``propagator.rebuild_pair_lists`` of this mesh:
+            (state in the frozen order, box, PairLists, aux)."""
+            b, aux = commit(s, b, aux)
+            return rebuild_jit(s, b, aux)
+
+        stepper.rebuild = rebuild
+        # (the jitted rebuild, as ``_jitted`` is the jitted step)
+        stepper._rebuild_jitted = rebuild_jit
 
     # expose the underlying jit cache so the Simulation's compile
     # watchdog (telemetry retrace events) can probe sharded launches too;

@@ -226,7 +226,8 @@ def sparse_need_matrix(x, y, z, h, keys, box, nbr, P: int, mesh=None):
 
 
 @functools.partial(jax.jit, static_argnames=("nbr", "P", "mesh"))
-def sparse_needs_and_runs(x, y, z, h, keys, box, nbr, P: int, mesh=None):
+def sparse_needs_and_runs(x, y, z, h, keys, box, nbr, P: int, mesh=None,
+                          radius_pad=0.0):
     """(need, runs). ``need``: the (P_dest, P_src) row-need matrix of the
     sparse cell-granular halo
     exchange: entry [k, j] = rows shard k's covered cells clip to shard
@@ -239,6 +240,8 @@ def sparse_needs_and_runs(x, y, z, h, keys, box, nbr, P: int, mesh=None):
     (pinned by tests/test_parallel.py). ``runs``: (P,) each slab's
     high-water of live runs a group (``max(ranges.ncells)`` of the same
     prologue), what the exchange's run-slot axis is sized from.
+    ``radius_pad``: the prologue's coverage slack (the pair lists' skin:
+    a list rebuild's halo stage runs on the inflated windows).
 
     The needs are taken the way the step takes them, under ``shard_map``
     over ``mesh`` (the run's; without one, the first P local devices):
@@ -280,7 +283,8 @@ def sparse_needs_and_runs(x, y, z, h, keys, box, nbr, P: int, mesh=None):
     def slab_need(kk, xk, yk, zk, hk):
         table = global_cell_table(kk, nbr.level, axis)
         ranges, cells = group_cell_ranges(xk, yk, zk, hk, None, box, nbr,
-                                          table=table, with_cells=True)
+                                          table=table, radius_pad=radius_pad,
+                                          with_cells=True)
         covered = coverage_from_runs(ranges.starts, ranges.lens, table, cells)
         return (_sparse_layout(covered, table, S, P)[2][None, :],
                 jnp.max(ranges.ncells)[None])
@@ -305,24 +309,28 @@ def _per_distance_needs(need, P: int):
 
 
 @functools.partial(jax.jit, static_argnames=("nbr", "P", "mesh"))
-def _sparse_halo_needs(x, y, z, h, keys, box, nbr, P: int, mesh=None):
+def _sparse_halo_needs(x, y, z, h, keys, box, nbr, P: int, mesh=None,
+                       radius_pad=0.0):
     """``_per_distance_needs`` of ``sparse_needs_and_runs``' matrix, then
     the fullest group's live runs over all slabs: one array, one fetch."""
-    need, runs = sparse_needs_and_runs(x, y, z, h, keys, box, nbr, P, mesh)
+    need, runs = sparse_needs_and_runs(x, y, z, h, keys, box, nbr, P, mesh,
+                                       radius_pad)
     return jnp.concatenate([_per_distance_needs(need, P),
                             jnp.max(runs)[None]])
 
 
 def device_sparse_halo(x, y, z, h, keys, box, nbr, P: int,
                        margin: float = 1.4, quantum: int = 256,
-                       mesh=None) -> Tuple[Tuple[int, ...], int]:
+                       mesh=None, radius_pad=0.0,
+                       ) -> Tuple[Tuple[int, ...], int]:
     """Size the sparse exchange from the current state: (caps, run_slots).
     ``caps``: the static per-distance row caps (the Hmax tuple of
     shard_halo_stage_sparse). ``run_slots``: the slots of its run axis
     (``pad_run_slots`` of the fullest group's live runs, at most the
     window's W3: ``PropagatorConfig.halo_runs``), under the same
     ``margin``: a trip of either grows both. P + 1 scalars to the host.
-    ``mesh``: the run's mesh (``sparse_needs_and_runs``)."""
+    ``mesh``: the run's mesh, ``radius_pad``: the pair lists' skin
+    (``sparse_needs_and_runs``)."""
     import dataclasses
 
     n = x.shape[0]
@@ -330,13 +338,85 @@ def device_sparse_halo(x, y, z, h, keys, box, nbr, P: int,
     if nbr.run_cap > S:
         nbr = dataclasses.replace(nbr, run_cap=S)
     *per_r, own, runs = np.asarray(fetch(_sparse_halo_needs(
-        x, y, z, h, keys, box, nbr, P, mesh)))
+        x, y, z, h, keys, box, nbr, P, mesh, radius_pad)))
     _own_slab_read(own, S, "sparse halo")
     pad = lambda v: min(
         int(-(-int(max(int(v), 1) * margin) // quantum) * quantum), S
     )
     return (tuple(pad(v) for v in per_r),
             min(pad_run_slots(runs, margin), nbr.window ** 3))
+
+
+@functools.partial(jax.jit, static_argnames=("nbr", "P", "mesh", "hmax",
+                                             "run_slots"))
+def _list_chunk_needs(x, y, z, h, keys, box, nbr, P: int, mesh,
+                      hmax: Tuple[int, ...], run_slots: int, skin):
+    """(P, 3) per slab: most candidate chunks a group streams, their sum
+    over the slab's groups in whole row tiles, and whether the halo
+    escaped ``hmax`` / ``run_slots``: the chunks of the runs as the
+    mesh's list build streams them, LOCALIZED into [own | annex] rows
+    (a run cut at a slab boundary or moved into the annex changes its
+    128-lane alignment, so the global runs' chunks are not these). Taken
+    under ``shard_map`` by the rebuild's own halo stage (``ROADMAP.md``
+    D14), the sorted arrays made as ``sparse_needs_and_runs`` makes them."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec
+
+    from sphexa_tpu.parallel.exchange import shard_halo_stage_sparse
+    from sphexa_tpu.sph.pair_lists import _run_chunks
+    from sphexa_tpu.sph.pallas_pairs import LIST_ROW_TILE, _round_up
+
+    order = jnp.argsort(keys)
+    xs, ys, zs, hs = x[order], y[order], z[order], h[order]
+    skeys = keys[order]
+    (axis,) = mesh.axis_names
+
+    def slab_chunks(kk, xk, yk, zk, hk):
+        ranges, _, _, escaped, _ = shard_halo_stage_sparse(
+            xk, yk, zk, hk, kk, box, nbr, P, hmax, axis,
+            run_slots=run_slots, radius_pad=skin)
+        per_group = jnp.sum(_run_chunks(ranges.starts, ranges.lens), axis=1)
+        return jnp.stack([
+            jnp.max(per_group),
+            jnp.sum(_round_up(per_group, LIST_ROW_TILE)),
+            escaped.astype(jnp.int32)])[None, :]
+
+    rows = PartitionSpec(axis)
+    return shard_map(slab_chunks, mesh=mesh, in_specs=(rows,) * 5,
+                     out_specs=rows, check_vma=False)(skeys, xs, ys, zs, hs)
+
+
+def device_list_caps(x, y, z, h, keys, box, nbr, skin: float, mesh,
+                     halo_margin: float = 1.4, slot_margin: float = 1.3):
+    """A mesh's persistent pair lists, sized: ``(halo_cells, halo_runs,
+    list_slot_cap, list_slots_cap)``. The halo caps are
+    ``device_sparse_halo``'s for the skin-inflated windows a rebuild's
+    halo stage runs on (and every steady step ships over); the list caps
+    ``estimate_list_caps``' per SLAB, the fullest slab's under
+    ``slot_margin``, counted on the runs that stage localizes under those
+    caps. ``h``: the smoothing lengths the lists are sized for."""
+    import dataclasses
+
+    from sphexa_tpu.neighbors.cell_list import pad_cap
+    from sphexa_tpu.sph.pair_lists import LIST_TABLE_TILE
+
+    P = mesh.size
+    S = x.shape[0] // P
+    hcells, hruns = device_sparse_halo(
+        x, y, z, h, keys, box, nbr, P=P, margin=halo_margin, mesh=mesh,
+        radius_pad=jnp.float32(skin))
+    if nbr.run_cap > S:
+        nbr = dataclasses.replace(nbr, run_cap=S)
+    need = np.asarray(fetch(_list_chunk_needs(
+        x, y, z, h, keys, box, nbr, P, mesh, tuple(min(c, S) for c in hcells),
+        hruns, jnp.float32(skin))))
+    if need[:, 2].any():
+        raise RuntimeError(
+            f"pair-list sizing: the halo sized a moment ago (caps {hcells}, "
+            f"{hruns} run slots) escaped on slabs "
+            f"{np.flatnonzero(need[:, 2]).tolist()}")
+    return (hcells, hruns, pad_cap(int(need[:, 0].max()), slot_margin, 8),
+            pad_cap(int(need[:, 1].max()), slot_margin, LIST_TABLE_TILE))
 
 
 # ---------------------------------------------------------------------------

@@ -328,6 +328,98 @@ def rebuild_pair_lists(state: ParticleState, box: Box,
     return state, box, lists, aux
 
 
+def _slab_lists_specs(axis: str, P: int):
+    """``shard_map`` specs of a mesh's PairLists: every per-group, per-row
+    and per-slab leaf is the slabs' concatenation, the counters and the
+    skin are one value for all (the rebuild reduces them over the axis)."""
+    from jax.sharding import PartitionSpec
+    from sphexa_tpu.parallel.exchange import FrozenHalo
+    from sphexa_tpu.sph.pair_lists import PairLists
+    from sphexa_tpu.sph.pallas_pairs import GroupRanges
+
+    Pp, Pr = PartitionSpec(axis), PartitionSpec()
+    return PairLists(
+        ranges=GroupRanges(starts=Pp, lens=Pp, shift_x=Pp, shift_y=Pp,
+                           shift_z=Pp, ncells=Pp, occupancy=Pr, boxl=Pr),
+        gidx=Pp, seg=Pp, cnt=Pp, fill=Pp, emit=Pp, tail=Pp,
+        overflow=Pr, slot_need=Pr, slots_live=Pr, chunks_live=Pr,
+        runs_live=Pr, lanes_total=Pr, xb=Pp, yb=Pp, zb=Pp, hb=Pp, skin=Pr,
+        halo=FrozenHalo(send=(Pp,) * (P - 1), rows=Pp, occ=Pp, runs=Pp))
+
+
+def _slab_nbr(cfg: PropagatorConfig, S_shard: int):
+    """The neighbour config a slab's stages run under: a merged run must
+    fit in one source slab so the boundary split pass leaves at most one
+    remainder per run (exchange._split_runs); a raw CELL wider than a
+    slab still crosses and trips the split-overflow sentinel instead
+    (pathological at any realistic shard size)."""
+    nbr = cfg.nbr
+    if nbr.run_cap > S_shard:
+        nbr = dataclasses.replace(nbr, run_cap=S_shard)
+    return nbr
+
+
+def rebuild_pair_lists_sharded(state: ParticleState, box: Box,
+                               cfg: PropagatorConfig, aux=None):
+    """``rebuild_pair_lists`` on a mesh (``cfg`` the sharded stepper's;
+    jitted by parallel/mesh.make_sharded_step): box regrow + the global
+    sort as the streamed mesh step runs them (this is where rows migrate
+    between slabs, and nowhere else), then ONE ``shard_map`` in which
+    every slab runs the sparse halo stage on its skin-inflated windows,
+    serves ``(x, y, z)`` once, builds its lists over [own | halo] rows
+    and freezes the send layout of that negotiation with them
+    (``PairLists.halo``). ``overflow``, ``slot_need``, ``slots_live`` and
+    the occupancy leave as the max over slabs, ``chunks_live``,
+    ``runs_live`` and ``lanes_total`` as sums; ``overflow`` carries 2
+    where a slab's halo escaped its caps (a halo re-size, not a list
+    re-size)."""
+    from jax.sharding import PartitionSpec
+    from sphexa_tpu.parallel import exchange as ex
+    from sphexa_tpu.sph.pair_lists import build_pair_lists
+
+    with phase_scope("sort"):
+        box = make_global_box(state.x, state.y, state.z, box)
+    state, keys, aux = _sort_by_keys(state, box, cfg.curve, aux=aux)
+    axis = cfg.shard_axis
+    P = cfg.mesh.shape[axis]
+    S_shard = state.x.shape[0] // P
+    nbr = _slab_nbr(cfg, S_shard)
+    hmax = tuple(min(c, S_shard) for c in cfg.halo_cells)
+    interpret = _pallas_interpret()
+    with phase_scope("neighbors"):
+        skin = jnp.float32(cfg.list_skin_rel) * 2.0 * jnp.max(state.h)
+
+    def build(box, skin, keys, x, y, z, h):
+        ranges, serve, jbuf, escaped, _, halo = ex.shard_halo_stage_sparse(
+            x, y, z, h, keys, box, nbr, P, hmax, axis,
+            run_slots=cfg.halo_runs, radius_pad=skin, freeze=True)
+        with phase_scope("neighbors"):
+            lists = build_pair_lists(
+                x, y, z, h, None, box, nbr, skin, cfg.list_slot_cap,
+                cfg.list_slots_cap, interpret=interpret, ranges=ranges,
+                jdata=jbuf((x, y, z), serve((x, y, z))))
+            # one value for all slabs: three reductions in one order
+            top = jax.lax.pmax(jnp.stack(
+                [lists.overflow, escaped.astype(jnp.int32), lists.slot_need,
+                 lists.slots_live, lists.ranges.occupancy]), axis)
+            count = jax.lax.psum(ex.chain_after(jnp.stack(
+                [lists.chunks_live, lists.runs_live]), top), axis)
+            lanes = jax.lax.psum(ex.chain_after(lists.lanes_total, count),
+                                 axis)
+        return lists._replace(
+            ranges=lists.ranges._replace(occupancy=top[4]),
+            overflow=top[0] + 2 * top[1], slot_need=top[2],
+            slots_live=top[3], chunks_live=count[0], runs_live=count[1],
+            lanes_total=lanes, halo=halo)
+
+    Pp, Pr = PartitionSpec(axis), PartitionSpec()
+    lists = shard_map(
+        build, mesh=cfg.mesh, in_specs=(Pr, Pr, Pp, Pp, Pp, Pp, Pp),
+        out_specs=_slab_lists_specs(axis, P), check_vma=False,
+    )(box, skin, keys, state.x, state.y, state.z, state.h)
+    return state, box, lists, aux
+
+
 def _gravity_sharded_stage(state, box, cfg, gtree, keys):
     """Distributed gravity under shard_map over the step's mesh: the open
     Barnes-Hut solve (any multipole order) is
@@ -589,10 +681,75 @@ def _shard_metrics(ranges, escaped, metrics, axis: str, token=None):
         }
 
 
-def _std_forces_sharded(state, box, cfg: PropagatorConfig, keys):
+#: what a list step's sharded stage returns beside SHARD_DIAG_KEYS
+_LIST_DIAG_KEYS = ("list_slack", "list_ok")
+
+
+def _sharded_head(keys, lists, axis: str, P: int):
+    """What a sharded force stage is handed in the sorted keys' place, its
+    ``shard_map`` spec and the diagnostics it returns: the keys of a
+    streamed step, or the persistent PairLists that replace them."""
+    from jax.sharding import PartitionSpec
+
+    if lists is None:
+        return keys, PartitionSpec(axis), SHARD_DIAG_KEYS
+    return (lists, _slab_lists_specs(axis, P),
+            SHARD_DIAG_KEYS + _LIST_DIAG_KEYS)
+
+
+def _open_stage(stage, lists, head, x, y, z, h, box, P: int, axis: str):
+    """Head of a sharded force stage's body: ``(serve, jbuf, the pair ops'
+    ``ranges`` / ``lists`` keywords, exchange metrics, escaped)``. A
+    streamed step negotiates its halo (``stage``, ``head`` the sorted
+    keys); a list step (``head`` the slab's lists) ships over the layout
+    frozen with them: nothing is negotiated, nothing can escape."""
+    if lists is None:
+        ranges, serve, jbuf, escaped, hmetrics = stage(x, y, z, h, head, box)
+        return serve, jbuf, {"ranges": ranges}, hmetrics, escaped
+    from sphexa_tpu.parallel.exchange import frozen_halo_stage
+
+    serve, jbuf, hmetrics = frozen_halo_stage(head.halo, P, axis)
+    return serve, jbuf, {"ranges": None, "lists": head}, hmetrics, False
+
+
+def _close_stage(walk, escaped, hmetrics, x, y, z, h, occ, token, cap: int,
+                 axis: str):
+    """Tail of a sharded force stage's body after its last pmin
+    (``token``): the occupancy's pmax with the halo's escape folded in,
+    then the metrics gather, chained into one order (the tail collectives
+    are mutually independent: rendezvous guard). A list step puts its
+    validity between the two: each slab's own rows against its own
+    build-time positions, pmin over the axis (a halo row's motion is
+    checked by its owner). Returns (occ, the per-shard diagnostics)."""
+    from sphexa_tpu.parallel import exchange as ex
+
+    lists = walk.get("lists")
+    if lists is None:
+        occ = ex.fold_escape_sentinel(
+            ex.chain_after(occ, token), escaped, cap, axis)
+        return occ, _shard_metrics(walk["ranges"], escaped, hmetrics, axis,
+                                   token=occ)
+    from sphexa_tpu.sph.pair_lists import list_slack
+
+    occ = jax.lax.pmax(ex.chain_after(occ, token), axis)
+    with phase_scope("neighbors"):
+        slack = jax.lax.pmin(
+            ex.chain_after(list_slack(x, y, z, h, lists), occ), axis)
+    sdiag = _shard_metrics(lists.ranges, escaped, hmetrics, axis, token=slack)
+    sdiag.update(list_slack=slack,
+                 list_ok=(slack >= 0.0).astype(jnp.int32))
+    return occ, sdiag
+
+
+def _std_forces_sharded(state, box, cfg: PropagatorConfig, keys, lists=None):
     """std pair-op stage under shard_map: per-device Mosaic kernels on the
     device's SFC slab, halos via the stage ``_halo_stage_fn`` chooses
     (sparse ppermute rounds by default, per-peer windows as the fallback).
+
+    ``lists``: a mesh's persistent PairLists (``rebuild_pair_lists_sharded``)
+    in place of ``keys``: every op walks the slab's lists over [own | halo]
+    rows, and a serve is a row gather by the frozen send layout + the same
+    ppermute rounds: no cell table, coverage, packing or localizing.
 
     The arrays arrive GLOBALLY sorted and slab-sharded (the sort is the
     domain redistribution, parallel/mesh.py). The shared prologue runs on
@@ -605,7 +762,6 @@ def _std_forces_sharded(state, box, cfg: PropagatorConfig, keys):
     pmax/pmin-reduced so every shard returns identical values.
     """
     from jax.sharding import PartitionSpec
-    from sphexa_tpu.parallel import exchange as ex
     from sphexa_tpu.sph import pallas_pairs as pp
 
     axis = cfg.shard_axis
@@ -614,27 +770,24 @@ def _std_forces_sharded(state, box, cfg: PropagatorConfig, keys):
     interpret = _pallas_interpret()
     P = cfg.mesh.shape[cfg.shard_axis]
     S_shard = state.x.shape[0] // P
-    # a merged run must fit in one source slab so the boundary split pass
-    # leaves at most one remainder per run (exchange._split_runs); a raw
-    # CELL wider than a slab still crosses and trips the split-overflow
-    # sentinel instead (pathological at any realistic shard size)
-    if nbr.run_cap > S_shard:
-        nbr = dataclasses.replace(nbr, run_cap=S_shard)
+    nbr = _slab_nbr(cfg, S_shard)
 
     stage = _halo_stage_fn(cfg, nbr, P, S_shard)
 
-    def forces(box, keys, x, y, z, h, m, vx, vy, vz, temp):
-        ranges, serve, jbuf, escaped, hmetrics = stage(x, y, z, h, keys, box)
+    def forces(box, head, x, y, z, h, m, vx, vy, vz, temp):
+        # ``head``: the sorted keys, or the lists that replace them
+        serve, jbuf, walk, hmetrics, escaped = _open_stage(
+            stage, lists, head, x, y, z, h, box, P, axis)
 
         halo1 = serve((x, y, z, m))
         rho, nc, occ = pp.pallas_density(
-            x, y, z, h, m, None, box, const, nbr, ranges=ranges,
+            x, y, z, h, m, None, box, const, nbr, **walk,
             jdata=jbuf((x, y, z, m), halo1), interpret=interpret,
         )
         p, c = hydro_std.compute_eos_std(temp, rho, const)
         halo2 = serve((m / rho,))
         cs, _ = pp.pallas_iad(
-            x, y, z, h, m / rho, None, box, const, nbr, ranges=ranges,
+            x, y, z, h, m / rho, None, box, const, nbr, **walk,
             jdata=jbuf((x, y, z, m / rho), (halo1[0], halo1[1], halo1[2],
                                             halo2[0])),
             interpret=interpret,
@@ -642,39 +795,38 @@ def _std_forces_sharded(state, box, cfg: PropagatorConfig, keys):
         halo3 = serve((h, vx, vy, vz, rho, p, c, *cs))
         ax, ay, az, du, dt_c, _ = pp.pallas_momentum_energy_std(
             x, y, z, vx, vy, vz, h, m, rho, p, c, *cs,
-            None, box, const, nbr, ranges=ranges,
+            None, box, const, nbr, **walk,
             jdata=jbuf((x, y, z, h, vx, vy, vz, m, rho, p, c, *cs),
                        (halo1[0], halo1[1], halo1[2], halo3[0], halo3[1],
                         halo3[2], halo3[3], halo1[3], halo3[4], halo3[5],
                         halo3[6], *halo3[7:])),
             interpret=interpret,
         )
-        # tail collectives (pmin, pmax, metrics gather) are mutually
-        # independent — chain them into one order (rendezvous guard)
-        dt_c = jax.lax.pmin(dt_c, axis)
-        occ = ex.fold_escape_sentinel(
-            ex.chain_after(occ, dt_c), escaped, cfg.nbr.cap, axis)
-        smetrics = _shard_metrics(ranges, escaped, hmetrics, axis,
-                                  token=occ)
+        # the tail collectives in one order: this one follows the serves
+        # by its data, _close_stage chains the rest on it
+        dt_c = jax.lax.pmin(dt_c, axis)  # jaxlint: disable=JXL006 -- head of the tail chain (_close_stage)
+        occ, smetrics = _close_stage(walk, escaped, hmetrics, x, y, z, h,
+                                     occ, dt_c, cfg.nbr.cap, axis)
         return rho, c, nc, occ, ax, ay, az, du, dt_c, smetrics
 
     Pp, Pr = PartitionSpec(axis), PartitionSpec()
+    head, head_spec, dkeys = _sharded_head(keys, lists, axis, P)
     # check_vma=False: pallas_call's out_shape carries no varying-axis
     # metadata, which the checker (correctly) refuses to infer; the pmax/
     # pmin reductions above guarantee the replicated outputs really are
     out = shard_map(
         forces,
         mesh=cfg.mesh,
-        in_specs=(Pr, Pp, Pp, Pp, Pp, Pp, Pp, Pp, Pp, Pp, Pp),
+        in_specs=(Pr, head_spec, Pp, Pp, Pp, Pp, Pp, Pp, Pp, Pp, Pp),
         out_specs=(Pp, Pp, Pp, Pr, Pp, Pp, Pp, Pp, Pr,
-                   {k: Pr for k in SHARD_DIAG_KEYS}),
+                   {k: Pr for k in dkeys}),
         check_vma=False,
-    )(box, keys, state.x, state.y, state.z, state.h, state.m,
+    )(box, head, state.x, state.y, state.z, state.h, state.m,
       state.vx, state.vy, state.vz, state.temp)
     return out
 
 
-def _ve_forces_sharded(state, box, cfg: PropagatorConfig, keys):
+def _ve_forces_sharded(state, box, cfg: PropagatorConfig, keys, lists=None):
     """VE pair-op stage under shard_map — the flagship propagator on the
     multi-chip fast path (HydroVeProp::computeForces, ve_hydro.hpp:131-208).
 
@@ -682,6 +834,7 @@ def _ve_forces_sharded(state, box, cfg: PropagatorConfig, keys):
     slab against the psum-built global cell table, candidate halos via
     the windowed all_to_all exchange, one serve round per reference halo
     epoch (xm; kx/prho/c/v; divv; alpha/gradv — ve_hydro.hpp:154-188).
+    ``lists``: as ``_std_forces_sharded``'s.
     """
     from jax.sharding import PartitionSpec
     from sphexa_tpu.parallel import exchange as ex
@@ -693,22 +846,22 @@ def _ve_forces_sharded(state, box, cfg: PropagatorConfig, keys):
     interpret = _pallas_interpret()
     P = cfg.mesh.shape[cfg.shard_axis]
     S_shard = state.x.shape[0] // P
-    if nbr.run_cap > S_shard:
-        nbr = dataclasses.replace(nbr, run_cap=S_shard)
+    nbr = _slab_nbr(cfg, S_shard)
 
     stage = _halo_stage_fn(cfg, nbr, P, S_shard)
 
-    def forces(box, min_dt, keys, x, y, z, h, m, vx, vy, vz, temp, alpha0):
-        ranges, serve, jbuf, escaped, hmetrics = stage(x, y, z, h, keys, box)
+    def forces(box, min_dt, head, x, y, z, h, m, vx, vy, vz, temp, alpha0):
+        serve, jbuf, walk, hmetrics, escaped = _open_stage(
+            stage, lists, head, x, y, z, h, box, P, axis)
 
         hx, hy, hz, hh, hm = serve((x, y, z, h, m))
         xm, nc, occ = pp.pallas_xmass(
-            x, y, z, h, m, None, box, const, nbr, ranges=ranges,
+            x, y, z, h, m, None, box, const, nbr, **walk,
             jdata=jbuf((x, y, z, m), (hx, hy, hz, hm)), interpret=interpret,
         )
         (hxm,) = serve((xm,))
         (kx, gradh), _ = pp.pallas_ve_def_gradh(
-            x, y, z, h, m, xm, None, box, const, nbr, ranges=ranges,
+            x, y, z, h, m, xm, None, box, const, nbr, **walk,
             jdata=jbuf((x, y, z, m, xm), (hx, hy, hz, hm, hxm)),
             interpret=interpret,
         )
@@ -716,7 +869,7 @@ def _ve_forces_sharded(state, box, cfg: PropagatorConfig, keys):
         hkx, hprho, hc, hvx, hvy, hvz = serve((kx, prho, c, vx, vy, vz))
         cs, dvout, _ = pp.pallas_iad_divv_curlv(
             x, y, z, vx, vy, vz, h, kx, xm,
-            None, box, const, nbr, ranges=ranges,
+            None, box, const, nbr, **walk,
             with_gradv=cfg.av_clean,
             jdata=jbuf((x, y, z, xm / kx, xm, vx, vy, vz),
                        (hx, hy, hz, hxm / hkx, hxm, hvx, hvy, hvz)),
@@ -727,7 +880,7 @@ def _ve_forces_sharded(state, box, cfg: PropagatorConfig, keys):
         (hdivv,) = serve((divv,))
         alpha = pp.pallas_av_switches(
             x, y, z, vx, vy, vz, h, c, kx, xm, divv, alpha0, *cs,
-            None, box, min_dt, const, nbr, ranges=ranges,
+            None, box, min_dt, const, nbr, **walk,
             jdata=jbuf((x, y, z, c, vx, vy, vz, xm / kx, divv),
                        (hx, hy, hz, hc, hvx, hvy, hvz, hxm / hkx, hdivv)),
             interpret=interpret,
@@ -737,7 +890,7 @@ def _ve_forces_sharded(state, box, cfg: PropagatorConfig, keys):
         hcs, hgv = hcs_gv[:6], hcs_gv[6:]
         ax, ay, az, du, dt_c, _ = pp.pallas_momentum_energy_ve(
             x, y, z, vx, vy, vz, h, m, prho, c, kx, xm, alpha, *cs,
-            None, box, const, nbr, nc=nc, gradv=gradv, ranges=ranges,
+            None, box, const, nbr, nc=nc, gradv=gradv, **walk,
             jdata=jbuf(
                 (x, y, z, h, vx, vy, vz, c, alpha, m, xm, kx, prho, *cs)
                 + tuple(gradv or ()),
@@ -746,25 +899,23 @@ def _ve_forces_sharded(state, box, cfg: PropagatorConfig, keys):
             ),
             interpret=interpret,
         )
-        # tail collectives (2x pmin, pmax, metrics gather) are mutually
-        # independent — chain them into one order (rendezvous guard)
+        # the tail collectives in one order (the rest in _close_stage)
         dt_c = jax.lax.pmin(dt_c, axis)
         dt_rho = jax.lax.pmin(ex.chain_after(dt_rho, dt_c), axis)
-        occ = ex.fold_escape_sentinel(
-            ex.chain_after(occ, dt_rho), escaped, cfg.nbr.cap, axis)
-        smetrics = _shard_metrics(ranges, escaped, hmetrics, axis,
-                                  token=occ)
+        occ, smetrics = _close_stage(walk, escaped, hmetrics, x, y, z, h,
+                                     occ, dt_rho, cfg.nbr.cap, axis)
         return rho, c, nc, occ, ax, ay, az, du, dt_c, dt_rho, alpha, smetrics
 
     Pp, Pr = PartitionSpec(axis), PartitionSpec()
+    head, head_spec, dkeys = _sharded_head(keys, lists, axis, P)
     out = shard_map(
         forces,
         mesh=cfg.mesh,
-        in_specs=(Pr, Pr, Pp, Pp, Pp, Pp, Pp, Pp, Pp, Pp, Pp, Pp, Pp),
+        in_specs=(Pr, Pr, head_spec, Pp, Pp, Pp, Pp, Pp, Pp, Pp, Pp, Pp, Pp),
         out_specs=(Pp, Pp, Pp, Pr, Pp, Pp, Pp, Pp, Pr, Pr, Pp,
-                   {k: Pr for k in SHARD_DIAG_KEYS}),
+                   {k: Pr for k in dkeys}),
         check_vma=False,
-    )(box, state.min_dt, keys, state.x, state.y, state.z, state.h, state.m,
+    )(box, state.min_dt, head, state.x, state.y, state.z, state.h, state.m,
       state.vx, state.vy, state.vz, state.temp, state.alpha)
     return out
 
@@ -789,10 +940,15 @@ def _force_stage_prologue(state, box, cfg: PropagatorConfig, lists, aux=None,
         from sphexa_tpu.sph.pair_lists import list_slack
 
         if cfg.shard_axis is not None:
-            raise NotImplementedError(
-                "persistent lists compose with single-device steps (with "
-                "or without self-gravity); a mesh step sorts and streams "
-                "every step")
+            if cfg.gravity is not None:
+                raise NotImplementedError(
+                    "persistent lists on a mesh run the hydro step "
+                    "families; the mesh's tree solve takes the global "
+                    "sort's slabs, so a step under self-gravity sorts "
+                    "and streams every step there")
+            # each slab checks its own rows inside the force stage's
+            # shard_map (_frozen_tail): no reduction out here
+            return state, box, None, None, aux
         with phase_scope("neighbors"):
             slack = list_slack(state.x, state.y, state.z, state.h, lists)
             ldiag = {"list_slack": slack,
@@ -839,7 +995,7 @@ def _std_forces(
     if cfg.backend == "pallas" and cfg.shard_axis is not None:
         # multi-chip fast path: per-shard Mosaic kernels under shard_map
         (rho, c, nc, occ, ax, ay, az, du, dt_courant,
-         sdiag) = _std_forces_sharded(state, box, cfg, keys)
+         sdiag) = _std_forces_sharded(state, box, cfg, keys, lists=lists)
     elif cfg.backend == "pallas":
         # fused search+op TPU kernels: one shared cell-range prologue,
         # neighbor lists never materialize (sph/pallas_pairs.py)
@@ -1016,7 +1172,8 @@ def _ve_forces(
     if cfg.backend == "pallas" and cfg.shard_axis is not None:
         # multi-chip fast path: per-shard Mosaic kernels + windowed halos
         (rho, c, nc, occ, ax, ay, az, du, dt_courant, dt_rho,
-         alpha, sdiag) = _ve_forces_sharded(state, box, cfg, keys)
+         alpha, sdiag) = _ve_forces_sharded(state, box, cfg, keys,
+                                            lists=lists)
     elif cfg.backend == "pallas":
         # fused search+op TPU engine for the full VE sequence — the
         # reference's flagship propagator (ve_hydro.hpp:131-208) on the

@@ -196,6 +196,8 @@ def make_propagator_config(
     dt_bins: Optional[int] = None,
     bin_sync_every: int = 1,
     bin_resort_drift: float = 0.0,
+    mesh=None,
+    halo_margin: float = 1.4,
 ) -> PropagatorConfig:
     """Size the static neighbor-search config from the current particle
     distribution (single source of truth — used by Simulation, tests and
@@ -215,9 +217,16 @@ def make_propagator_config(
     default host path keeps the native C++ runtime exercised
     single-device.
 
-    ``sizing_cache``: optional precomputed (keys, order) device arrays
-    for the device_sizing path, so a caller that also needs keys (the
-    gravity reconfigure) computes them once.
+    ``sizing_cache``: optional precomputed (keys, order, box the keys
+    were made in) device arrays for the device_sizing path, so a caller
+    that also needs keys (the gravity reconfigure) computes them once.
+
+    ``mesh`` (with ``device_sizing`` and ``use_lists``): the run's mesh.
+    Persistent lists on a mesh are sized per slab, and with them the halo
+    caps of the skin-inflated windows their rebuild negotiates over
+    (``halo_cells`` / ``halo_runs`` of the returned config, under
+    ``halo_margin``): parallel/sizing.device_list_caps, each count taken
+    under ``shard_map`` as the rebuild takes it.
 
     ``h_relax`` (>= 1): how far ``h`` still is from the fixed point of
     its update (kernels.h_fixed_point, read by the driver from a
@@ -265,7 +274,7 @@ def make_propagator_config(
         level = min(level, level_occ)
         occ, ext_d = sizing.sizing_stats(
             state.x, state.y, state.z, box, level, group, curve,
-            *(sizing_cache or (None, None))
+            *(sizing_cache[:2] if sizing_cache else (None, None))
         )
         cap = pad_cap(int(sizing.fetch(occ)))
         ext = np.asarray(sizing.fetch(ext_d))
@@ -309,9 +318,13 @@ def make_propagator_config(
     # a cap under run_cap bounds nothing, and outgrowing it re-sizes into
     # identical shapes (a recompile for no new buffer: Noh's central cells
     # gain 4 % a step). A mesh clamps run_cap to the slab AFTER this
-    # sizing (parallel/sizing.py), so there the cap stays as measured.
+    # sizing (parallel/sizing.py), so there the cap stays as measured,
+    # unless it walks lists: a list outlives many steps, and the clamp is
+    # known here (a slab's rows).
     if backend == "pallas" and not device_sizing:
         cap = max(cap, run_cap)
+    elif backend == "pallas" and use_lists and mesh is not None:
+        cap = max(cap, min(run_cap, state.n // mesh.size))
 
     # 10% radius slack absorbs drift between reconfigurations; a whole
     # margin cell costs ~2x window cells (every cell is a kernel iteration),
@@ -332,7 +345,9 @@ def make_propagator_config(
 
     nbr = make_nbr(size_window(4.0 * h_max * 1.1))
     slot_cap = slots_cap = 0
-    if use_lists and backend == "pallas" and not device_sizing:
+    halo_cells, halo_runs = (), 0
+    if use_lists and backend == "pallas" and (
+            mesh is not None or not device_sizing):
         from sphexa_tpu.sph.pair_lists import estimate_list_caps
         from sphexa_tpu.sph.pallas_pairs import engine_fold
 
@@ -361,6 +376,20 @@ def make_propagator_config(
                                        margin_cells=int(open_box)))
             if engine_fold(box, nbr):
                 nbr = make_nbr(size_window(4.0 * h_max * 1.1))
+            elif mesh is not None:
+                # per slab, and the halo of the inflated windows with it
+                from sphexa_tpu.parallel import sizing
+                from sphexa_tpu.sfc.keys import compute_sfc_keys
+
+                keys_d, _, gbox = sizing_cache or (
+                    compute_sfc_keys(state.x, state.y, state.z, box,
+                                     curve=curve), None, box)
+                halo_cells, halo_runs, slot_cap, slots_cap = (
+                    sizing.device_list_caps(
+                        state.x, state.y, state.z,
+                        state.h * _jnp.float32(h_relax), keys_d, gbox, nbr,
+                        skin, mesh, halo_margin=halo_margin,
+                        slot_margin=list_slot_margin))
             else:
                 # reuse the native sizing pass's keys/order (a second
                 # device keygen+argsort at 1M costs tens of ms per
@@ -376,6 +405,7 @@ def make_propagator_config(
         const=const, nbr=nbr, curve=curve, block=block, av_clean=av_clean,
         keep_accels=keep_accels, backend=backend,
         list_slot_cap=slot_cap, list_slots_cap=slots_cap,
+        halo_cells=halo_cells, halo_runs=halo_runs,
         list_skin_rel=list_skin_rel, obs=obs_spec,
         snap=snap_spec,
         dt_bins=dt_bins, bin_sync_every=bin_sync_every,
@@ -837,16 +867,19 @@ class Simulation:
                 self.chem = shard_state(self.chem, self._mesh)
         # persistent neighbor lists (sph/pair_lists.py): steady steps skip
         # the global sort + prologue and lane-compact the momentum ops;
-        # enabled on the single-device pallas path, with or without
+        # enabled on the pallas path: on one device with or without
         # self-gravity (the tree solve sorts a copy of its five inputs,
-        # propagator._add_gravity). The eligibility re-derives at every
-        # _configure (fold mode depends on the sized grid).
+        # propagator._add_gravity), on a mesh without it (each slab's
+        # lists over own + halo rows, _lists_eligible). The eligibility
+        # re-derives at every _configure (fold mode depends on the sized
+        # grid).
         self._want_lists = use_lists
         self._list_skin_rel = list_skin_rel
         self._lists = None
         # iteration at which the live (or last dropped) list was built;
         # None until the run's first build
         self._lists_built_it = None
+        self._layout_age = 0
         # why the next launch finds no list: the run's first, a
         # reconfigure dropped it, or a build that raised is retried
         self._list_reason = "first"
@@ -892,10 +925,17 @@ class Simulation:
     @property
     def _lists_eligible(self) -> bool:
         # blockdt steps run their own fold-key sort prologue and have no
-        # frozen-order fast path — lists stay off under dt_bins
+        # frozen-order fast path — lists stay off under dt_bins. On a mesh
+        # the hydro step families walk lists (each slab's over its own +
+        # halo rows, the send layout frozen with them); self-gravity there
+        # is the one thing left: the mesh's tree solve runs on the global
+        # sort's slabs (_gravity_sharded_stage), and under a frozen order
+        # it would need a key-sorted copy through GSPMD's sort every step
+        # and the accelerations' way back (ROADMAP S3)
         return (
             self._want_lists
-            and self._mesh is None
+            and not (self._mesh is not None
+                     and (self.gravity_on or self._halo_mode != "sparse"))
             and self.prop_name != "nbody"
             and not self._blockdt
         )
@@ -1000,7 +1040,8 @@ class Simulation:
                 list_skin_rel=self._list_skin_rel,
                 list_slot_margin=self._slot_margin,
                 h_relax=self._h_relax,
-                sizing_cache=sizing_cache[:2] if sizing_cache else None,
+                sizing_cache=sizing_cache,
+                mesh=self._mesh, halo_margin=self._halo_margin,
                 obs_spec=self._obs_spec,
                 snap_spec=self._snap_spec,
                 dt_bins=self.dt_bins, bin_sync_every=self.bin_sync_every,
@@ -1058,7 +1099,10 @@ class Simulation:
                 gbox = make_global_box(s.x, s.y, s.z, self.box)
                 keys = compute_sfc_keys(s.x, s.y, s.z, gbox,
                                         curve=self.curve)
-            if self._halo_mode == "sparse":
+            if self._cfg.halo_cells:
+                # sized with the pair lists, for their inflated windows
+                hcells, hruns = self._cfg.halo_cells, self._cfg.halo_runs
+            elif self._halo_mode == "sparse":
                 hcells, hruns = device_sparse_halo(
                     s.x, s.y, s.z, s.h, keys, gbox, self._cfg.nbr,
                     P=self._mesh.size, margin=self._halo_margin,
@@ -1351,7 +1395,13 @@ class Simulation:
         Replaces the per-step rebuild the reference does
         (find_neighbors.cuh) — between rebuilds the steady steps run on
         the frozen order. A slot-cap overflow re-sizes the static budget
-        (recompile) and retries, like every other cap.
+        (recompile) and retries, like every other cap. On a mesh the
+        program is the sharded stepper's (``stepper.rebuild``: the global
+        sort, then every slab's halo stage, list build and frozen send
+        layout in one ``shard_map``), its counts the fullest slab's
+        (``slot_need``, ``slots_live``) or the slabs' sums, and a halo
+        that escaped its caps there re-sizes like a list cap, under a
+        grown halo margin.
 
         One ``rebuild_lists`` event per call that built a list, with the
         WHY: ``reason`` (first | proactive | expiry | rollback |
@@ -1391,9 +1441,13 @@ class Simulation:
             # 16 GB chip out of memory; the flat table is 1.3 GB)
             self._lists = None
             with self.telemetry.span("sphexa:rebuild-lists"):
-                state, box, lists, aux = rebuild_pair_lists(
-                    self.state, self.box, self._cfg, aux
-                )
+                if self._mesh is not None:
+                    state, box, lists, aux = self._drain(
+                        self._stepper.rebuild(self.state, self.box, aux))
+                else:
+                    state, box, lists, aux = rebuild_pair_lists(
+                        self.state, self.box, self._cfg, aux
+                    )
                 overflow, need, live, chunks, runs = (
                     int(v) for v in _jax.device_get(
                         (lists.overflow, lists.slot_need, lists.slots_live,
@@ -1420,7 +1474,13 @@ class Simulation:
                     cover_steps=cover,
                 )
                 return
-            self._slot_margin *= 1.5
+            if overflow & 2:
+                # a slab's halo escaped the caps sized for the inflated
+                # windows: the mesh's sentinel, met at the rebuild
+                self._halo_margin *= 1.5
+                self.telemetry.count("halo_trips")
+            if overflow & 1:
+                self._slot_margin *= 1.5
             self._configure(reason="list-slot")
         raise RuntimeError("pair-list slot cap failed to converge")
 
@@ -1561,7 +1621,8 @@ class Simulation:
             return ("sharded", self.prop_name, self._cfg,
                     info.get("caps"), info.get("wmax"),
                     info.get("run_slots"),
-                    ginfo.get("caps"), ginfo.get("wmax"))
+                    ginfo.get("caps"), ginfo.get("wmax"),
+                    self._use_lists and self._lists is not None)
         return (self.prop_name, self._cfg, self.turb_cfg,
                 self.cooling_cfg, donate_now,
                 self._use_lists and self._lists is not None)
@@ -1611,10 +1672,18 @@ class Simulation:
         twin so the state is updated in place."""
         if self.debug_checks:
             return self._launch_debug()
+        lists = None
+        if self._use_lists:
+            if self._lists is None:
+                self._rebuild_lists(self._list_reason)
+            # (None where the rebuild found lists unavailable: stream)
+            lists = self._lists
         if self._mesh is not None:
-            return self._drain(
-                self._stepper.step_sim(self.sim_state, self._gtree)
-            )
+            # steps the send layout this launch ships over has served
+            self._layout_age = (0 if lists is None
+                                else self.iteration - self._lists_built_it)
+            return self._drain(self._stepper.step_sim(
+                self.sim_state, self._gtree, lists=lists))
         donate_now = donate_ok and self._donate_active
         if donate_now:
             # freshly-built states alias leaves (build_state shares one
@@ -1624,11 +1693,7 @@ class Simulation:
             # only ever pays on the first donated launch of a state)
             self.state = _dealias_leaves(self.state)
         step_fn = self._step_fn(donated=donate_now)
-        kw = {}
-        if self._use_lists:
-            if self._lists is None:
-                self._rebuild_lists(self._list_reason)
-            kw["lists"] = self._lists
+        kw = {} if lists is None else {"lists": lists}
         aux_cfg = (self.turb_cfg if self.prop_name == "turb-ve"
                    else self.cooling_cfg if self.prop_name == "std-cooling"
                    else None)
@@ -1737,6 +1802,9 @@ class Simulation:
                 bytes_per_step=int(info.get("bytes_per_step", 0)),
                 trips=int(tel.counters.get("halo_trips", 0)),
                 stage="sph", **run_fields(info, arr("shard_runs")),
+                # schema-v20: steps the layout the newest launch shipped
+                # over had served: a list step's is frozen at its rebuild
+                layout_age_steps=self._layout_age,
             )
         # schema-v7: the gravity near field gets its own exchange event
         # when the MAC-sized sparse serve is active (gshard_* diagnostics

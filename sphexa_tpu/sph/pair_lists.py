@@ -98,6 +98,9 @@ class PairLists(NamedTuple):
     zb: jax.Array         # against these (Verlet skin condition)
     hb: jax.Array
     skin: jax.Array       # () f32 — the coverage slack baked into ranges
+    halo: object = None   # a mesh slab's lists: the send layout frozen with
+    #                       them (parallel/exchange.FrozenHalo); the runs
+    #                       above then index [own rows | served halo rows]
 
     @property
     def slot_cap(self) -> int:
@@ -545,9 +548,16 @@ def _rotation_rows(rows, live):
 def build_pair_lists(
     x, y, z, h, sorted_keys, box: Box, cfg: NeighborConfig,
     skin, slot_cap: int, slots_cap: int, interpret: bool = False,
-    table=None,
+    table=None, ranges=None, jdata=None,
 ) -> PairLists:
     """Build the persistent lists from SFC-SORTED arrays (jit-safe).
+
+    ``ranges`` + ``jdata`` (a mesh slab, under ``shard_map``): the i-side
+    is the slab's own rows, ``jdata`` the ``(x, y, z)`` of its j-buffer
+    [own | served halo rows] and ``ranges`` its candidate runs localized
+    into that buffer, from a halo stage run with ``radius_pad = skin``
+    (parallel/exchange.shard_halo_stage_sparse). Without them the j-side
+    is the i-side and the runs are found here: one device.
 
     ``skin`` (traced f32) is the coverage slack; ``slot_cap`` the static
     per-group chunk-slot budget and ``slots_cap`` the static row budget
@@ -561,11 +571,12 @@ def build_pair_lists(
         raise ValueError(
             "persistent lists need per-cell image shifts; the tiny-grid "
             "fold mode streams instead (lists are a large-N optimization)")
-    ranges = group_cell_ranges(
-        x, y, z, h, sorted_keys, box, cfg, table=table, radius_pad=skin,
-    )
+    if ranges is None:
+        ranges = group_cell_ranges(
+            x, y, z, h, sorted_keys, box, cfg, table=table, radius_pad=skin,
+        )
     i_fields = _prep_i(x, y, z, h, (), cfg.group)
-    jp = pack_j_fields((x, y, z), cfg.dma_cap)
+    jp = pack_j_fields(jdata or (x, y, z), cfg.dma_cap)
     cnt, total = _count_marks(cfg, slot_cap, interpret, ranges, i_fields,
                               jp, skin)
 

@@ -107,13 +107,22 @@ import numpy as np
 #: another slab than they came from, in the window's last step:
 #: ``migrant_rows / rows`` is the share of that exchange that is real
 #: redistribution. No kind, no REQUIRED field: v19 readers accept v1-v18
-#: files.
-SCHEMA_VERSION = 19
+#: files;
+#: v20 persistent pair lists on a mesh: ``exchange`` of stage ``"sph"``
+#: gains the optional ``layout_age_steps``, the steps the send layout the
+#: newest launch shipped over had served when it shipped: a list step's
+#: layout is frozen with its lists at their rebuild (``PairLists.halo``)
+#: and ages with them; a streamed step negotiates its own and reads 0.
+#: The ``rebuild_lists`` event is emitted on a mesh with the fields it
+#: has (``slot_need`` / ``slots_live`` the fullest slab's, ``chunks_live``
+#: / ``runs_live`` the slabs' sums). No kind, no REQUIRED field: v20
+#: readers accept v1-v19 files.
+SCHEMA_VERSION = 20
 
 #: event schema versions this reader understands (older versions only
 #: ever ADD kinds, so the per-kind field table below covers them all)
 SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15,
-                      16, 17, 18, 19)
+                      16, 17, 18, 19, 20)
 
 #: every event kind the schema admits, with its required payload fields
 #: (beyond the envelope ``v``/``seq``/``t``/``kind``). The CLI's --strict
@@ -144,7 +153,8 @@ EVENT_KINDS: Dict[str, tuple] = {
     # actually moved per serve (sum(hmax) sparse / (P-1)*Wmax windowed);
     # since v14 with the optional ``run_slots`` / ``live_runs_max``;
     # since v19 also stage "sort": ``rows`` the rows sorted (an int),
-    # with the optional ``migrant_rows``
+    # with the optional ``migrant_rows``; since v20 (stage ``sph``) with
+    # the optional ``layout_age_steps``
     "exchange": ("it", "shipped_rows", "rows"),
     # per-window load record: per-shard particle counts + work proxies
     "shard_load": ("it", "particles"),

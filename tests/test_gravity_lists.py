@@ -218,9 +218,12 @@ class TestTheSolveSortsItsOwnCopy:
             assert int(shuffled_out[5][k]) == int(sorted_out[5][k])
 
     def test_the_mesh_is_still_refused(self):
+        """Under self-gravity: the hydro step families walk lists on a
+        mesh since PR 46 (tests/mesh_list_cases.py)."""
         sim, _ = make_sim("std", use_lists=False)
+        assert sim._cfg.gravity is not None
         cfg = dataclasses.replace(sim._cfg, shard_axis="p")
-        with pytest.raises(NotImplementedError, match="single-device"):
+        with pytest.raises(NotImplementedError, match="tree solve"):
             prop_mod._force_stage_prologue(sim.state, sim.box, cfg,
                                            lists=object())
 

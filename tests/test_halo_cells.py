@@ -439,8 +439,10 @@ TRIP_RUNNER = """
     state = jax.tree.map(
         lambda a: a[:n4] if getattr(a, "ndim", 0) >= 1 else a, state)
     sink = MemorySink()
+    # the STREAMED mesh step's sentinel (a mesh that walks lists ships over
+    # a frozen layout: nothing escapes inside a step there)
     sim = Simulation(state, box, const, prop="std", backend="pallas",
-                     num_devices=4, check_every=1,
+                     num_devices=4, check_every=1, use_lists=False,
                      telemetry=Telemetry(sinks=[sink]))
     started = sim._halo_info["run_slots"]
     margin0 = sim._halo_margin

@@ -98,7 +98,7 @@ class TestRegistry:
         from sphexa_tpu.telemetry.registry import (
             EVENT_KINDS, KIND_SINCE, SUPPORTED_VERSIONS)
 
-        assert SCHEMA_VERSION == 19 == SUPPORTED_VERSIONS[-1]
+        assert SCHEMA_VERSION == 20 == SUPPORTED_VERSIONS[-1]
         assert EVENT_KINDS["rebuild_lists"] == ("it",)
         assert 18 not in KIND_SINCE.values()
         e = {"v": v, "seq": 0, "t": 1.0, "kind": "rebuild_lists", "it": 10,
@@ -138,6 +138,30 @@ class TestRegistry:
                      rows=4189076, migrant_rows=212)
             for k in ("run_slots", "live_runs_max"):
                 del e[k]
+        sink = MemorySink()
+        t = Telemetry(sinks=[sink])
+        t.event("exchange", **{k: val for k, val in e.items()
+                               if k not in ("v", "seq", "t", "kind")})
+        (sent,) = sink.events
+        assert validate_event(e) == [] == validate_event(sent)
+        assert sent["v"] == SCHEMA_VERSION
+
+    @pytest.mark.parametrize("v", [14, 19, SCHEMA_VERSION])
+    def test_exchange_layout_age_field(self, v):
+        """Schema v20: ``exchange`` of stage ``sph`` carries the optional
+        ``layout_age_steps`` (the steps the shipped send layout had served:
+        a mesh list step's is frozen at its rebuild); no kind and no
+        required field came, so a v14 and a v19 writer's stay clean."""
+        from sphexa_tpu.telemetry.registry import EVENT_KINDS, KIND_SINCE
+
+        assert EVENT_KINDS["exchange"] == ("it", "shipped_rows", "rows")
+        assert 20 not in KIND_SINCE.values()
+        e = {"v": v, "seq": 0, "t": 1.0, "kind": "exchange", "it": 8,
+             "steps": 4, "mode": "sparse", "shipped_rows": 712704,
+             "rows": [590210, 617004, 617311, 590077], "stage": "sph",
+             "run_slots": 32, "live_runs_max": 21}
+        if v >= 20:
+            e.update(layout_age_steps=7)
         sink = MemorySink()
         t = Telemetry(sinks=[sink])
         t.event("exchange", **{k: val for k, val in e.items()
