@@ -29,6 +29,7 @@ from sphexa_tpu.init.noh import init_noh, noh_constants
 from sphexa_tpu.init.sedov import init_sedov, sedov_constants
 from sphexa_tpu.init.turbulence import init_turbulence, turbulence_constants
 from sphexa_tpu.init.wind_shock import init_wind_shock, wind_shock_constants
+from sphexa_tpu.telemetry.registry import span
 
 # case name -> init function; the name set matches the reference's --init
 # choices (main/src/init/factory.hpp:59-100)
@@ -88,7 +89,23 @@ def make_initializer(name: str) -> Callable:
     ``case:settings.json`` appends a JSON settings file whose keys override
     the case defaults (the reference's ``--init sedov:my_settings`` path,
     factory.hpp:47-48); ``case+need`` asks for a program capability.
+
+    The function returned runs under the host span ``sphexa:init-case``
+    (handle-less: a caller that constructs its ``Simulation`` afterwards
+    finds the span, and the initialiser's compiles, in that registry).
     """
+    init = _case_function(name)
+    case = split_case_spec(name)[0]
+
+    @functools.wraps(init)
+    def spanned(*args, **kwargs):
+        with span("sphexa:init-case", case=case):
+            return init(*args, **kwargs)
+
+    return spanned
+
+
+def _case_function(name: str) -> Callable:
     case, settings_path = split_case_spec(name)
     if case in CASES and settings_path is None:
         return CASES[case]

@@ -8,6 +8,7 @@ when particle motion invalidates it — the rare recompile boundary — and
 """
 
 import dataclasses
+import functools
 import os
 import time
 from typing import Callable, Dict, NamedTuple, Optional, Tuple
@@ -17,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from sphexa_tpu.telemetry import Telemetry, emit_memory_event
-from sphexa_tpu.telemetry.registry import set_current
+from sphexa_tpu.telemetry.registry import set_current, span
 
 from sphexa_tpu.gravity.traversal import GravityConfig, estimate_gravity_caps
 from sphexa_tpu.neighbors.cell_list import (
@@ -488,6 +489,20 @@ CONSUMED_KNOBS = (
 ) + _NBR_FORWARDED + _GRAV_FORWARDED
 
 
+def _under_construct_span(init):
+    """``Simulation.__init__`` whole under the host span
+    ``sphexa:construct``: opened without a handle, it reports to the
+    registry the constructor names current, after the initial
+    ``sphexa:reconfigure`` (and its sizing passes) closed inside it."""
+
+    @functools.wraps(init)
+    def construct(self, *args, **kwargs):
+        with span("sphexa:construct"):
+            init(self, *args, **kwargs)
+
+    return construct
+
+
 class Simulation:
     """Owns state + static configs; reconfigures (recompiles) only when the
     cell grid no longer covers the interaction radius or a cell overflows
@@ -496,6 +511,7 @@ class Simulation:
     #: leaf bucket size of the gravity tree
     grav_bucket = 64
 
+    @_under_construct_span
     def __init__(
         self,
         state: ParticleState,

@@ -163,6 +163,28 @@ def _crash_view(run_dir: str) -> Optional[Dict]:
     }
 
 
+def _compile_view(compiles: List[dict]) -> Dict:
+    """What the run's programs cost to come by (schema v21 ``compile``
+    events): how many, how many the persistent cache held or missed, and
+    the seconds of tracing + lowering, of cache loads and of backend
+    compiles (``backend_s`` where the cache did not hold the program)."""
+    def total(key, of):
+        return float(sum(e[key] for e in of
+                         if isinstance(e.get(key), (int, float))))
+
+    hits = [e for e in compiles if e.get("cache") == "hit"]
+    rest = [e for e in compiles if e.get("cache") != "hit"]
+    return {
+        "programs": len(compiles),
+        "hits": len(hits),
+        "misses": len([e for e in rest if e.get("cache") == "miss"]),
+        "trace_lower_s": total("trace_s", compiles)
+        + total("lower_s", compiles),
+        "retrieval_s": total("retrieval_s", hits),
+        "backend_compile_s": total("backend_s", rest),
+    }
+
+
 def summarize_run(run_dir: str) -> Dict:
     """Aggregate one run directory into the summary dict.
 
@@ -230,6 +252,7 @@ def summarize_run(run_dir: str) -> Dict:
         "reconfigures": len([e for e in _of_kind(events, "reconfigure")
                              if e.get("reason") != "initial"]),
         "imbalances": len(_of_kind(events, "imbalance")),
+        "compiles": _compile_view(_of_kind(events, "compile")),
         "phase_mean_s": {k: float(np.mean(v)) for k, v in sorted(
             phases.items())},
         "unknown_kinds": {str(k): int(n)
@@ -635,6 +658,14 @@ def render_summary(s: Dict) -> str:
         rows.append((f"phase {k} (mean)", _fmt_s(v)))
     if s.get("imbalances"):
         rows.append(("imbalance events", s["imbalances"]))
+    c = s.get("compiles") or {}
+    if c.get("programs"):
+        rows.append(("compiles", (
+            f"{c['programs']} programs, {c['hits']} cache hits, "
+            f"{c['misses']} misses; trace + lower "
+            f"{c['trace_lower_s']:.2f} s, cache load "
+            f"{c['retrieval_s']:.2f} s, backend compile "
+            f"{c['backend_compile_s']:.2f} s")))
     lines.append(render_table(rows))
     lines.extend(_render_crash(s.get("crash")))
     for kind, n in s.get("unknown_kinds", {}).items():

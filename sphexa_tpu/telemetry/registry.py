@@ -1,4 +1,4 @@
-"""The metrics registry: counters, gauges, typed events, host spans.
+"""The metrics registry: counters, typed events, host spans, compiles.
 
 One ``Telemetry`` instance is shared by everything that measures a run —
 the Simulation driver, the app loop, the benchmark — so every surface reports
@@ -14,7 +14,7 @@ and is pinned by tests/test_telemetry.py.
 import itertools
 import threading
 import time
-from collections import Counter
+from collections import Counter, deque
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -116,13 +116,20 @@ import numpy as np
 #: The ``rebuild_lists`` event is emitted on a mesh with the fields it
 #: has (``slot_need`` / ``slots_live`` the fullest slab's, ``chunks_live``
 #: / ``runs_live`` the slabs' sums). No kind, no REQUIRED field: v20
-#: readers accept v1-v19 files.
-SCHEMA_VERSION = 20
+#: readers accept v1-v19 files;
+#: v21 the compile kind (compile): what a program cost to come by, folded
+#: from jax.monitoring's per-compile events into one event a program
+#: (``_on_duration`` below): the outermost trace, the lowering, the
+#: backend's time, and whether the persistent cache held the executable.
+#: ``retrace`` says THAT a launch traced; ``compile`` says what it cost.
+#: v21 only ADDS a kind: v21 readers accept v1-v20 files strictly clean
+#: and a v20 reader counts ``compile`` under unknown_kinds.
+SCHEMA_VERSION = 21
 
 #: event schema versions this reader understands (older versions only
 #: ever ADD kinds, so the per-kind field table below covers them all)
 SUPPORTED_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15,
-                      16, 17, 18, 19, 20)
+                      16, 17, 18, 19, 20, 21)
 
 #: every event kind the schema admits, with its required payload fields
 #: (beyond the envelope ``v``/``seq``/``t``/``kind``). The CLI's --strict
@@ -224,23 +231,34 @@ EVENT_KINDS: Dict[str, tuple] = {
     # ``time.perf_counter_ns``, plus the span's own payload. Emitted at
     # exit, so children precede their parent in the stream
     "span": ("name", "id", "parent", "it", "t0_ns", "dur_ns"),
+    # -- v21: compile kind (the jax.monitoring listeners below) -----------
+    # one program compiled or loaded: ``fun`` ("jit(<name>)"), seconds of
+    # the outermost trace / the lowering / the backend (``backend_s`` holds
+    # the retrieval on a hit), ``cache`` ("hit" | "miss" | "off"),
+    # ``retrieval_s`` / ``saved_s`` as jax reports them on a hit (else 0),
+    # ``t1_ns`` (``perf_counter_ns`` when the backend returned), ``it`` and
+    # ``parent`` as on a span: the innermost span open in the compiling
+    # thread, so a compile belongs to the launch, the sizing pass or the
+    # initialiser it happened under
+    "compile": ("fun", "trace_s", "lower_s", "backend_s", "cache",
+                "retrieval_s", "saved_s", "t1_ns", "it", "parent"),
 }
 
 #: first schema version each kind appeared in (an older-versioned event
 #: carrying a newer kind is writer confusion, not forward compatibility)
-_V2_ONLY = frozenset({"exchange", "shard_load", "memory", "imbalance"})
-_V3_ONLY = frozenset({"physics", "numerics", "drift", "field_health"})
-_V4_ONLY = frozenset({"phase_attr", "crash"})
-_V5_ONLY = frozenset({"sweep", "tuning"})
-_V6_ONLY = frozenset({"dt_bins"})
-_V8_ONLY = frozenset({"snapshot"})
-_V9_ONLY = frozenset({"span"})
+_KINDS_ADDED = {
+    2: ("exchange", "shard_load", "memory", "imbalance"),
+    3: ("physics", "numerics", "drift", "field_health"),
+    4: ("phase_attr", "crash"),
+    5: ("sweep", "tuning"),
+    6: ("dt_bins",),
+    8: ("snapshot",),
+    9: ("span",),
+    21: ("compile",),
+}
 KIND_SINCE: Dict[str, int] = {
-    k: 9 if k in _V9_ONLY else 8 if k in _V8_ONLY else 6 if k in _V6_ONLY
-    else 5 if k in _V5_ONLY
-    else 4 if k in _V4_ONLY else 3 if k in _V3_ONLY
-    else 2 if k in _V2_ONLY else 1
-    for k in EVENT_KINDS
+    **dict.fromkeys(EVENT_KINDS, 1),
+    **{k: v for v, kinds in _KINDS_ADDED.items() for k in kinds},
 }
 
 #: kinds that already existed in schema v1 (kept for introspection)
@@ -297,9 +315,16 @@ _OPEN = threading.local()
 #: jax.profiler.TraceAnnotation, resolved on the first span (None = not
 #: tried yet, False = jax unavailable: the telemetry CLI never imports jax)
 _TRACE_ANNOTATION = None
-#: the registry that spans opened without a handle report to (``span``
-#: below): the latest Simulation's, else the one ``set_current`` named
+#: the registry that spans opened without a handle, and the compile
+#: listeners, report to: the latest Simulation's, else the one
+#: ``set_current`` named
 _CURRENT: Optional["Telemetry"] = None
+#: what closed while no registry was current (the initialiser's span and
+#: compiles, when the caller constructs its Simulation afterwards):
+#: ``(kind, payload)``, the oldest dropped past PENDING_MAX, handed to the
+#: first registry ``set_current`` names
+PENDING_MAX = 256
+_PENDING: deque = deque(maxlen=PENDING_MAX)
 
 
 def _trace_annotation():
@@ -313,10 +338,20 @@ def _trace_annotation():
     return _TRACE_ANNOTATION
 
 
+def _report(tel, kind, payload):
+    """Emit on ``tel``, or keep for the next current registry."""
+    if tel is None:
+        _PENDING.append((kind, payload))
+    else:
+        tel.event(kind, **payload)
+
+
 class Span:
     """One open host span (``Telemetry.span``). Item assignment adds to
     the payload of the event it emits on exit, for what is known only
-    once the work is done (``sp["bytes"] = n``)."""
+    once the work is done (``sp["bytes"] = n``). Opened without a handle
+    (``tel`` None) it reports to the registry current when it CLOSES, and
+    with none current then, to the pending list."""
 
     __slots__ = ("_tel", "_name", "_payload", "_ann", "_t0", "_it",
                  "id", "parent")
@@ -334,7 +369,7 @@ class Span:
         self.id = next(_SPAN_IDS)
         self.parent = stack[-1] if stack else None
         stack.append(self.id)
-        self._it = self._tel.iteration
+        self._it = None if self._tel is None else self._tel.iteration
         ann = _trace_annotation()
         self._ann = ann(self._name, id=self.id) if ann else None
         if self._ann is not None:
@@ -347,30 +382,110 @@ class Span:
         if self._ann is not None:
             self._ann.__exit__(*exc)
         _OPEN.stack.pop()
-        self._tel.event("span", name=self._name, id=self.id,
-                        parent=self.parent, it=self._it, t0_ns=self._t0,
-                        dur_ns=dur, **self._payload)
+        tel, it = self._tel, self._it
+        if tel is None:
+            tel = _CURRENT
+            it = 0 if tel is None else tel.iteration
+        _report(tel, "span", dict(
+            name=self._name, id=self.id, parent=self.parent, it=it,
+            t0_ns=self._t0, dur_ns=dur, **self._payload))
         return False
 
 
-class _NoSpan:
-    """What ``span`` hands out while no registry is current."""
+# ---------------------------------------------------------------------------
+# the compile listeners: jax.monitoring's per-compile events folded into
+# one ``compile`` event a program. jax fires them synchronously on the
+# compiling thread, and only when it traces, lowers or compiles: a launch
+# of a compiled program reaches no listener.
+# ---------------------------------------------------------------------------
 
-    def __enter__(self):
-        return self
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = "/jax/compilation_cache/"
+#: None = not tried yet, True = registered, False = jax unavailable
+_LISTENING = None
+#: the pieces of the compile in flight in each thread
+_FOLD = threading.local()
 
-    def __exit__(self, *exc):
-        return False
 
-    def __setitem__(self, key, value):
-        pass
+def _count_callback():
+    tel = _CURRENT
+    if tel is not None:
+        tel.counters["compile_callbacks"] += 1
 
 
-_NO_SPAN = _NoSpan()
+def _on_event(event, **kwargs):
+    """``cache_hits`` / ``compile_requests_use_cache``, between a
+    program's lowering and the end of its backend compile."""
+    _count_callback()
+    if event.startswith(_CACHE_EVENTS):
+        _FOLD.__dict__.setdefault("cache", set()).add(
+            event[len(_CACHE_EVENTS):])
+
+
+def _on_duration(event, duration, **kwargs):
+    """Keep a compile's pieces as they arrive; ``backend_compile_duration``
+    is the last of them and emits the event. Nested jits trace inside the
+    outermost and report before it, under their own names: the trace kept
+    is the last one named as the lowered module is (``jit(<name>)``)."""
+    _count_callback()
+    pieces = _FOLD.__dict__
+    if event == _TRACE_EVENT:
+        pieces.setdefault("traces", {})[kwargs.get("fun_name")] = duration
+    elif event == _LOWER_EVENT:
+        pieces["lower"] = (kwargs.get("fun_name"), duration)
+    elif event.startswith(_CACHE_EVENTS):
+        pieces[event[len(_CACHE_EVENTS):]] = duration
+    elif event == _BACKEND_EVENT:
+        fun = str(kwargs.get("fun_name"))
+        lowered, lower_s = pieces.get("lower", (None, 0.0))
+        traces = pieces.get("traces", {})
+        cache = pieces.get("cache", ())
+        stack = getattr(_OPEN, "stack", None)
+        tel = _CURRENT
+        payload = dict(
+            fun=fun,
+            trace_s=traces.get(fun[fun.find("(") + 1:-1], 0.0),
+            lower_s=lower_s if lowered == fun else 0.0,
+            backend_s=duration,
+            cache=("hit" if "cache_hits" in cache
+                   else "miss" if "compile_requests_use_cache" in cache
+                   and _cache_dir() else "off"),
+            retrieval_s=pieces.get("cache_retrieval_time_sec", 0.0),
+            saved_s=pieces.get("compile_time_saved_sec", 0.0),
+            t1_ns=time.perf_counter_ns(),
+            it=0 if tel is None else tel.iteration,
+            parent=stack[-1] if stack else None)
+        pieces.clear()
+        _report(tel, "compile", payload)
+
+
+def _cache_dir():
+    """The persistent cache's directory, if one is in effect: jax asks its
+    cache for every program (``compile_requests_use_cache``) whether or
+    not it has anywhere to keep one."""
+    import jax
+
+    return jax.config.jax_compilation_cache_dir
+
+
+def _listen():
+    """Register the two listeners, once a process (jax imported lazily,
+    like ``_trace_annotation``; without jax nothing compiles)."""
+    global _LISTENING
+    if _LISTENING is None:
+        try:
+            from jax import monitoring
+            monitoring.register_event_listener(_on_event)
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            _LISTENING = True
+        except Exception:
+            _LISTENING = False
 
 
 class Telemetry:
-    """Counters + gauges + an event stream over sinks + host spans.
+    """Counters + an event stream over sinks + host spans.
 
     With no sinks the registry still accumulates (retrace/rollback
     counts can be read without writing files); ``event()``
@@ -380,7 +495,6 @@ class Telemetry:
     def __init__(self, sinks=()):
         self.sinks = list(sinks)
         self.counters: Counter = Counter()
-        self.gauges: Dict[str, float] = {}
         #: the iteration at which the driver's current check window (or
         #: checked step) opened; every span is stamped with it
         self.iteration = 0
@@ -389,9 +503,6 @@ class Telemetry:
     # -- scalar metrics ----------------------------------------------------
     def count(self, name: str, n: int = 1) -> None:
         self.counters[name] += n
-
-    def gauge(self, name: str, value: float) -> None:
-        self.gauges[name] = float(value)
 
     # -- event stream ------------------------------------------------------
     def event(self, kind: str, **payload) -> None:
@@ -449,19 +560,31 @@ class Telemetry:
 
 
 def set_current(telemetry: Optional[Telemetry]) -> None:
-    """Name the registry that handle-less spans report to. Called where a
-    ``Simulation`` is constructed and by ``main()``; ``Telemetry.close``
-    un-names a registry that is current."""
+    """Name the registry that handle-less spans and the compile listeners
+    report to, and hand it what closed while none was current. Called
+    where a ``Simulation`` is constructed and by ``main()``;
+    ``Telemetry.close`` un-names a registry that is current."""
     global _CURRENT
+    _listen()
     _CURRENT = telemetry
+    while telemetry is not None and _PENDING:
+        kind, payload = _PENDING.popleft()
+        telemetry.event(kind, **payload)
 
 
-def span(name: str, **payload):
-    """``Telemetry.span`` on the process-current registry, for code that
-    is called without a telemetry handle (``analysis/compare.py``,
-    ``io/snapshot.py``); with none current, a no-op."""
-    tel = _CURRENT
-    return _NO_SPAN if tel is None else tel.span(name, **payload)
+def current() -> Optional[Telemetry]:
+    """The process-current registry (None: no Simulation constructed or
+    ``main()`` entered yet, or the last one closed)."""
+    return _CURRENT
+
+
+def span(name: str, **payload) -> Span:
+    """A span for code that is called without a telemetry handle
+    (``analysis/compare.py``, ``io/snapshot.py``, ``init``): it reports to
+    the process-current registry, and with none current when it closes it
+    is kept for the first one ``set_current`` names."""
+    _listen()
+    return Span(None, name, payload)
 
 
 # ---------------------------------------------------------------------------

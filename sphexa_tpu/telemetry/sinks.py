@@ -57,7 +57,11 @@ class JsonlSink:
 #: console reader actually wants to see; per-step launch/phases spam is
 #: left to the JSONL record)
 _NOTABLE = ("reconfigure", "rollback", "replay", "retrace", "trace",
-            "imbalance", "drift", "field_health", "tuning")
+            "imbalance", "drift", "field_health", "tuning", "compile")
+#: a ``compile`` is notable where the backend compiled for longer than
+#: this with a cache directory in effect (the "why is this start slow"
+#: line); cache loads and the many small programs are not
+_SLOW_COMPILE_S = 1.0
 
 
 class ConsoleSink:
@@ -75,6 +79,10 @@ class ConsoleSink:
 
     def emit(self, event: dict) -> None:
         if self._kinds is not None and event.get("kind") not in self._kinds:
+            return
+        if event.get("kind") == "compile" and not (
+                event.get("cache") == "miss"
+                and event.get("backend_s", 0.0) > _SLOW_COMPILE_S):
             return
         body = " ".join(
             f"{k}={v}" for k, v in event.items()

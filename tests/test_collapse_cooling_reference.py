@@ -101,7 +101,7 @@ class TestCellFiles:
             "evrard-cooling", "std-cooling", 1098340)
         listed = [m["name"] for m in bench["per_layer"]
                   if "workloads" not in m or CELL in m["workloads"]]
-        assert len(listed) == 31
+        assert len(listed) == 41
         assert {"sort_aux_ms_step", "cooling_radiated_share",
                 "gravity_ms_step", "cell_ranges_ms_step",
                 "cooling_network_ms_step"} <= set(listed)

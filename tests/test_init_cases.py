@@ -49,7 +49,7 @@ class TestFactory:
         (init.CAPABILITIES) leaves the case as it is."""
         assert split_case_spec(spec) == (case, settings)
         if settings is None and case in CASES:
-            assert make_initializer(spec) is CASES[case]
+            assert make_initializer(spec).__wrapped__ is CASES[case]
 
     @pytest.mark.parametrize("spec", ["noh+warp-drive",
                                       "noh+list-lifecycle+warp-drive",
@@ -76,7 +76,7 @@ class TestFactory:
         for f in files:
             init = json.load(open(f))["init"]
             case, _ = split_case_spec(init)
-            assert make_initializer(init) is CASES[case], f
+            assert make_initializer(init).__wrapped__ is CASES[case], f
             assert make_observable_spec(init) == make_observable_spec(case)
 
     def test_settings_file_overrides(self, tmp_path):

@@ -68,7 +68,7 @@ class TestSnapshotSchema:
         end)."""
         assert KIND_SINCE["snapshot"] == 8
         assert all(v < 8 for k, v in KIND_SINCE.items()
-                   if k not in ("snapshot", "span"))
+                   if k not in ("snapshot", "span", "compile"))
         # a v7 writer never emitted snapshots; its events validate as-is
         old = {"v": 7, "seq": 1, "t": 0.0, "kind": "step", "it": 1,
                "wall_s": 0.1, "dt": 1e-3, "reconfigured": False}
