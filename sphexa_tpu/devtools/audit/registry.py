@@ -405,7 +405,7 @@ def gravity_sharded():
     # too big for a baked-in jaxpr constant)
     return EntryCase(
         fn=lambda st, bb, k, gt: prop._gravity_sharded_stage(
-            st, bb, cfg_sh, gt, k),
+            st.x, st.y, st.z, st.m, st.h, k, bb, cfg_sh, gt),
         args=(sstate, sim.box, skeys, sim._gtree),
     )
 
@@ -459,7 +459,7 @@ def gravity_sharded_windowed():
     # and the all_gathered telemetry scalars ride the headroom
     return EntryCase(
         fn=lambda st, bb, k, gt: prop._gravity_sharded_stage(
-            st, bb, cfg_sh, gt, k),
+            st.x, st.y, st.z, st.m, st.h, k, bb, cfg_sh, gt),
         args=(sstate, sim.box, skeys, sim._gtree),
         exchange_budget_bytes=sum(cells) * 5 * 4 + _EXCHANGE_HEADROOM,
     )

@@ -95,11 +95,16 @@ def make_sharded_step(mesh: Mesh, cfg: PropagatorConfig, step_fn=step_hydro_std,
     ride the slab sharding + the in-step SFC sort.
 
     Persistent pair lists (``cfg.list_slot_cap`` > 0, sized by the
-    Simulation for the hydro step families): ``stepper.rebuild(state, box,
-    aux)`` is the jitted ``propagator.rebuild_pair_lists_sharded`` (global
-    sort + every slab's list build and frozen send layout), and
-    ``stepper(..., lists=)`` / ``step_sim(..., lists=)`` the steady list
-    step; without ``lists`` the same stepper streams.
+    Simulation for every step family with a sharded pair stage, with or
+    without self-gravity): ``stepper.rebuild(state, box, aux)`` is the
+    jitted ``propagator.rebuild_pair_lists_sharded`` (global sort + every
+    slab's list build and frozen send layout), and ``stepper(...,
+    lists=)`` / ``step_sim(..., lists=)`` the steady list step, in which
+    the particle arrays are the slabs of the lists' frozen order and,
+    under ``cfg.gravity``, the tree solve takes a key-sorted copy of its
+    five inputs through one global sort and sends the accelerations back
+    through another (``propagator._add_gravity``); without ``lists`` the
+    same stepper sorts the state and streams.
     """
     from sphexa_tpu.propagator import (
         STEP_AUX_SLOT,

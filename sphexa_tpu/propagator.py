@@ -219,10 +219,10 @@ def _sort_by_keys(state: ParticleState, box: Box, curve: str, aux=None,
 
     ``shards`` > 1 (plain path, an ``aux`` carried over that many equal
     slabs of a mesh): a fourth value, the rows whose sorted position lies
-    on another slab than the one they came from. GSPMD makes the aux
-    gather an all-gather of every slab's rows to every device; this says
-    how many of them the sort really moved (telemetry ``exchange`` stage
-    ``sort``).
+    on another slab than the one they came from (``_migrant_rows``). GSPMD
+    makes the aux gather an all-gather of every slab's rows to every
+    device; this says how many of them the sort really moved (telemetry
+    ``exchange`` stage ``sort``).
     """
     # sphexa/sort: the whole keygen + argsort + permute program is one
     # attribution phase (profiler traces; util/phases.py taxonomy)
@@ -267,11 +267,8 @@ def _sort_by_keys(state: ParticleState, box: Box, curve: str, aux=None,
             with stage_scope("sort", "aux"):
                 aux = permute_tree(aux, order)
                 if shards > 1:
-                    slab = n // shards
-                    home = jnp.arange(n, dtype=order.dtype) // slab
-                    migrants = jnp.sum(
-                        (order // slab != home).astype(jnp.int32))
-                    return state, sorted_keys, aux, migrants
+                    return (state, sorted_keys, aux,
+                            _migrant_rows(order, shards))
             return state, sorted_keys, aux
 
     with phase_scope("dt-bins"):
@@ -420,12 +417,14 @@ def rebuild_pair_lists_sharded(state: ParticleState, box: Box,
     return state, box, lists, aux
 
 
-def _gravity_sharded_stage(state, box, cfg, gtree, keys):
-    """Distributed gravity under shard_map over the step's mesh: the open
-    Barnes-Hut solve (any multipole order) is
-    traversal.compute_gravity_on_mesh; the periodic Ewald path (cartesian
-    quadrupole, traversal_ewald_cpu.hpp parity) the same shape round
-    compute_gravity_ewald. Near-field halo sizing: cfg.grav_cells
+def _gravity_sharded_stage(x, y, z, m, h, keys, box, cfg, gtree):
+    """Distributed gravity under shard_map over the step's mesh, on the
+    five GLOBAL key-sorted, slab-sharded source arrays and their sorted
+    keys (the state a streamed step just sorted, or the copy a list step
+    makes, ``_add_gravity``): the open Barnes-Hut solve (any multipole
+    order) is traversal.compute_gravity_on_mesh; the periodic Ewald path
+    (cartesian quadrupole, traversal_ewald_cpu.hpp parity) the same shape
+    round compute_gravity_ewald. Near-field halo sizing: cfg.grav_cells
     (traversal.near_field_windows)."""
     from jax.sharding import PartitionSpec
 
@@ -433,10 +432,10 @@ def _gravity_sharded_stage(state, box, cfg, gtree, keys):
     gcfg = dataclasses.replace(cfg.gravity, G=cfg.const.g, use_pallas=True)
     if cfg.ewald is None:
         return compute_gravity_on_mesh(
-            state.x, state.y, state.z, state.m, state.h, keys, box, gtree,
+            x, y, z, m, h, keys, box, gtree,
             cfg.grav_meta, gcfg, cfg.mesh, axis, cfg.grav_cells)
     P = cfg.mesh.shape[axis]
-    win = near_field_windows(cfg.grav_cells, state.x.shape[0] // P)
+    win = near_field_windows(cfg.grav_cells, x.shape[0] // P)
 
     def stage(box, keys, x, y, z, m, h):
         gx, gy, gz, egrav, diag = compute_gravity_ewald(
@@ -455,10 +454,33 @@ def _gravity_sharded_stage(state, box, cfg, gtree, keys):
         in_specs=(Pr, Pp, Pp, Pp, Pp, Pp, Pp),
         out_specs=(Pp, Pp, Pp, Pr, dspec),
         check_vma=False,
-    )(box, keys, state.x, state.y, state.z, state.m, state.h)
+    )(box, keys, x, y, z, m, h)
 
 
-def _key_sorted_sources(state, box, curve: str):
+def _on_slabs(cfg, arrays):
+    """``arrays`` as they are on one device; on a mesh held to the slabs'
+    sharding: a global sort's outputs lie where the partitioner left
+    them, and what takes them next (the tree solve's ``shard_map``, the
+    integrator on the state's rows) takes slabs."""
+    if cfg.shard_axis is None:
+        return arrays
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    slabs = NamedSharding(cfg.mesh, PartitionSpec(cfg.shard_axis))
+    return tuple(jax.lax.with_sharding_constraint(a, slabs) for a in arrays)
+
+
+def _migrant_rows(order, shards: int):
+    """Rows of a global sort over ``shards`` equal slabs whose sorted
+    position lies on another slab than the row they came from
+    (``order``: the sort's permutation)."""
+    n = order.shape[0]
+    slab = n // shards
+    home = jnp.arange(n, dtype=order.dtype) // slab
+    return jnp.sum((order // slab != home).astype(jnp.int32))
+
+
+def _key_sorted_sources(state, box, cfg):
     """The tree solve's five inputs in key order, of a state that is not:
     ``(gbox, sorted_keys, order, x, y, z, m, h)``. A list step keeps the
     order its lists froze (and the hydro grid's box), so the solve sorts
@@ -467,27 +489,32 @@ def _key_sorted_sources(state, box, curve: str):
     fields and an iota as payloads. On a v5e at 1.1M rows that sort reads
     5 ms where an argsort, the sorted keys' gather and a row gather of
     the stacked fields read 2 + 8 + 5.6: a gather pays per index, a
-    sort's payloads ride along (PERF.md, PR 44)."""
+    sort's payloads ride along (PERF.md, PR 44). On a mesh the operands
+    are the slabs of the frozen order and the sort is global (GSPMD
+    partitions it); its seven outputs leave as the slabs of the key
+    order, which is what the mesh's solve takes."""
     with phase_scope("sort"):
         with stage_scope("sort", "keys"):
             gbox = make_global_box(state.x, state.y, state.z, box)
             keys = compute_sfc_keys(state.x, state.y, state.z, gbox,
-                                    curve=curve)
+                                    curve=cfg.curve)
         with stage_scope("sort", "order"):
             iota = jnp.arange(keys.shape[0], dtype=jnp.int32)
-            sorted_keys, x, y, z, m, h, order = jax.lax.sort(
+            sorted_keys, x, y, z, m, h, order = _on_slabs(cfg, jax.lax.sort(
                 (keys, state.x, state.y, state.z, state.m, state.h, iota),
-                num_keys=1)
+                num_keys=1))
     return gbox, sorted_keys, order, x, y, z, m, h
 
 
-def _to_frozen_order(order, gx, gy, gz):
+def _to_frozen_order(order, gx, gy, gz, cfg):
     """The solve's accelerations back in the order the state is in: one
     sort keyed on ``order`` (a permutation) with the three as payloads
     (3.8 ms on a v5e at 1.1M rows; the inverse permutation and a row
-    gather of the (N, 3) stack read 12.5)."""
+    gather of the (N, 3) stack read 12.5); on a mesh a global sort again,
+    out as the slabs of the frozen order."""
     with phase_scope("sort"), stage_scope("sort", "permute"):
         _, gx, gy, gz = jax.lax.sort((order, gx, gy, gz), num_keys=1)
+        gx, gy, gz = _on_slabs(cfg, (gx, gy, gz))
     return gx, gy, gz
 
 
@@ -498,22 +525,23 @@ def _add_gravity(state, box, keys, cfg, gtree, ax, ay, az):
     (main/src/propagator/gravity_wrapper.hpp:97-123). The solve runs on
     key-sorted arrays: the state the step just sorted (``keys`` its
     sorted keys), or, in a list step (``keys`` None: the state is in the
-    lists' frozen order), a key-sorted copy of ``x, y, z, m, h`` it makes
-    itself, with the accelerations brought back to the frozen order. The
-    sort work reads under phase ``sort``, the solve under its own phases.
-    Returns updated accels, egrav, the acceleration dt candidate, and
-    solver diagnostics (all order-free).
+    lists' frozen order, on one device or on a mesh), a key-sorted copy
+    of ``x, y, z, m, h`` it makes itself, with the accelerations brought
+    back to the frozen order. The sort work reads under phase ``sort``,
+    the solve under its own phases. Returns updated accels, egrav, the
+    acceleration dt candidate, and solver diagnostics (all order-free);
+    a list step on a mesh adds ``sort_migrant_rows``, the rows of the
+    copy that lie on another slab than their frozen row: what a list's
+    age has done to the slabs' key ranges.
     """
     x, y, z, m, h = state.x, state.y, state.z, state.m, state.h
     order = None
     if keys is None:
-        # (the mesh's stage takes the state itself, in key order)
-        assert cfg.shard_axis is None, "lists do not reach the mesh's solve"
         box, keys, order, x, y, z, m, h = _key_sorted_sources(
-            state, box, cfg.curve)
+            state, box, cfg)
     if cfg.shard_axis is not None:
         gx, gy, gz, egrav, gdiag = _gravity_sharded_stage(
-            state, box, cfg, gtree, keys
+            x, y, z, m, h, keys, box, cfg, gtree
         )
     elif cfg.ewald is not None:
         gcfg = dataclasses.replace(cfg.gravity, G=cfg.const.g)
@@ -528,7 +556,11 @@ def _add_gravity(state, box, keys, cfg, gtree, ax, ay, az):
             gtree, cfg.grav_meta, gcfg,
         )
     if order is not None:
-        gx, gy, gz = _to_frozen_order(order, gx, gy, gz)
+        gx, gy, gz = _to_frozen_order(order, gx, gy, gz, cfg)
+        if cfg.shard_axis is not None:
+            with phase_scope("sort"), stage_scope("sort", "order"):
+                gdiag = {**gdiag, "sort_migrant_rows": _migrant_rows(
+                    order, cfg.mesh.shape[cfg.shard_axis])}
     ax, ay, az = ax + gx, ay + gy, az + gz
     with phase_scope("timestep"):
         dt_acc = acceleration_timestep(ax, ay, az, cfg.const)
@@ -749,7 +781,8 @@ def _std_forces_sharded(state, box, cfg: PropagatorConfig, keys, lists=None):
     ``lists``: a mesh's persistent PairLists (``rebuild_pair_lists_sharded``)
     in place of ``keys``: every op walks the slab's lists over [own | halo]
     rows, and a serve is a row gather by the frozen send layout + the same
-    ppermute rounds: no cell table, coverage, packing or localizing.
+    ppermute rounds: no cell table, coverage, packing or localizing. The
+    arrays are then the slabs of the lists' frozen order.
 
     The arrays arrive GLOBALLY sorted and slab-sharded (the sort is the
     domain redistribution, parallel/mesh.py). The shared prologue runs on
@@ -923,11 +956,12 @@ def _ve_forces_sharded(state, box, cfg: PropagatorConfig, keys, lists=None):
 def _force_stage_prologue(state, box, cfg: PropagatorConfig, lists, aux=None,
                           keys=None):
     """Shared head of the force stages: list mode (frozen order, validity
-    diagnostics; with or without self-gravity on one device, whose solve
-    sorts a copy of its own inputs, ``_add_gravity``) vs per-step box
-    regrow + global sort. Returns
+    diagnostics; with or without self-gravity, on one device or on a
+    mesh: the tree solve sorts a copy of its own inputs, ``_add_gravity``)
+    vs per-step box regrow + global sort. Returns
     (state, box, keys, ldiag, aux); keys is None in list mode. ``ldiag``
-    is the prologue's own diagnostics: the list's validity in list mode,
+    is the prologue's own diagnostics: the list's validity in list mode
+    on one device (a mesh's slabs check theirs in the sharded stage),
     ``sort_migrant_rows`` where an aux state rides a mesh's sort, else
     None.
 
@@ -940,14 +974,8 @@ def _force_stage_prologue(state, box, cfg: PropagatorConfig, lists, aux=None,
         from sphexa_tpu.sph.pair_lists import list_slack
 
         if cfg.shard_axis is not None:
-            if cfg.gravity is not None:
-                raise NotImplementedError(
-                    "persistent lists on a mesh run the hydro step "
-                    "families; the mesh's tree solve takes the global "
-                    "sort's slabs, so a step under self-gravity sorts "
-                    "and streams every step there")
             # each slab checks its own rows inside the force stage's
-            # shard_map (_frozen_tail): no reduction out here
+            # shard_map (_close_stage): no reduction out here
             return state, box, None, None, aux
         with phase_scope("neighbors"):
             slack = list_slack(state.x, state.y, state.z, state.h, lists)
@@ -983,8 +1011,9 @@ def _std_forces(
     prologue; a ``list_ok`` diagnostic reports the Verlet-skin validity
     of THIS step's input positions (an invalid step is discarded and
     replayed by the driver, like a cap overflow). Under self-gravity
-    (one device) the hydro stays in the frozen order and the tree solve
-    takes a key-sorted copy of ``x, y, z, m, h`` (``_add_gravity``)."""
+    (one device or a mesh) the hydro stays in the frozen order and the
+    tree solve takes a key-sorted copy of ``x, y, z, m, h``
+    (``_add_gravity``)."""
     const = cfg.const
     state, box, keys, ldiag, aux = _force_stage_prologue(
         state, box, cfg, lists, aux, keys=keys

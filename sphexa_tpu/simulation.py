@@ -883,12 +883,11 @@ class Simulation:
                 self.chem = shard_state(self.chem, self._mesh)
         # persistent neighbor lists (sph/pair_lists.py): steady steps skip
         # the global sort + prologue and lane-compact the momentum ops;
-        # enabled on the pallas path: on one device with or without
-        # self-gravity (the tree solve sorts a copy of its five inputs,
-        # propagator._add_gravity), on a mesh without it (each slab's
-        # lists over own + halo rows, _lists_eligible). The eligibility
-        # re-derives at every _configure (fold mode depends on the sized
-        # grid).
+        # enabled on the pallas path, on one device and on a mesh (each
+        # slab's lists over own + halo rows, _lists_eligible), with or
+        # without self-gravity (the tree solve sorts a copy of its five
+        # inputs, propagator._add_gravity). The eligibility re-derives at
+        # every _configure (fold mode depends on the sized grid).
         self._want_lists = use_lists
         self._list_skin_rel = list_skin_rel
         self._lists = None
@@ -942,16 +941,14 @@ class Simulation:
     def _lists_eligible(self) -> bool:
         # blockdt steps run their own fold-key sort prologue and have no
         # frozen-order fast path — lists stay off under dt_bins. On a mesh
-        # the hydro step families walk lists (each slab's over its own +
-        # halo rows, the send layout frozen with them); self-gravity there
-        # is the one thing left: the mesh's tree solve runs on the global
-        # sort's slabs (_gravity_sharded_stage), and under a frozen order
-        # it would need a key-sorted copy through GSPMD's sort every step
-        # and the accelerations' way back (ROADMAP S3)
+        # every step family walks lists (each slab's over its own + halo
+        # rows, the send layout frozen with them; under self-gravity the
+        # mesh's tree solve takes a key-sorted copy of its five inputs,
+        # propagator._add_gravity); only the sparse halo mode freezes a
+        # send layout, so the windowed mode keeps streaming
         return (
             self._want_lists
-            and not (self._mesh is not None
-                     and (self.gravity_on or self._halo_mode != "sparse"))
+            and not (self._mesh is not None and self._halo_mode != "sparse")
             and self.prop_name != "nbody"
             and not self._blockdt
         )
@@ -1839,11 +1836,15 @@ class Simulation:
                 trips=int(tel.counters.get("grav_halo_trips", 0)),
                 stage="gravity", **run_fields(ginfo, arr("gshard_runs")),
             )
-        # schema-v19: the per-step global sort of a step that carries an
-        # aux state over the mesh (std-cooling's chemistry). GSPMD ships
-        # every slab's rows to every device for that gather
-        # (``shipped_rows``, per device); ``migrant_rows`` of the sorted
-        # ``rows`` really changed slab in the window's last step
+        # schema-v19: the per-step global sort of a mesh step. Streamed
+        # with an aux state (std-cooling's chemistry): GSPMD ships every
+        # slab's rows to every device for that gather (``shipped_rows``,
+        # per device), and ``migrant_rows`` of the sorted ``rows`` really
+        # changed slab in the window's last step. On lists under
+        # self-gravity: the sort of the tree solve's key-ordered copy,
+        # and ``migrant_rows`` the rows of it that lie on another slab
+        # than their frozen row (what the list's age did to the slabs'
+        # key ranges)
         migrants = diagnostics.get("sort_migrant_rows")
         if migrants is not None:
             tel.event(

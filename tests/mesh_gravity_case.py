@@ -130,11 +130,20 @@ RUNNER = """
     s0 = sim.state
     gbox = make_global_box(s0.x, s0.y, s0.z, sim.box)
     keys0 = compute_sfc_keys(s0.x, s0.y, s0.z, gbox, curve=sim.curve)
+    h0, pad = s0.h, 0.0
+    if sim._use_lists:
+        # on lists the halo is sized for the windows their rebuild
+        # negotiates over: the relaxed h, the skin's pad
+        # (make_propagator_config)
+        h0 = s0.h * jnp.float32(sim._h_relax)
+        pad = jnp.float32(sim._cfg.list_skin_rel * 2.0
+                          * float(jnp.max(s0.h)) * sim._h_relax)
     halo_caps = dict(
         run=list(sim._halo_info["caps"]),
         fresh=list(device_sparse_halo(
-            *(np.asarray(a) for a in (s0.x, s0.y, s0.z, s0.h, keys0)), gbox,
-            sim._cfg.nbr, P=4, margin=sim._halo_margin)[0]))
+            *(np.asarray(a) for a in (s0.x, s0.y, s0.z, h0, keys0)), gbox,
+            sim._cfg.nbr, P=4, margin=sim._halo_margin,
+            radius_pad=pad)[0]))
     if case == "tripped":
         sim._grav_halo_margin = {trip_margin}
         sim._configure(reason="test-undersize")

@@ -147,18 +147,19 @@ def _xyz(sim, row):
     return np.asarray([s.x[row], s.y[row], s.z[row]], np.float32)
 
 
-def _crossing(sim):
+def _crossing(sim, side=SIDE):
     """Where to put the last row of slab 0 so that a small move takes it
     across the slab boundary of the key order: ``(start, end)``, either
     side of the first row of slab 1 along an axis on which the curve runs
     forward there, 0.1 of a particle spacing apart (no other key lies
-    between). The state is key-sorted (a rebuild has just run)."""
+    between). The state is key-sorted (a rebuild has just run); ``side``:
+    the lattice's cells along the box."""
     import jax.numpy as jnp
 
     from sphexa_tpu.sfc.keys import compute_sfc_keys
 
     S = sim.state.n // P
-    eps = 0.05 * float(sim.box.lengths[0]) / SIDE
+    eps = 0.05 * float(sim.box.lengths[0]) / side
     rows = np.stack([_xyz(sim, r) for r in (S - 2, S, S + 1)])
     for axis in range(3):
         for sign in (1.0, -1.0):

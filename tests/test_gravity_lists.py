@@ -10,10 +10,10 @@ for ``std``, ``ve`` and ``std-cooling`` (``nc`` equal for every particle,
 the sums to the tolerances tests/pair_list_cases.py holds lists to against
 streaming, ``egrav`` and the dt candidates); the solve's ``gx, gy, gz`` of a
 shuffled state in that state's order; the gate (``use_lists=False`` streams
-under gravity, the mesh is still refused in the prologue); and the driver's
-list lifecycle under gravity with ``chem`` as the aux state: rebuilds keep
-the chemistry row-aligned, a forced ``list-expiry`` rolls back and replays,
-a forced gravity ``overflow`` reconfigure rebuilds the lists."""
+under gravity; the mesh's half is tests/mesh_gravity_list_cases.py); and the
+driver's list lifecycle under gravity with ``chem`` as the aux state:
+rebuilds keep the chemistry row-aligned, a forced ``list-expiry`` rolls back
+and replays, a forced gravity ``overflow`` reconfigure rebuilds the lists."""
 
 import dataclasses
 
@@ -216,16 +216,6 @@ class TestTheSolveSortsItsOwnCopy:
                                                        rel=1e-5)
         for k in ("m2p_max", "p2p_max", "leaf_occ"):
             assert int(shuffled_out[5][k]) == int(sorted_out[5][k])
-
-    def test_the_mesh_is_still_refused(self):
-        """Under self-gravity: the hydro step families walk lists on a
-        mesh since PR 46 (tests/mesh_list_cases.py)."""
-        sim, _ = make_sim("std", use_lists=False)
-        assert sim._cfg.gravity is not None
-        cfg = dataclasses.replace(sim._cfg, shard_axis="p")
-        with pytest.raises(NotImplementedError, match="tree solve"):
-            prop_mod._force_stage_prologue(sim.state, sim.box, cfg,
-                                           lists=object())
 
 
 @pytest.mark.parametrize("init", ["evrard", "noh", "sedov"])

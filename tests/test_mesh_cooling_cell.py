@@ -279,7 +279,8 @@ def test_configuration_states_the_one_chip_cells_widths(config):
 def test_one_mesh_step_holds_all_three(mesh_run):
     r = mesh_run
     assert r["iteration"] == STEPS and r["particles"] % 4 == 0
-    assert r["engine"]["backend"] == "pallas" and not r["engine"]["lists"]
+    # (since PR 48 a mesh under self-gravity walks pair lists too)
+    assert r["engine"]["backend"] == "pallas" and r["engine"]["lists"]
     assert r["engine"]["gravity"]["use_pallas"]
     # the SPH halo and the near field are the sized sparse serves
     assert r["halo"] == "sparse" and len(r["grav_cells"]) == 3
